@@ -23,11 +23,14 @@ trading a small recompute for not storing mt*nb^2 of T tiles in HBM).
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+# packed-Householder geqrf: jax 0.9 exposes only qr/householder_product
+# publicly, so the primitive comes from the private module; imported
+# at module load so a jax that moves it fails here, not mid-panel
+from jax._src.lax.linalg import geqrf as _geqrf
 
 from ..core.enums import Diag, MatrixType, Side, Uplo
 from ..core.methods import MethodFactor, MethodGels
@@ -58,30 +61,6 @@ class LQFactors(NamedTuple):
     taus: jax.Array        # (m_pad,)
 
 
-@functools.cache
-def _resolve_native_geqrf():
-    """Locate jax's packed-Householder geqrf: the public
-    jax.lax.linalg.geqrf when this jax exposes it, else the private
-    module path older versions kept it under. Returns None (once, with
-    a logged signal) when neither resolves — correctness is preserved
-    by the fori_loop panel, but the measured ~4x panel speedup
-    silently disappearing was a round-3 advisor finding, so the
-    fallback is no longer silent."""
-    public = getattr(jax.lax.linalg, "geqrf", None)
-    if public is not None:
-        return public
-    try:                     # pragma: no cover - old-jax surface
-        from jax._src.lax.linalg import geqrf as geqrf_prim
-        return geqrf_prim
-    except ImportError:      # pragma: no cover - jax surface moved
-        import logging
-        logging.getLogger(__name__).warning(
-            "slate_tpu: jax exposes no geqrf primitive (public or "
-            "private surface); QR panels fall back to the fori_loop "
-            "kernel — expect ~4x slower panel factorization")
-        return None
-
-
 def _native_geqrf(a: jax.Array):
     """XLA's geqrf primitive (packed Householder + taus — LAPACK on
     CPU, blocked expander on TPU), or None where its dtype support
@@ -92,10 +71,7 @@ def _native_geqrf(a: jax.Array):
     # (methods.py native_lu_dtype_ok) — bf16 falls back
     if not MethodFactor.native_lu_dtype_ok(a.dtype):
         return None
-    geqrf_prim = _resolve_native_geqrf()
-    if geqrf_prim is None:
-        return None
-    packed, taus = geqrf_prim(a)
+    packed, taus = _geqrf(a)
     w = a.shape[1]
     if taus.shape[0] < w:
         # wide panels (m < w) carry only min(m, w) reflectors; pad the

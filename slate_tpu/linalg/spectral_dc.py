@@ -11,7 +11,7 @@ whole staged pipeline at the Auto method (eig.py routes it).
 
 Where the time goes in the stock implementation, measured on v5e
 (PERF.md "Round-5: in-house spectral divide & conquer"; raw runs in
-experiments/r5_*.out):
+untracked experiment outputs):
   * lax.linalg.eigh @8192 f32: 4.82 s (152 nominal GFLOP/s).
   * One stock qdwh polar @4096: 123.5 ms = 55 n^3-flop-equivalents at
     the same-process gemm rate — the first 2 iterations go through the

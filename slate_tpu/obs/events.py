@@ -141,12 +141,10 @@ def _tracing() -> bool:
     """True when called under a jax trace (the Python body of a jitted
     driver runs only while (re)compiling — a cache hit never reaches
     it, which is exactly the recompile signal metrics.record_trace
-    keys on)."""
-    try:
-        import jax
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return False
+    keys on). No `except`: if jax drops this name the detector must
+    fail a test, not read "never tracing"."""
+    from jax._src import core
+    return not core.trace_state_clean()
 
 
 @contextlib.contextmanager

@@ -1,12 +1,5 @@
-"""shard_map compatibility shim.
-
-jax moved shard_map twice: new trees expose `jax.shard_map` with a
-`check_vma` flag; older ones (<= 0.4.x) keep it under
-`jax.experimental.shard_map.shard_map` with the same flag named
-`check_rep`. Every explicit-schedule module (parallel/collectives.py,
-the dist/ algorithm package) goes through this one resolver so the
-surface difference lives in exactly one place.
-"""
+"""The one `shard_map` entry of the explicit-schedule modules
+(parallel/collectives.py, the dist/ algorithm package)."""
 
 from __future__ import annotations
 
@@ -14,14 +7,8 @@ import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """`jax.shard_map` where this jax has it, else the experimental
-    path with `check_vma` mapped onto its old `check_rep` name. The
-    default (False) matches the explicit-collective modules: values
+    """`jax.shard_map` with `check_vma` off by default: values
     replicated by hand-placed all_gather/psum/ppermute trees are
     intended, not statically inferable."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -1,0 +1,160 @@
+"""Compile-only checks against a DESCRIBED TPU v5e (no chip attached).
+
+The TPU compiler is installed here and compiles for a topology that is
+described, not attached (guide on-chip-measurement §2, rehearsal 3).
+This catches what interpret mode cannot: Mosaic alignment refusals and
+scoped-VMEM overruns of the Pallas kernels, and HBM overruns of the
+streamed update program. Nothing runs — a pass here is not a chip run.
+
+`_on_tpu()` sees the CPU under pytest, so the jitted ``_*_pallas(...,
+interp=False)`` functions are lowered directly with ShapeDtypeStructs
+placed on the described device. The topology is described only inside
+a fixture: one process at a time may load libtpu, so nothing may touch
+it at import or collection time, and these cases stay in ONE file.
+"""
+
+import functools
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+#: one v5e chip's HBM (bytes); memory_analysis must fit under it
+V5E_HBM = 16 << 30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:    # no libtpu / lock held by another process
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to a persistent
+    # cache but cannot be read back without the chip: keep it off
+    from jax.experimental.compilation_cache import compilation_cache
+    was = (jax.config.jax_enable_compilation_cache,
+           jax.config.jax_enable_x64)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # conftest turns x64 on for the CPU tier; the chip runs without
+    # it, and so do these compiles (the Mosaic lowering recurses
+    # without end on x64 weak-typed scalars)
+    jax.config.update("jax_enable_x64", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was[0])
+    jax.config.update("jax_enable_x64", was[1])
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, dev, *shapes, kernel=True, limit_s=60.0, **static):
+    """Lower `fn` on the described device and compile; returns the
+    compiled program. Fails when the compile outlasts `limit_s`, or
+    when a `kernel` program holds no Mosaic custom call."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=dev) for s, d in shapes]
+    t0 = time.perf_counter()
+    compiled = fn.lower(*args, **static).compile()
+    took = time.perf_counter() - t0
+    assert took < limit_s, f"compile took {took:.1f}s (limit {limit_s})"
+    assert not kernel or "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+#: the serve phase of chip_smoke.py drives these shapes
+_B, _N, _K = 8, 1024, 4
+
+
+def _ragged(name, **kw):
+    from slate_tpu.ops import pallas_kernels as pk
+    return jax.jit(functools.partial(getattr(pk, name), interp=False,
+                                     **kw))
+
+
+@pytest.mark.parametrize("kernel", ["potrf", "getrf"])
+def test_ragged_factor_kernels_compile(one_chip, kernel):
+    from slate_tpu.ops import pallas_kernels as pk
+    kw = {"blk": pk.RAGGED_BLK} if kernel == "potrf" \
+        else {"ib": pk.RAGGED_BLK}
+    fn = _ragged("_ragged_%s_pallas" % kernel, B=_B, N=_N, **kw)
+    _compile(fn, one_chip, ((_B,), jnp.int32),
+             ((_B, _N, _N), jnp.float32))
+
+
+@pytest.mark.parametrize("upper,trans,unit", [
+    (False, False, False),      # posv forward sweep
+    (False, True, False),       # posv backward sweep (L^T)
+    (False, False, True),       # gesv unit-lower sweep
+    (True, False, False),       # gesv upper sweep
+])
+def test_ragged_trsm_compiles(one_chip, upper, trans, unit):
+    from slate_tpu.ops import pallas_kernels as pk
+    fn = _ragged("_ragged_trsm_pallas", B=_B, N=_N, K=_K,
+                 blk=pk.RAGGED_BLK, upper=upper, trans=trans, unit=unit)
+    _compile(fn, one_chip, ((_B,), jnp.int32),
+             ((_B, _N, _N), jnp.float32), ((_B, _N, _K), jnp.float32))
+
+
+def test_ragged_ceiling_off_the_lane_tile_compiles(one_chip):
+    """A ragged ceiling is a multiple of lcm(align, blk) = 32, not of
+    the 128-lane tile: the kernels pad to the tile themselves."""
+    from slate_tpu.ops import pallas_kernels as pk
+    n = 928
+    assert n % pk.RAGGED_BLK == 0 and n % 128
+    fn = _ragged("_ragged_potrf_pallas", B=_B, N=n, blk=pk.RAGGED_BLK)
+    _compile(fn, one_chip, ((_B,), jnp.int32),
+             ((_B, n, n), jnp.float32))
+
+
+def test_lu_panel_bf16_compiles(one_chip):
+    """The cold route for bf16 LU panels (linalg/lu.py _lu_panel)."""
+    from slate_tpu.ops import pallas_kernels as pk
+    m, w = 2048, 128
+    _compile(pk._lu_panel_pallas, one_chip, ((m, w), jnp.bfloat16),
+             m=m, w=w, interp=False)
+
+
+@pytest.mark.parametrize("m,w", [
+    (2048, 256), (4096, 128), (4096, 32)])
+def test_lu_panel_rec_compiles_at_its_gate(one_chip, m, w):
+    """The gate and the compiler agree: the largest single-dispatch
+    shapes `_rec_shape_reason` admits (element budget at the widest
+    panel, and the height cap at a full and a sub-lane-tile width)
+    compile in under a minute."""
+    from slate_tpu.ops import pallas_kernels as pk
+    assert w <= pk.LU_REC_MAX_W and m <= pk.LU_REC_MAX_M
+    assert m * w <= pk.LU_REC_MAX_ELEMS
+    assert pk._rec_shape_reason(m, w, jnp.float32) is None
+    _compile(pk._lu_panel_rec_pallas, one_chip, ((m, w), jnp.float32),
+             m=m, w=w, ib=pk.LU_REC_IB, interp=False)
+
+
+def test_givens_apply_compiles(one_chip):
+    from slate_tpu.ops import pallas_kernels as pk
+    rows = n = 1024
+    blk = pk.GIVENS_CHAIN_BLK
+    assert pk._chain_shape_ok(rows, n, blk)
+    rb = pk._chain_rb(rows, n, blk)
+    _compile(pk._givens_apply_pallas, one_chip,
+             ((rows, n), jnp.float32),
+             ((n // blk, 2 * blk, 2 * blk), jnp.float32),
+             rows=rows, n=n, blk=blk, rb=rb, interp=False)
+
+
+def test_stream_update_fits_the_device(one_chip):
+    """The streamed Cholesky's visit kernel at chip_smoke.py's panel
+    shape: a (32768, 4096) target updated by a 4096-wide factor
+    panel, arguments + output + temporaries under one chip's HBM."""
+    from slate_tpu.linalg import ooc
+    n, w = 32768, 4096
+    compiled = _compile(ooc._panel_apply, one_chip,
+                        ((n, w), jnp.float32), ((n, w), jnp.float32),
+                        w=w, kernel=False)
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes)
+    assert 0 < total < V5E_HBM, total

@@ -1,6 +1,7 @@
 """Tester CLI, simplified API, trace, printing tests."""
 
 import numpy as np
+import pytest
 
 import slate_tpu as st
 from slate_tpu import Side, TiledMatrix, Uplo
@@ -127,3 +128,30 @@ def test_print_tile_corners_crop_padding(rng):
     out = sprint_matrix("A", st.Matrix(a, mb=4), verbose=3)
     assert "99.0000" in out            # true bottom-right corner
     assert "tile row 2" in out
+
+
+@pytest.mark.parametrize("placed", [None, "/nonexistent/placed_cache"])
+def test_compile_cache_placement(placed):
+    """utils/compile_cache.enable(): with JAX_COMPILATION_CACHE_DIR set
+    nothing is configured in code (JAX reads the variable); otherwise
+    the cache is placed at the fixed <checkout>/.jax_cache. Run in a
+    child pinned to the CPU: the helper is for the mains and must
+    never place a cache inside the pytest process."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = placed
+    code = ("import jax; from slate_tpu.utils import compile_cache; "
+            "print(compile_cache.enable()); "
+            "print(jax.config.jax_compilation_cache_dir)")
+    out = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-400:]
+    want = placed or str(root / ".jax_cache")
+    assert out.stdout.split() == [want, want]

@@ -138,16 +138,18 @@ def test_lu_panel_rec_reconstructs(rng):
 
 
 def test_lu_panel_rec_tall_split_exact_pivoting(rng):
-    """The tall-panel path (acceptance): a height above
-    NATIVE_LU_MAX_M factors through the JAX-level halving with the
+    """The tall-panel path: a panel over the single-dispatch element
+    budget factors through the JAX-level halving with the
     row-block-gridded trailing update, with the pivot sequence
-    bitwise equal to the full-height fori panel. The single-dispatch
-    element budget is forced down so the split machinery runs at a
-    tier-1-friendly size; the height itself exceeds the native LU
-    custom call's TPU compile limit (methods.NATIVE_LU_MAX_M = 8192
-    rows for f32 — on TPU this panel has no native route at all)."""
+    bitwise equal to the full-height fori panel. The budget is forced
+    down so the split machinery runs; the height is the tallest the
+    gate admits (LU_REC_MAX_M — the v5e compiler refuses taller
+    panels however narrow, PR 22 compile-only finding, so heights
+    above methods.NATIVE_LU_MAX_M no longer route here)."""
     from slate_tpu.core.methods import NATIVE_LU_MAX_M
-    m, w = NATIVE_LU_MAX_M + 128, 32
+    m, w = pk.LU_REC_MAX_M, 32
+    assert pk._rec_shape_reason(NATIVE_LU_MAX_M + 128, w,
+                                jnp.float32) == "height"
     a_np = np.zeros((m, w), np.float32)
     rng2 = np.random.default_rng(7)
     a_np[:] = (rng2.integers(-8, 9, (m, w)) / 16.0)
