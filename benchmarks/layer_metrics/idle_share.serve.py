@@ -1,0 +1,3 @@
+"""Device idle share of the traced slice of serving (lib/readers.py)."""
+
+from benchmarks.lib.readers import idle_share as compute  # noqa: F401
