@@ -47,7 +47,9 @@ import numpy as np
 
 from . import bucket as _bucket
 from . import drivers as _drivers
+from ..obs import events as _ev
 from ..obs import ledger as _ledger
+from ..obs import metrics as _om
 from ..resil import faults as _faults
 from ..resil import guard as _guard
 
@@ -318,7 +320,9 @@ class CoalescingQueue:
             if len(pend) >= self.max_batch:
                 flush_now = True
         if flush_now:
-            self.flush(key)
+            # a full bucket is dispatched in the CALLER'S thread
+            with _ev.span("batch::inline_flush", cat="batch", op=op):
+                self.flush(key)
         elif self._flusher is not None:
             self._wake.set()
         return ticket
@@ -428,8 +432,24 @@ class CoalescingQueue:
                                               op=op)
 
     def _dispatch(self, key, entries) -> None:
-        if key[1] == RAGGED:
-            return self._dispatch_ragged(key, entries)
+        """One group's turn on the thread that flushes it. With the
+        obs bus on the turn is a `batch::flush` span whose children
+        (`batch::stack`, `batch::dispatch`, `batch::fetch`,
+        `batch::resolve`) split it at the ledger record's timestamps,
+        and every ticket's wait from submit to here is observed."""
+        run = self._dispatch_ragged if key[1] == RAGGED \
+            else self._dispatch_bucket
+        if not _ev.enabled():
+            return run(key, entries)
+        now = time.perf_counter()
+        for e in entries:
+            _om.observe("batch.queue_wait_seconds",
+                        now - e[0]._t_submit)
+        with _ev.span("batch::flush", cat="batch", op=key[0],
+                      bucket=key[1], occupancy=len(entries)):
+            return run(key, entries)
+
+    def _dispatch_bucket(self, key, entries) -> None:
         op, bm, bn, nrhs, _dt = key
         spec = _drivers.OPS[op]
         tickets = [e[0] for e in entries]
@@ -448,18 +468,23 @@ class CoalescingQueue:
             from ..obs import reqtrace as _rt
             fid = _rt.next_flush_id()
         t_led = time.perf_counter() if (led_on or traced) else 0.0
+        span = _ev.span
         try:
-            stack = np.stack([e[1] for e in entries])
-            rhs = np.stack([e[2] for e in entries]) if spec.has_rhs \
-                else None
-            stack, rhs, batch_pad = self._pad_batch_pow2(stack, rhs)
+            with span("batch::stack", cat="batch"):
+                stack = np.stack([e[1] for e in entries])
+                rhs = np.stack([e[2] for e in entries]) \
+                    if spec.has_rhs else None
+                stack, rhs, batch_pad = self._pad_batch_pow2(stack,
+                                                             rhs)
             t_stage = time.perf_counter() if (led_on or traced) \
                 else 0.0
-            out = self._dispatch_guarded(
-                op, lambda: _drivers._dispatch(op, stack, rhs,
-                                               donate=self._donate))
+            with span("batch::dispatch", cat="batch"):
+                out = self._dispatch_guarded(
+                    op, lambda: _drivers._dispatch(
+                        op, stack, rhs, donate=self._donate))
             parts = out if isinstance(out, tuple) else (out,)
-            hosts = [np.asarray(o) for o in parts]
+            with span("batch::fetch", cat="batch"):
+                hosts = [np.asarray(o) for o in parts]
             if led_on:
                 t_done = time.perf_counter()
                 with self._lock:
@@ -481,13 +506,14 @@ class CoalescingQueue:
                     phases={"stage": t_stage - t_led,
                             "factor": t_done - t_stage},
                     meta=meta)
-            for i, (t, _pa, _pb, (m, n)) in enumerate(entries):
-                if t.trace is not None:
-                    t.t_flush = t_led
-                    t.t_dispatch = t_stage
-                    t.flush_id = fid
-                t._resolve(value=_crop(op, [h[i] for h in hosts],
-                                       m, n, nrhs))
+            with span("batch::resolve", cat="batch"):
+                for i, (t, _pa, _pb, (m, n)) in enumerate(entries):
+                    if t.trace is not None:
+                        t.t_flush = t_led
+                        t.t_dispatch = t_stage
+                        t.flush_id = fid
+                    t._resolve(value=_crop(op, [h[i] for h in hosts],
+                                           m, n, nrhs))
             if traced:
                 _rt.record_flush(
                     op, t_led, time.perf_counter(), fid,
@@ -521,25 +547,32 @@ class CoalescingQueue:
             from ..obs import reqtrace as _rt
             fid = _rt.next_flush_id()
         t_led = time.perf_counter() if (led_on or traced) else 0.0
+        span = _ev.span
         try:
             sizes = [e[3][1] for e in entries]
-            ceil = _bucket.ragged_ceiling(sizes, blk=blk,
-                                          align=self._align)
-            stack = np.stack([_bucket.pad_square(e[1], ceil, "zero")
-                              for e in entries])
-            rhs = np.stack([_bucket.pad_rhs(e[2], ceil, nrhs)
-                            for e in entries]) if spec.has_rhs else None
-            stack, rhs, batch_pad = self._pad_batch_pow2(stack, rhs)
-            szarr = np.asarray(
-                sizes + [sizes[-1]] * batch_pad, np.int32)
+            with span("batch::stack", cat="batch"):
+                ceil = _bucket.ragged_ceiling(sizes, blk=blk,
+                                              align=self._align)
+                stack = np.stack(
+                    [_bucket.pad_square(e[1], ceil, "zero")
+                     for e in entries])
+                rhs = np.stack([_bucket.pad_rhs(e[2], ceil, nrhs)
+                                for e in entries]) \
+                    if spec.has_rhs else None
+                stack, rhs, batch_pad = self._pad_batch_pow2(stack,
+                                                             rhs)
+                szarr = np.asarray(
+                    sizes + [sizes[-1]] * batch_pad, np.int32)
             t_stage = time.perf_counter() if (led_on or traced) \
                 else 0.0
-            out = self._dispatch_guarded(
-                op, lambda: _drivers.ragged_dispatch(
-                    op, stack, szarr, rhs, blk=blk,
-                    donate=self._donate))
+            with span("batch::dispatch", cat="batch"):
+                out = self._dispatch_guarded(
+                    op, lambda: _drivers.ragged_dispatch(
+                        op, stack, szarr, rhs, blk=blk,
+                        donate=self._donate))
             parts = out if isinstance(out, tuple) else (out,)
-            hosts = [np.asarray(o) for o in parts]
+            with span("batch::fetch", cat="batch"):
+                hosts = [np.asarray(o) for o in parts]
             if led_on:
                 t_done = time.perf_counter()
                 with self._lock:
@@ -560,13 +593,14 @@ class CoalescingQueue:
                     phases={"stage": t_stage - t_led,
                             "factor": t_done - t_stage},
                     meta=meta)
-            for i, (t, _pa, _pb, (m, n)) in enumerate(entries):
-                if t.trace is not None:
-                    t.t_flush = t_led
-                    t.t_dispatch = t_stage
-                    t.flush_id = fid
-                t._resolve(value=_crop(op, [h[i] for h in hosts],
-                                       m, n, nrhs))
+            with span("batch::resolve", cat="batch"):
+                for i, (t, _pa, _pb, (m, n)) in enumerate(entries):
+                    if t.trace is not None:
+                        t.t_flush = t_led
+                        t.t_dispatch = t_stage
+                        t.flush_id = fid
+                    t._resolve(value=_crop(op, [h[i] for h in hosts],
+                                           m, n, nrhs))
             if traced:
                 _rt.record_flush(
                     op, t_led, time.perf_counter(), fid,

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import events as obs_events
 from .enums import Diag, MatrixType, Op, Uplo
 from .exceptions import DimensionError, slate_assert
 
@@ -49,7 +50,15 @@ def _asarray_warn_downcast(a):
     solver accuracy — every TiledMatrix constructor funnels through
     this so the warning cannot be bypassed."""
     orig_dtype = getattr(a, "dtype", None)
-    out = jnp.asarray(a)
+    if obs_events.enabled() and isinstance(a, np.ndarray):
+        # a host array: the upload a solve's wall contains (the
+        # hand-over to the runtime; the transfer itself is not waited
+        # for)
+        with obs_events.span("matrix::h2d", cat="staging",
+                             bytes=int(a.nbytes)):
+            out = jnp.asarray(a)
+    else:
+        out = jnp.asarray(a)
     global _warned_downcast
     if (not _warned_downcast and orig_dtype is not None
             and orig_dtype in (np.float64, np.complex128)

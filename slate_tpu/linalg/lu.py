@@ -30,6 +30,7 @@ from ..core.exceptions import slate_assert
 from ..core.methods import MethodFactor, MethodLU
 from ..core.options import Option, OptionsLike, get_option
 from ..core.tiles import TiledMatrix, ceil_div, pad_diag_identity
+from ..obs import events as obs_events
 from ..obs.events import instrument_driver
 from ..resil import guard as _rguard
 from .blas3 import _store, trsm
@@ -295,6 +296,10 @@ def _getrf_carry(a: jax.Array, nb: int) -> Tuple[jax.Array, jax.Array]:
     perms = []       # (m_k,) composed local permutation per step
     pivs = []
     from ..core.methods import MethodLUPanel
+    from .blocked import assemble_packed
+    # every line of these loops is an eager launch the device waits
+    # for; the spans say which of them the host was dispatching
+    span = obs_events.span
     for k in range(nt):
         k0, k1 = k * nb, min((k + 1) * nb, kmax)
         w = k1 - k0
@@ -304,43 +309,55 @@ def _getrf_carry(a: jax.Array, nb: int) -> Tuple[jax.Array, jax.Array]:
         # Native (cold default where dtype + height allow), so a
         # measured pallas_rec/fori cache entry reroutes this consumer
         # too
-        if MethodLUPanel.resolve(trail.shape[0], w, trail.dtype) \
-                is MethodLUPanel.Native:
-            lu, piv, perm = jax.lax.linalg.lu(trail[:, :w])
-            piv = piv.astype(jnp.int32)
-        else:
-            # panels the native call cannot take (scoped-vmem height
-            # limit / dtype) or that the tune cache routed elsewhere:
-            # _lu_panel arbitrates (true partial pivoting preserved)
-            lu, piv = _lu_panel(trail[:, :w])
-            perm = _compose_swaps(piv, trail.shape[0])
-        pivs.append(k0 + piv)
-        perms.append(perm)
-        panels.append(lu)
-        if k1 < N:
-            rest = _permute_rows(trail[:, w:], perm)
-            u12 = jax.lax.linalg.triangular_solve(
-                lu[:w, :w], rest[:w], left_side=True, lower=True,
-                unit_diagonal=True)
-            urows.append(u12)
-            if k1 < M:
-                trail = rest[w:] - jnp.matmul(
-                    lu[w:, :w], u12, precision=jax.lax.Precision.HIGHEST)
+        with span("getrf::panel", cat="step", k=k):
+            native = MethodLUPanel.resolve(
+                trail.shape[0], w, trail.dtype) is MethodLUPanel.Native
+            if native:
+                lu, piv, perm = jax.lax.linalg.lu(trail[:, :w])
             else:
-                trail = rest[w:]
+                # panels the native call cannot take (scoped-vmem
+                # height limit / dtype) or that the tune cache routed
+                # elsewhere: _lu_panel arbitrates (true partial
+                # pivoting preserved)
+                lu, piv = _lu_panel(trail[:, :w])
+        with span("getrf::pivots", cat="step", k=k):
+            if native:
+                piv = piv.astype(jnp.int32)
+            else:
+                perm = _compose_swaps(piv, trail.shape[0])
+            pivs.append(k0 + piv)
+            perms.append(perm)
+            panels.append(lu)
+            if k1 < N:
+                rest = _permute_rows(trail[:, w:], perm)
+        if k1 < N:
+            with span("getrf::update", cat="step", k=k):
+                u12 = jax.lax.linalg.triangular_solve(
+                    lu[:w, :w], rest[:w], left_side=True, lower=True,
+                    unit_diagonal=True)
+                urows.append(u12)
+                if k1 < M:
+                    trail = rest[w:] - jnp.matmul(
+                        lu[w:, :w], u12,
+                        precision=jax.lax.Precision.HIGHEST)
+                else:
+                    trail = rest[w:]
     # final row order per panel: panel k's rows get permuted by the
     # suffix action of perms[k+1:]
-    reordered = []
-    for k in range(nt):
-        m_k = panels[k].shape[0]
-        q = jnp.arange(m_k)
-        for j in range(k + 1, nt):
-            off = j * nb - k * nb
-            q = jnp.concatenate([q[:off], q[off:][perms[j]]], axis=0)
-        reordered.append(_permute_rows(panels[k], q))
-    from .blocked import assemble_packed
-    out = assemble_packed(reordered, urows, nb, kmax, M, N, a.dtype)
-    return out, jnp.concatenate(pivs)
+    with span("getrf::reorder", cat="step", nt=nt):
+        reordered = []
+        for k in range(nt):
+            m_k = panels[k].shape[0]
+            q = jnp.arange(m_k)
+            for j in range(k + 1, nt):
+                off = j * nb - k * nb
+                q = jnp.concatenate([q[:off], q[off:][perms[j]]],
+                                    axis=0)
+            reordered.append(_permute_rows(panels[k], q))
+        out = assemble_packed(reordered, urows, nb, kmax, M, N,
+                              a.dtype)
+        pivots = jnp.concatenate(pivs)
+    return out, pivots
 
 
 def _getrf_pipelined(a: jax.Array, nb: int, grid=None
@@ -469,6 +486,7 @@ def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
         # natively (program size grows with nt — the documented trade
         # for honoring an explicit Option.BlockSize there).
         if N % nb == 0:
+            obs_events.note(form="scan", nb=nb)
             return _lu_scan(a, nb, pivot, grid, tournament=tournament)
         cand = _scan_nb(N, nb, 8)     # %8 widths suit every panel path
         if tile_nb and N % tile_nb == 0 and \
@@ -479,6 +497,7 @@ def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
             # a degenerate divisor (N with no usable factor <= nb)
             # would make the scan run absurdly narrow steps; the
             # carry/unrolled fall-through is the better cliff
+            obs_events.note(form="scan", nb=cand)
             return _lu_scan(a, cand, pivot, grid, tournament=tournament)
     if pivot and not tournament and grid is None and nt > 1 \
             and MethodFactor.native_lu_dtype_ok(a.dtype):
@@ -496,10 +515,18 @@ def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
             # bound that; getrf_tntpiv (CALU) is the matmul-rate
             # alternative at these heights
             nb = min(nb, 256)
+        if obs_events.enabled():
+            from ..core.methods import MethodLUPanel
+            obs_events.note(form="carry", nb=nb,
+                            panel=MethodLUPanel.resolve(
+                                M, min(nb, kmax), a.dtype).value)
         return _getrf_carry(a, nb)
     if pivot and not tournament and lookahead >= 1 and nt > 1:
+        obs_events.note(form="pipelined", nb=nb)
         return _getrf_pipelined(a, nb, grid)
+    obs_events.note(form="unrolled", nb=nb)
     ipiv = jnp.arange(kmax, dtype=jnp.int32)
+    span = obs_events.span      # the names of _getrf_carry's steps
     for k in range(nt):
         k0, k1 = k * nb, min((k + 1) * nb, kmax)
         w = k1 - k0
@@ -516,24 +543,31 @@ def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
             a = a.at[k0:, k0:k1].set(panel)
             ipiv = ipiv.at[k0:k1].set(k0 + piv)
         elif pivot:
-            panel, piv = _lu_panel(a[k0:, k0:k1])
-            a = a.at[k0:, k0:k1].set(panel)
-            perm = _compose_swaps(piv, M - k0)
-            if k0 > 0:
-                a = a.at[k0:, :k0].set(_permute_rows(a[k0:, :k0], perm))
-            if k1 < N:
-                a = a.at[k0:, k1:].set(_permute_rows(a[k0:, k1:], perm))
-            ipiv = ipiv.at[k0:k1].set(k0 + piv)
+            with span("getrf::panel", cat="step", k=k):
+                panel, piv = _lu_panel(a[k0:, k0:k1])
+                a = a.at[k0:, k0:k1].set(panel)
+            with span("getrf::pivots", cat="step", k=k):
+                perm = _compose_swaps(piv, M - k0)
+                if k0 > 0:
+                    a = a.at[k0:, :k0].set(
+                        _permute_rows(a[k0:, :k0], perm))
+                if k1 < N:
+                    a = a.at[k0:, k1:].set(
+                        _permute_rows(a[k0:, k1:], perm))
+                ipiv = ipiv.at[k0:k1].set(k0 + piv)
         else:
-            panel, _ = _nopiv_panel(a[k0:, k0:k1])
-            a = a.at[k0:, k0:k1].set(panel)
+            with span("getrf::panel", cat="step", k=k):
+                panel, _ = _nopiv_panel(a[k0:, k0:k1])
+                a = a.at[k0:, k0:k1].set(panel)
         if k1 < N:
-            u12 = _lu_u12(a[k0:k1, k0:k1], a[k0:k1, k1:], grid)
-            a = a.at[k0:k1, k1:].set(u12)
-            if k1 < M:
-                upd = jnp.matmul(a[k1:, k0:k1], u12,
-                                 precision=jax.lax.Precision.HIGHEST)
-                a = constrain(a.at[k1:, k1:].add(-upd), grid)
+            with span("getrf::update", cat="step", k=k):
+                u12 = _lu_u12(a[k0:k1, k0:k1], a[k0:k1, k1:], grid)
+                a = a.at[k0:k1, k1:].set(u12)
+                if k1 < M:
+                    upd = jnp.matmul(
+                        a[k1:, k0:k1], u12,
+                        precision=jax.lax.Precision.HIGHEST)
+                    a = constrain(a.at[k1:, k1:].add(-upd), grid)
     return a, ipiv
 
 
@@ -697,7 +731,8 @@ def getrf(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
         return getrf_nopiv(A, opts)
     if method is MethodLU.CALU:
         return getrf_tntpiv(A, opts)
-    r, a = _prep(A)
+    with obs_events.span("getrf::prep", cat="step"):
+        r, a = _prep(A)
     grid = get_option(opts, Option.Grid, None)
     dtype_ok = MethodFactor.native_lu_dtype_ok(a.dtype)
     fmethod = get_option(opts, Option.MethodFactor, MethodFactor.Auto)
@@ -736,6 +771,9 @@ def getrf(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
             "on TPU (scoped-vmem height limit, methods.NATIVE_LU_MAX_M"
             "); falling back to the Tiled blocked path", stacklevel=2)
         fmethod = MethodFactor.Tiled
+    # the route, on the driver span that is already open: the routing
+    # layer's record (the blocked forms add their form, nb and panel)
+    obs_events.note(lu=method.value, factor=fmethod.value)
     if fmethod is MethodFactor.Fused:
         # single fused XLA program (native blocked LU with partial
         # pivoting); pivots come back in the same LAPACK swap-target
@@ -748,9 +786,11 @@ def getrf(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
             pivot=True, grid=grid,
             lookahead=get_option(opts, Option.Lookahead), tile_nb=r.nb)
     from .info import lu_info
+    with obs_events.span("getrf::info", cat="step"):
+        info = lu_info(lu, r.m, r.n)
     return LUFactors(dataclasses.replace(r, data=lu,
                                          mtype=MatrixType.General), ipiv,
-                     lu_info(lu, r.m, r.n))
+                     info)
 
 
 def getrf_nopiv(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
@@ -812,16 +852,17 @@ def getrs(F: LUFactors, B: TiledMatrix, opts: OptionsLike = None,
                             uplo=Uplo.Lower, diag=Diag.Unit)
     U = dataclasses.replace(LU, mtype=MatrixType.Triangular,
                             uplo=Uplo.Upper, diag=Diag.NonUnit)
-    if trans is Op.NoTrans:
-        X = apply_pivots(F.pivots, B)
-        X = trsm(Side.Left, 1.0, L, X, opts)
-        X = trsm(Side.Left, 1.0, U, X, opts)
-    else:
-        flip = (lambda M: M.conj_transpose()) if trans is Op.ConjTrans \
-            else (lambda M: M.transpose())
-        X = trsm(Side.Left, 1.0, flip(U), B, opts)
-        X = trsm(Side.Left, 1.0, flip(L), X, opts)
-        X = apply_pivots(F.pivots, X, forward=False)
+    with obs_events.span("getrs", cat="step", trans=trans.name):
+        if trans is Op.NoTrans:
+            X = apply_pivots(F.pivots, B)
+            X = trsm(Side.Left, 1.0, L, X, opts)
+            X = trsm(Side.Left, 1.0, U, X, opts)
+        else:
+            flip = (lambda M: M.conj_transpose()) \
+                if trans is Op.ConjTrans else (lambda M: M.transpose())
+            X = trsm(Side.Left, 1.0, flip(U), B, opts)
+            X = trsm(Side.Left, 1.0, flip(L), X, opts)
+            X = apply_pivots(F.pivots, X, forward=False)
     return X
 
 

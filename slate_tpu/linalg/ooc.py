@@ -566,7 +566,14 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     ck = _rckpt.maybe_checkpointer(
         ckpt_path, "potrf_ooc", a, panel_cols, nt, every=ckpt_every,
         extra_meta={"precision": _precision_meta(lo)})
-    out = ck.factor if ck is not None else np.zeros_like(a)
+    if ck is not None:
+        out = ck.factor
+    else:
+        # the factor's host buffer, zero-filled: n^2 elements touched
+        # before the first panel is staged
+        with obs_events.span("ooc::alloc", cat="staging",
+                             bytes=int(a.nbytes)):
+            out = np.zeros_like(a)
     eng = stream.engine_for(n, panel_cols, a.dtype,
                             budget_bytes=cache_budget_bytes,
                             resident_dtype=lo)

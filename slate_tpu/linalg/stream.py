@@ -126,7 +126,13 @@ def _h2d(x: np.ndarray) -> jax.Array:
     obs_metrics.inc("ooc.h2d_bytes", int(x.nbytes))
     with obs_events.span("ooc::h2d", cat="staging",
                          bytes=int(x.nbytes)):
-        return jnp.asarray(np.ascontiguousarray(x))
+        # the host-side copy against the hand-over to the runtime; the
+        # transfer is not waited for (observing must not change the
+        # program)
+        with obs_events.span("ooc::h2d_pack", cat="staging"):
+            packed = np.ascontiguousarray(x)
+        with obs_events.span("ooc::h2d_put", cat="staging"):
+            return jnp.asarray(packed)
 
 
 def _d2h(x: jax.Array, out: Optional[np.ndarray] = None,
@@ -147,7 +153,8 @@ def _d2h(x: jax.Array, out: Optional[np.ndarray] = None,
     if out is None:
         out = np.empty(x.shape, np.dtype(x.dtype))
     if m < 2048:
-        out[...] = np.asarray(x)
+        with obs_events.span("ooc::d2h", cat="staging"):
+            out[...] = np.asarray(x)
         return out
     step = ceil_div(m, threads)
     bounds = [(i, min(i + step, m)) for i in range(0, m, step)]
@@ -560,8 +567,10 @@ class StreamEngine:
         if not futs:
             return
         t0 = time.perf_counter()
-        for f in futs:
-            f.result()
+        with obs_events.span("ooc::wait_write", cat="staging",
+                             buf=buf, idx=idx):
+            for f in futs:
+                f.result()
         _ledger.credit("cache", time.perf_counter() - t0)
 
     def _upload(self, buf: str, idx: int, loader: Callable) -> Any:
@@ -629,7 +638,9 @@ class StreamEngine:
             fut = self._pending.pop(key, None)
         if fut is not None:
             t0 = time.perf_counter()
-            arr = fut.result()
+            with obs_events.span("ooc::wait_stage", cat="staging",
+                                 buf=buf, idx=idx, kind="prefetch"):
+                arr = fut.result()
             dt = time.perf_counter() - t0
             self.prefetch_wait_seconds += dt
             _ledger.credit("stage", dt)
@@ -641,7 +652,9 @@ class StreamEngine:
         t0 = time.perf_counter()
         # the sync upload is a ledger `stage` frame (self-time: the
         # writeback fence inside _upload charges `cache`, not stage)
-        with _ledger.frame("stage"):
+        with _ledger.frame("stage"), \
+                obs_events.span("ooc::wait_stage", cat="staging",
+                                buf=buf, idx=idx, kind="sync"):
             arr = self._upload(buf, idx, loader)
         self.sync_upload_seconds += time.perf_counter() - t0
         if use_cache:
@@ -700,7 +713,10 @@ class StreamEngine:
                 fut = self._pending.pop(key, None)
             if fut is not None:
                 t0 = time.perf_counter()
-                arr = fut.result()
+                with obs_events.span("ooc::wait_stage", cat="staging",
+                                     buf=buf, idx=idx,
+                                     kind="prefetch"):
+                    arr = fut.result()
                 dt = time.perf_counter() - t0
                 self.prefetch_wait_seconds += dt
                 _ledger.credit("stage", dt)
@@ -715,7 +731,10 @@ class StreamEngine:
         blocks: list = []
         if misses:
             t0 = time.perf_counter()
-            with _ledger.frame("stage"):
+            with _ledger.frame("stage"), \
+                    obs_events.span("ooc::wait_stage", cat="staging",
+                                    buf=buf, idx=idxs[misses[0]],
+                                    kind="sync"):
                 for pos in misses:
                     self._wait_write(buf, idxs[pos])
                 blocks = [np.ascontiguousarray(loaders[pos]())
@@ -878,8 +897,10 @@ class StreamEngine:
             if not futs:
                 return
             t0 = time.perf_counter()
-            for f in futs:
-                f.result()
+            with obs_events.span("ooc::wait_write", cat="staging",
+                                 n=len(futs)):
+                for f in futs:
+                    f.result()
             dt = time.perf_counter() - t0
             self.d2h_wait_seconds += dt
             _ledger.credit("cache", dt)

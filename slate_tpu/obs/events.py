@@ -14,7 +14,14 @@ per-thread vectors the same way at finish) and a category:
 
     trace   utils/trace.py blocks and marks
     phase   driver phase timers (trace.phases / Timers.phase)
-    driver  driver-entry spans (the `driver` hook below)
+    driver  driver-entry spans (the `driver` hook below; `note` adds
+            the route a driver resolved to)
+    step    what the host dispatches inside a driver (getrf::panel,
+            getrf::pivots, ...)
+    staging host<->device copies and the waits for them (ooc::*,
+            matrix::h2d)
+    batch   a flusher's turn on one group (batch::flush and children)
+    serve   served requests (serve::submit; reqtrace's commits)
     jit     compile-side records (tracing spans, recompile instants,
             backend-compile durations from jax.monitoring)
     tune    autotuner decision marks
@@ -24,6 +31,12 @@ per-thread vectors the same way at finish) and a category:
 Everything is gated on ONE module flag read without a lock: disabled,
 every hook is a single boolean check (the zero-cost contract drivers
 rely on — instrumentation stays wired in production code paths).
+Enabled, `span` and the `driver` hook also hold a
+`jax.profiler.TraceAnnotation` open for the span's life: under a
+profiler session the same spans land in the xplane's host plane, on
+the device trace's clock (benchmarks/lib/hostspans.py reads them
+there). Records published after the fact (`publish(..., t0, t1)`) are
+not bridged.
 The store is a bounded ring (EVENT_CAP) so an always-on bus cannot
 grow without bound; drops are counted, never silent.
 """
@@ -54,6 +67,12 @@ PH_FLOW_END = "f"
 EVENT_CAP = 100_000
 
 _enabled = False
+#: jax.profiler.TraceAnnotation, bound by enable() (this module
+#: imports no jax before that)
+_annotation = None
+#: per thread, the driver spans open on it (innermost last): `note`
+#: adds the route a driver resolved to the span that is already open
+_open = threading.local()
 _lock = threading.Lock()
 _events: "collections.deque[Event]" = collections.deque(
     maxlen=EVENT_CAP)
@@ -79,7 +98,9 @@ class Event:
 def enable() -> None:
     """Turn the bus on (also installs the jax.monitoring compile-time
     listener once — obs/metrics.py)."""
-    global _enabled
+    global _enabled, _annotation
+    from jax.profiler import TraceAnnotation
+    _annotation = TraceAnnotation
     _enabled = True
     from . import metrics
     metrics.install_jax_monitoring()
@@ -113,19 +134,59 @@ def publish(name: str, ph: str = PH_INSTANT, t0: Optional[float] = None,
         _events.append(ev)
 
 
-@contextlib.contextmanager
+class _NoSpan:
+    """What `span()` hands back while the bus is off: one shared
+    object whose enter and exit do nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """An open span: published to the bus on exit, and held open as a
+    `jax.profiler.TraceAnnotation` meanwhile, so that a profiler
+    session records it in the xplane's host plane on the device
+    trace's clock. The annotation is the bridge to the profiler, not a
+    second store; with no session running it costs half a
+    microsecond. Enter and exit on the thread that does the work."""
+    __slots__ = ("name", "cat", "args", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, cat: str, args: Dict[str, Any]):
+        self.name, self.cat, self.args = name, cat, args
+
+    def __enter__(self):
+        self._ann = _annotation(self.name, **self.args)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        publish(self.name, PH_SPAN, self.t0, self.t1, cat=self.cat,
+                args=self.args or None)
+        return False
+
+    def note(self, **args) -> None:
+        """Arguments learnt after the span was opened."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+
 def span(name: str, cat: str = "", **args):
-    """RAII span published on exit (the trace::Block shape, but into
-    the shared bus)."""
+    """RAII span (the trace::Block shape, but into the shared bus and,
+    under a profiler session, the profiler's trace). Disabled, the
+    shared no-op: no object, no timestamp."""
     if not _enabled:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        publish(name, PH_SPAN, t0, time.perf_counter(), cat=cat,
-                args=args or None)
+        return _NO_SPAN
+    return _Span(name, cat, args)
 
 
 def instant(name: str, cat: str = "", **args) -> None:
@@ -174,15 +235,30 @@ def driver(op: str, shape: Optional[Tuple[int, ...]] = None,
         metrics.record_trace(op, sig)
     else:
         metrics.inc("driver.%s.calls" % op)
-    t0 = time.perf_counter()
+    stack = getattr(_open, "spans", None)
+    if stack is None:
+        stack = _open.spans = []
+    sp = _Span(op, "jit" if tracing else "driver", a)
+    stack.append(sp)
     try:
-        yield
+        with sp:
+            yield
     finally:
-        t1 = time.perf_counter()
-        cat = "jit" if tracing else "driver"
-        publish(op, PH_SPAN, t0, t1, cat=cat, args=a or None)
+        stack.pop()
         metrics.observe("%s.%s_seconds" % (op, "trace" if tracing
-                                           else "wall"), t1 - t0)
+                                           else "wall"), sp.t1 - sp.t0)
+
+
+def note(**args) -> None:
+    """Add arguments to the innermost driver span open on this thread,
+    in its bus record and in its annotation: how a driver says which
+    route it resolved to, after its span was opened. No-op with the
+    bus off or no driver span open."""
+    if not _enabled:
+        return
+    stack = getattr(_open, "spans", None)
+    if stack:
+        stack[-1].note(**args)
 
 
 def instrument_driver(op: str):
@@ -245,24 +321,12 @@ def clear() -> None:
         _dropped = 0
 
 
-def drain(cats: Optional[Tuple[str, ...]] = None) -> List[Event]:
-    """Atomically snapshot and clear (trace.finish / export use this
-    so concurrent publishers cannot land between read and clear).
-    With `cats`, only events in those categories are removed and
-    returned — trace.finish() drains just the legacy trace store's
-    categories so it cannot destroy a concurrent obs session's
-    driver/compile records. The drop counter tracks lifetime ring
-    evictions and resets only on a FULL drain/clear; a partial drain
-    deliberately leaves it (the evictions still happened)."""
+def drain() -> List[Event]:
+    """Atomically snapshot and clear (the exporter uses this so
+    concurrent publishers cannot land between read and clear)."""
     global _dropped
     with _lock:
-        if cats is None:
-            evs = list(_events)
-            _events.clear()
-            _dropped = 0
-            return evs
-        evs = [e for e in _events if e.cat in cats]
-        kept = [e for e in _events if e.cat not in cats]
+        evs = list(_events)
         _events.clear()
-        _events.extend(kept)
+        _dropped = 0
     return evs

@@ -2,9 +2,8 @@
 (ISSUE 3 tentpole part 3). The produced object follows the Trace Event
 Format (the JSON `chrome://tracing` and ui.perfetto.dev load): one
 `traceEvents` array of {ph, ts, name, ...} records, timestamps in
-microseconds. This supersedes utils/trace.py's SVG as the primary
-timeline — `trace.finish()` stays as a thin quick-look view over the
-same bus.
+microseconds. Under a profiler session the same spans are also in
+the profiler's trace (obs/events.py bridges them there).
 
 Multihost (ISSUE 5 satellite; ROADMAP "one Perfetto view shows the
 whole mesh"): each host writes its own trace file, and `host=`

@@ -52,6 +52,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..batch.queue import CoalescingQueue
+from ..obs import events as _ev
 from ..obs import metrics as _om
 from ..obs import reqtrace as _rt
 from ..resil import faults as _faults
@@ -211,7 +212,20 @@ class Server:
         the RPC server passes the client header's {"trace", "span"}
         — so one request shares a single trace_id across the process
         boundary. With the FROZEN obs/reqtrace row off this is one
-        boolean: no span, no header growth, bitwise results."""
+        boolean: no span, no header growth, bitwise results.
+
+        With the obs bus on, the caller's thread holds a
+        `serve::submit` span from here to the return: what keeps a
+        sender (a `batch::inline_flush` inside it is the queue
+        flushing a full bucket in this thread)."""
+        if not _ev.enabled():
+            return self._submit(op, a, b, tenant, trace_parent)
+        with _ev.span("serve::submit", cat="serve", op=op,
+                      n=int(np.shape(a)[-1])):
+            return self._submit(op, a, b, tenant, trace_parent)
+
+    def _submit(self, op: str, a, b, tenant: str,
+                trace_parent) -> ServeTicket:
         if self._closed or self._draining:
             raise ServeRejected(
                 "reject", tenant, op,

@@ -1,23 +1,18 @@
 """Tracing / phase timers (reference auxiliary/Trace.hh:98-108 RAII
-events + Trace.cc:359-627 SVG timeline; per-phase timer map returned in
-opts, heev.cc:108).
+events; per-phase timer map returned in opts, heev.cc:108).
 
-Since ISSUE 3 this module is a thin view over the unified event bus
-(slate_tpu/obs/events.py): `on()`/`off()` toggle the bus, `block`/
-`mark` publish spans/instants into it, and `finish()` renders the SVG
-quick-look from the bus's merged stream — ALL threads' events, unlike
-the old per-thread buffers where OOC host-staging phases recorded off
-the main thread silently vanished. The primary timeline artifact is
-now the Perfetto JSON (obs/export.py: chrome_trace / write_trace);
-the SVG stays for eyeballing without tooling.
+A thin view over the unified event bus (slate_tpu/obs/events.py):
+`on()`/`off()` toggle the bus, `block`/`mark` publish spans/instants
+into it. The timeline is the Perfetto JSON (obs/export.py:
+chrome_trace / write_trace) or, under a profiler session, the
+profiler's own trace, where every bus span is an annotation on the
+device's clock. The reference's SVG view (Trace.cc:359-627) is gone.
 """
 
 from __future__ import annotations
 
 import contextlib
 import time
-from typing import Optional
-from xml.sax.saxutils import escape
 
 from ..obs import events as _bus
 
@@ -30,9 +25,7 @@ def on() -> None:
 def off() -> None:
     """Disables the SHARED bus (one process-wide flag, ISSUE 3): a
     concurrently enabled obs session (bench --obs, tester
-    --trace-out) stops collecting too. Inside such a session, prefer
-    finish() alone — it renders and clears only this module's
-    categories and leaves collection running."""
+    --trace-out) stops collecting too."""
     _bus.disable()
 
 
@@ -91,53 +84,3 @@ def phases(opts):
     def bus_phase(name):
         return _bus.span(name, cat="phase")
     return bus_phase
-
-
-#: the bus categories this module's legacy surface owns — what the
-#: old per-thread store held. finish() drains ONLY these: a
-#: concurrent obs session's driver/jit/comms/metric records survive a
-#: user's trace.on()/finish() cycle (obs/export.py owns those).
-_TRACE_CATS = ("trace", "phase", "tune")
-
-
-def finish(path: Optional[str] = None) -> Optional[str]:
-    """Emit the SVG timeline (reference Trace::finish, Trace.cc:359-594)
-    from the bus's merged multi-thread stream and clear those events
-    (only this module's categories, see _TRACE_CATS). Returns the
-    SVG text (also written to path). Event names are XML-escaped: tuner
-    marks legitimately contain <>& (e.g. "tune::eig.method=<MethodEig.
-    DC: 'dc'> [frozen]") and must not produce malformed SVG."""
-    evs = _bus.drain(cats=_TRACE_CATS)
-    if not evs:
-        return None
-    t_min = min(e.t0 for e in evs)
-    t_max = max(e.t1 for e in evs)
-    span = max(t_max - t_min, 1e-9)
-    width, row_h, pad = 1000.0, 22.0, 4.0
-    names = sorted({e.name for e in evs})
-    colors = ["#4e79a7", "#f28e2b", "#59a14f", "#e15759", "#76b7b2",
-              "#edc948", "#b07aa1", "#9c755f"]
-    color = {n: colors[i % len(colors)] for i, n in enumerate(names)}
-    rows = {n: i for i, n in enumerate(names)}
-    h = row_h * len(names) + 2 * pad
-    parts = [f'<svg xmlns="http://www.w3.org/2000/svg" '
-             f'width="{width + 220}" height="{h}">']
-    for n in names:
-        y = pad + rows[n] * row_h
-        parts.append(f'<text x="4" y="{y + row_h * 0.7:.1f}" '
-                     f'font-size="12">{escape(n)}</text>')
-    for e in evs:
-        x = 200 + (e.t0 - t_min) / span * width
-        w = max((e.t1 - e.t0) / span * width, 0.5)
-        y = pad + rows[e.name] * row_h
-        parts.append(f'<rect x="{x:.1f}" y="{y:.1f}" width="{w:.1f}" '
-                     f'height="{row_h - 4:.1f}" fill="{color[e.name]}">'
-                     f'<title>{escape(e.name)}: '
-                     f'{(e.t1 - e.t0) * 1e3:.2f} ms</title>'
-                     f'</rect>')
-    parts.append("</svg>")
-    svg = "\n".join(parts)
-    if path:
-        with open(path, "w") as f:
-            f.write(svg)
-    return svg

@@ -159,6 +159,27 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture
+def host_plane(tmp_path):
+    """`host_plane(body, names)`: run `body()` under a profiler session
+    with the benchmark's own options (benchmarks/lib/tracer.py) and
+    return the events named in `names` that the xplane's host planes
+    hold, as [(start ns, end ns, name, {argument: value})]: where a
+    bus span must be seen on the profiler's clock."""
+    def run(body, names):
+        from benchmarks.lib import hostspans, reduce_trace
+        from benchmarks.lib.tracer import Tracer
+        tr = Tracer(str(tmp_path / "trace"))
+        tr.start()
+        try:
+            body()
+        finally:
+            tr.stop()
+        return hostspans.host_events(reduce_trace.load(tr.xplane()),
+                                     set(names))
+    return run
+
+
 @pytest.fixture(scope="session")
 def grid8():
     import slate_tpu as st

@@ -32,21 +32,31 @@ def test_simplified_api(rng):
     assert np.all(np.asarray(w) > 0)
 
 
-def test_timers_and_trace(tmp_path):
+def test_timers_and_trace(host_plane):
+    from slate_tpu import obs
     from slate_tpu.utils import Timers, trace
     t = Timers()
     with t.phase("posv::potrf"):
         pass
     assert "posv::potrf" in t.values
+    obs.clear()
     trace.on()
-    with trace.block("gemm"):
-        pass
-    with trace.block("potrf"):
-        pass
-    svg = trace.finish(str(tmp_path / "t.svg"))
-    trace.off()
-    assert svg and "<svg" in svg and "gemm" in svg
-    assert (tmp_path / "t.svg").exists()
+
+    def body():
+        with trace.block("gemm"):
+            pass
+        with trace.block("potrf"):
+            pass
+
+    try:
+        seen = host_plane(body, ["gemm", "potrf"])
+    finally:
+        trace.off()
+    # the blocks are in the bus and on the profiler's timeline
+    assert [e.name for e in obs.bus_events(cat="trace")] \
+        == ["gemm", "potrf"]
+    assert sorted(e[2] for e in seen) == ["gemm", "potrf"]
+    obs.clear()
 
 
 def test_print_matrix(rng, capsys):
