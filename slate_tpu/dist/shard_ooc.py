@@ -1136,7 +1136,8 @@ def shard_potrf_ooc(a: np.ndarray, grid: ProcessGrid,
         _host_ckpt_path(ckpt_path), "shard_potrf_ooc", a, w, nt,
         every=ckpt_every,
         extra_meta={"precision": _precision_meta(lo)})
-    out = ck.factor if ck is not None else np.zeros_like(a)
+    # np.zeros maps untouched pages (linalg/ooc.py potrf_ooc)
+    out = ck.factor if ck is not None else np.zeros(a.shape, a.dtype)
     epoch = _agree_epoch(grid, ck.epoch) if ck is not None else 0
     local_dev = jax.local_devices()[0]
     eng = stream.engine_for(n, w, a.dtype,

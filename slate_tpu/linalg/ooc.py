@@ -569,11 +569,15 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     if ck is not None:
         out = ck.factor
     else:
-        # the factor's host buffer, zero-filled: n^2 elements touched
-        # before the first panel is staged
+        # the factor's host buffer, mapped and not touched
+        # (np.zeros_like would fill all n^2 elements before the first
+        # panel is staged): the strictly upper blocks are never
+        # written and stay the exact zeros the cached full-height
+        # panels mirror; the writer's _d2h threads first touch the
+        # rest, and a full-height read the zeros above its block
         with obs_events.span("ooc::alloc", cat="staging",
                              bytes=int(a.nbytes)):
-            out = np.zeros_like(a)
+            out = np.zeros(a.shape, a.dtype)
     eng = stream.engine_for(n, panel_cols, a.dtype,
                             budget_bytes=cache_budget_bytes,
                             resident_dtype=lo)

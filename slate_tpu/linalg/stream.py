@@ -36,7 +36,9 @@ over PCIe. This module is those two moves for the host<->HBM stream:
   onto host<->HBM transfers). Writeback futures are keyed like cache
   entries, so a later cache MISS that must re-read a panel from host
   memory first waits for that panel's writeback — never for the whole
-  queue.
+  queue. At most ``WRITES_IN_FLIGHT`` writebacks are unfinished at
+  once: queuing one more waits for the oldest, which is the engine's
+  only backpressure from the device on the driver's main thread.
 * ``StreamEngine.stash`` — the multi-shard extension (ISSUE 7): a
   DIRTY working panel (a trailing-update state the host copy does not
   yet reflect, as in the sharded right-looking schedules of
@@ -63,6 +65,21 @@ of the H2D saving the casts give back. The engine itself stays
 dtype-agnostic — ``resident_dtype`` declares the expectation for
 budget math and stats, and the f32 mode passes None, leaving this
 module's behavior bit-identical.
+
+Host staging (PR 26): a panel that has to be made contiguous before
+the runtime can take it (every column slice of the C-ordered operand
+or factor) is packed into one of a small, process-wide ring of host
+buffers that are reused from panel to panel, engine to engine and
+call to call, never into a fresh array: on the v5e host the first
+touch of freshly mapped pages, not the strided read, was 94% of a
+streamed solve's staging time (PERF.md, PR 26). The ring holds at
+most ``prefetch_depth + 2`` slots of the deepest engine seen (3 at
+the frozen depth of 1), each as large as the largest panel staged
+through it and kept for the life of the process: 1.6 GB of host
+memory for 537 MB panels, beside an 8.6 GB operand and factor. A slot
+is recycled only when the transfer that read it is over (the span
+``ooc::wait_ring`` is the wait), and on a backend whose device arrays
+may alias host memory (the CPU) the put is made to copy.
 
 Budget contract: ``cache_budget_bytes=0`` disables the cache entirely
 and every fetch takes the exact upload path the pre-engine drivers
@@ -108,31 +125,174 @@ RESERVE_PANELS = 4
 #: allocator needs slack for kernel temps beyond the working panels
 AUTO_BUDGET_FRACTION = 0.9
 
+#: writebacks that may be unfinished at once: the one the writer is
+#: copying out and one queued behind it. Each holds its device panel,
+#: and a driver's main thread waits for nothing else on the device, so
+#: this is also what keeps the host from dispatching a whole
+#: factorization ahead of the chip: with staging at memory speed
+#: (PR 26) potrf_ooc at n=32768 had six of eight steps' temporaries
+#: allocated at once, 13.6 GB of a 16 GB chip, where the slow pack had
+#: held it to 6.5 GB
+WRITES_IN_FLIGHT = 2
+
 #: most recent finished engine's stats (bench.py --ooc extras); a
 #: plain module slot, last-writer-wins — the bench runs one driver at
 #: a time
 _last_stats: Dict[str, Any] = {}
 
 
+class _StageSlot:
+    """One reused host staging buffer of the ring below."""
+
+    __slots__ = ("buf", "last", "busy")
+
+    def __init__(self) -> None:
+        self.buf: Optional[np.ndarray] = None   # flat uint8
+        self.last: Any = None     # device array last made from buf
+        self.busy = False         # a thread is packing into buf
+
+
+class _StageRing:
+    """The process-wide ring of reused host staging buffers behind
+    ``_h2d`` (module doc, "Host staging"). ``acquire`` hands out a
+    slot whose last transfer is over, ``release`` takes it back with
+    the device array just made from it. The lock covers the
+    bookkeeping only, never a copy or a wait for the device."""
+
+    def __init__(self, slots: int = 2) -> None:
+        self._cv = threading.Condition()
+        #: least recently released first: of the free slots the head
+        #: is the one whose transfer has had the longest to finish
+        self._slots: list = []
+        self._cap = int(slots)
+
+    def reserve(self, slots: int) -> None:
+        """Allow up to `slots` slots: an engine asks for its
+        ``prefetch_depth + 2`` (one being packed per staging thread,
+        one per transfer in flight); the deepest engine seen wins."""
+        with self._cv:
+            self._cap = max(self._cap, int(slots))
+
+    def _sweep(self) -> None:
+        # under the lock: forget every device array whose transfer is
+        # over, so the ring never keeps a consumed panel alive in HBM
+        for s in self._slots:
+            if s.last is not None and s.last.is_ready():
+                s.last = None
+
+    def sweep(self) -> None:
+        with self._cv:
+            self._sweep()
+
+    def acquire(self, nbytes: int) -> Tuple[_StageSlot, bool]:
+        """A slot of at least `nbytes` that nothing reads any more,
+        and whether its pages were touched before (False for a new or
+        regrown buffer). Blocks, under ``ooc::wait_ring``, while every
+        slot is being packed by another thread or while the chosen
+        slot's last transfer is not ready: the runtime may read a
+        staging buffer until then."""
+        with self._cv:
+            while True:
+                self._sweep()
+                free = [s for s in self._slots if not s.busy]
+                slot = next((s for s in free if s.last is None), None)
+                if slot is None and len(self._slots) < self._cap:
+                    slot = _StageSlot()
+                    self._slots.append(slot)
+                if slot is None and free:
+                    slot = free[0]
+                if slot is not None:
+                    slot.busy = True
+                    last, slot.last = slot.last, None
+                    break
+                with obs_events.span("ooc::wait_ring", cat="staging",
+                                     on="slot"):
+                    self._cv.wait()
+        try:
+            if last is not None:
+                with obs_events.span("ooc::wait_ring", cat="staging",
+                                     on="transfer"):
+                    last.block_until_ready()
+            reused = slot.buf is not None and slot.buf.nbytes >= nbytes
+            if not reused:
+                slot.buf = np.empty(nbytes, np.uint8)
+        except BaseException:
+            self.release(slot, None)    # a failed transfer, no memory
+            raise
+        return slot, reused
+
+    def release(self, slot: _StageSlot, arr) -> None:
+        """Hand `slot` back; `arr` is the device array made from it
+        (None when the staging failed), whose readiness the next
+        ``acquire`` of this slot waits for."""
+        with self._cv:
+            slot.last = arr
+            slot.busy = False
+            self._slots.remove(slot)
+            self._slots.append(slot)
+            self._sweep()
+            self._cv.notify()
+
+
+#: one ring for the process: posv_ooc builds two engines a solve, and
+#: a ring per engine would first-touch its slots again each time
+_ring = _StageRing()
+
+
+def _aliases_host() -> bool:
+    """Whether ``jnp.asarray`` of a host buffer may hand back a device
+    array that IS that buffer (the CPU backend's zero-copy put): a
+    recycled staging slot would then rewrite an earlier panel, so
+    ``_h2d`` makes the backend copy there."""
+    dev = jax.config.jax_default_device
+    platform = getattr(dev, "platform", dev) or jax.default_backend()
+    return platform == "cpu"
+
+
 def _h2d(x: np.ndarray) -> jax.Array:
     """Host-to-device copy via a contiguous staging buffer: jax's
     transfer of a non-contiguous numpy view (any column slice of a
     C-ordered matrix) marshals element-wise; one host-side memcpy buys
-    the contiguous path (the gap is not measured on the current
-    machine)."""
+    the contiguous path. What the memcpy writes INTO decides its cost:
+    on the v5e host a fresh ``np.ascontiguousarray`` of a 537 MB panel
+    runs at 0.9 GB/s (every page of a new mapping faults in), the same
+    copy into a reused, already touched buffer at 11 GB/s
+    (benchmarks/tools/pack_probe.py, PERF.md PR 26). So a source that
+    needs the copy is packed into a slot of the process-wide ring; a
+    source that is already contiguous (the ``astype`` results of
+    ``demote_host``, a right-hand side, a ``np.take`` result) passes
+    through untouched. The hand-over does not wait for the transfer
+    (0.5 ms for a panel the link then moves in 55 ms): the ring does,
+    before it recycles the slot."""
     import jax.numpy as jnp
-    if not obs_events.enabled():
-        return jnp.asarray(np.ascontiguousarray(x))
-    obs_metrics.inc("ooc.h2d_bytes", int(x.nbytes))
-    with obs_events.span("ooc::h2d", cat="staging",
-                         bytes=int(x.nbytes)):
+    on, nbytes = obs_events.enabled(), int(x.nbytes)
+    if on:
+        obs_metrics.inc("ooc.h2d_bytes", nbytes)
+    with obs_events.span("ooc::h2d", cat="staging", bytes=nbytes):
         # the host-side copy against the hand-over to the runtime; the
         # transfer is not waited for (observing must not change the
         # program)
-        with obs_events.span("ooc::h2d_pack", cat="staging"):
-            packed = np.ascontiguousarray(x)
-        with obs_events.span("ooc::h2d_put", cat="staging"):
-            return jnp.asarray(packed)
+        if x.flags.c_contiguous:
+            with obs_events.span("ooc::h2d_pack", cat="staging"):
+                packed = np.ascontiguousarray(x)
+            with obs_events.span("ooc::h2d_put", cat="staging"):
+                return jnp.asarray(packed)
+        slot, reused = _ring.acquire(nbytes)
+        arr = None
+        try:
+            with obs_events.span("ooc::h2d_pack", cat="staging"):
+                packed = slot.buf[:nbytes].view(x.dtype).reshape(x.shape)
+                np.copyto(packed, x)
+            with obs_events.span("ooc::h2d_put", cat="staging"):
+                arr = jnp.array(packed) if _aliases_host() \
+                    else jnp.asarray(packed)
+        finally:
+            _ring.release(slot, arr)
+    if on and reused:
+        obs_metrics.inc("ooc.h2d_stage_reuse_bytes", nbytes)
+    elif on:
+        obs_metrics.inc("ooc.h2d_stage_fresh_bytes", nbytes)
+    return arr
 
 
 def _d2h(x: jax.Array, out: Optional[np.ndarray] = None,
@@ -517,6 +677,7 @@ class StreamEngine:
         self.cache = PanelCache(budget_bytes, policy, pins=pins,
                                 resident_dtype=resident_dtype)
         self.prefetch_depth = max(int(prefetch_depth), 0)
+        _ring.reserve(self.prefetch_depth + 2)
         self._h2d_pool = cf.ThreadPoolExecutor(
             1, thread_name_prefix="ooc-h2d") \
             if self.prefetch_depth > 0 else None
@@ -871,6 +1032,8 @@ class StreamEngine:
         k+1's visit stream. np.asarray on the worker blocks until the
         producing computation is done — exactly the sync the main
         thread no longer pays."""
+        self._throttle_writes()
+
         def task():
             t0 = time.perf_counter()
             with obs_events.span("ooc::writeback", cat="staging",
@@ -886,6 +1049,25 @@ class StreamEngine:
         fut = self._d2h_pool.submit(task)
         with self._lock:
             self._writes.setdefault((buf, idx), []).append(fut)
+
+    def _throttle_writes(self) -> None:
+        """Block the queuing thread until fewer than WRITES_IN_FLIGHT
+        writebacks are unfinished (a failed one counts as finished:
+        its error is raised where it is today, by the fence or the
+        drain that reads its result)."""
+        while True:
+            with self._lock:
+                unfinished = [f for fs in self._writes.values()
+                              for f in fs if not f.done()]
+            if len(unfinished) < WRITES_IN_FLIGHT:
+                return
+            t0 = time.perf_counter()
+            with obs_events.span("ooc::wait_write", cat="staging",
+                                 throttle=True):
+                cf.wait(unfinished, return_when=cf.FIRST_COMPLETED)
+            dt = time.perf_counter() - t0
+            self.d2h_wait_seconds += dt
+            _ledger.credit("cache", dt)
 
     def wait_writes(self) -> None:
         """Drain the writeback queue (drivers call this before
@@ -964,6 +1146,7 @@ class StreamEngine:
         if self._h2d_pool is not None:
             self._h2d_pool.shutdown(wait=True)
         self._d2h_pool.shutdown(wait=True)
+        _ring.sweep()
         s = self.stats()
         if obs_events.enabled():
             obs_metrics.inc("ooc.cache.hits", s["hits"])

@@ -6,6 +6,10 @@ measurably cutting the left-looking H2D revisit volume (the ISSUE 4
 acceptance: >= 40% reduction at nt >= 8 with a budget holding >= nt/2
 panels, read from the obs metrics snapshot)."""
 
+import sys
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -383,3 +387,252 @@ def test_engine_stats_surface():
                 "budget_bytes", "policy"):
         assert key in s, key
     assert s["hits"] > 0
+
+
+# -- the host staging ring (PR 26) ----------------------------------------
+
+@pytest.fixture
+def ring(monkeypatch):
+    """A ring of this test's own in place of the process-wide one."""
+    r = stream._StageRing()
+    monkeypatch.setattr(stream, "_ring", r)
+    return r
+
+
+def _f32(rng, m, n):
+    return rng.standard_normal((m, n)).astype(np.float32)
+
+
+@pytest.mark.parametrize("guarded", [True, False])
+def test_ring_recycled_slot_leaves_earlier_panel_intact(
+        rng, ring, monkeypatch, guarded):
+    """Two panels staged in turn through ONE slot: on a backend whose
+    device arrays may alias host memory (this one) the first device
+    array survives only because _h2d makes the put copy; with that
+    guard forced off the recycled slot rewrites it."""
+    if not guarded:
+        monkeypatch.setattr(stream, "_aliases_host", lambda: False)
+    ring._cap = 1
+    a = _f32(rng, 1024, 2048)
+    # the backend aliases only a well aligned buffer: give the slot one
+    slot, _ = ring.acquire(a.nbytes // 2)
+    raw = np.empty(a.nbytes // 2 + 4096, np.uint8)
+    off = -raw.ctypes.data % 4096
+    slot.buf = raw[off:off + a.nbytes // 2]
+    ring.release(slot, None)
+    first = stream._h2d(a[:, :1024])
+    second = stream._h2d(a[:, 1024:])
+    assert len(ring._slots) == 1
+    assert np.array_equal(np.asarray(second), a[:, 1024:])
+    assert np.array_equal(np.asarray(first), a[:, :1024]) == guarded
+
+
+class _Transfer:
+    """A stand-in for a device array whose transfer the test ends."""
+
+    def __init__(self):
+        self.done = threading.Event()
+        self.waited_for = threading.Event()
+
+    def is_ready(self):
+        return self.done.is_set()
+
+    def block_until_ready(self):
+        self.waited_for.set()
+        assert self.done.wait(30)
+        return self
+
+
+def test_ring_acquire_waits_for_the_slots_last_transfer(ring, obs_on):
+    """A slot whose last transfer is not ready is not handed out: a
+    ring with room makes another slot, a full one blocks under
+    ooc::wait_ring until the transfer is over."""
+    slot, reused = ring.acquire(1 << 16)
+    assert not reused
+    inflight = _Transfer()
+    ring.release(slot, inflight)
+    other, _ = ring.acquire(1 << 16)            # room: a second slot
+    assert other is not slot and len(ring._slots) == 2
+    ring.release(other, None)
+    ring._cap = 2
+    got = []
+    ring.release(ring.acquire(1 << 16)[0], _Transfer())   # both in flight
+    t = threading.Thread(
+        target=lambda: got.append(ring.acquire(1 << 16)), daemon=True)
+    t.start()
+    assert inflight.waited_for.wait(30)
+    t.join(0.1)
+    assert t.is_alive() and not got             # blocked on the transfer
+    inflight.done.set()
+    t.join(30)
+    assert not t.is_alive()
+    assert got[0][0] is slot and got[0][1]      # the oldest release, reused
+    waits = [e for e in obs_on.bus_events(cat="staging")
+             if e.name == "ooc::wait_ring"]
+    assert len(waits) == 1 and waits[0].dur >= 0.09
+    assert waits[0].args["on"] == "transfer"
+    # every slot busy: the next acquire waits for a release
+    t2 = threading.Thread(
+        target=lambda: got.append(ring.acquire(1 << 16)), daemon=True)
+    for s in ring._slots:
+        if not s.busy:
+            s.last.done.set()
+            ring.acquire(1 << 16)
+    t2.start()
+    t2.join(0.2)
+    assert t2.is_alive()
+    ring.release(slot, None)
+    t2.join(30)
+    assert not t2.is_alive() and got[1][0] is slot
+    assert len(ring._slots) == 2
+
+
+def test_ring_eight_threads_each_get_their_own_bytes(rng, ring):
+    """More staging threads than slots, a shortened switch interval:
+    every device array holds the bytes of the source it was made from,
+    and the ring stays within its bound."""
+    srcs = [_f32(rng, 256, 512) for _ in range(8)]
+    bad, old = [], sys.getswitchinterval()
+
+    def stage(t):
+        for rep in range(20):
+            src = srcs[t][:, rep % 4::4]        # a strided view
+            if not np.array_equal(np.asarray(stream._h2d(src)), src):
+                bad.append((t, rep))
+
+    sys.setswitchinterval(1e-5)
+    try:
+        ts = [threading.Thread(target=stage, args=(t,), daemon=True)
+              for t in range(8)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in ts) and not bad, bad
+    assert 1 <= len(ring._slots) <= 2           # no engine reserved more
+    assert not any(s.busy for s in ring._slots)
+
+
+def test_ring_is_bounded_and_reused_across_engines(rng, ring, obs_on):
+    """At most prefetch_depth + 2 slots, and posv_ooc's second engine
+    (and a second call) find the first one's slots touched: nothing
+    fresh is staged once the slots hold the largest panel."""
+    from slate_tpu.obs import metrics
+    n, w = 256, 32
+    a = _spd(rng, n, np.float32)
+    b = _f32(rng, n, 2)
+    ooc.posv_ooc(a, b, panel_cols=w)            # two engines
+    assert 1 <= len(ring._slots) <= 3           # how many: the threads' luck
+    assert not any(s.busy or s.last is not None for s in ring._slots)
+    # fill the ring to its bound, every slot as large as the largest panel
+    held = [ring.acquire(n * w * 4)[0] for _ in range(3)]
+    for slot in held:
+        ring.release(slot, None)
+    assert len(ring._slots) == 3
+    bufs = {id(s.buf) for s in ring._slots}
+    c0 = metrics.snapshot()["counters"]
+    ooc.posv_ooc(a, b, panel_cols=w)            # two more engines
+    c1 = metrics.snapshot()["counters"]
+    assert c1["ooc.h2d_stage_fresh_bytes"] == c0["ooc.h2d_stage_fresh_bytes"]
+    assert c1["ooc.h2d_stage_reuse_bytes"] > c0["ooc.h2d_stage_reuse_bytes"]
+    assert {id(s.buf) for s in ring._slots} == bufs
+    with StreamEngine(prefetch_depth=3):
+        assert ring._cap == 5                   # the deepest engine seen
+    with StreamEngine(prefetch_depth=0):
+        assert ring._cap == 5
+
+
+def test_potrf_factor_bitwise_what_fresh_staging_gave(rng, ring,
+                                                       monkeypatch):
+    """The ring and the untouched factor buffer change no bit: the
+    factor at the streamed cell's rehearsal size equals the one the
+    parent's staging (a fresh contiguous copy per panel, a zero-filled
+    buffer) returns, cached and uncached, and its strictly upper
+    blocks are exact zeros."""
+    import jax.numpy as jnp
+    n, w = 512, 64
+    a = _spd(rng, n, np.float32)
+    budget = 5 * n * w * 4
+    got = [ooc.potrf_ooc(a, panel_cols=w, cache_budget_bytes=bb)
+           for bb in (budget, 0)]
+    assert ring._slots                          # the ring did stage them
+    monkeypatch.setattr(
+        stream, "_h2d", lambda x: jnp.asarray(np.ascontiguousarray(x)))
+    monkeypatch.setattr(np, "zeros", lambda shape, dtype=float, **kw:
+                        np.full(shape, 0, dtype))
+    want = ooc.potrf_ooc(a, panel_cols=w, cache_budget_bytes=budget)
+    for L in got:
+        assert L.dtype == want.dtype and np.array_equal(L, want)
+        assert L.tobytes() == want.tobytes()
+        assert not np.triu(L, 1).any()
+    assert np.abs(want @ want.T - a).max() < 1e-3 * np.abs(a).max()
+
+
+def test_stage_counters_cover_the_copied_share_of_h2d(rng, ring, obs_on):
+    """ooc.h2d_stage_reuse_bytes + ooc.h2d_stage_fresh_bytes is the
+    share of ooc.h2d_bytes that needed the host-side copy: every panel
+    of posv_ooc (a column slice), and not its contiguous right-hand
+    side."""
+    from slate_tpu.obs import metrics
+    n, w = 256, 64
+    a = _spd(rng, n, np.float32)
+    b = _f32(rng, n, 3)
+    ooc.posv_ooc(a, b, panel_cols=w, cache_budget_bytes=2 * n * w * 4)
+    c = metrics.snapshot()["counters"]
+    staged = c["ooc.h2d_stage_reuse_bytes"] + c["ooc.h2d_stage_fresh_bytes"]
+    assert staged == c["ooc.h2d_bytes"] - b.nbytes
+    assert c["ooc.h2d_stage_fresh_bytes"] > 0
+    # a contiguous source passes through and is counted in neither
+    stream._h2d(np.ascontiguousarray(a[:, :w]))
+    c2 = metrics.snapshot()["counters"]
+    assert c2["ooc.h2d_bytes"] == c["ooc.h2d_bytes"] + n * w * 4
+    assert c2["ooc.h2d_stage_reuse_bytes"] + \
+        c2["ooc.h2d_stage_fresh_bytes"] == staged
+
+
+def test_writebacks_in_flight_are_bounded(monkeypatch, obs_on):
+    """The engine's backpressure: queuing a writeback while
+    WRITES_IN_FLIGHT are unfinished waits for the oldest, so a driver
+    cannot dispatch a whole factorization ahead of the device."""
+    gates = [threading.Event() for _ in range(4)]
+    started = []
+
+    def slow_d2h(dev, out=None):
+        started.append(dev)
+        assert gates[dev].wait(30)
+        return out
+
+    monkeypatch.setattr(stream, "_d2h", slow_d2h)
+    out = np.zeros((4, 2))
+    with StreamEngine() as eng:
+        queued = []
+
+        def driver():
+            for k in range(4):
+                eng.write("L", k, k, out[k:k + 1])
+                queued.append(k)
+
+        def settles_at(count):
+            deadline = time.monotonic() + 30
+            while len(queued) < count and time.monotonic() < deadline:
+                time.sleep(0.01)
+            t.join(0.15)                        # and goes no further
+            return len(queued) == count
+
+        t = threading.Thread(target=driver, daemon=True)
+        t.start()
+        assert settles_at(stream.WRITES_IN_FLIGHT)
+        gates[0].set()                          # the oldest finishes
+        assert settles_at(stream.WRITES_IN_FLIGHT + 1)
+        for g in gates:
+            g.set()
+        t.join(30)
+        assert not t.is_alive() and queued == [0, 1, 2, 3]
+        eng.wait_writes()
+        assert started == [0, 1, 2, 3]
+        assert eng.d2h_wait_seconds >= 0.25
+    waits = [e for e in obs_on.bus_events(cat="staging")
+             if e.name == "ooc::wait_write" and e.args.get("throttle")]
+    assert len(waits) == 2
