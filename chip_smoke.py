@@ -402,8 +402,6 @@ def phase_serve(args, rng, comp):
 def phase_mesh(args, rng, comp):
     """Option.Grid on the four local chips (2x2) against the same two
     calls on device 0 alone, in this one process."""
-    import dataclasses
-
     import jax
     import jax.numpy as jnp
 
@@ -414,20 +412,16 @@ def phase_mesh(args, rng, comp):
           count=len(jax.devices()))
     n, nrhs, mb = args.n, args.nrhs, args.mb
     grid = st.make_grid(2, 2, devices=jax.devices()[:4])
-    dev0 = jax.devices()[0]
     a = sym_dominant(rng, n)
     b = rng.standard_normal((n, nrhs)).astype(np.float32)
     g = rng.standard_normal((n, n)).astype(np.float32)
 
-    def place(M, where):
-        return dataclasses.replace(M, data=jax.device_put(M.data, where))
-
-    def run(where, opts):
-        A = place(st.HermitianMatrix(st.Uplo.Lower, a, mb=mb), where)
-        B = place(st.Matrix(b, mb=mb), where)
-        G = place(st.Matrix(g, mb=mb), where)
-        C = place(st.TiledMatrix.zeros(n, n, mb, dtype=jnp.float32),
-                  where)
+    def run(on, opts):
+        """`on`: the grid, or None for the default device (chip 0)."""
+        A = st.HermitianMatrix(st.Uplo.Lower, a, mb=mb, grid=on)
+        B = st.Matrix(b, mb=mb, grid=on)
+        G = st.Matrix(g, mb=mb, grid=on)
+        C = st.TiledMatrix.zeros(n, n, mb, dtype=jnp.float32, grid=on)
         gopts = dict(opts)
         if Option.Grid in opts:
             gopts[Option.MethodGemm] = MethodGemm.Summa
@@ -444,8 +438,8 @@ def phase_mesh(args, rng, comp):
 
     tiled = {Option.MethodFactor: MethodFactor.Tiled}
     with grid.mesh:
-        four = run(grid.matrix_sharding(), {Option.Grid: grid, **tiled})
-    one = run(dev0, tiled)
+        four = run(grid, {Option.Grid: grid, **tiled})
+    one = run(None, tiled)
     a64 = np.tril(a).astype(np.float64)
     a64 = a64 + np.tril(a64, -1).T
     for name in ("posv", "gemm"):

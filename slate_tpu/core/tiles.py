@@ -44,13 +44,19 @@ from .exceptions import DimensionError, slate_assert
 _warned_downcast = False
 
 
-def _asarray_warn_downcast(a):
+def _asarray_warn_downcast(a, grid=None, shape=None):
     """jnp.asarray with the one-time float64-downcast warning: with jax
     x64 disabled, double input silently becomes single, which changes
     solver accuracy — every TiledMatrix constructor funnels through
-    this so the warning cannot be bypassed."""
+    this so the warning cannot be bypassed. With a `grid` the array
+    goes to the mesh instead, block by block and padded to `shape`
+    there (parallel/sharding.place)."""
     orig_dtype = getattr(a, "dtype", None)
-    if obs_events.enabled() and isinstance(a, np.ndarray):
+    if grid is not None:
+        from ..parallel.sharding import place
+        out = place(a if hasattr(a, "nbytes") else np.asarray(a), grid,
+                    shape)
+    elif obs_events.enabled() and isinstance(a, np.ndarray):
         # a host array: the upload a solve's wall contains (the
         # hand-over to the runtime; the transfer itself is not waited
         # for)
@@ -187,22 +193,27 @@ class TiledMatrix:
     def from_dense(cls, a, mb: int = 256, nb: Optional[int] = None,
                    mtype: MatrixType = MatrixType.General,
                    uplo: Uplo = Uplo.General, diag: Diag = Diag.NonUnit,
-                   kl: int = -1, ku: int = -1) -> "TiledMatrix":
+                   kl: int = -1, ku: int = -1, grid=None
+                   ) -> "TiledMatrix":
         """Wrap a dense array, padding to tile multiples (reference
-        fromLAPACK, Matrix.hh:58).
+        fromLAPACK, Matrix.hh:58). With a ProcessGrid as `grid` the
+        storage is laid over the mesh as P('p','q'), each device sent
+        its own block straight from `a`: the way to build a matrix
+        that no single device could hold (parallel/sharding.place).
 
         Double-precision input with jax x64 disabled is downcast to
         single by jax; that silently changes solver accuracy, so the
         first occurrence warns (enable x64 via
         ``jax.config.update("jax_enable_x64", True)`` — CPU mesh only;
         TPU has no native f64 path — or pass f32 data explicitly)."""
-        a = _asarray_warn_downcast(a)
-        if a.ndim != 2:
-            raise DimensionError(f"expected 2D, got {a.shape}")
+        if np.ndim(a) != 2:
+            raise DimensionError(f"expected 2D, got {np.shape(a)}")
         nb = nb or mb
-        m, n = a.shape
+        m, n = np.shape(a)
         mp, np_ = round_up(max(m, 1), mb), round_up(max(n, 1), nb)
-        a = jnp.pad(a, ((0, mp - m), (0, np_ - n)))
+        a = _asarray_warn_downcast(a, grid, (mp, np_))
+        if grid is None:
+            a = jnp.pad(a, ((0, mp - m), (0, np_ - n)))
         return cls(data=a, m=m, n=n, mb=mb, nb=nb, mtype=mtype, uplo=uplo,
                    diag=diag, kl=kl, ku=ku)
 
@@ -269,10 +280,14 @@ class TiledMatrix:
 
     @classmethod
     def zeros(cls, m: int, n: int, mb: int = 256, nb: Optional[int] = None,
-              dtype=jnp.float32, **kw) -> "TiledMatrix":
+              dtype=jnp.float32, grid=None, **kw) -> "TiledMatrix":
         nb = nb or mb
-        data = jnp.zeros((round_up(max(m, 1), mb), round_up(max(n, 1), nb)),
-                         dtype)
+        shape = (round_up(max(m, 1), mb), round_up(max(n, 1), nb))
+        where = None
+        if grid is not None:
+            from ..parallel.sharding import fitted_sharding
+            where = fitted_sharding(shape, grid)
+        data = jnp.zeros(shape, dtype, device=where)
         return cls(data=data, m=m, n=n, mb=mb, nb=nb, **kw)
 
     def emptyLike(self, m: Optional[int] = None, n: Optional[int] = None,

@@ -22,7 +22,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ..core.enums import MatrixType, Side, Uplo
+from ..core.enums import Diag, MatrixType, Side, Uplo
 from ..core.exceptions import DimensionError, slate_assert
 from ..core.options import OptionsLike
 from ..core.tiles import TiledMatrix
@@ -209,13 +209,20 @@ def trsm(side: Side, alpha, A: TiledMatrix, B: TiledMatrix,
     from .blocked import trsm_dense
     ra = A.resolve()
     lower = ra.uplo is Uplo.Lower
-    # to_dense applies the triangle/band masks and bakes Diag.Unit ones
-    # onto the diagonal, so the solve always sees the logical matrix.
-    a = ra.to_dense()
+    grid = get_option(opts, Option.Grid, None)
+    if grid is not None and ra.mtype is MatrixType.Triangular \
+            and ra.diag is Diag.NonUnit:
+        # the grid's block loops read the stored triangle and nothing
+        # else: no masked copy of the whole matrix on every device
+        a = ra.data[:ra.m, :ra.n]
+    else:
+        # to_dense applies the triangle/band masks and bakes Diag.Unit
+        # ones onto the diagonal, so the solve sees the logical matrix
+        a = ra.to_dense()
     b = _logical(B)
     x = trsm_dense(a, jnp.asarray(alpha, b.dtype) * b,
                    left=(side is Side.Left), lower=lower, nb=ra.nb,
-                   grid=get_option(opts, Option.Grid, None))
+                   grid=grid)
     return _store(B, x)
 
 

@@ -137,6 +137,9 @@ def trsm_left(a: jax.Array, b: jax.Array, lower: bool, nb: int,
                     for j in range(0, b.shape[1], k_slab)]
             return jnp.concatenate(outs, axis=1)
         return direct(b)
+    if nt > CHOL_SCAN_THRESHOLD and n == nt * nb:
+        return trsm_left_scan(a, b, lower, nb, unit_diagonal, precision,
+                              grid)
     x = b
     order = range(nt) if lower else range(nt - 1, -1, -1)
     for k in order:
@@ -152,6 +155,38 @@ def trsm_left(a: jax.Array, b: jax.Array, lower: bool, nb: int,
             upd = jnp.matmul(a[:k0, k0:k1], xk, precision=precision)
             x = constrain(x.at[:k0].add(-upd), grid)
     return x
+
+
+def trsm_left_scan(a: jax.Array, b: jax.Array, lower: bool, nb: int,
+                   unit_diagonal: bool = False, precision=_HI,
+                   grid=None) -> jax.Array:
+    """The grid loop of `trsm_left` as ONE compiled block step under
+    fori_loop, for nt > CHOL_SCAN_THRESHOLD (n a multiple of nb): the
+    step takes column block k of A full-height, rows at or past the
+    diagonal block masked to zero, so every step has one shape (twice
+    the update FLOPs of the shrinking loop, n^2 k in all). Compiled
+    for a v5e 2x2, the two unrolled sweeps of a potrs at n=49152,
+    nt=96 kept the compiler 5 min 23 s and were refused (278.72 GB of
+    HBM wanted a chip); this form compiles in 3.4 s (PERF.md, PR 27).
+    Blocks are read and written as in `cholesky_scan`."""
+    from ..parallel.sharding import constrain
+    n = a.shape[0]
+    nt = n // nb
+    rows = jnp.arange(n)
+
+    def step(i, x):
+        k = i if lower else nt - 1 - i
+        colblk = _take_block(a, k, nb, 1, grid)
+        inv = invert_triangular(_take_block(colblk, k, nb, 0, grid),
+                                lower, unit_diagonal)
+        xk = jnp.matmul(inv, _take_block(x, k, nb, 0, grid),
+                        precision=precision)
+        rest = rows >= (k + 1) * nb if lower else rows < k * nb
+        upd = jnp.matmul(jnp.where(rest[:, None], colblk, 0), xk,
+                         precision=precision)
+        return constrain(_put_block(x - upd, xk, k, nb, 0, grid), grid)
+
+    return jax.lax.fori_loop(0, nt, step, b)
 
 
 def trsm_dense(a: jax.Array, b: jax.Array, *, left: bool, lower: bool,
@@ -319,22 +354,68 @@ def chol_loop_pipelined(a: jax.Array, nb: int, diag_factor,
 
 #: block-step count above which the Tiled Cholesky switches from the
 #: Python-unrolled shrinking-slice loop (minimal FLOPs, program size
-#: O(nt)) to the fixed-shape fori_loop (O(1) program, ~3x trailing
-#: FLOPs from full-height masked panels) — compile time stays bounded
-#: for huge-n distributed runs (reference task emission scales to
-#: nt=512, potrf.cc:85)
+#: O(nt)) to the fixed-shape fori_loop (O(1) program; every step
+#: updates the whole matrix, 2 n^3 FLOPs against n^3/3: six times)
+#: — compile time stays bounded for huge-n distributed runs
+#: (reference task emission scales to nt=512, potrf.cc:85).
+#: `trsm_left`'s grid loop switches to its scan form at the same count
 CHOL_SCAN_THRESHOLD = 64
+
+
+def _take_block(a: jax.Array, k, nb: int, axis: int, grid) -> jax.Array:
+    """Block k (k traced) of the nb-blocks of `a` along `axis`: rows
+    k*nb:(k+1)*nb for axis 0, columns for axis 1. Under a grid the
+    block is picked by a one-hot mask over the block axis and summed
+    (exact: every other term is a zero): a `dynamic_slice` at a traced
+    offset along a sharded dimension makes the SPMD partitioner
+    all-gather the WHOLE operand onto every device (9.0 GB a chip at
+    n=49152 on 2x2; PERF.md, PR 27), while the masked sum stays
+    sharded at the price of one pass over `a`."""
+    if grid is None:
+        start, size = [0, 0], list(a.shape)
+        start[axis], size[axis] = k * nb, nb
+        return jax.lax.dynamic_slice(a, start, size)
+    blocks = a.shape[axis] // nb
+    sel = (jnp.arange(blocks) == k)[
+        tuple(slice(None) if d == axis else None for d in range(3))]
+    view = a.shape[:axis] + (blocks, nb) + a.shape[axis + 1:]
+    return jnp.sum(jnp.where(sel, a.reshape(view), 0), axis=axis)
+
+
+def _put_block(a: jax.Array, blk: jax.Array, k, nb: int, axis: int,
+               grid) -> jax.Array:
+    """`a` with block k along `axis` replaced by `blk`; under a grid a
+    select against the tiled block, for `_take_block`'s reason."""
+    if grid is None:
+        start = [0, 0]
+        start[axis] = k * nb
+        return jax.lax.dynamic_update_slice(a, blk, start)
+    reps, here = [1, 1], [None, None]
+    reps[axis] = a.shape[axis] // nb
+    here[axis] = slice(None)
+    here = (jnp.arange(a.shape[axis]) // nb == k)[tuple(here)]
+    return jnp.where(here, jnp.tile(blk, reps), a)
+
+
+def chol_form(n: int, nb: int, guarded: bool = False) -> str:
+    """Which loop factors an order-n matrix in nb-blocks: "scan" above
+    CHOL_SCAN_THRESHOLD block steps, else "unrolled" (the guarded
+    loops of linalg/info.py have no scan form)."""
+    scan = ceil_div(n, nb) > CHOL_SCAN_THRESHOLD and not guarded
+    return "scan" if scan else "unrolled"
 
 
 def cholesky_scan(a: jax.Array, nb: int, precision=_HI,
                   grid=None) -> jax.Array:
     """Lower Cholesky as ONE compiled block step iterated by fori_loop:
-    every step slices a fixed (N, nb) column block with dynamic_slice,
+    every step takes a fixed (N, nb) column block at a traced offset,
     factors the diagonal block, forms the panel full-height (rows above
     the panel masked to zero so the trailing matmul leaves factored
     columns untouched), and applies one full-size trailing update.
     Program size independent of nt — the compile-time-safe form of
-    chol_loop for nt > CHOL_SCAN_THRESHOLD."""
+    chol_loop for nt > CHOL_SCAN_THRESHOLD. Blocks are read and
+    written through `_take_block` and `_put_block`, so that under a
+    grid no step gathers the matrix."""
     from ..parallel.sharding import constrain
     n = a.shape[0]
     nt = ceil_div(n, nb)
@@ -343,10 +424,9 @@ def cholesky_scan(a: jax.Array, nb: int, precision=_HI,
     def step(k, a):
         k0 = k * nb
         k1 = k0 + nb
-        d = jax.lax.dynamic_slice(a, (k0, k0), (nb, nb))
-        lkk = chol_diag_factor(d)
-        lkk = jnp.tril(lkk)
-        colblk = jax.lax.dynamic_slice(a, (0, k0), (n, nb))
+        colblk = _take_block(a, k, nb, 1, grid)
+        lkk = jnp.tril(chol_diag_factor(
+            _take_block(colblk, k, nb, 0, grid)))
         # full-height panel solve: rhs rows are independent in the
         # right-side solve, so the dead rows cost only masked FLOPs
         pan = _chol_panel_solve(lkk, colblk, grid, precision)
@@ -354,13 +434,11 @@ def cholesky_scan(a: jax.Array, nb: int, precision=_HI,
         upd = jnp.matmul(pan, jnp.conj(pan.T), precision=precision)
         a = constrain(a - upd, grid)
         # write the factored column block: L_kk on the diagonal, the
-        # panel below, existing content above
-        newblk = jnp.where((rows >= k1)[:, None], pan, 0)
-        newblk = jax.lax.dynamic_update_slice(newblk, lkk, (k0, 0))
-        keep = (rows < k0)[:, None]
-        cur = jax.lax.dynamic_slice(a, (0, k0), (n, nb))
-        newblk = jnp.where(keep, cur, newblk)
-        return jax.lax.dynamic_update_slice(a, newblk, (0, k0))
+        # panel below, existing content above (the update is zero in
+        # this block's columns, so `colblk` still holds it)
+        newblk = _put_block(pan, lkk, k, nb, 0, grid)
+        newblk = jnp.where((rows < k0)[:, None], colblk, newblk)
+        return _put_block(a, newblk, k, nb, 1, grid)
 
     return jax.lax.fori_loop(0, nt, step, a)
 
@@ -382,7 +460,7 @@ def cholesky_blocked(a: jax.Array, nb: int,
     order. The huge-nt scan form has a fixed one-step body and ignores
     the knob (its fori_loop carries no cross-step independence to
     exploit)."""
-    if ceil_div(a.shape[0], nb) > CHOL_SCAN_THRESHOLD:
+    if chol_form(a.shape[0], nb) == "scan":
         return cholesky_scan(a, nb, precision, grid)
 
     def diag_factor(s):

@@ -14,6 +14,7 @@ broadcasts the reference hand-codes as tileBcast.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,7 @@ from ..core.methods import MethodFactor
 from ..core.options import (Option, OptionsLike, get_option,
                             get_option_tuned)
 from ..core.tiles import TiledMatrix, ceil_div, pad_diag_identity
+from ..obs import events as obs_events
 from ..obs.events import instrument_driver
 from .blas3 import trsm
 
@@ -39,6 +41,46 @@ def _chol_blocked(a: jax.Array, nb: int,
     from .blocked import cholesky_blocked
     return cholesky_blocked(a, nb, precision=precision, grid=grid,
                             lookahead=lookahead)
+
+
+def _potrf_prep(A: TiledMatrix, r: TiledMatrix, nb: int,
+                fast: bool) -> jax.Array:
+    """The (np_, np_) array potrf factors, np_ a multiple of nb with an
+    identity on the padded diagonal: A's stored triangle as it is
+    (`fast`; transposed for Upper), or its mirrored logical matrix."""
+    if fast:
+        a = r.data if r.uplo is Uplo.Lower else jnp.conj(r.data.T)
+        return pad_diag_identity(a, r.n, r.n)
+    np_ = ceil_div(max(r.n, 1), nb) * nb
+    full = A.to_dense()                  # mirrored logical matrix
+    a = jnp.pad(full, ((0, np_ - r.m), (0, np_ - r.n)))
+    return pad_diag_identity(a, r.m, r.n)
+
+
+@functools.lru_cache(maxsize=None)
+def _grid_potrf_programs(grid):
+    """`_potrf_prep` and `_chol_blocked` as compiled programs whose
+    results stay on `grid`; jit keys them by the matrix's shape, dtype
+    and structure and by the blocking."""
+    from ..parallel.sharding import constrain
+
+    def prep(A, r, nb, fast):
+        return constrain(_potrf_prep(A, r, nb, fast), grid)
+
+    def tiled(a, nb, lookahead):
+        return constrain(_chol_blocked(a, nb, grid=grid,
+                                       lookahead=lookahead), grid)
+
+    return (jax.jit(prep, static_argnums=(2, 3)),
+            jax.jit(tiled, static_argnums=(1,),
+                    static_argnames=("lookahead",)))
+
+
+@functools.lru_cache(maxsize=None)
+def _grid_potrs_program(grid):
+    """`potrs` on `grid` as one compiled program (two sweeps of some
+    hundreds of block steps between them when dispatched eagerly)."""
+    return jax.jit(lambda A, B: _potrs(A, B, {Option.Grid: grid}))
 
 
 @instrument_driver("potrf")
@@ -81,41 +123,59 @@ def potrf(A: TiledMatrix, opts: OptionsLike = None,
     # square padded storage, multiple of nb; output uses mb = nb so the
     # factor's tile geometry is self-consistent even if input mb != nb
     np_ = ceil_div(max(r.n, 1), nb) * nb
-    if method is MethodFactor.Fused and not return_info \
-            and r.data.shape == (np_, np_) and r.mb == nb \
-            and A.mtype is not MatrixType.HermitianBand:
-        # fast prep: the factorization only ever reads the stored
-        # triangle, so skip the Hermitian mirror (a transpose pass over
-        # the whole matrix) and hand the raw padded storage — lower for
-        # Lower, transposed storage for Upper — straight to the kernel
-        a = r.data if r.uplo is Uplo.Lower else jnp.conj(r.data.T)
-        a = pad_diag_identity(a, r.n, r.n)
+    # fast prep: neither XLA's kernel nor the blocked loops read past
+    # the stored triangle, so skip the Hermitian mirror (a transpose
+    # pass over the whole matrix; across a mesh an all-to-all and a
+    # second copy of it) and hand the raw padded storage — lower for
+    # Lower, transposed storage for Upper — straight to the
+    # factorization
+    fast = (not return_info and r.data.shape == (np_, np_)
+            and r.mb == nb and A.mtype is not MatrixType.HermitianBand)
+    lookahead = None
+    if return_info or method is not MethodFactor.Fused:
+        lookahead = get_option_tuned(opts, Option.Lookahead, "potrf",
+                                     n=r.n, dtype=r.dtype)
+    if obs_events.enabled():
+        from .blocked import chol_form
+        obs_events.note(
+            factor=method.value, nb=nb, nt=np_ // nb,
+            form=("native" if method is MethodFactor.Fused
+                  and not return_info
+                  else chol_form(np_, nb, guarded=return_info)),
+            grid="1x1" if grid is None else "%dx%d" % (grid.p, grid.q))
+    if grid is not None and not return_info:
+        # across a mesh the prep and the factorization are one compiled
+        # program each: dispatched eagerly, every mask and slice of
+        # the steps is an array and a launch of its own, and some are
+        # whole matrices on one device
+        prep, tiled = _grid_potrf_programs(grid)
     else:
-        full = A.to_dense()                  # mirrored logical matrix
-        a = jnp.pad(full, ((0, np_ - r.m), (0, np_ - r.n)))
-        a = pad_diag_identity(a, r.m, r.n)
+        prep, tiled = _potrf_prep, functools.partial(_chol_blocked,
+                                                     grid=grid)
+    with obs_events.span("posv::prep", cat="step"):
+        if fast and r.uplo is Uplo.Lower and np_ == r.n:
+            a = r.data      # read as stored: nothing to prepare
+        else:
+            a = prep(A, r, nb, fast)
     info = None
-    if return_info:
-        # guarded tiled path: survives non-SPD input and reports the
-        # exact first failed leading-minor index (XLA's native cholesky
-        # NaNs the whole output on CPU, so its NaN pattern cannot
-        # reconstruct LAPACK's info)
-        from .info import cholesky_blocked_info
-        L, info = cholesky_blocked_info(
-            a, nb, grid,
-            lookahead=get_option_tuned(opts, Option.Lookahead,
-                                       "potrf", n=r.n, dtype=r.dtype))
-    elif method is MethodFactor.Fused:
-        # single fused XLA program — the fastest single-device path
-        # (the reference's Target::Devices switch, potrf.cc:262-277);
-        # symmetrize_input=False skips a whole-matrix transpose pass (the
-        # kernel reads only the lower triangle, like LAPACK potrf)
-        L = jax.lax.linalg.cholesky(a, symmetrize_input=False)
-    else:
-        L = _chol_blocked(
-            a, nb, grid=grid,
-            lookahead=get_option_tuned(opts, Option.Lookahead,
-                                       "potrf", n=r.n, dtype=r.dtype))
+    with obs_events.span("posv::factor", cat="step"):
+        if return_info:
+            # guarded tiled path: survives non-SPD input and reports
+            # the exact first failed leading-minor index (XLA's native
+            # cholesky NaNs the whole output on CPU, so its NaN pattern
+            # cannot reconstruct LAPACK's info)
+            from .info import cholesky_blocked_info
+            L, info = cholesky_blocked_info(a, nb, grid,
+                                            lookahead=lookahead)
+        elif method is MethodFactor.Fused:
+            # single fused XLA program — the fastest single-device path
+            # (the reference's Target::Devices switch,
+            # potrf.cc:262-277); symmetrize_input=False skips a
+            # whole-matrix transpose pass (the kernel reads only the
+            # lower triangle, like LAPACK potrf)
+            L = jax.lax.linalg.cholesky(a, symmetrize_input=False)
+        else:
+            L = tiled(a, nb, lookahead=lookahead)
     if r.uplo is Uplo.Upper:
         data = jnp.conj(L.T)
     else:
@@ -132,10 +192,20 @@ def potrf(A: TiledMatrix, opts: OptionsLike = None,
     return out
 
 
+@instrument_driver("potrs")
 def potrs(A: TiledMatrix, B: TiledMatrix,
           opts: OptionsLike = None) -> TiledMatrix:
     """Solve using the factor from potrf (reference src/potrs.cc:75-77:
-    two triangular solves)."""
+    two triangular solves). Under Option.Grid the two are one compiled
+    program; `trsm` reads no other option."""
+    grid = get_option(opts, Option.Grid, None)
+    if grid is not None:
+        return _grid_potrs_program(grid)(A, B)
+    return _potrs(A, B, opts)
+
+
+def _potrs(A: TiledMatrix, B: TiledMatrix,
+           opts: OptionsLike = None) -> TiledMatrix:
     if A.uplo is Uplo.Lower:
         X = trsm(Side.Left, 1.0, A, B, opts)            # L y = b
         X = trsm(Side.Left, 1.0, A.conj_transpose(), X, opts)  # L^H x = y
@@ -167,7 +237,7 @@ def posv(A: TiledMatrix, B: TiledMatrix, opts: OptionsLike = None,
         return L, dataclasses.replace(meta, data=data), info
     with ph("posv::potrf"):
         L = potrf(A, opts)
-    with ph("posv::potrs"):
+    with ph("posv::potrs"), obs_events.span("posv::solve", cat="step"):
         X = potrs(L, B, opts)
     return L, X
 

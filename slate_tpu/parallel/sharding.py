@@ -33,6 +33,7 @@ Two TPU-native mechanisms replace it:
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -111,26 +112,20 @@ def undistribute(A: TiledMatrix, grid: ProcessGrid) -> TiledMatrix:
 
 # -- constraint helpers used by the Tiled driver paths --------------------
 
-def constrain(x: jax.Array, grid: Optional[ProcessGrid],
-              spec: Optional[P] = None) -> jax.Array:
-    """with_sharding_constraint when a grid is present, identity
-    otherwise — lets the blocked drivers be grid-agnostic.
-
-    Mesh axes that do not divide the corresponding dimension are
-    dropped from the spec (XLA requires divisibility): a ragged RHS
-    (say 10 columns on a q=4 grid) keeps its row sharding and
-    replicates over 'q' instead of erroring — the balance degrades
-    gracefully exactly where the reference's block-cyclic assignment
-    would leave partial tiles."""
-    if grid is None:
-        return x
+def fitted_sharding(shape: Tuple[int, ...], grid: ProcessGrid,
+                    spec: Optional[P] = None) -> NamedSharding:
+    """`spec` (default P('p','q')) on `grid` for an array of `shape`,
+    with every mesh axis that does not divide its dimension dropped
+    (XLA requires divisibility): a ragged RHS (say 10 columns on a q=4
+    grid) keeps its row sharding and replicates over 'q' instead of
+    erroring — the balance degrades gracefully exactly where the
+    reference's block-cyclic assignment would leave partial tiles."""
     if spec is None:
         spec = P("p", "q")
     sizes = dict(grid.mesh.shape)
-    entries = list(spec) + [None] * (x.ndim - len(spec))
+    entries = list(spec) + [None] * (len(shape) - len(spec))
     fixed = []
-    for dim in range(x.ndim):
-        e = entries[dim]
+    for dim, e in zip(shape, entries):
         if e is None:
             fixed.append(None)
             continue
@@ -138,9 +133,61 @@ def constrain(x: jax.Array, grid: Optional[ProcessGrid],
         prod = 1
         for nm in names:
             prod *= sizes[nm]
-        fixed.append(e if x.shape[dim] % prod == 0 else None)
+        fixed.append(e if dim % prod == 0 else None)
+    return NamedSharding(grid.mesh, P(*fixed))
+
+
+def constrain(x: jax.Array, grid: Optional[ProcessGrid],
+              spec: Optional[P] = None) -> jax.Array:
+    """with_sharding_constraint when a grid is present, identity
+    otherwise — lets the blocked drivers be grid-agnostic. The spec is
+    fitted to x's shape (`fitted_sharding`)."""
+    if grid is None:
+        return x
     return jax.lax.with_sharding_constraint(
-        x, NamedSharding(grid.mesh, P(*fixed)))
+        x, fitted_sharding(x.shape, grid, spec))
+
+
+@functools.lru_cache(maxsize=None)
+def _pad_program(grid: ProcessGrid):
+    """Zero-pad an array on `grid` up to a (static) shape, result on
+    `grid`: one jitted function a grid."""
+    def pad(x, shape):
+        return constrain(jnp.pad(x, [(0, t - s) for s, t
+                                     in zip(x.shape, shape)]), grid)
+    return jax.jit(pad, static_argnums=1)
+
+
+def place(a, grid: ProcessGrid,
+          shape: Optional[Tuple[int, ...]] = None) -> jax.Array:
+    """`a` on `grid` as P('p','q'), zero-padded up to `shape`; what the
+    matrix constructors' ``grid=`` argument calls. Each device is sent
+    its own block of `a` (from host memory when `a` is a numpy array:
+    the whole is never on one chip; the padding is added on the mesh),
+    and the call returns when every shard is there.
+
+    With the obs bus on, the placement is the span `grid::place`
+    (bytes, devices; open until the shards are ready); a host array's
+    hand-over is `matrix::h2d` inside it, and the bytes handed to the
+    devices add to the counter `grid.h2d_bytes`: `a.nbytes` when the
+    grid's axes divide a's dimensions, more where a block is sent to
+    several devices."""
+    from ..obs import events as obs_events, metrics as obs_metrics
+    sharding = fitted_sharding(a.shape, grid)
+    with obs_events.span("grid::place", cat="staging",
+                         bytes=int(a.nbytes), devices=grid.nprocs):
+        if obs_events.enabled() and isinstance(a, np.ndarray):
+            with obs_events.span("matrix::h2d", cat="staging",
+                                 bytes=int(a.nbytes),
+                                 devices=grid.nprocs):
+                out = jax.device_put(a, sharding)
+            obs_metrics.inc("grid.h2d_bytes", sum(
+                s.data.nbytes for s in out.addressable_shards))
+        else:
+            out = jax.device_put(a, sharding)
+        if shape is not None and tuple(shape) != out.shape:
+            out = _pad_program(grid)(out, tuple(shape))
+        return jax.block_until_ready(out)
 
 
 def panel_spec() -> P:

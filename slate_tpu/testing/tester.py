@@ -71,12 +71,9 @@ def _mk_band(a, kd):
 def run_one(routine: str, n: int, dtype, nb: int, check: bool,
             ref: bool, seed: int = 42, grid=None) -> Dict:
     """Run one (routine, n, dtype, nb[, grid]) config. With a
-    ProcessGrid, inputs are device_put on the mesh and the drivers get
-    Option.Grid + MethodFactor.Tiled — the reference tester's `-p -q`
-    grid sweep (test.cc:685)."""
-    import dataclasses as _dc
-
-    import jax
+    ProcessGrid, inputs are built on the mesh (the constructors'
+    ``grid=``) and the drivers get Option.Grid + MethodFactor.Tiled —
+    the reference tester's `-p -q` grid sweep (test.cc:685)."""
     import slate_tpu as st
     from slate_tpu.core.methods import MethodFactor
     from slate_tpu.core.options import Option
@@ -85,12 +82,6 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
     if grid is not None:
         opts = {Option.Grid: grid, Option.MethodFactor:
                 MethodFactor.Tiled}
-
-    def place(M):
-        if grid is None:
-            return M
-        return _dc.replace(
-            M, data=jax.device_put(M.data, grid.matrix_sharding()))
 
     rng = np.random.default_rng(seed)
     real = np.float64 if dtype in (np.float64, np.complex128) \
@@ -112,9 +103,9 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
     err = None
     if routine == "gemm":
         a, b, c = mk((n, n)), mk((n, n)), mk((n, n))
-        C = st.gemm(1.0, place(st.Matrix(a, mb=nb)),
-                    place(st.Matrix(b, mb=nb)),
-                    0.0, place(st.Matrix(c, mb=nb)), opts)
+        C = st.gemm(1.0, st.Matrix(a, mb=nb, grid=grid),
+                    st.Matrix(b, mb=nb, grid=grid),
+                    0.0, st.Matrix(c, mb=nb, grid=grid), opts)
         out = C.to_numpy()
         t = time.perf_counter() - t0
         if check:
@@ -127,7 +118,7 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
                 np.linalg.norm(a @ b) * n * eps + 1e-300)
     elif routine in ("potrf", "posv"):
         a = mk((n, n), spd=True)
-        A = place(st.HermitianMatrix(st.Uplo.Lower, a, mb=nb))
+        A = st.HermitianMatrix(st.Uplo.Lower, a, mb=nb, grid=grid)
         if routine == "potrf":
             L = st.potrf(A, opts)
             out = L.to_numpy()
@@ -141,7 +132,7 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
                     np.linalg.norm(lref) * n * eps + 1e-300)
         else:
             b = mk((n, nrhs))
-            _, X = st.posv(A, place(st.Matrix(b, mb=nb)), opts)
+            _, X = st.posv(A, st.Matrix(b, mb=nb, grid=grid), opts)
             x = X.to_numpy()
             t = time.perf_counter() - t0
             if check:
@@ -155,7 +146,7 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
     elif routine in ("getrf", "gesv"):
         a = mk((n, n))
         if routine == "getrf":
-            F = st.getrf(place(st.Matrix(a, mb=nb)), opts)
+            F = st.getrf(st.Matrix(a, mb=nb, grid=grid), opts)
             out = F.LU.to_numpy()
             t = time.perf_counter() - t0
             if check:
@@ -178,15 +169,15 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
                 import scipy.linalg as _sla
                 b = mk((n, nrhs))
                 xr = _sla.lu_solve(_sla.lu_factor(a), b)
-                x = st.getrs(F, place(st.Matrix(b, mb=nb)),
+                x = st.getrs(F, st.Matrix(b, mb=nb, grid=grid),
                              opts).to_numpy()
                 err = np.linalg.norm(x - xr) / (
                     np.linalg.norm(xr) * n * eps
                     * max(np.linalg.cond(a), 1.0) + 1e-300)
         else:
             b = mk((n, nrhs))
-            _, X = st.gesv(place(st.Matrix(a, mb=nb)),
-                           place(st.Matrix(b, mb=nb)), opts)
+            _, X = st.gesv(st.Matrix(a, mb=nb, grid=grid),
+                           st.Matrix(b, mb=nb, grid=grid), opts)
             x = X.to_numpy()
             t = time.perf_counter() - t0
             if check:
@@ -201,7 +192,7 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
         m2 = n
         a = mk((m2, n))
         if routine == "geqrf":
-            F = st.geqrf(place(st.Matrix(a, mb=nb)), opts)
+            F = st.geqrf(st.Matrix(a, mb=nb, grid=grid), opts)
             t = time.perf_counter() - t0
             if check:
                 R = np.triu(F.QR.to_numpy())
@@ -213,8 +204,8 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
                     np.linalg.norm(a) * n * eps)
         else:
             b = mk((m2, nrhs))
-            X = st.gels(place(st.Matrix(a, mb=nb)),
-                        place(st.Matrix(b, mb=nb)), opts)
+            X = st.gels(st.Matrix(a, mb=nb, grid=grid),
+                        st.Matrix(b, mb=nb, grid=grid), opts)
             x = X.to_numpy()[:n]
             t = time.perf_counter() - t0
             if check:
@@ -229,7 +220,7 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
                     * max(np.linalg.cond(a), 1.0))
     elif routine == "heev":
         a = mk((n, n), herm=True)
-        A = place(st.HermitianMatrix(st.Uplo.Lower, a, mb=nb))
+        A = st.HermitianMatrix(st.Uplo.Lower, a, mb=nb, grid=grid)
         w, V = st.heev(A, opts)
         t = time.perf_counter() - t0
         if check:
@@ -242,7 +233,7 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
                 np.linalg.norm(wr) * n * eps + 1e-300)
     elif routine == "svd":
         a = mk((n, n))
-        s, U, Vh = st.svd(place(st.Matrix(a, mb=nb)), opts)
+        s, U, Vh = st.svd(st.Matrix(a, mb=nb, grid=grid), opts)
         t = time.perf_counter() - t0
         if check:
             rec = (U.to_numpy() * np.asarray(s)[None, :]) @ Vh.to_numpy()
@@ -254,8 +245,8 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
     elif routine == "hesv":
         a = mk((n, n), herm=True)        # indefinite
         b = mk((n, nrhs))
-        A = place(st.HermitianMatrix(st.Uplo.Lower, a, mb=nb))
-        _, X = st.hesv(A, place(st.Matrix(b, mb=nb)), opts)
+        A = st.HermitianMatrix(st.Uplo.Lower, a, mb=nb, grid=grid)
+        _, X = st.hesv(A, st.Matrix(b, mb=nb, grid=grid), opts)
         x = X.to_numpy()
         t = time.perf_counter() - t0
         if check:
@@ -272,15 +263,15 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
         if routine == "pbsv":
             a = ((a + a.conj().T) / 2
                  + 4 * np.sqrt(n) * np.eye(n)).astype(dtype)
-            A = place(st.HermitianBandMatrix(st.Uplo.Lower, kd, a,
-                                             mb=nb))
+            A = st.HermitianBandMatrix(st.Uplo.Lower, kd, a, mb=nb,
+                                       grid=grid)
             solve = st.pbsv
         else:
             a = (a + 4 * np.eye(n, dtype=dtype)).astype(dtype)
-            A = place(st.BandMatrix(kd, kd, a, mb=nb))
+            A = st.BandMatrix(kd, kd, a, mb=nb, grid=grid)
             solve = st.gbsv
         b = mk((n, nrhs))
-        _, X = solve(A, place(st.Matrix(b, mb=nb)), opts)
+        _, X = solve(A, st.Matrix(b, mb=nb, grid=grid), opts)
         x = X.to_numpy()
         t = time.perf_counter() - t0
         if check:
@@ -309,9 +300,9 @@ def run_one(routine: str, n: int, dtype, nb: int, check: bool,
         kd = max(min(nb // 2, n // 4), 1)
         a = _mk_band(mk((n, n)), kd).astype(dtype)
         b = mk((n, n))
-        A = place(st.BandMatrix(kd, kd, a, mb=nb))
-        C = st.gbmm(1.0, A, place(st.Matrix(b, mb=nb)), 0.0,
-                    place(st.Matrix(np.zeros_like(b), mb=nb)), opts)
+        A = st.BandMatrix(kd, kd, a, mb=nb, grid=grid)
+        C = st.gbmm(1.0, A, st.Matrix(b, mb=nb, grid=grid), 0.0,
+                    st.Matrix(np.zeros_like(b), mb=nb, grid=grid), opts)
         out = C.to_numpy()
         t = time.perf_counter() - t0
         if check or ref:

@@ -22,7 +22,7 @@ from slate_tpu.core.methods import MethodFactor
 from slate_tpu.core.options import Option
 from slate_tpu.parallel.sharding import (cyclic_tile_order,
                                          distribute_cyclic, from_cyclic,
-                                         to_cyclic, undistribute)
+                                         place, to_cyclic, undistribute)
 
 
 def dist_opts(grid):
@@ -30,8 +30,7 @@ def dist_opts(grid):
 
 
 def shard(grid, A):
-    return dataclasses.replace(
-        A, data=jax.device_put(A.data, grid.matrix_sharding()))
+    return dataclasses.replace(A, data=place(A.data, grid))
 
 
 def spd(rng, n):
