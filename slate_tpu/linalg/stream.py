@@ -69,10 +69,12 @@ module's behavior bit-identical.
 Host staging (PR 26): a panel that has to be made contiguous before
 the runtime can take it (every column slice of the C-ordered operand
 or factor) is packed into one of a small, process-wide ring of host
-buffers that are reused from panel to panel, engine to engine and
-call to call, never into a fresh array: on the v5e host the first
-touch of freshly mapped pages, not the strided read, was 94% of a
-streamed solve's staging time (PERF.md, PR 26). The ring holds at
+buffers (the class is core/staging.py's ``StageRing``, which the
+mesh's placement uses too) that are reused from panel to panel,
+engine to engine and call to call, never into a fresh array: on the
+v5e host the first touch of freshly mapped pages, not the strided
+read, was 94% of a streamed solve's staging time (PERF.md, PR 26).
+The ring holds at
 most ``prefetch_depth + 2`` slots of the deepest engine seen (3 at
 the frozen depth of 1), each as large as the largest panel staged
 through it and kept for the life of the process: 1.6 GB of host
@@ -110,6 +112,8 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from ..core.staging import (StageRing as _StageRing,
+                            aliases_host as _aliases_host)
 from ..core.tiles import ceil_div
 from ..obs import events as obs_events
 from ..obs import ledger as _ledger
@@ -141,112 +145,9 @@ WRITES_IN_FLIGHT = 2
 _last_stats: Dict[str, Any] = {}
 
 
-class _StageSlot:
-    """One reused host staging buffer of the ring below."""
-
-    __slots__ = ("buf", "last", "busy")
-
-    def __init__(self) -> None:
-        self.buf: Optional[np.ndarray] = None   # flat uint8
-        self.last: Any = None     # device array last made from buf
-        self.busy = False         # a thread is packing into buf
-
-
-class _StageRing:
-    """The process-wide ring of reused host staging buffers behind
-    ``_h2d`` (module doc, "Host staging"). ``acquire`` hands out a
-    slot whose last transfer is over, ``release`` takes it back with
-    the device array just made from it. The lock covers the
-    bookkeeping only, never a copy or a wait for the device."""
-
-    def __init__(self, slots: int = 2) -> None:
-        self._cv = threading.Condition()
-        #: least recently released first: of the free slots the head
-        #: is the one whose transfer has had the longest to finish
-        self._slots: list = []
-        self._cap = int(slots)
-
-    def reserve(self, slots: int) -> None:
-        """Allow up to `slots` slots: an engine asks for its
-        ``prefetch_depth + 2`` (one being packed per staging thread,
-        one per transfer in flight); the deepest engine seen wins."""
-        with self._cv:
-            self._cap = max(self._cap, int(slots))
-
-    def _sweep(self) -> None:
-        # under the lock: forget every device array whose transfer is
-        # over, so the ring never keeps a consumed panel alive in HBM
-        for s in self._slots:
-            if s.last is not None and s.last.is_ready():
-                s.last = None
-
-    def sweep(self) -> None:
-        with self._cv:
-            self._sweep()
-
-    def acquire(self, nbytes: int) -> Tuple[_StageSlot, bool]:
-        """A slot of at least `nbytes` that nothing reads any more,
-        and whether its pages were touched before (False for a new or
-        regrown buffer). Blocks, under ``ooc::wait_ring``, while every
-        slot is being packed by another thread or while the chosen
-        slot's last transfer is not ready: the runtime may read a
-        staging buffer until then."""
-        with self._cv:
-            while True:
-                self._sweep()
-                free = [s for s in self._slots if not s.busy]
-                slot = next((s for s in free if s.last is None), None)
-                if slot is None and len(self._slots) < self._cap:
-                    slot = _StageSlot()
-                    self._slots.append(slot)
-                if slot is None and free:
-                    slot = free[0]
-                if slot is not None:
-                    slot.busy = True
-                    last, slot.last = slot.last, None
-                    break
-                with obs_events.span("ooc::wait_ring", cat="staging",
-                                     on="slot"):
-                    self._cv.wait()
-        try:
-            if last is not None:
-                with obs_events.span("ooc::wait_ring", cat="staging",
-                                     on="transfer"):
-                    last.block_until_ready()
-            reused = slot.buf is not None and slot.buf.nbytes >= nbytes
-            if not reused:
-                slot.buf = np.empty(nbytes, np.uint8)
-        except BaseException:
-            self.release(slot, None)    # a failed transfer, no memory
-            raise
-        return slot, reused
-
-    def release(self, slot: _StageSlot, arr) -> None:
-        """Hand `slot` back; `arr` is the device array made from it
-        (None when the staging failed), whose readiness the next
-        ``acquire`` of this slot waits for."""
-        with self._cv:
-            slot.last = arr
-            slot.busy = False
-            self._slots.remove(slot)
-            self._slots.append(slot)
-            self._sweep()
-            self._cv.notify()
-
-
 #: one ring for the process: posv_ooc builds two engines a solve, and
 #: a ring per engine would first-touch its slots again each time
-_ring = _StageRing()
-
-
-def _aliases_host() -> bool:
-    """Whether ``jnp.asarray`` of a host buffer may hand back a device
-    array that IS that buffer (the CPU backend's zero-copy put): a
-    recycled staging slot would then rewrite an earlier panel, so
-    ``_h2d`` makes the backend copy there."""
-    dev = jax.config.jax_default_device
-    platform = getattr(dev, "platform", dev) or jax.default_backend()
-    return platform == "cpu"
+_ring = _StageRing("ooc")
 
 
 def _h2d(x: np.ndarray) -> jax.Array:

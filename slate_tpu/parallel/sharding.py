@@ -33,6 +33,7 @@ Two TPU-native mechanisms replace it:
 
 from __future__ import annotations
 
+import concurrent.futures as cf
 import functools
 from typing import Optional, Tuple
 
@@ -41,8 +42,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..core.staging import StageRing, aliases_host
 from ..core.tiles import TiledMatrix, ceil_div
 from .mesh import ProcessGrid
+from .smap import shard_map
 
 
 def cyclic_tile_order(nt: int, p: int) -> np.ndarray:
@@ -158,6 +161,94 @@ def _pad_program(grid: ProcessGrid):
     return jax.jit(pad, static_argnums=1)
 
 
+#: bytes a staged chunk of a strided block aims at; the chunk height
+#: follows from it and the block (`_chunk_rows`). With two slots a
+#: chip (one being packed, one in flight) the ring holds 2.1 GB of
+#: host memory on a 2x2 grid, whatever the matrix
+STAGE_CHUNK_BYTES = 256 << 20
+
+#: the mesh's ring of reused host staging buffers (core/staging.py)
+_ring = StageRing("grid")
+
+
+def _chunk_rows(block: np.ndarray) -> int:
+    """Rows of one staged chunk of `block`: the block cut into the
+    fewest equal row chunks of at most `STAGE_CHUNK_BYTES`, the height
+    rounded up to the 8 sublanes of a device tile."""
+    m = block.shape[0]
+    chunks = max(1, ceil_div(block.nbytes, STAGE_CHUNK_BYTES))
+    return min(m, ceil_div(ceil_div(m, chunks), 8) * 8)
+
+
+def _send_block(block: np.ndarray, dev) -> list:
+    """`block` of a host array on `dev`, as the row chunks it was sent
+    in (one for a contiguous block, which the runtime takes as it is;
+    a strided one goes through the ring, chunk by chunk, and the pack
+    of a chunk runs under the transfer of the one before). Does not
+    wait for the transfers: the ring does, before it rewrites a
+    slot."""
+    from ..obs import events as obs_events, metrics as obs_metrics
+    if block.flags.c_contiguous:
+        with obs_events.span("grid::put", cat="shard", device=dev.id):
+            return [jax.device_put(block, dev)]
+    copies, rows, out = aliases_host(dev), _chunk_rows(block), []
+    for r0 in range(0, block.shape[0], rows):
+        x = block[r0:r0 + rows]
+        nbytes = int(x.nbytes)
+        slot, reused = _ring.acquire(nbytes)
+        arr = None
+        try:
+            with obs_events.span("grid::pack", cat="shard",
+                                 bytes=nbytes, device=dev.id):
+                packed = slot.buf[:nbytes].view(x.dtype).reshape(x.shape)
+                np.copyto(packed, x)
+            with obs_events.span("grid::put", cat="shard",
+                                 device=dev.id):
+                arr = jax.device_put(
+                    np.array(packed) if copies else packed, dev)
+        finally:
+            _ring.release(slot, arr)
+        obs_metrics.inc("grid.stage_reuse_bytes" if reused
+                        else "grid.stage_fresh_bytes", nbytes)
+        out.append(arr)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _assemble_program(sharding: NamedSharding):
+    """Each device's row chunks into its block, all devices in one
+    program and nothing crossing between them: one jitted function a
+    sharding (and one compiled program a shape and chunk count)."""
+    def blocks(chunks):
+        return jnp.concatenate(chunks, axis=0)
+    return jax.jit(shard_map(blocks, sharding.mesh, (sharding.spec,),
+                             sharding.spec), out_shardings=sharding)
+
+
+def _place_host(a: np.ndarray, sharding: NamedSharding) -> jax.Array:
+    """The host array `a` under `sharding`, each addressable device
+    sent its own block (`_send_block`), the devices side by side."""
+    devs, where = zip(
+        *sharding.addressable_devices_indices_map(a.shape).items())
+    blocks = [np.asarray(a[idx]) for idx in where]
+    if len(blocks) == 1 or all(b.flags.c_contiguous for b in blocks):
+        sent = list(map(_send_block, blocks, devs))
+    else:
+        _ring.reserve(2 * len(blocks))
+        with cf.ThreadPoolExecutor(len(blocks), "grid-place") as pool:
+            sent = list(pool.map(_send_block, blocks, devs))
+    if len(sent[0]) == 1:
+        return jax.make_array_from_single_device_arrays(
+            a.shape, sharding, [chunks[0] for chunks in sent])
+    # chunk k of every device as one array spread like the whole, so
+    # that one program makes every device's block from its own chunks
+    over = a.shape[0] // blocks[0].shape[0]
+    parts = [jax.make_array_from_single_device_arrays(
+        (over * ck[0].shape[0],) + a.shape[1:], sharding, list(ck))
+        for ck in zip(*sent, strict=True)]
+    return _assemble_program(sharding)(parts)
+
+
 def place(a, grid: ProcessGrid,
           shape: Optional[Tuple[int, ...]] = None) -> jax.Array:
     """`a` on `grid` as P('p','q'), zero-padded up to `shape`; what the
@@ -166,28 +257,55 @@ def place(a, grid: ProcessGrid,
     the whole is never on one chip; the padding is added on the mesh),
     and the call returns when every shard is there.
 
+    How a host array's blocks travel (PR 28). Handed whole to
+    ``jax.device_put(a, sharding)``, the runtime linearizes each chip's
+    strided block itself, into fresh pages of its own: four 2.4 GB
+    blocks of a 49152 x 49152 f32 matrix reached a 2x2 grid at 1.8
+    GB/s together (ledger, PR 27), while a contiguous chunk from a
+    reused, already touched host buffer reaches one chip at the link's
+    speed (tools/place_probe.py; PERF.md, PR 28). So a block that is
+    C-contiguous (a q = 1 grid's row blocks, a 1 x 1 grid, a vector)
+    goes to its device as it is, and a strided one is cut into row
+    chunks of about `STAGE_CHUNK_BYTES`, each copied into a slot of
+    the mesh's ring of reused host buffers (core/staging.py, the stream
+    engine's class) and handed over from there, a thread a device, the
+    pack of one chunk under the transfer of the one before. One
+    program then makes each device's block from its chunks, on the
+    device. The result is bitwise what ``device_put`` gives, under the
+    same sharding. Host memory: two slots a device. Device memory: a
+    block and its chunks, until the block is made. A device array goes
+    as before.
+
     With the obs bus on, the placement is the span `grid::place`
     (bytes, devices; open until the shards are ready); a host array's
-    hand-over is `matrix::h2d` inside it, and the bytes handed to the
+    hand-overs are `matrix::h2d` inside it, and in that each chunk's
+    copy is `grid::pack` (bytes, device) and each hand-over `grid::put`
+    (device), on the device's staging thread. The bytes handed to the
     devices add to the counter `grid.h2d_bytes`: `a.nbytes` when the
     grid's axes divide a's dimensions, more where a block is sent to
-    several devices."""
+    several devices. The bytes copied into a touched slot add to
+    `grid.stage_reuse_bytes`, those into a slot's first or regrown
+    buffer to `grid.stage_fresh_bytes`; contiguous blocks are in
+    neither."""
     from ..obs import events as obs_events, metrics as obs_metrics
     sharding = fitted_sharding(a.shape, grid)
     with obs_events.span("grid::place", cat="staging",
                          bytes=int(a.nbytes), devices=grid.nprocs):
-        if obs_events.enabled() and isinstance(a, np.ndarray):
+        if not isinstance(a, np.ndarray):
+            out = jax.device_put(a, sharding)
+        else:
             with obs_events.span("matrix::h2d", cat="staging",
                                  bytes=int(a.nbytes),
                                  devices=grid.nprocs):
-                out = jax.device_put(a, sharding)
-            obs_metrics.inc("grid.h2d_bytes", sum(
-                s.data.nbytes for s in out.addressable_shards))
-        else:
-            out = jax.device_put(a, sharding)
+                out = _place_host(a, sharding)
+            if obs_events.enabled():
+                obs_metrics.inc("grid.h2d_bytes", sum(
+                    s.data.nbytes for s in out.addressable_shards))
         if shape is not None and tuple(shape) != out.shape:
             out = _pad_program(grid)(out, tuple(shape))
-        return jax.block_until_ready(out)
+        out = jax.block_until_ready(out)
+        _ring.sweep()       # the chunks are consumed: let them go
+        return out
 
 
 def panel_spec() -> P:

@@ -394,7 +394,7 @@ def test_engine_stats_surface():
 @pytest.fixture
 def ring(monkeypatch):
     """A ring of this test's own in place of the process-wide one."""
-    r = stream._StageRing()
+    r = stream._StageRing("ooc")
     monkeypatch.setattr(stream, "_ring", r)
     return r
 
