@@ -32,7 +32,7 @@ the run uses the backend jax finds and fails where it finds none.
 
 Flags (combinable with the default sweep unless noted): ``--micro``
 ``--tune`` ``--ooc`` ``--serve`` ``--serve-daemon`` ``--shard``
-``--faults`` ``--graph`` ``--fuse`` ``--lint``
+``--faults`` ``--graph`` ``--lint``
 run their own suites; ``--obs`` enables the observability bus for the
 whole run, ships the metrics/driver/analysis snapshot in the headline
 extras, AND runs the **regression leg** (ISSUE 14): the current run's
@@ -1714,239 +1714,6 @@ def bench_graph():
     return 0
 
 
-def bench_fuse():
-    """`--fuse`: fused visit sweeps (ISSUE 20) — visit_fuse="fused"
-    vs the FROZEN "per_panel" walk on the same problems. GATES on
-    (a) >= 60% fewer update dispatches at nt=16 (measured by the
-    ooc.visits_fused / ooc.visit_dispatches_saved coalescing
-    counters against the nt*(nt-1)/2 per-panel visit count; the
-    left-looking ladder's actual reduction is 87.5%), (b) numeric
-    agreement per op at the route's documented grade (geqrf BITWISE
-    — the fused sweep is the per-panel kernel under a scan; potrf /
-    getrf allclose — the wide GEMM reassociates; getrf pivots
-    IDENTICAL), (c) the jit cache bounded by the count-bucket
-    ladder: a same-shape rerun adds ZERO visit_fuse_compiles and
-    ZERO jit.recompiles, (d) the sharded fused route bitwise vs the
-    sharded walk with >= 95% of its wall attributed to named ledger
-    phases. Issue-loop overhead per node is REPORTED against the
-    unfused graph route (the fused graph has fewer, fatter nodes).
-    With ``--obs`` also on the command line, the regression leg
-    compares these extras against the checked-in BENCH trajectory."""
-    import numpy as np
-    from slate_tpu import obs
-    import slate_tpu as st
-    from slate_tpu.dist import shard_ooc
-    from slate_tpu.linalg import ooc
-    from slate_tpu.obs import metrics as om
-
-    obs.enable()
-    try:
-        n = int(os.environ.get("SLATE_FUSE_N", "1024"))
-    except ValueError:
-        n = 1024
-    w = max(n // 16, 32)
-    nt = (n + w - 1) // w
-    grid = st.make_grid()
-    rng = np.random.default_rng(0)
-    x = rng.standard_normal((n, n)).astype(np.float32)
-    a = x @ x.T / n + 4.0 * np.eye(n, dtype=np.float32)
-    g = x + 0.2 * n * np.eye(n, dtype=np.float32)
-    budget = 64 * n * w * 4
-    extras = {"n": n, "panel_cols": w, "nt": nt,
-              "grid": [grid.p, grid.q],
-              "cache_budget_bytes": budget}
-
-    def counters():
-        return dict(om.snapshot()["counters"])
-
-    results = {}
-
-    def run(name, fn):
-        c0 = counters()
-        t0 = time.perf_counter()
-        try:
-            out = fn()
-        except Exception as e:
-            extras["%s_error" % name] = str(e)[:160]
-            emit({"fuse": name, "error": str(e)[:160]})
-            return None
-        wall = time.perf_counter() - t0
-        c1 = counters()
-        nodes = int(c1.get("sched.nodes_issued", 0)
-                    - c0.get("sched.nodes_issued", 0))
-        over = float(c1.get("sched.issue_overhead_seconds", 0)
-                     - c0.get("sched.issue_overhead_seconds", 0))
-        rec = {"wall_s": round(wall, 4),
-               "nodes_issued": nodes,
-               "issue_overhead_s": round(over, 6),
-               "issue_overhead_per_node_us":
-                   round(1e6 * over / nodes, 3) if nodes else 0.0,
-               "visits_fused": int(c1.get("ooc.visits_fused", 0)
-                                   - c0.get("ooc.visits_fused", 0)),
-               "dispatches_saved": int(
-                   c1.get("ooc.visit_dispatches_saved", 0)
-                   - c0.get("ooc.visit_dispatches_saved", 0)),
-               "fuse_compiles": int(
-                   c1.get("ooc.visit_fuse_compiles", 0)
-                   - c0.get("ooc.visit_fuse_compiles", 0)),
-               "jit_recompiles": int(c1.get("jit.recompiles", 0)
-                                     - c0.get("jit.recompiles", 0))}
-        extras[name] = rec
-        emit(dict({"fuse": name}, **rec))
-        results[name] = out
-        return out
-
-    run("potrf_per_panel",
-        lambda: ooc.potrf_ooc(a, panel_cols=w,
-                              cache_budget_bytes=budget,
-                              visit_fuse="per_panel"))
-    run("potrf_fused",
-        lambda: ooc.potrf_ooc(a, panel_cols=w,
-                              cache_budget_bytes=budget,
-                              visit_fuse="fused"))
-    run("geqrf_per_panel",
-        lambda: ooc.geqrf_ooc(g, panel_cols=w,
-                              cache_budget_bytes=budget,
-                              visit_fuse="per_panel"))
-    run("geqrf_fused",
-        lambda: ooc.geqrf_ooc(g, panel_cols=w,
-                              cache_budget_bytes=budget,
-                              visit_fuse="fused"))
-    run("getrf_per_panel",
-        lambda: ooc.getrf_tntpiv_ooc(g, panel_cols=w,
-                                     cache_budget_bytes=budget,
-                                     visit_fuse="per_panel"))
-    run("getrf_fused",
-        lambda: ooc.getrf_tntpiv_ooc(g, panel_cols=w,
-                                     cache_budget_bytes=budget,
-                                     visit_fuse="fused"))
-
-    ok = True
-    # (b) numeric agreement per op at the route's documented grade
-    pv, fv = results.get("potrf_per_panel"), \
-        results.get("potrf_fused")
-    if pv is not None and fv is not None:
-        close = bool(np.allclose(pv, fv, rtol=1e-4, atol=1e-4))
-        extras["potrf_fused_allclose"] = close
-        ok &= close
-    else:
-        ok = False
-    pv, fv = results.get("geqrf_per_panel"), \
-        results.get("geqrf_fused")
-    if pv is not None and fv is not None:
-        bit = bool(np.array_equal(np.asarray(pv[0]),
-                                  np.asarray(fv[0]))
-                   and np.array_equal(np.asarray(pv[1]),
-                                      np.asarray(fv[1])))
-        extras["geqrf_fused_bitwise"] = bit
-        ok &= bit
-    else:
-        ok = False
-    pv, fv = results.get("getrf_per_panel"), \
-        results.get("getrf_fused")
-    if pv is not None and fv is not None:
-        piv = bool(np.array_equal(np.asarray(pv[1]),
-                                  np.asarray(fv[1])))
-        close = bool(np.allclose(np.asarray(pv[0]),
-                                 np.asarray(fv[0]),
-                                 rtol=1e-3, atol=1e-3))
-        extras["getrf_fused_pivots_identical"] = piv
-        extras["getrf_fused_allclose"] = close
-        ok &= piv and close
-    else:
-        ok = False
-
-    # (a) the dispatch-reduction gate at nt=16: per_panel issues one
-    # update dispatch per visit (nt*(nt-1)/2); the fused route
-    # replaces each multi-member sweep with ONE
-    visits_total = nt * (nt - 1) // 2
-    saved = sum(extras.get(k, {}).get("dispatches_saved", 0)
-                for k in ("potrf_fused",))
-    red = saved / visits_total if visits_total else 0.0
-    extras["fuse_update_dispatches_per_panel"] = visits_total
-    extras["fuse_update_dispatches_fused"] = visits_total - saved
-    extras["fuse_dispatch_reduction"] = round(red, 4)
-    emit({"fuse": "dispatch_reduction", "per_panel": visits_total,
-          "fused": visits_total - saved, "reduction": round(red, 4)})
-    ok &= red >= 0.60
-
-    # (c) retrace guard: the jit cache keys on the count bucket, so
-    # a same-shape rerun adds nothing
-    rerun = run("potrf_fused_rerun",
-                lambda: ooc.potrf_ooc(a, panel_cols=w,
-                                      cache_budget_bytes=budget,
-                                      visit_fuse="fused"))
-    if rerun is not None:
-        rr = extras["potrf_fused_rerun"]
-        steady = rr["fuse_compiles"] == 0 \
-            and rr["jit_recompiles"] == 0
-        extras["fuse_rerun_steady_state"] = steady
-        ok &= steady
-    else:
-        ok = False
-    # issue overhead: fused vs unfused graph route (REPORTED)
-    run("potrf_graph_unfused",
-        lambda: ooc.potrf_ooc(a, panel_cols=w,
-                              cache_budget_bytes=budget,
-                              scheduler="graph"))
-    gr = extras.get("potrf_graph_unfused")
-    fr = extras.get("potrf_fused_rerun")
-    if gr and fr and gr["issue_overhead_per_node_us"]:
-        extras["fuse_issue_overhead_ratio"] = round(
-            fr["issue_overhead_per_node_us"]
-            / gr["issue_overhead_per_node_us"], 4)
-
-    # (d) sharded fused route: bitwise vs the sharded walk, >= 95%
-    # of the wall attributed to named ledger phases
-    from slate_tpu.obs import ledger as obs_ledger
-    from slate_tpu.obs import xprof as obs_xprof
-    try:
-        Lw = shard_ooc.shard_potrf_ooc(a, grid, panel_cols=w,
-                                       cache_budget_bytes=budget)
-        obs_ledger.reset()
-        obs_ledger.enable()
-        t0 = time.perf_counter()
-        Lf = shard_ooc.shard_potrf_ooc(a, grid, panel_cols=w,
-                                       cache_budget_bytes=budget,
-                                       visit_fuse="fused")
-        wall = time.perf_counter() - t0
-        bit = bool(np.array_equal(np.asarray(Lw), np.asarray(Lf)))
-        extras["potrf_shard_fused_bitwise"] = bit
-        ok &= bit
-        att = obs_xprof.attribute_run(
-            records=obs_ledger.records("shard_potrf_ooc"))
-        frac = att["total_wall_s"] / wall if wall > 0 else 0.0
-        rec = {"wall_s": round(wall, 4),
-               "ledger_records": att["records"],
-               "attributed_s": att["total_wall_s"],
-               "fraction_attributed": round(frac, 4),
-               "buckets": att["buckets"]}
-        extras["fuse_ledger_attribution"] = rec
-        emit(dict({"fuse": "ledger_attribution"}, **rec))
-        ok &= frac >= 0.95
-    except Exception as e:
-        extras["fuse_shard_error"] = str(e)[:160]
-        ok = False
-    finally:
-        obs_ledger.disable()
-        obs_ledger.reset()
-
-    if "--obs" in sys.argv[1:]:
-        # the regression comparator reads the numeric fuse extras
-        # (dispatch reduction, attribution fraction, walls) against
-        # the most recent BENCH_r*.json
-        try:
-            bench_obs_regression(extras)
-        except Exception as e:
-            extras["obs_regression"] = {
-                "skipped": "error: %s" % str(e)[:120]}
-
-    emit({"metric": "fuse", "value": 1 if ok else 0,
-          "unit": "suite", "vs_baseline": 1 if ok else 0,
-          "extras": extras})
-    return 0
-
-
 def bench_elastic():
     """`--elastic`: the elastic mesh (ISSUE 19) — throughput-driven
     panel re-ownership under a seeded straggler, on a REAL 2-process
@@ -2753,7 +2520,6 @@ def main():
     shard = "--shard" in sys.argv[1:]
     with_faults = "--faults" in sys.argv[1:]
     with_graph = "--graph" in sys.argv[1:]
-    with_fuse = "--fuse" in sys.argv[1:]
     with_elastic = "--elastic" in sys.argv[1:]
     with_obs = "--obs" in sys.argv[1:]
 
@@ -2761,8 +2527,7 @@ def main():
         # pure AST — runs (and must stay green) with no backend at all
         return bench_lint()
 
-    if (shard or with_faults or with_graph or with_fuse
-            or with_elastic) and (
+    if (shard or with_faults or with_graph or with_elastic) and (
             os.environ.get("JAX_PLATFORMS", "").startswith("cpu")):
         # the sharded-OOC suite needs a mesh: on the CPU tier pin 8
         # virtual devices BEFORE the in-process backend initializes
@@ -2791,8 +2556,6 @@ def main():
         return bench_faults()
     if with_graph:
         return bench_graph()
-    if with_fuse:
-        return bench_fuse()
     if with_elastic:
         return bench_elastic()
 

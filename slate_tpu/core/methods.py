@@ -480,45 +480,6 @@ class MethodScheduler(enum.Enum):
             else m
 
 
-class MethodVisitFuse(enum.Enum):
-    """Update-dispatch granularity of the streaming OOC drivers
-    (ISSUE 20):
-
-      * ``PerPanel``: one jitted visit kernel per (factor panel,
-        target panel) pair — the hand-written dispatch schedule of
-        linalg/ooc.py and dist/shard_ooc.py, untouched (O(nt^2)
-        launches per stream);
-      * ``Fused``: each step's update sweep coalesced into ONE
-        dispatch — a single wide GEMM over the concatenated factor
-        widths for the potrf/getrf left-looking visits, an in-jit
-        ``lax.scan`` for geqrf's ordered compact-WY applies and the
-        sharded right-looking trailing sweep — compiled once per
-        (height, width, count-bucket) so the jit cache stays bounded.
-
-    ``Auto`` resolves through the tune cache (the ``ooc/visit_fuse``
-    tunable; FROZEN default "per_panel"), so a COLD CACHE keeps the
-    per-panel dispatch stream bit-identically — the fused route is an
-    earned (measured, ``bench.py --fuse``) or explicit decision,
-    pinned by tests."""
-    Auto = "auto"
-    PerPanel = "per_panel"
-    Fused = "fused"
-
-    @staticmethod
-    def resolve(n: int, dtype) -> "MethodVisitFuse":
-        """The tuned/frozen ``ooc/visit_fuse`` route (unknown values
-        from a newer cache demote to the frozen PerPanel, never an
-        error)."""
-        from ..tune.select import resolve as _resolve
-        try:
-            m = str2method("visit_fuse", str(_resolve(
-                "ooc", "visit_fuse", n=n, dtype=dtype)))
-        except KeyError:
-            m = MethodVisitFuse.PerPanel
-        return MethodVisitFuse.PerPanel if m is MethodVisitFuse.Auto \
-            else m
-
-
 class MethodOwnership(enum.Enum):
     """Panel-ownership policy of the sharded OOC stream (ISSUE 19):
 
@@ -582,7 +543,7 @@ def str2method(family: str, s: str):
         "lu_panel": MethodLUPanel, "ooc": MethodOOC,
         "lu_pivot": MethodLUPivot, "precision": MethodPrecision,
         "batch": MethodBatchStrategy, "scheduler": MethodScheduler,
-        "ownership": MethodOwnership, "visit_fuse": MethodVisitFuse,
+        "ownership": MethodOwnership,
     }[family]
     for mem in fam:
         if mem.value.lower() == s.lower() or mem.name.lower() == s.lower():

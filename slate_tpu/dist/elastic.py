@@ -441,9 +441,7 @@ def run_elastic(ctrl: ElasticController, *, op: str, bc, st,
                 make_payload: Callable, complete: Callable,
                 replay: Callable, apply: Callable,
                 tail_step: Optional[Callable], led, ck, eng,
-                step_obs: Callable, nt: int,
-                fused_apply: Optional[Callable] = None,
-                fuse_meta: Optional[dict] = None) -> None:
+                step_obs: Callable, nt: int) -> None:
     """The segmented elastic issue loop (shard_ooc._run_stream's
     elastic route; module doc).
 
@@ -460,13 +458,7 @@ def run_elastic(ctrl: ElasticController, *, op: str, bc, st,
     panels moved here need nothing — the next segment's graph simply
     contains their catch-up nodes. Elastic always runs the graph
     route: ownership is a graph-construction input here, which is
-    the whole mechanism.
-
-    ``fused_apply``/``fuse_meta`` (ISSUE 20): forwarded to every
-    segment's graph — the fused trailing sweep composes with remap
-    because membership is re-derived per segment from the CURRENT
-    ownership map and ``applied_through`` prunes sweeps already
-    absorbed; the meta sidecar folds into each slot's ledger commit."""
+    the whole mechanism."""
     from ..sched import policies as _policies
     from ..sched.runtime import execute as _execute
     panels = list(factor_panels)
@@ -483,7 +475,7 @@ def run_elastic(ctrl: ElasticController, *, op: str, bc, st,
             payload_shape=payload_shape, make_payload=make_payload,
             complete=complete, replay=replay, apply=apply,
             tail=tail_step, applied_through=st.applied_through,
-            trailing_to=nt, fused_apply=fused_apply)
+            trailing_to=nt)
 
         def _begin(k, _b0=b0, _sched=sched):
             if led is not None:
@@ -497,8 +489,7 @@ def run_elastic(ctrl: ElasticController, *, op: str, bc, st,
                 eng.wait_writes()   # every panel <= k is durable;
                 ck.commit(k + 1)    # the in-flight panel is NOT
             if led is not None:
-                led.commit(**(fuse_meta.pop(k, {})
-                              if fuse_meta else {}))
+                led.commit()
 
         t_seg = time.perf_counter()
         wait0 = bc.wait_seconds

@@ -818,124 +818,10 @@ def _publish_overlap(op: str, bc: PanelBroadcaster,
                        overlap=round(bc.overlap_fraction(), 4))
 
 
-# -- fused trailing sweeps (ISSUE 20) -------------------------------------
-#
-# One dispatch per update phase for the sharded right-looking walk:
-# every non-promoted owned panel consuming broadcast record s is
-# stacked and the record applied across the stack by an in-jit
-# lax.scan whose body is the SAME per-panel visit kernel — identical
-# operands, identical per-member arithmetic — so the fused sweep is
-# BITWISE equal to the per-panel route (pinned by tests). One
-# compiled program per (height, frame width, count-bucket); the
-# power-of-two bucket ladder (linalg/ooc._fuse_bucket) bounds the jit
-# cache exactly the way the single-engine fused visits do. potrf
-# members have per-panel suffix heights (n - p*w): each is embedded
-# at its global row offset in a full-height slab (stream._embed_rows)
-# and the visiting frame masked below the member's offset, so every
-# real row sees the exact per-panel dot product while padding rows
-# stay exact zero; geqrf/getrf members are all full-height (m, w), so
-# the stack is direct. Ragged-width members (the last panel when
-# w does not divide n) are applied per-panel by the driver's plain
-# ``apply`` inside the fused closure — membership stays the slot's
-# whole sweep, arithmetic stays per-panel-exact.
-
-_HI = jax.lax.Precision.HIGHEST
-
-
-@functools.partial(jax.jit, static_argnames=("w",))
-def _fused_sweep_potrf(Ss, frame, offs, w: int):
-    """Stacked potrf trailing sweep: Ss (b, n, w) members embedded at
-    row offsets `offs` (b,), frame the full-height broadcast factor
-    column. Per member: mask the frame below the member's offset and
-    run _panel_apply's exact product — rows below the offset are
-    0 - 0 @ top = exact zero (the embedding pad survives)."""
-    rows = jnp.arange(frame.shape[0])
-
-    def body(c, inp):
-        S, off = inp
-        masked = jnp.where((rows >= off)[:, None], frame, 0)
-        top = jax.lax.dynamic_slice(
-            frame, (off, jnp.asarray(0, off.dtype)),
-            (w, frame.shape[1]))
-        return c, S - jnp.matmul(masked, jnp.conj(top.T),
-                                 precision=_HI)
-
-    return jax.lax.scan(body, 0, (Ss, offs))[1]
-
-
-@functools.partial(jax.jit, static_argnames=("w",))
-def _fused_sweep_potrf_mx(Ss, frame, offs, w: int):
-    """Mixed twin of _fused_sweep_potrf: frame arrives in the lo
-    dtype, each rank-w product accumulates in S's dtype (the
-    _panel_apply_mx contract, linalg/ooc.py)."""
-    rows = jnp.arange(frame.shape[0])
-
-    def body(c, inp):
-        S, off = inp
-        masked = jnp.where((rows >= off)[:, None], frame, 0)
-        top = jax.lax.dynamic_slice(
-            frame, (off, jnp.asarray(0, off.dtype)),
-            (w, frame.shape[1]))
-        return c, S - jnp.matmul(masked, jnp.conj(top.T),
-                                 precision=_HI,
-                                 preferred_element_type=S.dtype)
-
-    return jax.lax.scan(body, 0, (Ss, offs))[1]
-
-
-@jax.jit
-def _fused_sweep_qr(Ss, Pk, tk, k0):
-    """Stacked geqrf trailing sweep: the scan body IS _qr_visit, so
-    each member of Ss (b, m, w) absorbs record (Pk, tk, k0) through
-    the per-panel kernel's exact ops."""
-    from ..linalg import ooc as _ooc
-
-    def body(c, S):
-        return c, _ooc._qr_visit(S, Pk, tk, k0)
-
-    return jax.lax.scan(body, 0, Ss)[1]
-
-
-@jax.jit
-def _fused_sweep_qr_mx(Ss, Pk, tk, k0):
-    """Mixed twin of _fused_sweep_qr (body: _qr_visit_mx)."""
-    from ..linalg import ooc as _ooc
-
-    def body(c, S):
-        return c, _ooc._qr_visit_mx(S, Pk, tk, k0)
-
-    return jax.lax.scan(body, 0, Ss)[1]
-
-
-@jax.jit
-def _fused_sweep_lu(Ss, Pk, g, k0):
-    """Stacked getrf trailing sweep: the scan body IS _lu_visit_orig
-    (gather to elimination order, strip solve + trailing product,
-    scatter back)."""
-    from ..linalg import ooc as _ooc
-
-    def body(c, S):
-        return c, _ooc._lu_visit_orig(S, Pk, g, k0)
-
-    return jax.lax.scan(body, 0, Ss)[1]
-
-
-@jax.jit
-def _fused_sweep_lu_mx(Ss, Pk, g, k0):
-    """Mixed twin of _fused_sweep_lu (body: _lu_visit_orig_mx)."""
-    from ..linalg import ooc as _ooc
-
-    def body(c, S):
-        return c, _ooc._lu_visit_orig_mx(S, Pk, g, k0)
-
-    return jax.lax.scan(body, 0, Ss)[1]
-
-
 def _run_stream(op: str, use_graph: bool, *, sched, bc, st, depth,
                 epoch, factor_panels, tail_panels, payload_shape,
                 make_payload, complete, replay, apply, tail_step,
-                led, ck, eng, step_obs, nt, elastic=None,
-                fused_apply=None, fuse_meta=None) -> None:
+                led, ck, eng, step_obs, nt, elastic=None) -> None:
     """One issue loop for all three sharded drivers (ISSUE 17): the
     legacy ``_BcastPipeline`` walk (``scheduler="walk"`` — the frozen
     cold route, bit-identical to the PR 11-16 drivers), or the
@@ -951,14 +837,7 @@ def _run_stream(op: str, use_graph: bool, *, sched, bc, st, depth,
     segment, ownership re-derived from measured throughput at each
     boundary). Elastic always constructs graphs regardless of the
     ``ooc/scheduler`` row: ownership is a graph-construction input,
-    which is the whole re-label-and-rebuild mechanism.
-
-    ``fused_apply``/``fuse_meta`` (ISSUE 20): the driver's stacked
-    one-dispatch trailing-sweep closure and its per-slot ledger-meta
-    sidecar — forwarded to ``sharded_stream`` (and through every
-    elastic segment), with the meta folded into the slot's ledger
-    commit. Fused implies the graph route (the walk has no fused
-    node), so ``use_graph`` is already True whenever these are set."""
+    which is the whole re-label-and-rebuild mechanism."""
     if elastic is not None:
         from . import elastic as _elastic
         _elastic.run_elastic(
@@ -967,8 +846,7 @@ def _run_stream(op: str, use_graph: bool, *, sched, bc, st, depth,
             payload_shape=payload_shape, make_payload=make_payload,
             complete=complete, replay=replay, apply=apply,
             tail_step=tail_step, led=led, ck=ck, eng=eng,
-            step_obs=step_obs, nt=nt, fused_apply=fused_apply,
-            fuse_meta=fuse_meta)
+            step_obs=step_obs, nt=nt)
         return
     last = factor_panels[-1] if len(factor_panels) else -1
     if use_graph:
@@ -979,7 +857,7 @@ def _run_stream(op: str, use_graph: bool, *, sched, bc, st, depth,
             factor_panels=factor_panels, tail_panels=tail_panels,
             payload_shape=payload_shape, make_payload=make_payload,
             complete=complete, replay=replay, apply=apply,
-            tail=tail_step, fused_apply=fused_apply)
+            tail=tail_step)
 
         def _begin(k):
             if led is not None:
@@ -993,8 +871,7 @@ def _run_stream(op: str, use_graph: bool, *, sched, bc, st, depth,
                 eng.wait_writes()   # every panel <= k is durable;
                 ck.commit(k + 1)    # the in-flight panel is NOT
             if led is not None:
-                led.commit(**(fuse_meta.pop(k, {})
-                              if fuse_meta else {}))
+                led.commit()
 
         _execute(g, op=op, nt=nt, begin_step=_begin, end_step=_end)
         # deep lookahead keys every node below slot nt-1, so the
@@ -1055,8 +932,7 @@ def shard_potrf_ooc(a: np.ndarray, grid: ProcessGrid,
                     ckpt_every: Optional[int] = None,
                     precision=None,
                     scheduler=None,
-                    ownership=None,
-                    visit_fuse=None) -> np.ndarray:
+                    ownership=None) -> np.ndarray:
     """Sharded out-of-core lower Cholesky (module doc): panels owned
     2D-block-cyclically, each host staging only its shard, factor
     panels broadcast over the tree. Returns the full host-resident
@@ -1102,29 +978,19 @@ def shard_potrf_ooc(a: np.ndarray, grid: ProcessGrid,
     ``ownership`` (ISSUE 19): ``"static"`` (FROZEN ``mesh/ownership``
     default — the pure cyclic map) or ``"elastic"`` (throughput-
     driven re-ownership, dist/elastic.py — bitwise vs static; with
-    uniform throughput the remapper never fires).
-
-    ``visit_fuse`` (ISSUE 20): ``"per_panel"`` (FROZEN
-    ``ooc/visit_fuse`` default — one update dispatch per (panel,
-    step) pair, the bitwise-pinned cold route) or ``"fused"`` — each
-    broadcast record's trailing sweep over the owned panels collapses
-    into ONE stacked in-jit scan dispatch (_fused_sweep_potrf;
-    bitwise equal to per_panel, pinned). Fused implies the graph
-    route — the walk has no fused node."""
+    uniform throughput the remapper never fires)."""
     from ..linalg import stream
-    from ..linalg.ooc import (_fuse_bucket, _fuse_note_compile,
-                              _panel_apply, _panel_apply_mx,
+    from ..linalg.ooc import (_panel_apply, _panel_apply_mx,
                               _panel_cols, _panel_factor,
                               _precision_meta, _resolve_precision,
-                              _resolve_scheduler, _resolve_visit_fuse)
+                              _resolve_scheduler)
     from .elastic import ElasticController, _resolve_ownership
     a = np.asarray(a)
     n = a.shape[0]
     w = min(_panel_cols(panel_cols, n, a.dtype), n)
     nt = ceil_div(n, w)
     lo = _resolve_precision(precision, n, a.dtype)
-    use_fuse = _resolve_visit_fuse(visit_fuse, n, a.dtype)
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype) or use_fuse
+    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     depth = _shard_lookahead(lookahead, n, a.dtype)
     ctrl = ElasticController("shard_potrf_ooc", grid, nt,
                              n=n, dtype=a.dtype) \
@@ -1204,39 +1070,6 @@ def shard_potrf_ooc(a: np.ndarray, grid: ProcessGrid,
             return _panel_apply(S_j, Lr, min(w, n - j0))
         return _panel_apply_mx(S_j, Lr, min(w, n - j0))
 
-    fuse_meta: Dict[int, dict] = {}
-
-    def fused_apply(Ss, frame, ps, s):
-        # full-width members stack; the ragged-width last panel (if
-        # present) keeps its exact per-panel apply inside this node
-        full = [i for i, p in enumerate(ps)
-                if min(w, n - p * w) == w]
-        if len(full) < 2:
-            return [apply(S, frame, p) for S, p in zip(Ss, ps)]
-        out_s = list(Ss)
-        count = len(full)
-        bucket = _fuse_bucket(count)
-        stk = [stream._embed_rows(Ss[i], ps[i] * w, n=n)
-               for i in full]
-        stk += [jnp.zeros_like(stk[0])] * (bucket - count)
-        offs = jnp.asarray([ps[i] * w for i in full]
-                           + [0] * (bucket - count), jnp.int32)
-        _fuse_note_compile("shard_potrf_ooc", n, int(frame.shape[1]),
-                           w, bucket, str(frame.dtype))
-        fn = _fused_sweep_potrf if lo is None \
-            else _fused_sweep_potrf_mx
-        res = fn(jnp.stack(stk), frame, offs, w=w)
-        for idx, i in enumerate(full):
-            p = ps[i]
-            out_s[i] = stream._suffix_rows(res[idx], p * w,
-                                           rows=n - p * w)
-        for i, p in enumerate(ps):
-            if i not in full:
-                out_s[i] = apply(Ss[i], frame, p)
-        fuse_meta[s] = {"fused_members": [ps[i] for i in full],
-                        "fused_width": count * w}
-        return out_s
-
     led = _ledger.recorder("shard_potrf_ooc", nt=nt,
                            spill_dir=_host_ckpt_path(ckpt_path))
     try:
@@ -1247,9 +1080,7 @@ def shard_potrf_ooc(a: np.ndarray, grid: ProcessGrid,
                     make_payload=make_payload, complete=complete,
                     replay=replay, apply=apply, tail_step=None,
                     led=led, ck=ck, eng=eng, step_obs=step_obs,
-                    nt=nt, elastic=ctrl,
-                    fused_apply=fused_apply if use_fuse else None,
-                    fuse_meta=fuse_meta if use_fuse else None)
+                    nt=nt, elastic=ctrl)
         _health.heartbeat("shard_potrf_ooc", nt, nt)   # completion
         if led is not None:
             led.begin(nt, epoch=epoch, drain=True)       # final drain record
@@ -1273,8 +1104,7 @@ def shard_geqrf_ooc(a: np.ndarray, grid: ProcessGrid,
                     ckpt_every: Optional[int] = None,
                     precision=None,
                     scheduler=None,
-                    ownership=None,
-                    visit_fuse=None):
+                    ownership=None):
     """Sharded out-of-core Householder QR: same ownership walk,
     broadcast tree, and lookahead pipeline as shard_potrf_ooc,
     full-height panel states, the broadcast payload carrying the
@@ -1294,19 +1124,12 @@ def shard_geqrf_ooc(a: np.ndarray, grid: ProcessGrid,
     taus are identical across the mesh at bf16-update accuracy.
 
     ``ownership`` (ISSUE 19): "static" | "elastic" — the
-    shard_potrf_ooc contract.
-
-    ``visit_fuse`` (ISSUE 20): "per_panel" | "fused" — the
-    shard_potrf_ooc contract; the fused sweep's scan body IS
-    _qr_visit (_fused_sweep_qr), so the route is bitwise equal to
-    per_panel (pinned). Fused implies the graph route."""
+    shard_potrf_ooc contract."""
     from ..linalg import stream
-    from ..linalg.ooc import (_fuse_bucket, _fuse_note_compile,
-                              _panel_cols, _precision_meta,
+    from ..linalg.ooc import (_panel_cols, _precision_meta,
                               _qr_apply_fresh, _qr_panel_factor,
                               _qr_visit, _qr_visit_mx,
-                              _resolve_precision, _resolve_scheduler,
-                              _resolve_visit_fuse)
+                              _resolve_precision, _resolve_scheduler)
     from .elastic import ElasticController, _resolve_ownership
     a = np.asarray(a)
     m, n = a.shape
@@ -1314,8 +1137,7 @@ def shard_geqrf_ooc(a: np.ndarray, grid: ProcessGrid,
     w = min(_panel_cols(panel_cols, n, a.dtype), n)
     nt = ceil_div(n, w)
     lo = _resolve_precision(precision, n, a.dtype)
-    use_fuse = _resolve_visit_fuse(visit_fuse, n, a.dtype)
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype) or use_fuse
+    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     depth = _shard_lookahead(lookahead, n, a.dtype)
     ctrl = ElasticController("shard_geqrf_ooc", grid, nt,
                              n=n, dtype=a.dtype) \
@@ -1421,32 +1243,6 @@ def shard_geqrf_ooc(a: np.ndarray, grid: ProcessGrid,
             return _qr_visit(S_j, Pk, tk, k0)
         return _qr_visit_mx(S_j, Pk, tk, k0)
 
-    fuse_meta: Dict[int, dict] = {}
-
-    def fused_apply(Ss, rec, ps, s):
-        full = [i for i, p in enumerate(ps)
-                if min(w, n - p * w) == w]
-        if len(full) < 2:
-            return [apply(S, rec, p) for S, p in zip(Ss, ps)]
-        out_s = list(Ss)
-        Pk, tk, k0 = rec
-        count = len(full)
-        bucket = _fuse_bucket(count)
-        stk = [Ss[i] for i in full]
-        stk += [jnp.zeros_like(stk[0])] * (bucket - count)
-        _fuse_note_compile("shard_geqrf_ooc", m, int(Pk.shape[1]),
-                           w, bucket, str(Pk.dtype))
-        fn = _fused_sweep_qr if lo is None else _fused_sweep_qr_mx
-        res = fn(jnp.stack(stk), Pk, tk, k0)
-        for idx, i in enumerate(full):
-            out_s[i] = res[idx]
-        for i, p in enumerate(ps):
-            if i not in full:
-                out_s[i] = apply(Ss[i], rec, p)
-        fuse_meta[s] = {"fused_members": [ps[i] for i in full],
-                        "fused_width": count * w}
-        return out_s
-
     def tail_step(k):
         # all updates applied: the state IS the final U block — one
         # broadcast replicates it so every host's factor is complete.
@@ -1472,9 +1268,7 @@ def shard_geqrf_ooc(a: np.ndarray, grid: ProcessGrid,
                     make_payload=make_payload, complete=complete,
                     replay=replay, apply=apply, tail_step=tail_step,
                     led=led, ck=ck, eng=eng, step_obs=step_obs,
-                    nt=nt, elastic=ctrl,
-                    fused_apply=fused_apply if use_fuse else None,
-                    fuse_meta=fuse_meta if use_fuse else None)
+                    nt=nt, elastic=ctrl)
         _health.heartbeat("shard_geqrf_ooc", nt, nt)   # completion
         if led is not None:
             led.begin(nt, epoch=epoch, drain=True)       # final drain record
@@ -1499,8 +1293,7 @@ def shard_getrf_ooc(a: np.ndarray, grid: ProcessGrid,
                     ckpt_every: Optional[int] = None,
                     precision=None,
                     scheduler=None,
-                    ownership=None,
-                    visit_fuse=None):
+                    ownership=None):
     """Sharded out-of-core tournament-pivot LU (module doc — the PR 7
     deferral, closed): same ownership walk and broadcast tree as
     shard_potrf_ooc, full-height panel states kept in ORIGINAL row
@@ -1539,29 +1332,21 @@ def shard_getrf_ooc(a: np.ndarray, grid: ProcessGrid,
     the original-order store mirrors the promoted column.
 
     ``ownership`` (ISSUE 19): "static" | "elastic" — the
-    shard_potrf_ooc contract.
-
-    ``visit_fuse`` (ISSUE 20): "per_panel" | "fused" — the
-    shard_potrf_ooc contract; the fused sweep's scan body IS
-    _lu_visit_orig (_fused_sweep_lu), so the route is bitwise equal
-    to per_panel (pinned). Fused implies the graph route."""
+    shard_potrf_ooc contract."""
     from ..core.exceptions import slate_assert
     from ..linalg import stream
     from . import elastic as _elastic_mod
     from ..linalg.ca import fix_degenerate_selection
     from ..linalg.lu import tnt_swaps_host
-    from ..linalg.ooc import (_fuse_bucket, _fuse_note_compile,
-                              _lu_visit_orig, _lu_visit_orig_mx,
+    from ..linalg.ooc import (_lu_visit_orig, _lu_visit_orig_mx,
                               _panel_cols, _precision_meta,
                               _resolve_precision, _resolve_scheduler,
-                              _resolve_visit_fuse, _tnt_factor,
-                              _tnt_select, _tnt_tail_cols,
-                              _finalize_lapack_order)
+                              _tnt_factor, _tnt_select,
+                              _tnt_tail_cols, _finalize_lapack_order)
     a = np.asarray(a)
     m, n = a.shape
     lo = _resolve_precision(precision, n, a.dtype)
-    use_fuse = _resolve_visit_fuse(visit_fuse, n, a.dtype)
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype) or use_fuse
+    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     # the pivot payload row(s) ride the FRAME dtype: row indices must
     # sit inside its exact-integer window or np.rint decodes WRONG
     # rows silently — make it a loud error instead. The mixed mode's
@@ -1736,34 +1521,6 @@ def shard_getrf_ooc(a: np.ndarray, grid: ProcessGrid,
         return _lu_visit_orig_mx(S_j, rec["Pk"], rec["g"],
                                  rec["k0"])
 
-    fuse_meta: Dict[int, dict] = {}
-
-    def fused_apply(Ss, rec, ps, s):
-        if rec["g"] is None:
-            rec["g"] = jnp.asarray(perms[rec["k"]].astype(np.int32))
-        full = [i for i, p in enumerate(ps)
-                if min(w, n - p * w) == w]
-        if len(full) < 2:
-            return [apply(S, rec, p) for S, p in zip(Ss, ps)]
-        out_s = list(Ss)
-        count = len(full)
-        bucket = _fuse_bucket(count)
-        stk = [Ss[i] for i in full]
-        stk += [jnp.zeros_like(stk[0])] * (bucket - count)
-        _fuse_note_compile("shard_getrf_ooc", m,
-                           int(rec["Pk"].shape[1]), w, bucket,
-                           str(rec["Pk"].dtype))
-        fn = _fused_sweep_lu if lo is None else _fused_sweep_lu_mx
-        res = fn(jnp.stack(stk), rec["Pk"], rec["g"], rec["k0"])
-        for idx, i in enumerate(full):
-            out_s[i] = res[idx]
-        for i, p in enumerate(ps):
-            if i not in full:
-                out_s[i] = apply(Ss[i], rec, p)
-        fuse_meta[s] = {"fused_members": [ps[i] for i in full],
-                        "fused_width": count * w}
-        return out_s
-
     def tail_step(k):
         # all updates applied: the original-order state IS the final
         # U block — one broadcast replicates it so every host's
@@ -1789,9 +1546,7 @@ def shard_getrf_ooc(a: np.ndarray, grid: ProcessGrid,
                     make_payload=make_payload, complete=complete,
                     replay=replay, apply=apply, tail_step=tail_step,
                     led=led, ck=ck, eng=eng, step_obs=step_obs,
-                    nt=nt, elastic=ctrl,
-                    fused_apply=fused_apply if use_fuse else None,
-                    fuse_meta=fuse_meta if use_fuse else None)
+                    nt=nt, elastic=ctrl)
         _health.heartbeat("shard_getrf_ooc", nt, nt)   # completion
         if led is not None:
             led.begin(nt, epoch=epoch, drain=True)       # final drain record

@@ -161,72 +161,6 @@ def _resolve_scheduler(scheduler, n: int, dtype) -> bool:
     return m is MethodScheduler.Graph
 
 
-def _resolve_visit_fuse(visit_fuse, n: int, dtype) -> bool:
-    """Update-dispatch arbitration for the streaming drivers (ISSUE
-    20): explicit ``visit_fuse`` argument > measured ``ooc/visit_fuse``
-    tune entry > FROZEN "per_panel" (core/methods.MethodVisitFuse — a
-    COLD CACHE keeps the one-dispatch-per-visit stream bit-identically;
-    the fused sweep is earned or explicit, pinned by tests). Returns
-    True for the fused route (one coalesced dispatch per update
-    phase). The fused route always runs through the task-graph
-    runtime — its sweep IS a graph-node grouping — so the drivers OR
-    this into their scheduler resolution."""
-    from ..core.methods import MethodVisitFuse, str2method
-    m = visit_fuse if visit_fuse is not None else MethodVisitFuse.Auto
-    if isinstance(m, str):
-        m = str2method("visit_fuse", m)
-    if m is MethodVisitFuse.Auto:
-        m = MethodVisitFuse.resolve(n, dtype)
-    return m is MethodVisitFuse.Fused
-
-
-# -- fused visit sweeps (ISSUE 20) ----------------------------------------
-#
-# One dispatch per update phase: a stream step's j=0..k-1 visit
-# kernels coalesce into a single jitted program — a wide GEMM over the
-# concatenated factor widths for the potrf/getrf left-looking visits
-# (the visiting panels gather into ONE stacked operand via
-# stream.StreamEngine.gather_stacked), an in-jit lax.scan over the
-# stacked reflector panels for geqrf's ordered compact-WY applies.
-# Sweep counts pad up to a power-of-two bucket (exact-zero columns /
-# exact-identity scan steps), so the jit cache compiles once per
-# (height, w, count-bucket) instead of once per count — the PR 19
-# tree_allreduce retrace lesson, pinned by the ooc.visit_fuse_compiles
-# counter.
-
-
-def _fuse_bucket(count: int) -> int:
-    """Power-of-two count bucket (>= 2) a fused sweep pads up to."""
-    b = 2
-    while b < count:
-        b *= 2
-    return b
-
-
-#: compile-key memo behind the ``ooc.visit_fuse_compiles`` counter —
-#: one entry per (op, height, width, bucket, dtype) jit specialization
-#: the fused kernels have been traced at (tests reset it alongside the
-#: metrics registry)
-_FUSE_SEEN: set = set()
-
-
-def _fuse_note_compile(*key) -> None:
-    if key in _FUSE_SEEN:
-        return
-    _FUSE_SEEN.add(key)
-    if obs_events.enabled():
-        obs_metrics.inc("ooc.visit_fuse_compiles")
-
-
-def _fuse_count_visits(count: int) -> None:
-    """Publish the fused-sweep dispatch accounting: `count` member
-    visits landed in one dispatch, so `count - 1` launches were
-    saved vs the per-panel route."""
-    if obs_events.enabled():
-        obs_metrics.inc("ooc.visits_fused", count)
-        obs_metrics.inc("ooc.visit_dispatches_saved", count - 1)
-
-
 def _herm_operand(a: np.ndarray) -> np.ndarray:
     """The Hermitian residual operator for posv_ooc's refinement:
     potrf_ooc reads only the LOWER triangle, so a caller may store
@@ -483,8 +417,7 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
               cache_budget_bytes=None, grid=None,
               method=None, ckpt_path: Optional[str] = None,
               ckpt_every: Optional[int] = None,
-              precision=None, scheduler=None,
-              visit_fuse=None) -> np.ndarray:
+              precision=None, scheduler=None) -> np.ndarray:
     """Lower Cholesky of a host-resident Hermitian matrix (lower
     triangle read), streaming one column panel through the accelerator
     at a time. Returns the host-resident lower factor; n is bounded by
@@ -525,18 +458,6 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     holds; the returned factor is f32 with bf16-grade update error —
     posv_ooc's refinement (or an explicit f32 rerun) is the accuracy
     contract.
-
-    ``visit_fuse`` (ISSUE 20): the update-dispatch mode, resolved
-    explicit > tuned ``ooc/visit_fuse`` > FROZEN "per_panel"
-    (core/methods.MethodVisitFuse — the cold cache keeps the
-    one-dispatch-per-visit stream bit-identically, pinned by test).
-    Under "fused" panel k's j=0..k-1 rank-w visits coalesce into ONE
-    wide GEMM over the width-concatenated factor panels
-    (stream.gather_stacked serves cache residents and batches the
-    misses into a single H2D), routed through the task-graph runtime
-    as one fused_update node; results match per_panel to <= 1e-12
-    (the wide GEMM sums the k rank-w terms in one reassociated
-    contraction).
     """
     a = np.asarray(a)
     n = a.shape[0]
@@ -554,14 +475,12 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                 a, grid, panel_cols=panel_cols,
                 cache_budget_bytes=cache_budget_bytes,
                 ckpt_path=ckpt_path, ckpt_every=ckpt_every,
-                precision=precision, scheduler=scheduler,
-                visit_fuse=visit_fuse),
+                precision=precision, scheduler=scheduler),
             lambda: potrf_ooc(a, panel_cols, cache_budget_bytes,
                               ckpt_path=ckpt_path,
                               ckpt_every=ckpt_every,
                               precision=precision,
-                              scheduler=scheduler,
-                              visit_fuse=visit_fuse),
+                              scheduler=scheduler),
             "potrf_ooc", grid)
     ck = _rckpt.maybe_checkpointer(
         ckpt_path, "potrf_ooc", a, panel_cols, nt, every=ckpt_every,
@@ -586,15 +505,12 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     ld = stream.host_demoter(lo)
     visit = _panel_apply if lo is None else _panel_apply_mx
     epoch0 = ck.epoch if ck is not None else 0
-    use_fuse = _resolve_visit_fuse(visit_fuse, n, a.dtype)
-    # the fused sweep IS a graph-node grouping, so it implies the
-    # graph route; per_panel leaves the scheduler arbitration alone
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype) or use_fuse
+    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     led = _ledger.recorder("potrf_ooc", nt=nt, spill_dir=ckpt_path)
     # the panel loop body as closures (ISSUE 17): the walk below and
     # the left_looking graph policy drive the SAME code — the graph
     # route changes only who owns the issue order, never the ops
-    S_live, F, fuse_meta = {}, {}, {}
+    S_live, F = {}, {}
 
     def _stage(k):
         _rfaults.check("step", op="potrf_ooc", step=k)
@@ -637,45 +553,6 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
         with _ledger.frame("update"):
             S_live[k] = visit(S_live[k], Lj, w)
 
-    def _fused_update(k, js):
-        # ONE dispatch for panel k's whole visit sweep (ISSUE 20):
-        # the j=0..k-1 rank-w products collapse into a single wide
-        # GEMM — _panel_apply's top-w rows of the width-concatenated
-        # operand ARE the stacked visitor tops, so the per-panel
-        # kernel applies unchanged to the stacked operand
-        k0 = k * panel_cols
-        w = min(k0 + panel_cols, n) - k0
-        js = list(js)
-        if eng.caching:
-            loaders = [(lambda j0=j * panel_cols,
-                        j1=min((j + 1) * panel_cols, n):
-                        ld(out[:, j0:j1])) for j in js]
-            view = (k0, n - k0)
-        else:
-            loaders = [(lambda j0=j * panel_cols,
-                        j1=min((j + 1) * panel_cols, n):
-                        ld(out[k0:, j0:j1])) for j in js]
-            view = None
-        with _ledger.frame("stage"):
-            Lcat = eng.gather_stacked("L", js, loaders, view=view)
-        count = len(js)
-        bucket = _fuse_bucket(count)
-        if bucket > count:
-            # pad up to the count bucket with exact-zero columns
-            # (zero terms in the wide GEMM) so the jit cache compiles
-            # once per (height, w, bucket), not once per count
-            Lcat = jnp.concatenate(
-                [Lcat, jnp.zeros((Lcat.shape[0],
-                                  (bucket - count) * panel_cols),
-                                 Lcat.dtype)], axis=1)
-        _fuse_note_compile("potrf_ooc", int(Lcat.shape[0]), w,
-                           bucket, str(Lcat.dtype))
-        with _ledger.frame("update"):
-            S_live[k] = visit(S_live[k], Lcat, w)
-        _fuse_count_visits(count)
-        fuse_meta[k] = {"fused_members": js,
-                        "fused_width": count * panel_cols}
-
     def _factor(k):
         w = min(k * panel_cols + panel_cols, n) - k * panel_cols
         if k + 1 < nt:
@@ -710,9 +587,7 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
             eng.wait_writes()           # every panel <= k is durable
             ck.commit(k + 1)
         if led is not None:
-            # fused steps carry their member list + fused width into
-            # the step record (the update phase is credited ONCE)
-            led.commit(**fuse_meta.pop(k, {}))
+            led.commit()
 
     try:
         if use_graph:
@@ -720,8 +595,7 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                 "potrf_ooc", panels=range(epoch0, nt),
                 updates=lambda k: range(k), stage=_stage,
                 update=_update, factor=_factor,
-                writeback=_writeback,
-                fused_update=_fused_update if use_fuse else None)
+                writeback=_writeback)
             _sched_execute(g, op="potrf_ooc", nt=nt,
                            begin_step=_begin, end_step=_end)
         else:
@@ -984,7 +858,7 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
               chunk: Optional[int] = None,
               ckpt_path: Optional[str] = None,
               ckpt_every: Optional[int] = None,
-              precision=None, scheduler=None, visit_fuse=None):
+              precision=None, scheduler=None):
     """LU of a host-resident (m, n) matrix, streaming one column
     panel through the accelerator at a time (left-looking; reference
     src/getrf.cc:327 runs the same factorization at any n the
@@ -1048,16 +922,6 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
             "partial-pivot fixup rewrites panels the cache holds "
             "demoted); drop pivot='partial' or precision='bf16'")
         mode = MethodLUPivot.Tournament
-    if _resolve_visit_fuse(visit_fuse, n, a.dtype):
-        # the fused visit sweep (ISSUE 20) rides the immutable
-        # tournament stream — the partial-pivot walk has no graph
-        # route for a fused_update node to live on
-        slate_assert(
-            asked is not MethodLUPivot.Partial,
-            "the fused OOC LU visit sweep is tournament-only (the "
-            "partial-pivot walk has no graph route); drop "
-            "pivot='partial' or visit_fuse='fused'")
-        mode = MethodLUPivot.Tournament
     if _route_shard(n, ceil_div(n, w), grid, method, a.dtype):
         slate_assert(
             asked is None or asked is MethodLUPivot.Tournament,
@@ -1070,21 +934,18 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                 a, grid, panel_cols=w, incore_nb=incore_nb,
                 cache_budget_bytes=cache_budget_bytes, chunk=chunk,
                 ckpt_path=ckpt_path, ckpt_every=ckpt_every,
-                precision=precision, scheduler=scheduler,
-                visit_fuse=visit_fuse),
+                precision=precision, scheduler=scheduler),
             lambda: getrf_tntpiv_ooc(
                 a, w, incore_nb, cache_budget_bytes, chunk=chunk,
                 ckpt_path=ckpt_path, ckpt_every=ckpt_every,
-                precision=precision, scheduler=scheduler,
-                visit_fuse=visit_fuse),
+                precision=precision, scheduler=scheduler),
             "getrf_ooc", grid)
     if mode is MethodLUPivot.Tournament:
         return getrf_tntpiv_ooc(a, w, incore_nb, cache_budget_bytes,
                                 chunk=chunk, ckpt_path=ckpt_path,
                                 ckpt_every=ckpt_every,
                                 precision=precision,
-                                scheduler=scheduler,
-                                visit_fuse=visit_fuse)
+                                scheduler=scheduler)
     slate_assert(
         ckpt_path is None,
         "partial-pivot OOC LU cannot checkpoint (row-swap fixups "
@@ -1206,87 +1067,6 @@ def _lu_visit_orig(S: jax.Array, Lj: jax.Array, g: jax.Array, j0
     return jnp.zeros_like(S).at[g].set(Sp)
 
 
-@functools.partial(jax.jit, static_argnames=("w", "bucket"))
-def _lu_visit_fused(S: jax.Array, Lcat: jax.Array, g: jax.Array,
-                    count, w: int, bucket: int) -> jax.Array:
-    """Panel S's whole LU visit sweep in ONE dispatch (ISSUE 20):
-    Lcat concatenates the full-width visiting factor panels j=0..
-    count-1 (original row order, visitor j's diagonal block at row
-    j*w), zero-padded with exact-zero column blocks up to `bucket`
-    so the jit cache compiles once per (m, w, bucket). One gather
-    `g` = perms[last visitor] serves every member: positions < j1
-    never move after step j, later steps permute only the not-yet-
-    eliminated positions among themselves, and both the strip solves
-    and the per-row trailing products are invariant to the gather
-    order of those rows. Phase 1 is a lax.scan over the members
-    computing the U strips (each strip's correction reads the U
-    buffer, whose not-yet-written rows are exact zero); phase 2 is
-    ONE wide trailing GEMM below the fused strips — the per-panel
-    route's count separate rank-w subtractions reassociated into a
-    single contraction (allclose <= 1e-12, not bitwise). Padded scan
-    steps read an exact-zero diagonal block (unit solve = identity)
-    and their garbage U rows meet only the zero pad columns in the
-    trailing product — exact no-ops."""
-    m, wS = S.shape
-    Sp = jnp.take(S, g, axis=0)
-    Lp = jnp.take(Lcat, g, axis=0)
-    rows = jnp.arange(m)
-
-    def body(U, i):
-        j0 = i * w
-        Srow = jax.lax.dynamic_slice(Sp, (j0, 0), (w, wS))
-        Lrow = jax.lax.dynamic_slice(Lp, (j0, 0), (w, bucket * w))
-        rhs = Srow - jnp.matmul(Lrow, U, precision=_HI)
-        Ljj = jax.lax.dynamic_slice(Lp, (j0, j0), (w, w))
-        Ui = _unit_lower_solve_capped(Ljj, rhs)
-        return jax.lax.dynamic_update_slice(U, Ui, (j0, 0)), None
-
-    U, _ = jax.lax.scan(body, jnp.zeros((bucket * w, wS), S.dtype),
-                        jnp.arange(bucket))
-    strip = (rows < count * w)[:, None]
-    trail = Sp - jnp.matmul(jnp.where(strip, 0, Lp), U,
-                            precision=_HI)
-    take = min(m, bucket * w)
-    Um = jnp.zeros((m, wS), S.dtype).at[:take].set(U[:take])
-    return jnp.zeros_like(S).at[g].set(jnp.where(strip, Um, trail))
-
-
-@functools.partial(jax.jit, static_argnames=("w", "bucket"))
-def _lu_visit_fused_mx(S: jax.Array, Lcat: jax.Array, g: jax.Array,
-                       count, w: int, bucket: int) -> jax.Array:
-    """Mixed twin of _lu_visit_fused: the stacked visitor operand
-    arrives in the lo dtype, strip solves run in full precision
-    against the promoted diagonal blocks, both the scan corrections
-    and the wide trailing product take lo inputs accumulating in S's
-    dtype (_lu_visit_mx's discipline, fused)."""
-    lo = Lcat.dtype
-    m, wS = S.shape
-    Sp = jnp.take(S, g, axis=0)
-    Lp = jnp.take(Lcat, g, axis=0)
-    rows = jnp.arange(m)
-
-    def body(U, i):
-        j0 = i * w
-        Srow = jax.lax.dynamic_slice(Sp, (j0, 0), (w, wS))
-        Lrow = jax.lax.dynamic_slice(Lp, (j0, 0), (w, bucket * w))
-        rhs = Srow - jnp.matmul(Lrow, U.astype(lo), precision=_HI,
-                                preferred_element_type=S.dtype)
-        Ljj = jax.lax.dynamic_slice(Lp, (j0, j0),
-                                    (w, w)).astype(S.dtype)
-        Ui = _unit_lower_solve_capped(Ljj, rhs)
-        return jax.lax.dynamic_update_slice(U, Ui, (j0, 0)), None
-
-    U, _ = jax.lax.scan(body, jnp.zeros((bucket * w, wS), S.dtype),
-                        jnp.arange(bucket))
-    strip = (rows < count * w)[:, None]
-    trail = Sp - jnp.matmul(jnp.where(strip, 0, Lp), U.astype(lo),
-                            precision=_HI,
-                            preferred_element_type=S.dtype)
-    take = min(m, bucket * w)
-    Um = jnp.zeros((m, wS), S.dtype).at[:take].set(U[:take])
-    return jnp.zeros_like(S).at[g].set(jnp.where(strip, Um, trail))
-
-
 @functools.partial(jax.jit, static_argnames=("wf", "chunk"))
 def _tnt_select(S: jax.Array, idx: jax.Array, live, wf: int,
                 chunk=None) -> jax.Array:
@@ -1393,8 +1173,7 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                      chunk: Optional[int] = None,
                      ckpt_path: Optional[str] = None,
                      ckpt_every: Optional[int] = None,
-                     precision=None, scheduler=None,
-                     visit_fuse=None):
+                     precision=None, scheduler=None):
     """Tournament-pivot (CALU) LU of a host-resident (m, n) matrix,
     streaming one column panel at a time — the out-of-core twin of
     getrf_tntpiv (reference src/getrf_tntpiv.cc:169-222). Returns
@@ -1438,15 +1217,7 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     the factor columns in bf16 (the immutable store is what makes
     demoted residents sound for LU), select/factor stay f32, and the
     checkpoint meta records the mode so a mismatched resume starts
-    fresh. gesv_ooc's refinement is the accuracy contract.
-
-    ``visit_fuse`` (ISSUE 20, potrf_ooc doc): under "fused" a panel's
-    full-width visits coalesce into one gathered scan + wide trailing
-    GEMM dispatch (_lu_visit_fused, one shared gather, count padded
-    to a power-of-two bucket); a ragged last member (kmax inside its
-    panel) stays per-panel after the fused dispatch. Results match
-    per_panel to <= 1e-12 (the trailing subtractions reassociate into
-    one contraction); the FROZEN "per_panel" default is bitwise."""
+    fresh. gesv_ooc's refinement is the accuracy contract."""
     from .ca import fix_degenerate_selection
     from .lu import tnt_swaps_host
     a = np.asarray(a)
@@ -1509,14 +1280,12 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                 gdev[j] = dev
         return dev
 
-    use_fuse = _resolve_visit_fuse(visit_fuse, n, a.dtype)
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype) or use_fuse
-    fvisit = _lu_visit_fused if lo is None else _lu_visit_fused_mx
+    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     led = _ledger.recorder("getrf_tntpiv_ooc", nt=nt,
                            spill_dir=ckpt_path)
     # loop body as closures (ISSUE 17; potrf_ooc comment) — the walk
     # and the left_looking graph policy drive the same code
-    S_live, F, fuse_meta = {}, {}, {}
+    S_live, F = {}, {}
 
     def _stage(k):
         _rfaults.check("step", op="getrf_tntpiv_ooc", step=k)
@@ -1546,42 +1315,6 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                          ld(stored[:, p0:p1]))
         with _ledger.frame("update"):
             S_live[k] = visit(S_live[k], Lj, _g(j), j0)
-
-    def _fused_update(k, js):
-        # ONE dispatch for panel k's visit sweep (ISSUE 20): the
-        # full-width members (a prefix of js — ragged means kmax
-        # falls inside the LAST factor panel) stack into one gathered
-        # scan + wide-trailing-GEMM kernel sharing a single index
-        # gather; the ragged member, if any, stays per-panel AFTER
-        # the fused dispatch — it is the max j, so the ascending
-        # visit order (and the PR 11 fault discipline) holds
-        js = list(js)
-        full = [j for j in js if (j + 1) * w <= kmax]
-        if len(full) > 1:
-            loaders = [(lambda j0=j * w:
-                        ld(stored[:, j0:j0 + w])) for j in full]
-            with _ledger.frame("stage"):
-                Lcat = eng.gather_stacked("LU", full, loaders)
-            count = len(full)
-            bucket = _fuse_bucket(count)
-            if bucket > count:
-                Lcat = jnp.concatenate(
-                    [Lcat, jnp.zeros((m, (bucket - count) * w),
-                                     Lcat.dtype)], axis=1)
-            _fuse_note_compile("getrf_tntpiv_ooc", m, w, bucket,
-                               str(Lcat.dtype))
-            with _ledger.frame("update"):
-                S_live[k] = fvisit(S_live[k], Lcat, _g(full[-1]),
-                                   count, w=w, bucket=bucket)
-            _fuse_count_visits(count)
-            fuse_meta[k] = {"fused_members": full,
-                            "fused_width": count * w}
-        else:
-            for j in full:
-                _update(k, j)
-        for j in js:
-            if j not in full:
-                _update(k, j)
 
     def _factor(k):
         k0, k1 = k * w, min(k * w + w, n)
@@ -1638,7 +1371,7 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
             eng.wait_writes()           # every panel <= k is durable
             ck.commit(k + 1)
         if led is not None:
-            led.commit(**fuse_meta.pop(k, {}))
+            led.commit()
 
     try:
         if use_graph:
@@ -1648,8 +1381,7 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                                                  w)),
                 stage=_stage, update=_update, factor=_factor,
                 writeback=_writeback,
-                has_factor=lambda k: k * w < kmax,
-                fused_update=_fused_update if use_fuse else None)
+                has_factor=lambda k: k * w < kmax)
             _sched_execute(g, op="getrf_tntpiv_ooc", nt=nt,
                            begin_step=_begin, end_step=_end)
         else:
@@ -1812,64 +1544,6 @@ def _qr_apply_fresh(S_rest: jax.Array, packed: jax.Array,
     return S_rest - jnp.matmul(V, W, precision=_HI)
 
 
-@functools.partial(jax.jit, static_argnames=("bucket",))
-def _qr_visit_fused(S: jax.Array, Pcat: jax.Array,
-                    taucat: jax.Array, j0s: jax.Array,
-                    bucket: int) -> jax.Array:
-    """Panel S's whole compact-WY visit sweep in ONE dispatch (ISSUE
-    20): a lax.scan over the stacked reflector panels runs
-    _qr_visit's exact body in ascending visitor order — the fused
-    sweep is a reordering-free serialization of the per-panel
-    applies, BITWISE equal to them (the Householder applies do not
-    commute, so this is the only legal fusion shape for QR). Pcat
-    concatenates the full-width packed visitor panels, zero-padded
-    up to `bucket` members; a padded slot (zero panel, zero taus,
-    offset 0) is an exact identity — _larft's zero-tau recursion
-    yields an exactly-zero T, so the step subtracts V @ 0."""
-    from .qr import _larft, _panel_V
-    m = S.shape[0]
-    w = Pcat.shape[1] // bucket
-    Pstk = Pcat.reshape(m, bucket, w).transpose(1, 0, 2)
-
-    def body(S, inp):
-        Pj, tauj, j0 = inp
-        V = _panel_V(Pj, j0)
-        T = _larft(V, tauj)
-        W = jnp.matmul(jnp.conj(V.T), S, precision=_HI)
-        W = jnp.matmul(jnp.conj(T.T), W, precision=_HI)
-        return S - jnp.matmul(V, W, precision=_HI), None
-
-    S, _ = jax.lax.scan(body, S, (Pstk, taucat, j0s))
-    return S
-
-
-@functools.partial(jax.jit, static_argnames=("bucket",))
-def _qr_visit_fused_mx(S: jax.Array, Pcat: jax.Array,
-                       taucat: jax.Array, j0s: jax.Array,
-                       bucket: int) -> jax.Array:
-    """Mixed twin of _qr_visit_fused: _qr_visit_mx's body under the
-    scan — lo tall matmuls accumulating in S's dtype, the w x w T
-    algebra in full precision from the promoted V."""
-    from .qr import _larft, _panel_V
-    lo = Pcat.dtype
-    m = S.shape[0]
-    w = Pcat.shape[1] // bucket
-    Pstk = Pcat.reshape(m, bucket, w).transpose(1, 0, 2)
-
-    def body(S, inp):
-        Pj, tauj, j0 = inp
-        V = _panel_V(Pj, j0)
-        T = _larft(V.astype(S.dtype), tauj)
-        W = jnp.matmul(jnp.conj(V.T), S.astype(lo), precision=_HI,
-                       preferred_element_type=S.dtype)
-        W = jnp.matmul(jnp.conj(T.T), W, precision=_HI)
-        return S - jnp.matmul(V, W.astype(lo), precision=_HI,
-                              preferred_element_type=S.dtype), None
-
-    S, _ = jax.lax.scan(body, S, (Pstk, taucat, j0s))
-    return S
-
-
 @instrument_driver("geqrf_ooc")
 def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
               incore_ib: int = 128, cache_budget_bytes=None,
@@ -1877,7 +1551,7 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
               grid=None, method=None,
               ckpt_path: Optional[str] = None,
               ckpt_every: Optional[int] = None,
-              precision=None, scheduler=None, visit_fuse=None):
+              precision=None, scheduler=None):
     """Householder QR of a host-resident (m, n) matrix, streaming one
     column panel at a time (left-looking; reference src/geqrf.cc:26).
     Returns (QR_packed, taus) in the same packed contract as geqrf:
@@ -1898,15 +1572,7 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     factorization, so the result carries bf16-grade update error —
     the mode is for pipelines that can pay it (or measure it).
     Composed runs (engine= shared) never mix: the shared cache must
-    hold one dtype's residents.
-
-    ``visit_fuse`` (ISSUE 20, potrf_ooc doc): under "fused" a
-    panel's ordered compact-WY applies run as ONE in-jit lax.scan
-    over the stacked reflector panels (_qr_visit_fused) — BITWISE
-    equal to the per-panel applies (a reordering-free serialization;
-    Householder applies do not commute, so QR fuses the dispatch,
-    not the math). A ragged last member stays per-panel after the
-    fused dispatch, preserving the apply order."""
+    hold one dtype's residents."""
     from ..core.exceptions import slate_assert
     a = np.asarray(a)
     m, n = a.shape
@@ -1935,14 +1601,12 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                 a, grid, panel_cols=w, incore_ib=incore_ib,
                 cache_budget_bytes=cache_budget_bytes,
                 ckpt_path=ckpt_path, ckpt_every=ckpt_every,
-                precision=precision, scheduler=scheduler,
-                visit_fuse=visit_fuse),
+                precision=precision, scheduler=scheduler),
             lambda: geqrf_ooc(a, w, incore_ib, cache_budget_bytes,
                               ckpt_path=ckpt_path,
                               ckpt_every=ckpt_every,
                               precision=precision,
-                              scheduler=scheduler,
-                              visit_fuse=visit_fuse),
+                              scheduler=scheduler),
             "geqrf_ooc", grid)
     nt = ceil_div(n, w)
     # checkpoint/resume (resil/, ISSUE 9): factor + taus live in
@@ -1968,16 +1632,14 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
         if own else engine
     ld = stream.host_demoter(lo)
     visit = _qr_visit if lo is None else _qr_visit_mx
-    fvisit = _qr_visit_fused if lo is None else _qr_visit_fused_mx
     epoch0 = ck.epoch if ck is not None else 0
-    use_fuse = _resolve_visit_fuse(visit_fuse, n, a.dtype)
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype) or use_fuse
+    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     led = _ledger.recorder("geqrf_ooc", nt=nt,
                            spill_dir=ckpt_path if engine is None
                            else None)
     # loop body as closures (ISSUE 17; potrf_ooc comment) — the walk
     # and the left_looking graph policy drive the same code
-    S_live, F, fuse_meta = {}, {}, {}
+    S_live, F = {}, {}
 
     def _stage(k):
         _rfaults.check("step", op="geqrf_ooc", step=k)
@@ -2002,48 +1664,6 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                          ld(out[:, p0:p1]))
         with _ledger.frame("update"):
             S_live[k] = visit(S_live[k], Pj, _h2d(taus[j0:j1]), j0)
-
-    def _fused_update(k, js):
-        # ONE dispatch for the ordered compact-WY sweep (ISSUE 20):
-        # the full-width members (a prefix of js) scan inside one
-        # jit in ascending order — bitwise vs the per-panel applies;
-        # a ragged last member stays per-panel AFTER the fused
-        # dispatch, preserving the apply order (and the PR 11 fault
-        # discipline: it is the max j)
-        js = list(js)
-        full = [j for j in js if (j + 1) * w <= kmax]
-        if len(full) > 1:
-            loaders = [(lambda j0=j * w:
-                        ld(out[:, j0:j0 + w])) for j in full]
-            with _ledger.frame("stage"):
-                Pcat = eng.gather_stacked("QR", full, loaders)
-            count = len(full)
-            bucket = _fuse_bucket(count)
-            if bucket > count:
-                Pcat = jnp.concatenate(
-                    [Pcat, jnp.zeros((m, (bucket - count) * w),
-                                     Pcat.dtype)], axis=1)
-            tstk = np.zeros((bucket, w), taus.dtype)
-            for i, j in enumerate(full):
-                tstk[i] = taus[j * w:(j + 1) * w]
-            # tiny offset vector, deliberately NOT via _h2d (the _g
-            # discipline: h2d counters stay panel-pure)
-            j0s = np.zeros((bucket,), np.int32)
-            j0s[:count] = np.asarray(full, np.int32) * w
-            _fuse_note_compile("geqrf_ooc", m, w, bucket,
-                               str(Pcat.dtype))
-            with _ledger.frame("update"):
-                S_live[k] = fvisit(S_live[k], Pcat, _h2d(tstk),
-                                   jnp.asarray(j0s), bucket=bucket)
-            _fuse_count_visits(count)
-            fuse_meta[k] = {"fused_members": full,
-                            "fused_width": count * w}
-        else:
-            for j in full:
-                _update(k, j)
-        for j in js:
-            if j not in full:
-                _update(k, j)
 
     def _pref_next(k):
         k0 = k * w
@@ -2094,7 +1714,7 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
             eng.wait_writes()           # every panel <= k is durable
             ck.commit(k + 1)
         if led is not None:
-            led.commit(**fuse_meta.pop(k, {}))
+            led.commit()
 
     try:
         if use_graph:
@@ -2104,8 +1724,7 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                                                  w)),
                 stage=_stage, update=_update, factor=_factor,
                 writeback=_writeback,
-                has_factor=lambda k: k * w < kmax,
-                fused_update=_fused_update if use_fuse else None)
+                has_factor=lambda k: k * w < kmax)
             _sched_execute(g, op="geqrf_ooc", nt=nt,
                            begin_step=_begin, end_step=_end)
         else:

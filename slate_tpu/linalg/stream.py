@@ -107,7 +107,7 @@ import concurrent.futures as cf
 import functools
 import threading
 import time
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import numpy as np
@@ -743,92 +743,6 @@ class StreamEngine:
         ok = self.cache.put(self.cache.key(buf, idx), arr)
         self._drain_spills()
         return ok
-
-    def gather_stacked(self, buf: str, idxs: Sequence[int],
-                       loaders: Sequence[Callable],
-                       view: Optional[Tuple[Any, int]] = None) -> Any:
-        """Serve panels ``buf[idxs]`` as ONE width-concatenated device
-        array — the fused visit sweep's stacked factor operand (ISSUE
-        20). Cache residents and pending prefetches are collected
-        per-panel through exactly :meth:`fetch`'s hit/pending paths;
-        the remaining misses are batched into a SINGLE host-side
-        concatenate and ONE guarded H2D (the ``h2d`` fault site fires
-        once, keyed by the first missing panel), then split back into
-        per-panel cache entries so later steps still hit (concatenate
-        then slice is exact, so a split entry is bit-identical to the
-        panel uploaded alone). With the cache off and nothing pending
-        this degenerates to the one stacked upload served as-is — the
-        batched analogue of the uncached fetch path."""
-        import jax.numpy as jnp
-        parts: list = [None] * len(idxs)
-        misses: list = []
-        use_cache = self.cache.enabled
-        for pos, idx in enumerate(idxs):
-            key = self.cache.key(buf, idx)
-            if use_cache:
-                arr = self.cache.get(
-                    key, None if view is None else view[1])
-                if arr is not None:
-                    parts[pos] = self._serve(arr, view)
-                    continue
-            with self._lock:
-                fut = self._pending.pop(key, None)
-            if fut is not None:
-                t0 = time.perf_counter()
-                with obs_events.span("ooc::wait_stage", cat="staging",
-                                     buf=buf, idx=idx,
-                                     kind="prefetch"):
-                    arr = fut.result()
-                dt = time.perf_counter() - t0
-                self.prefetch_wait_seconds += dt
-                _ledger.credit("stage", dt)
-                if use_cache:
-                    self.cache.put(key, arr)
-                    self._drain_spills()
-                    parts[pos] = self._serve(arr, view)
-                else:
-                    parts[pos] = arr
-                continue
-            misses.append(pos)
-        blocks: list = []
-        if misses:
-            t0 = time.perf_counter()
-            with _ledger.frame("stage"), \
-                    obs_events.span("ooc::wait_stage", cat="staging",
-                                    buf=buf, idx=idxs[misses[0]],
-                                    kind="sync"):
-                for pos in misses:
-                    self._wait_write(buf, idxs[pos])
-                blocks = [np.ascontiguousarray(loaders[pos]())
-                          for pos in misses]
-                host = blocks[0] if len(blocks) == 1 \
-                    else np.concatenate(blocks, axis=1)
-                stacked = _guard_transfer(
-                    "h2d", lambda: _h2d(host),
-                    buf=buf, idx=idxs[misses[0]])
-                with self.cache._lock:
-                    self.cache.uploaded_bytes += _nbytes(stacked)
-            self.sync_upload_seconds += time.perf_counter() - t0
-            if len(misses) == len(idxs) and not use_cache \
-                    and view is None:
-                return stacked   # the pure uncached batched upload
-            off = 0
-            for pos, blk in zip(misses, blocks):
-                wj = int(blk.shape[1])
-                arr = stacked[:, off:off + wj]
-                off += wj
-                if use_cache:
-                    self.cache.put(self.cache.key(buf, idxs[pos]),
-                                   arr)
-                    parts[pos] = self._serve(arr, view)
-                else:
-                    # cache-off loaders return the exact kernel
-                    # input; `view` is ignored, same as fetch()
-                    parts[pos] = arr
-            self._drain_spills()
-        if len(parts) == 1:
-            return parts[0]
-        return jnp.concatenate(parts, axis=1)
 
     # -- dirty working panels (multi-shard extension, ISSUE 7) ------
 

@@ -10,11 +10,6 @@ with a node *kind* from the closed set :data:`NODE_KINDS`:
                 composed OOC solve policies; no current constructor
                 emits one)
     update      a trailing-panel update against a finished panel
-    fused_update  one coalesced dispatch covering a step's whole
-                update sweep (ISSUE 20 — the per-(panel, step) update
-                nodes of a slot grouped into a single wide-GEMM /
-                lax.scan kernel launch; ledger-credits the ``update``
-                phase once with per-member meta)
     bcast       broadcast issue/completion of a factored panel
     writeback   durable writeback of results (device->host mirrors)
 
@@ -50,8 +45,8 @@ from ..core.exceptions import slate_assert
 
 #: the CLOSED set of node kinds (tools/slate_lint SL701 pins the
 #: attribution tables below complete over it)
-NODE_KINDS = ("stage", "factor", "solve", "update", "fused_update",
-              "bcast", "writeback")
+NODE_KINDS = ("stage", "factor", "solve", "update", "bcast",
+              "writeback")
 
 #: node kind -> obs/ledger.py PHASES attribution column. 1:1 onto the
 #: ledger's closed phase set: the executor wraps each node's closure
@@ -64,7 +59,6 @@ PHASE_OF_KIND = {
     "factor": "factor",
     "solve": "update",
     "update": "update",
-    "fused_update": "update",
     "bcast": "bcast_wait",
     "writeback": "cache",
 }
@@ -80,7 +74,6 @@ FAULT_SITE_OF_KIND = {
     "factor": None,
     "solve": None,
     "update": None,
-    "fused_update": None,
     "bcast": "ppermute",
     "writeback": "d2h",
 }

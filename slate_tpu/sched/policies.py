@@ -46,7 +46,7 @@ from __future__ import annotations
 
 import time
 from functools import partial
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, Optional, Sequence
 
 from ..obs import events as obs_events
 from ..obs import ledger as _ledger
@@ -62,9 +62,7 @@ def left_looking(op: str, *,
                  update: Callable[[int, int], None],
                  factor: Callable[[int], None],
                  writeback: Callable[[int], None],
-                 has_factor: Optional[Callable[[int], bool]] = None,
-                 fused_update: Optional[
-                     Callable[[int, Sequence[int]], None]] = None
+                 has_factor: Optional[Callable[[int], bool]] = None
                  ) -> TaskGraph:
     """Single-engine left-looking stream as a graph.
 
@@ -78,28 +76,15 @@ def left_looking(op: str, *,
     panel j's writeback — for j below the resume epoch that producer
     is outside the graph (the update closure reads the durable
     factor mirror), so the edge is simply absent.
-
-    ``fused_update(k, js)`` (ISSUE 20) coalesces panel k's whole
-    visit sweep into ONE ``fused_update`` node (one dispatch over the
-    concatenated factor widths) whenever the sweep has more than one
-    member; single-visit sweeps keep the per-panel ``update`` node
-    (one visit is already one dispatch). Absent, the construction is
-    byte-identical to the per-panel graph (the cold-route pin)."""
+    """
     g = TaskGraph(op)
     wb: Dict[int, Any] = {}
     for k in panels:
         prev = g.add("stage", partial(stage, k), panel=k, key=(k, 0))
-        js = list(updates(k))
-        if fused_update is not None and len(js) > 1:
-            prev = g.add("fused_update",
-                         partial(fused_update, k, js), panel=k,
-                         key=(k, 1, 0),
-                         deps=[prev] + [wb.get(j) for j in js])
-        else:
-            for j in js:
-                prev = g.add("update", partial(update, k, j), panel=k,
-                             step=j, key=(k, 1, j),
-                             deps=[prev, wb.get(j)])
+        for j in updates(k):
+            prev = g.add("update", partial(update, k, j), panel=k,
+                         step=j, key=(k, 1, j),
+                         deps=[prev, wb.get(j)])
         if has_factor is None or has_factor(k):
             prev = g.add("factor", partial(factor, k), panel=k,
                          key=(k, 2), deps=[prev])
@@ -119,9 +104,7 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
                    tail: Optional[Callable[[int], None]] = None,
                    applied_through: Optional[Callable[[int], int]]
                    = None,
-                   trailing_to: Optional[int] = None,
-                   fused_apply: Optional[Callable] = None
-                   ) -> TaskGraph:
+                   trailing_to: Optional[int] = None) -> TaskGraph:
     """The sharded right-looking walk as a graph (module doc table).
 
     Takes the SAME driver closures _BcastPipeline takes (payload_shape
@@ -142,19 +125,7 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
     pruned-aware consumer still needs their record, which keeps the
     per-segment replay H2D proportional to actual catch-up instead
     of O(nt^2) across segments. Defaults (None/None) are exactly the
-    unsegmented PR 17 construction.
-
-    ``fused_apply(Ss, rec, ps, s)`` (ISSUE 20): when supplied, each
-    slot's trailing sweep over the owned panels — every non-promoted
-    update consuming record ``s`` — collapses into ONE
-    ``fused_update`` node whose closure stages all members, fires
-    each member's ``step`` fault check in ascending panel order (the
-    PR 11 once-per-panel discipline; the checked-set keeps later
-    per-panel nodes from re-firing it), and issues the driver's one
-    stacked dispatch. Promoted window catch-up updates stay
-    per-panel (they interleave with the factor stream), as do
-    single-member sweeps (already one dispatch). Absent, the
-    construction is byte-identical to the per-panel graph."""
+    unsegmented PR 17 construction."""
     d = max(int(depth), 0)
     ep = int(epoch)
     at = applied_through if applied_through is not None \
@@ -217,24 +188,6 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
     pref_of = {sweep0[i]: sweep0[i + 1]
                for i in range(len(sweep0) - 1)}
 
-    # fused sweep membership (ISSUE 20): slot -> its non-promoted
-    # owned consumers, in the per-panel sweep's intra-slot key order
-    # (window tails first, then ascending). In fused mode EVERY sweep
-    # node — the multi-member fused dispatch and the single-member
-    # per-panel fallback alike — is constructed at its slot's
-    # assembly iteration, so a panel's update chain is built in
-    # ascending record order even when its slots alternate between
-    # fused and solo (segmented ``applied_through`` maps make the
-    # member sets non-monotone across slots).
-    sweep_of: Dict[int, List[int]] = {}
-    if fused_apply is not None:
-        for q in mine_tr:
-            for s in range(at(q), min(q, last + 1)):
-                if not _promo(q, s):
-                    sweep_of.setdefault(s, []).append(q)
-        for s in sweep_of:
-            sweep_of[s].sort(key=lambda q: (0 if q <= s + d else 1, q))
-
     # --- node closures ----------------------------------------------
     def _run_stage(p: int) -> None:
         sj[p] = st.take(p)
@@ -268,37 +221,6 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
         if not promo:
             obs_metrics.inc("ooc.shard.update_seconds",
                             time.perf_counter() - t0)
-
-    def _run_fused_update(s: int, members: List[int]) -> None:
-        # each member's step check, ascending panel order (PR 11
-        # once-per-panel discipline — the checked-set keeps the
-        # members' later per-panel nodes from re-firing it)
-        for p in sorted(members):
-            _chk(p)
-        t0 = time.perf_counter()
-        Ss = []
-        with _ledger.frame("stage"):
-            for p in members:
-                S = sj.pop(p, None)
-                if S is None:
-                    S = st.take(p)
-                Ss.append(S)
-        r = recs[s]
-        with obs_events.span("shard::update", cat="shard", step=s,
-                             fused=len(members)), \
-                _ledger.frame("update"):
-            Ss = fused_apply(Ss, r, list(members), s)
-        for p, S in zip(members, Ss):
-            st.stash(p, S)
-        remaining[s] -= len(members)
-        if remaining[s] <= 0:
-            recs.pop(s, None)
-        if obs_events.enabled():
-            obs_metrics.inc("ooc.visits_fused", len(members))
-            obs_metrics.inc("ooc.visit_dispatches_saved",
-                            len(members) - 1)
-        obs_metrics.inc("ooc.shard.update_seconds",
-                        time.perf_counter() - t0)
 
     def _run_factor(i: int) -> None:
         _chk(i)
@@ -344,11 +266,9 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
         npanels = max(npanels, int(trailing_to))
     for p in range(npanels):
         if p in mine_set:
-            prev = un_last.get(p)
+            prev = None
             for s in range(at(p), min(p, last + 1)):
                 promo = _promo(p, s)
-                if fused_apply is not None and not promo:
-                    continue     # built at slot s's iteration below
                 if promo:
                     key = (max(p - d, 0), 1, p, s, 1)
                 else:
@@ -391,37 +311,6 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
                                panel=p, owner=owner,
                                key=(slot_wb(p), 0, p, 0, 0),
                                deps=[wbn.get(p - 1)])
-            # slot p's trailing sweep in fused mode (ISSUE 20): one
-            # fused_update node when the sweep has >1 member; the
-            # per-panel fallback for a solo member (already one
-            # dispatch). Built here — after record p's writeback/
-            # replay node — so every member's chain grows in
-            # ascending record order.
-            ms = sweep_of.get(p, ())
-            if len(ms) > 1:
-                fn = g.add(
-                    "fused_update",
-                    partial(_run_fused_update, p, list(ms)),
-                    step=p, owner=sched.owner_flat(p),
-                    key=(p, 4, 0 if ms[0] <= p + d else 1, ms[0], 1),
-                    deps=[wbn.get(p)] + [un_last.get(q) for q in ms])
-                for q in ms:
-                    un_last[q] = fn
-            elif len(ms) == 1:
-                q = ms[0]
-                key = (p, 4, 0 if q <= p + d else 1, q, 1)
-                prevq = un_last.get(q)
-                if prevq is None:
-                    prevq = g.add("stage", partial(_run_stage, q),
-                                  panel=q,
-                                  owner=sched.owner_flat(q),
-                                  key=key[:-1] + (0,))
-                un_last[q] = g.add(
-                    "update",
-                    partial(_run_update, q, p, False,
-                            pref_of.get(q) if p == 0 else None),
-                    panel=q, step=p, owner=sched.owner_flat(p),
-                    key=key, deps=[prevq, wbn.get(p)])
         elif p in tail_set:
             prev_tail = g.add("bcast", partial(_run_tail, p),
                               panel=p, owner=sched.owner_flat(p),

@@ -104,17 +104,6 @@ FROZEN: Dict[tuple, Any] = {
     # an earned (bench --graph) or explicit decision (core/methods
     # .MethodScheduler)
     ("ooc", "scheduler"): "walk",          # walk | graph
-    # fused visit sweeps (ISSUE 20): "per_panel" keeps one jitted
-    # visit kernel per (factor panel, target panel) pair — the PR 19
-    # dispatch schedule bit-identically on a cold cache; "fused"
-    # coalesces each step's update sweep into ONE dispatch (wide GEMM
-    # over concatenated factor widths for the potrf/getrf
-    # left-looking visits, an in-jit lax.scan for geqrf's ordered
-    # compact-WY applies and the sharded trailing sweep), compiled
-    # once per (height, width, count-bucket) — an earned (bench
-    # --fuse, real-MXU hardware round) or explicit decision
-    # (core/methods.MethodVisitFuse)
-    ("ooc", "visit_fuse"): "per_panel",    # per_panel | fused
     # elastic mesh ownership (ISSUE 19): "static" keeps the pure
     # 2D-block-cyclic CyclicSchedule assignment bit-identically on a
     # cold cache; "elastic" re-derives per-host effective throughput

@@ -21,9 +21,6 @@ two processes on the global 2x4 virtual-CPU mesh, exercising
     cold route is bitwise on the real mesh for all three drivers
     (default vs explicit "f32"), and the bf16 mode's broadcast
     frames carry exactly half the bytes across the process boundary;
-  * fused visit sweeps (ISSUE 20): visit_fuse="fused" on the real
-    mesh — one stacked-scan dispatch per owned slot's sweep, bitwise
-    vs the per-panel walk, coalescing counters nonzero on both hosts;
   * per-host obs staging spans exported with the PR 5 tid namespace,
     so the parent can merge both hosts' Perfetto traces into one
     timeline.
@@ -203,37 +200,6 @@ mp.emit("shard_graph", proc=pid,
                                           np.asarray(lug))
                            and np.array_equal(np.asarray(piv2),
                                               np.asarray(pivg))))
-
-# -- fused visit sweeps (ISSUE 20): visit_fuse="fused" across the
-# process boundary — each owned slot's non-promoted consumers land in
-# ONE stacked-scan dispatch, bitwise vs the per-panel walk's depth-0
-# factors (at depth 0 EVERY owned sweep is fuseable, so both hosts
-# coalesce), and the coalescing counters prove dispatches were saved
-# on BOTH hosts
-metrics.reset()
-Lf = shard_ooc.shard_potrf_ooc(a, grid, panel_cols=w,
-                               cache_budget_bytes=budget,
-                               visit_fuse="fused")
-qrf, tauf = shard_ooc.shard_geqrf_ooc(g, grid, panel_cols=w,
-                                      cache_budget_bytes=budget,
-                                      visit_fuse="fused")
-luf, pivf = shard_ooc.shard_getrf_ooc(lp, grid, panel_cols=w,
-                                      cache_budget_bytes=budget,
-                                      visit_fuse="fused")
-c = metrics.snapshot()["counters"]
-mp.emit("shard_fuse", proc=pid,
-        potrf_bitwise=bool(np.array_equal(np.asarray(L1),
-                                          np.asarray(Lf))),
-        geqrf_bitwise=bool(np.array_equal(np.asarray(qr1),
-                                          np.asarray(qrf))
-                           and np.array_equal(np.asarray(tau1),
-                                              np.asarray(tauf))),
-        getrf_bitwise=bool(np.array_equal(np.asarray(lu1),
-                                          np.asarray(luf))
-                           and np.array_equal(np.asarray(piv1),
-                                              np.asarray(pivf))),
-        visits_fused=int(c.get("ooc.visits_fused", 0)),
-        dispatches_saved=int(c.get("ooc.visit_dispatches_saved", 0)))
 
 # -- mixed-precision streaming (ISSUE 12): the frozen cold route is
 # bitwise on the REAL mesh for all three drivers (default vs explicit

@@ -104,17 +104,6 @@ def test_two_process_shard_ooc(tmp_path):
         assert gr["potrf_bitwise"] and gr["geqrf_bitwise"] \
             and gr["getrf_bitwise"]
 
-    # fused visit sweeps (ISSUE 20): one stacked-scan dispatch per
-    # owned slot's sweep on the real mesh — bitwise vs the per-panel
-    # walk for all three drivers, and every host coalesced at least
-    # one multi-member sweep (saved = fused - sweeps > 0)
-    for r in recs:
-        fz = r["shard_fuse"]
-        assert fz["potrf_bitwise"] and fz["geqrf_bitwise"] \
-            and fz["getrf_bitwise"]
-        assert fz["visits_fused"] > 0
-        assert 0 < fz["dispatches_saved"] < fz["visits_fused"]
-
     # mixed-precision streaming (ISSUE 12): the frozen cold route is
     # bitwise on the real mesh (default vs explicit "f32" for all
     # three drivers), and the bf16 potrf's broadcast frames carried

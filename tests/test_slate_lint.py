@@ -44,14 +44,13 @@ def test_registry_covers_all_analyzers():
         "shard-lookahead", "precision", "tune-keys",
         "lock-discipline", "obs-literals", "fault-sites",
         "flight-recorder", "sched-graph", "reqtrace-ctx",
-        "elastic-mesh", "visit-fuse"}
+        "elastic-mesh"}
     codes = {c for a in REGISTRY.values() for c in a.codes}
     assert {"SL101", "SL102", "SL103", "SL104", "SL105", "SL106",
             "SL201", "SL202", "SL203", "SL301", "SL401", "SL402",
             "SL501", "SL502", "SL503", "SL601", "SL602",
             "SL603", "SL701", "SL702", "SL703", "SL801",
-            "SL802", "SL803", "SL901", "SL902", "SL903",
-            "SL1001", "SL1002", "SL1003"} == codes
+            "SL802", "SL803", "SL901", "SL902", "SL903"} == codes
 
 
 def test_clean_on_live_tree():
@@ -964,85 +963,6 @@ def test_elastic_mesh_catches_table_blind_override(tmp_path):
     res = _only(repo, "elastic-mesh")
     assert _codes(res.findings) == ["SL901"]
     assert "owner_coords" in res.findings[0].message
-
-
-# -- visit-fuse (SL1001/SL1002/SL1003) ------------------------------------
-
-_FUSE_GRAPH = """
-    NODE_KINDS = ("stage", "update", "fused_update", "factor")
-    PHASE_OF_KIND = {"stage": "stage", "update": "update",
-                     "fused_update": "update", "factor": "factor"}
-    FAULT_SITE_OF_KIND = {"stage": "h2d", "update": None,
-                          "fused_update": None, "factor": "step"}
-"""
-
-_FUSE_KERNELS = """
-    def _qr_visit_fused(S, Pcat, taucat, j0s, bucket):
-        return S - Pcat @ S
-
-    def _qr_visit_fused_mx(S, Pcat, taucat, j0s, bucket):
-        return S - jnp.matmul(Pcat, S,
-                              preferred_element_type=S.dtype)
-
-    def _fused_sweep_qr(Ss, Pk, tk, k0):
-        return _qr_visit(Ss, Pk, tk, k0)
-
-    def _fused_sweep_qr_mx(Ss, Pk, tk, k0):
-        return _qr_visit_mx(Ss, Pk, tk, k0)
-"""
-
-
-def test_visit_fuse_clean(tmp_path):
-    repo = _write(tmp_path, {
-        "slate_tpu/sched/graph.py": _FUSE_GRAPH,
-        "slate_tpu/tune/cache.py": """
-            FROZEN = {("ooc", "visit_fuse"): "per_panel"}
-        """,
-        "slate_tpu/core/methods.py": """
-            def resolve_visit_fuse(n, dtype):
-                return _resolve("ooc", "visit_fuse", n=n,
-                                dtype=dtype)
-        """,
-        "slate_tpu/linalg/ooc.py": _FUSE_KERNELS,
-    })
-    res = _only(repo, "visit-fuse")
-    assert res.findings == []
-
-
-def test_visit_fuse_catches_all_three(tmp_path):
-    repo = _write(tmp_path, {
-        "slate_tpu/sched/graph.py": """
-            NODE_KINDS = ("stage", "update")  # kind missing: SL1001
-            PHASE_OF_KIND = {"stage": "stage", "update": "update",
-                             "fused_update": "factor"}  # SL1001
-            FAULT_SITE_OF_KIND = {"stage": "h2d",
-                                  "update": None}       # SL1001
-        """,
-        "slate_tpu/tune/cache.py": """
-            FROZEN = {("ooc", "scheduler"): "walk"}  # row gone: SL1002
-        """,
-        "slate_tpu/linalg/ooc.py": """
-            def _fused_sweep_lu(Ss, Pk, g, k0):
-                # mixed marker on the BASE + no twin: SL1003 twice
-                return jnp.matmul(Ss, Pk,
-                                  preferred_element_type=Ss.dtype)
-
-            def _lu_visit_fused(S, Lcat, g, count, w, bucket):
-                return S - Lcat @ S
-
-            def _lu_visit_fused_mx(S, Lcat, g, count, w, bucket):
-                return S - Lcat @ S   # markerless twin: SL1003
-        """,
-    })
-    res = _only(repo, "visit-fuse")
-    assert _codes(res.findings) == [
-        "SL1001", "SL1001", "SL1001", "SL1002", "SL1002",
-        "SL1003", "SL1003", "SL1003"]
-    msgs = " ".join(f.message for f in res.findings)
-    assert "fused_update" in msgs
-    assert "('ooc', 'visit_fuse')" in msgs
-    assert "_fused_sweep_lu_mx twin" in msgs
-    assert "_lu_visit_fused_mx" in msgs
 
 
 # -- baseline + CLI ------------------------------------------------------

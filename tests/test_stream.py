@@ -570,26 +570,180 @@ def test_potrf_factor_bitwise_what_fresh_staging_gave(rng, ring,
     assert np.abs(want @ want.T - a).max() < 1e-3 * np.abs(a).max()
 
 
-def test_stage_counters_cover_the_copied_share_of_h2d(rng, ring, obs_on):
+#: the streamed drivers on a square f32 problem: (what to call with
+#: (a_spd, a_general, b, w, budget), the bytes of ooc.h2d_bytes whose
+#: source is contiguous already and so passes _h2d uncopied)
+_DRIVERS = {
+    "potrf_ooc": (
+        lambda spd, g, b, w, bud: ooc.potrf_ooc(
+            spd, panel_cols=w, cache_budget_bytes=bud),
+        lambda n, nt, w, b: 0),
+    # the partial-pivot walk gathers each input panel through its row
+    # permutation, and numpy returns the gather contiguous
+    "getrf_ooc": (
+        lambda spd, g, b, w, bud: ooc.getrf_ooc(
+            g, panel_cols=w, cache_budget_bytes=bud),
+        lambda n, nt, w, b: n * n * 4),
+    "getrf_tntpiv_ooc": (
+        lambda spd, g, b, w, bud: ooc.getrf_tntpiv_ooc(
+            g, panel_cols=w, cache_budget_bytes=bud),
+        lambda n, nt, w, b: 0),
+    # one row of w taus beside every visiting reflector panel
+    "geqrf_ooc": (
+        lambda spd, g, b, w, bud: ooc.geqrf_ooc(
+            g, panel_cols=w, cache_budget_bytes=bud),
+        lambda n, nt, w, b: (nt * (nt - 1) // 2) * w * 4),
+    "posv_ooc": (
+        lambda spd, g, b, w, bud: ooc.posv_ooc(
+            spd, b, panel_cols=w, cache_budget_bytes=bud),
+        lambda n, nt, w, b: b.nbytes),
+}
+
+
+@pytest.mark.parametrize("driver", sorted(_DRIVERS))
+def test_stream_staging_goes_through_the_ring(rng, ring, obs_on, driver):
     """ooc.h2d_stage_reuse_bytes + ooc.h2d_stage_fresh_bytes is the
     share of ooc.h2d_bytes that needed the host-side copy: every panel
-    of posv_ooc (a column slice), and not its contiguous right-hand
-    side."""
+    a streamed driver stages (a column slice of a host matrix), the
+    revisits a two-panel cache forces among them, and not the sources
+    that are contiguous already. A path that copies a panel into a
+    buffer of its own and hands _h2d the copy leaves its bytes out of
+    the sum."""
     from slate_tpu.obs import metrics
-    n, w = 256, 64
-    a = _spd(rng, n, np.float32)
+    n, w = 256, 32
+    nt = n // w
+    run, contiguous = _DRIVERS[driver]
     b = _f32(rng, n, 3)
-    ooc.posv_ooc(a, b, panel_cols=w, cache_budget_bytes=2 * n * w * 4)
+    run(_spd(rng, n, np.float32), _f32(rng, n, n), b, w, 2 * n * w * 4)
     c = metrics.snapshot()["counters"]
+    assert c["ooc.cache.misses"] > nt           # panels staged again
     staged = c["ooc.h2d_stage_reuse_bytes"] + c["ooc.h2d_stage_fresh_bytes"]
-    assert staged == c["ooc.h2d_bytes"] - b.nbytes
+    assert staged == c["ooc.h2d_bytes"] - contiguous(n, nt, w, b)
+    assert staged >= nt * n * w * 4 // 2
     assert c["ooc.h2d_stage_fresh_bytes"] > 0
     # a contiguous source passes through and is counted in neither
-    stream._h2d(np.ascontiguousarray(a[:, :w]))
+    stream._h2d(np.ascontiguousarray(b))
     c2 = metrics.snapshot()["counters"]
-    assert c2["ooc.h2d_bytes"] == c["ooc.h2d_bytes"] + n * w * 4
+    assert c2["ooc.h2d_bytes"] == c["ooc.h2d_bytes"] + b.nbytes
     assert c2["ooc.h2d_stage_reuse_bytes"] + \
         c2["ooc.h2d_stage_fresh_bytes"] == staged
+
+
+def test_stream_copies_host_panels_in_h2d_only():
+    """By reading linalg/stream.py: no function but _h2d makes a host
+    copy of a panel (np.ascontiguousarray, or joining panels into a new
+    array), so no staging path goes around the ring."""
+    import ast
+    tree = ast.parse(open(stream.__file__).read())
+    fresh = {"ascontiguousarray", "concatenate", "stack", "hstack"}
+    found = []
+    for fn in ast.walk(tree):
+        if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            continue
+        for node in ast.walk(fn):
+            if isinstance(node, ast.Call) \
+                    and isinstance(node.func, ast.Attribute) \
+                    and node.func.attr in fresh \
+                    and isinstance(node.func.value, ast.Name) \
+                    and node.func.value.id == "np":
+                found.append((fn.name, node.func.attr))
+    assert found == [("_h2d", "ascontiguousarray")]
+
+
+@pytest.mark.parametrize("driver", sorted(_DRIVERS))
+def test_stream_rerun_compiles_nothing(rng, obs_on, driver):
+    """A second call at the same shape traces and compiles nothing
+    (the tier-1 twin of benchmarks/run.py's exit 3): the jit.* counters
+    of the compile listener and obs.metrics.recompiles() stay where the
+    first call left them, while a program jitted anew moves them."""
+    import jax
+    from slate_tpu.obs import metrics
+    n, w = 256, 32          # the staging test's shapes: compiled once
+    run, _ = _DRIVERS[driver]
+    args = (_spd(rng, n, np.float32), _f32(rng, n, n), _f32(rng, n, 3),
+            w, 2 * n * w * 4)
+
+    def jit_counters():
+        c = metrics.snapshot()["counters"]
+        return {k: v for k, v in c.items() if k.startswith("jit.")}
+
+    run(*args)
+    before, recompiled = jit_counters(), metrics.recompiles()
+    run(*args)
+    assert jit_counters() == before
+    assert metrics.recompiles() == recompiled
+    jax.jit(lambda x: x * 3.25 + 1.0)(np.ones(7, np.float32))
+    assert jit_counters().get("jit.backend_compile_seconds", 0.0) \
+        > before.get("jit.backend_compile_seconds", 0.0)
+
+
+# -- what one posv_ooc stages, from its schedule --------------------------
+
+def _posv_staged_panels(nt, resident):
+    """Host model of posv_ooc's staging in full-height panels
+    (n x w): the schedule of linalg/ooc.py restated, with the panel
+    cache as a list. The cache holds `resident` full-height panels;
+    when full it gives up the most recently used one that is not one
+    of the last two touched (policy mru, two pins)."""
+    held, pins, staged = [], [], 0.0
+
+    def touch(j):
+        """A fetch or a put of panel j; True when it was resident."""
+        nonlocal pins
+        hit = j in held
+        if hit:
+            held.remove(j)
+        else:
+            while len(held) >= resident:
+                victim = next((p for p in reversed(held)
+                               if p not in pins), None)
+                if victim is None:
+                    return False            # only pinned panels: not kept
+                held.remove(victim)
+        held.append(j)
+        pins = (pins + [j])[-2:]
+        return hit
+
+    for k in range(nt):                     # potrf_ooc, left-looking
+        below = (nt - k) / nt               # rows k0: of a panel
+        staged += below                     # A[k0:, k], never cached
+        for j in range(k):                  # the visits of L[:, j]
+            if not resident:
+                staged += below             # uncached: rows k0: only
+            elif not touch(j):
+                staged += 1.0               # cached: full height
+        if resident:
+            touch(k)                        # the factored panel is put
+    held, pins = [], []                     # potrs_ooc: an engine of its own
+    for j in [*range(nt), *reversed(range(nt))]:
+        if not resident or not touch(j):    # forward, then backward
+            staged += 1.0
+    return staged
+
+
+@pytest.mark.parametrize("nt,resident,panels", [
+    (8, 5, 20.5), (8, 0, 31.0), (8, 8, 12.5), (6, 3, 21.5), (4, 2, 9.5)])
+def test_posv_ooc_staged_bytes_match_schedule(rng, obs_on, nt, resident,
+                                              panels):
+    """ooc.h2d_bytes of one posv_ooc is what its schedule says: the
+    input panels below their diagonal, the visits a cache of `resident`
+    full-height panels misses, potrs_ooc's two sweeps, and the
+    right-hand side. `panels` is the model's answer, written down so
+    that a change of schedule shows in the diff; (8, 5) is the streamed
+    cell's shape, 20.5 panels and the right-hand side, which at n=32768,
+    w=4096, nrhs=8 is the 11.006902272 GB the benchmark reads as
+    stream.h2d_gb."""
+    from slate_tpu.obs import metrics
+    assert _posv_staged_panels(nt, resident) == panels
+    assert int(_posv_staged_panels(8, 5) * 32768 * 4096 * 4) \
+        + 32768 * 8 * 4 == 11006902272
+    w, nrhs = 32, 8
+    n = nt * w
+    a, b = _spd(rng, n, np.float32), _f32(rng, n, nrhs)
+    ooc.posv_ooc(a, b, panel_cols=w,
+                 cache_budget_bytes=resident * n * w * 4)
+    got = metrics.snapshot()["counters"]["ooc.h2d_bytes"]
+    assert got == int(panels * n * w * 4) + n * nrhs * 4
 
 
 def test_writebacks_in_flight_are_bounded(monkeypatch, obs_on):

@@ -75,15 +75,6 @@ Rule-numbering history (the check_instrumented.py lineage):
                        relabels the committed prefix, FROZEN mesh/*
                        rows + literal readers (:mod:`.elastic_mesh`)
 
-* PR 20 (ISSUE 20):
-
-    SL1001/SL1002/SL1003  fused-visit-sweep contract: the
-                       fused_update kind registered with its
-                       update-phase/no-own-site contract, FROZEN
-                       ooc/visit_fuse row + literal reader, _mx
-                       twin discipline over the fused kernels
-                                             (:mod:`.visit_fuse`)
-
 Extending: add a module with a ``@core.register(name, codes, doc)``
 function ``analyze(repo) -> [core.Finding]``, import it below, and
 give it one clean + one violating fixture case in
@@ -106,6 +97,5 @@ from . import flight          # noqa: F401,E402
 from . import sched_graph     # noqa: F401,E402
 from . import reqtrace_ctx    # noqa: F401,E402
 from . import elastic_mesh    # noqa: F401,E402
-from . import visit_fuse      # noqa: F401,E402
 
 from .obs_literals import generate_reference  # noqa: F401,E402
