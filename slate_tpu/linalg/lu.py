@@ -20,6 +20,7 @@ convention, 0-based) — the reference's Pivots = vector<vector<Pivot>>
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -27,14 +28,14 @@ import jax.numpy as jnp
 
 from ..core.enums import Diag, MatrixType, Op, Side, Uplo
 from ..core.exceptions import slate_assert
-from ..core.methods import MethodFactor, MethodLU
+from ..core.methods import MethodFactor, MethodLU, MethodLUPanel
 from ..core.options import Option, OptionsLike, get_option
 from ..core.tiles import TiledMatrix, ceil_div, pad_diag_identity
 from ..obs import events as obs_events
 from ..obs.events import instrument_driver
 from ..resil import guard as _rguard
 from .blas3 import _store, trsm
-from .blocked import invert_triangular
+from .blocked import assemble_packed, invert_triangular
 
 
 class LUFactors(NamedTuple):
@@ -268,6 +269,76 @@ def _lu_u12(l11: jax.Array, rhs: jax.Array, grid) -> jax.Array:
     return jnp.matmul(linv, rhs, precision=jax.lax.Precision.HIGHEST)
 
 
+@functools.partial(jax.jit, static_argnames=("w", "method"))
+def _carry_panel(trail: jax.Array, w: int, method: MethodLUPanel):
+    """One step's panel of `_getrf_carry`: the leading `w` columns
+    factored, as (packed LU, local swap targets, their composed
+    permutation). `method` is the route the caller resolved; it is
+    static, so a tune entry that moves it gets a program of its own.
+    The native custom call returns the composed permutation itself."""
+    panel = trail[:, :w]
+    if method is MethodLUPanel.Native:
+        lu, piv, perm = jax.lax.linalg.lu(panel)
+        return lu, piv.astype(jnp.int32), perm
+    # panels the native call cannot take (scoped-vmem height limit /
+    # dtype) or that the tune cache routed elsewhere: _lu_panel
+    # arbitrates (true partial pivoting preserved)
+    lu, piv = _lu_panel(panel)
+    return lu, piv, _compose_swaps(piv, trail.shape[0])
+
+
+@functools.partial(jax.jit, static_argnames="w")
+def _carry_swap(trail: jax.Array, perm: jax.Array, w: int) -> jax.Array:
+    """The columns right of a step's panel, in the panel's row order.
+    `w` is static."""
+    return _permute_rows(trail[:, w:], perm)
+
+
+@jax.jit
+def _carry_update(lu: jax.Array, rest: jax.Array
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """One step's U12 strip and the trailing matrix it leaves."""
+    w = lu.shape[1]
+    u12 = jax.lax.linalg.triangular_solve(
+        lu[:w], rest[:w], left_side=True, lower=True,
+        unit_diagonal=True)
+    if lu.shape[0] == w:
+        return u12, rest[w:]
+    return u12, rest[w:] - jnp.matmul(
+        lu[w:], u12, precision=jax.lax.Precision.HIGHEST)
+
+
+@functools.partial(jax.jit, static_argnames=("nb", "kmax", "M", "N"))
+def _carry_finish(panels, perms, urows, pivs, *, nb: int, kmax: int,
+                  M: int, N: int) -> Tuple[jax.Array, jax.Array]:
+    """The end of `_getrf_carry` as one program: every panel into its
+    final row order, the packed factor, the global pivots.
+
+    Panel k was emitted in step k's row order, and the later steps'
+    permutations act on its rows below the diagonal block. With q_k
+    the composed action of perms[k+1:] on panel k's rows,
+
+        q_{nt-1} = identity,
+        q_k      = [0 .. nb) ++ nb + perms[k+1][q_{k+1}],
+
+    so going backward costs one (m_{k+1},) index gather and one panel
+    gather a step (the role of the reference's deferred laswp
+    application, getrf.cc row-swap tasks). `pivs` are each step's
+    local swap targets; step k's global ones are k*nb + pivs[k].
+    Under the one jit `assemble_packed`'s strip overlays update in
+    place."""
+    head = jnp.arange(nb, dtype=jnp.int32)
+    reordered = list(panels)        # the last is in its final order
+    q = jnp.arange(panels[-1].shape[0], dtype=jnp.int32)
+    for k in range(len(panels) - 2, -1, -1):
+        q = jnp.concatenate([head, nb + perms[k + 1][q]])
+        reordered[k] = _permute_rows(panels[k], q)
+    out = assemble_packed(reordered, urows, nb, kmax, M, N,
+                          panels[0].dtype)
+    pivots = jnp.concatenate([k * nb + p for k, p in enumerate(pivs)])
+    return out, pivots
+
+
 def _getrf_carry(a: jax.Array, nb: int) -> Tuple[jax.Array, jax.Array]:
     """Single-device blocked LU that carries the SHRINKING trailing
     matrix as the loop state instead of updating the full matrix in
@@ -281,12 +352,11 @@ def _getrf_carry(a: jax.Array, nb: int) -> Tuple[jax.Array, jax.Array]:
     Row-swap bookkeeping: XLA's native LU returns the panel's COMPOSED
     permutation, which is applied to the remaining columns by one
     gather per step. Already-emitted L panels are NOT touched per step
-    — each panel is emitted in its step's row order, and the suffix
-    permutations of later steps are composed into one final gather per
-    panel (nt cheap (m,) index compositions + nt panel gathers — the
-    role of the reference's deferred laswp application,
-    getrf.cc row-swap tasks)."""
-    from ..core.methods import MethodFactor
+    — each panel is emitted in its step's row order, and
+    `_carry_finish` brings them all into the final order at the end.
+
+    The host dispatches three compiled programs a step (panel, swap,
+    update) and one at the end, each under the span that names it."""
     M, N = a.shape
     kmax = min(M, N)
     nt = ceil_div(kmax, nb)
@@ -294,69 +364,35 @@ def _getrf_carry(a: jax.Array, nb: int) -> Tuple[jax.Array, jax.Array]:
     panels = []      # (m_k, w_k) packed panel, step-k row order
     urows = []       # (w_k, N - k1) U12 strips
     perms = []       # (m_k,) composed local permutation per step
-    pivs = []
-    from ..core.methods import MethodLUPanel
-    from .blocked import assemble_packed
-    # every line of these loops is an eager launch the device waits
-    # for; the spans say which of them the host was dispatching
+    pivs = []        # (w_k,) local swap targets per step
     span = obs_events.span
     for k in range(nt):
-        k0, k1 = k * nb, min((k + 1) * nb, kmax)
-        w = k1 - k0
-        # panel-route arbitration (MethodLUPanel): the native custom
-        # call keeps its fast path — it returns the composed
-        # permutation directly — but only when the resolved route IS
-        # Native (cold default where dtype + height allow), so a
-        # measured pallas_rec/fori cache entry reroutes this consumer
-        # too
+        k1 = min((k + 1) * nb, kmax)
+        w = k1 - k * nb
+        # panel-route arbitration (MethodLUPanel), out here at every
+        # call, so a measured pallas_rec/fori cache entry reroutes
+        # this consumer too
         with span("getrf::panel", cat="step", k=k):
-            native = MethodLUPanel.resolve(
-                trail.shape[0], w, trail.dtype) is MethodLUPanel.Native
-            if native:
-                lu, piv, perm = jax.lax.linalg.lu(trail[:, :w])
-            else:
-                # panels the native call cannot take (scoped-vmem
-                # height limit / dtype) or that the tune cache routed
-                # elsewhere: _lu_panel arbitrates (true partial
-                # pivoting preserved)
-                lu, piv = _lu_panel(trail[:, :w])
+            m = trail.shape[0]
+            method = MethodLUPanel.resolve(m, w, trail.dtype)
+            if method is MethodLUPanel.Fori:
+                # its one-shot instant, which a compiled panel would
+                # raise only while it is traced
+                _surface_fori_fallback(m, w, trail.dtype)
+            lu, piv, perm = _carry_panel(trail, w, method)
         with span("getrf::pivots", cat="step", k=k):
-            if native:
-                piv = piv.astype(jnp.int32)
-            else:
-                perm = _compose_swaps(piv, trail.shape[0])
-            pivs.append(k0 + piv)
+            pivs.append(piv)
             perms.append(perm)
             panels.append(lu)
             if k1 < N:
-                rest = _permute_rows(trail[:, w:], perm)
+                rest = _carry_swap(trail, perm, w)
         if k1 < N:
             with span("getrf::update", cat="step", k=k):
-                u12 = jax.lax.linalg.triangular_solve(
-                    lu[:w, :w], rest[:w], left_side=True, lower=True,
-                    unit_diagonal=True)
+                u12, trail = _carry_update(lu, rest)
                 urows.append(u12)
-                if k1 < M:
-                    trail = rest[w:] - jnp.matmul(
-                        lu[w:, :w], u12,
-                        precision=jax.lax.Precision.HIGHEST)
-                else:
-                    trail = rest[w:]
-    # final row order per panel: panel k's rows get permuted by the
-    # suffix action of perms[k+1:]
     with span("getrf::reorder", cat="step", nt=nt):
-        reordered = []
-        for k in range(nt):
-            m_k = panels[k].shape[0]
-            q = jnp.arange(m_k)
-            for j in range(k + 1, nt):
-                off = j * nb - k * nb
-                q = jnp.concatenate([q[:off], q[off:][perms[j]]],
-                                    axis=0)
-            reordered.append(_permute_rows(panels[k], q))
-        out = assemble_packed(reordered, urows, nb, kmax, M, N,
-                              a.dtype)
-        pivots = jnp.concatenate(pivs)
+        out, pivots = _carry_finish(panels, perms, urows, pivs, nb=nb,
+                                    kmax=kmax, M=M, N=N)
     return out, pivots
 
 

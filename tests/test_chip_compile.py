@@ -158,3 +158,28 @@ def test_stream_update_fits_the_device(one_chip):
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes)
     assert 0 < total < V5E_HBM, total
+
+
+def test_getrf_carry_finish_compiles_at_the_cells_shape(one_chip):
+    """The carry form's finish (PR 30) at incore-gesv's shape, n=8192
+    in 8 panels of 1024: eight panel gathers, the assembly and its
+    strip overlays as ONE program, whose temporaries stay a small
+    part of the matrix (the overlays update in place; issued one at a
+    time each copied the whole 256 MiB)."""
+    from slate_tpu.linalg import lu
+    n, nb = 8192, 1024
+    nt = n // nb
+
+    def shapes(f):
+        return [jax.ShapeDtypeStruct(*f(k), sharding=one_chip)
+                for k in range(nt)]
+
+    compiled = lu._carry_finish.lower(
+        shapes(lambda k: ((n - k * nb, nb), jnp.float32)),
+        shapes(lambda k: ((n - k * nb,), jnp.int32)),
+        shapes(lambda k: ((nb, n - (k + 1) * nb), jnp.float32))[:-1],
+        shapes(lambda k: ((nb,), jnp.int32)),
+        nb=nb, kmax=n, M=n, N=n).compile()
+    ma = compiled.memory_analysis()
+    assert ma.output_size_in_bytes >= n * n * 4
+    assert ma.temp_size_in_bytes < n * n * 4 // 8, ma.temp_size_in_bytes

@@ -336,6 +336,160 @@ def test_getrf_carry_rectangular(rng):
         np.testing.assert_allclose(L @ U, pa, rtol=1e-10, atol=1e-11)
 
 
+# -- the carry form's compiled pieces (PR 30) ------------------------------
+
+def _finish_twin(panels, perms, urows, pivs, nb, kmax, M, N):
+    """What `_getrf_carry` did from the host before its finish was one
+    program, in numpy: for each panel the later steps' permutations
+    composed one by one (quadratic in the step count), the panel
+    gathered through the result, the packed factor assembled, the
+    pivots offset and joined."""
+    nt = len(panels)
+    out = np.zeros((M, N), panels[0].dtype)
+    for k in range(nt):
+        q = np.arange(panels[k].shape[0])
+        for j in range(k + 1, nt):
+            off = (j - k) * nb
+            q = np.concatenate([q[:off], q[off:][perms[j]]])
+        out[k * nb:, k * nb:k * nb + panels[k].shape[1]] = panels[k][q]
+    for k, strip in enumerate(urows):
+        out[k * nb:k * nb + strip.shape[0],
+            min((k + 1) * nb, kmax):] = strip
+    return out, np.concatenate([k * nb + p for k, p in enumerate(pivs)])
+
+
+@pytest.mark.parametrize("nt", [2, 3, 8])
+@pytest.mark.parametrize("shape", ["square", "tall", "wide", "ragged"])
+def test_carry_finish_matches_the_quadratic_loop(rng, shape, nt):
+    """`lu._carry_finish` composes the suffix permutations by a
+    backward recurrence inside one program; factor and pivots are
+    bitwise what the step-by-step loop gave, on lists of the shapes
+    `_getrf_carry` hands it."""
+    import jax.numpy as jnp
+    from slate_tpu.linalg import lu as lumod
+    nb = 8
+    kmax = nt * nb - (3 if shape == "ragged" else 0)
+    M = kmax + (13 if shape == "tall" else 0)
+    N = kmax + (13 if shape == "wide" else 0)
+    panels, perms, urows, pivs = [], [], [], []
+    for k in range(nt):
+        k1 = min((k + 1) * nb, kmax)
+        m, w = M - k * nb, k1 - k * nb
+        panels.append(rng.standard_normal((m, w)).astype(np.float32))
+        perms.append(rng.permutation(m).astype(np.int32))
+        pivs.append(rng.integers(0, m, w).astype(np.int32))
+        if k1 < N:
+            urows.append(
+                rng.standard_normal((w, N - k1)).astype(np.float32))
+    want, want_piv = _finish_twin(panels, perms, urows, pivs, nb, kmax,
+                                  M, N)
+    dev = [[jnp.asarray(x) for x in xs]
+           for xs in (panels, perms, urows, pivs)]
+    out, piv = lumod._carry_finish(*dev, nb=nb, kmax=kmax, M=M, N=N)
+    assert out.dtype == want.dtype and piv.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(out), want)
+    np.testing.assert_array_equal(np.asarray(piv), want_piv)
+
+
+@pytest.fixture
+def obs_on():
+    """Event bus + metrics on, reset around the test."""
+    from slate_tpu import obs
+    from slate_tpu.obs import metrics
+    obs.enable()
+    obs.clear()
+    metrics.reset()
+    yield obs
+    obs.disable()
+    obs.clear()
+    metrics.reset()
+
+
+def _carry_case(rng):
+    from slate_tpu.core.options import Option
+    a = rng.standard_normal((256, 200)).astype(np.float32)
+    return st.Matrix(a, mb=64), {Option.BlockSize: 64}
+
+
+def test_getrf_rerun_compiles_nothing(rng, obs_on):
+    """A second carry-form getrf of a shape traces and compiles
+    nothing (the tier-1 twin of benchmarks/run.py's exit 3, as
+    test_stream.py::test_stream_rerun_compiles_nothing): its programs
+    are module-level and keyed on shapes, so their caches outlive the
+    call."""
+    import jax
+    from slate_tpu.obs import metrics
+    A, opts = _carry_case(rng)
+
+    def jit_counters():
+        c = metrics.snapshot()["counters"]
+        return {k: v for k, v in c.items() if k.startswith("jit.")}
+
+    F0 = st.getrf(A, opts)
+    before, recompiled = jit_counters(), metrics.recompiles()
+    assert before.get("jit.backend_compile_seconds", 0.0) > 0.0
+    F1 = st.getrf(A, opts)
+    assert jit_counters() == before
+    assert metrics.recompiles() == recompiled
+    np.testing.assert_array_equal(np.asarray(F1.LU.data),
+                                  np.asarray(F0.LU.data))
+    jax.jit(lambda x: x * 3.25 + 1.0)(np.ones(7, np.float32))
+    assert jit_counters()["jit.backend_compile_seconds"] \
+        > before["jit.backend_compile_seconds"]
+
+
+def test_getrf_carry_spans_hold_one_program_each(rng, obs_on, tmp_path):
+    """On a second call (nothing left to trace) every step span of
+    the carry form still opens at run time, and the host dispatches
+    one compiled program under each: `getrf::reorder` holds
+    `_carry_finish` alone, where it held some thirty eager index
+    operations a panel. Read from the profiler's host plane, where
+    each dispatch of a jitted function is a `PjitFunction(name)`
+    event and each bus span an annotation on the same clock."""
+    from benchmarks.lib import reduce_trace
+    from benchmarks.lib.tracer import Tracer
+    A, opts = _carry_case(rng)        # 256 x 200 at nb 64: 4 steps
+    st.getrf(A, opts)
+    obs_on.clear()
+    tr = Tracer(str(tmp_path / "trace"))
+    tr.start()
+    try:
+        F = st.getrf(A, opts)
+        F.LU.data.block_until_ready()
+    finally:
+        tr.stop()
+    steps = [e.name for e in obs_on.bus_events(cat="step")]
+    want = {"getrf::panel": 4, "getrf::pivots": 4, "getrf::update": 3,
+            "getrf::reorder": 1}
+    assert {n: steps.count(n) for n in want} == want
+    spans, calls = [], []
+    for plane in reduce_trace.load(tr.xplane()).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in want:
+                    spans.append((e.start_ns, e.start_ns + e.duration_ns,
+                                  e.name))
+                elif e.name.startswith("PjitFunction("):
+                    calls.append((e.start_ns, e.start_ns + e.duration_ns,
+                                  e.name[13:-1]))
+    # the runtime marks a dispatch twice, one event inside the other
+    calls.sort()
+    calls = [c for prev, c in zip([(0, 0, "")] + calls, calls)
+             if prev[1] < c[1]]
+    assert sorted(n for _, _, n in spans) == sorted(
+        n for n, c in want.items() for _ in range(c))
+    held = {n: [] for n in want}
+    for s0, s1, name in sorted(spans):
+        held[name].append([c for t, _, c in calls if s0 <= t < s1])
+    assert held["getrf::reorder"] == [["_carry_finish"]]
+    assert held["getrf::panel"] == [["_carry_panel"]] * 4
+    # the last step of a tall matrix has no columns to its right
+    assert held["getrf::pivots"] == [["_carry_swap"]] * 3 + [[]]
+    assert held["getrf::update"] == [["_carry_update"]] * 3
+
+
 def test_getrf_blocksize_option(rng):
     """Option.BlockSize overrides the algorithmic panel width without
     changing results (the blocking is a schedule knob, not a numerics
