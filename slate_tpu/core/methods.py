@@ -66,6 +66,21 @@ class MethodCholQR(enum.Enum):
         return MethodCholQR.HerkC
 
 
+#: Largest observed condition number at which gels' Auto keeps CholQR.
+#: CholQR rests on the Gram matrix, where A's conditioning is squared:
+#: on the shape alone it read 0.087 cond^2 eps against Householder QR's
+#: 0.1-0.4 cond eps (f32, 2048 x 256, rotated geometric spectrum: 30
+#: times worse at cond 1e2, NaN from 1e4, where A^H A is no longer
+#: numerically positive definite). Its refinement step
+#: (qr._cholqr_solve) wins QR grade back while cond^2 times the Gram
+#: matrix's own rounding stays far under 1, and that rounding grows
+#: with the rows summed: 1e-5 at 65536 rows on the TPU. 8 holds the
+#: product under 1e-3 there; the estimate is a lower bound (power
+#: iterations on the Gram factor), and a matrix twice as ill
+#: conditioned as its estimate is still inside that.
+GELS_CHOLQR_MAX_COND = 8.0
+
+
 class MethodGels(enum.Enum):
     """Reference method.hh:237: QR (robust) vs CholQR (fast,
     well-conditioned tall-skinny). TSQR is the communication-avoiding
@@ -78,15 +93,30 @@ class MethodGels(enum.Enum):
     TSQR = "tsqr"
 
     @staticmethod
-    def select(m: int, n: int, on_grid: bool = False) -> "MethodGels":
-        # single device: tall-skinny -> CholQR (reference heuristic).
-        # On a mesh the same regime routes to the cross-device TSQR
-        # tree (dist/tsqr.py): one log-depth R combine of (n, n)
-        # blocks versus CholQR's gathered Gram + replicated Cholesky,
-        # with QR-grade robustness (the ttqrt rationale,
-        # geqrf.cc:161).
-        if m >= 3 * n:
-            return MethodGels.TSQR if on_grid else MethodGels.CholQR
+    def tall(m: int, n: int) -> bool:
+        """The shape class in which a Gram-based or tree route can
+        beat the blocked Householder QR at all."""
+        return m >= 3 * n
+
+    @staticmethod
+    def select(m: int, n: int, on_grid: bool = False,
+               gram_cond: float = float("inf")) -> "MethodGels":
+        """The shape says which routes are candidates; what the
+        caller observed says whether CholQR is one. `gram_cond` is
+        the condition number gels estimated from the Cholesky factor
+        of A^H A (qr._gram_factor_cond), inf when that factor failed
+        or nothing could be observed (under a jit trace): CholQR only
+        at or under GELS_CHOLQR_MAX_COND, like the reference's
+        "gels_cholqr for well-conditioned" made a rule. On a mesh the
+        tall regime routes to the cross-device TSQR tree
+        (dist/tsqr.py): one log-depth R combine of (n, n) blocks
+        versus CholQR's gathered Gram + replicated Cholesky, with
+        QR-grade robustness (the ttqrt rationale, geqrf.cc:161)."""
+        if MethodGels.tall(m, n):
+            if on_grid:
+                return MethodGels.TSQR
+            if gram_cond <= GELS_CHOLQR_MAX_COND:
+                return MethodGels.CholQR
         return MethodGels.QR
 
 

@@ -33,9 +33,12 @@ import jax.numpy as jnp
 from jax._src.lax.linalg import geqrf as _geqrf
 
 from ..core.enums import Diag, MatrixType, Side, Uplo
+from ..core.exceptions import SlateError
 from ..core.methods import MethodFactor, MethodGels
 from ..core.options import Option, OptionsLike, get_option
 from ..core.tiles import TiledMatrix, ceil_div
+from ..obs import events as obs_events
+from ..obs import metrics as obs_metrics
 from ..obs.events import instrument_driver
 from ..ops.householder import reflect as _reflect
 from .blas3 import _store, trsm
@@ -371,6 +374,7 @@ def geqrf(A: TiledMatrix, opts: OptionsLike = None, *,
                            n=r.n, dtype=a.dtype)
         if r.n >= 1 and r.m >= aspect * r.n \
                 and dtsqr.eligible(grid, (r.m, r.n)):
+            obs_events.note(factor="tiled", form="tsqr")
             return _geqrf_tsqr_grid(grid, r, opts)
     if grid is None and method is MethodFactor.Auto:
         # measured crossover (PERF.md): below ~4k the one-call native
@@ -396,6 +400,7 @@ def geqrf(A: TiledMatrix, opts: OptionsLike = None, *,
         # native kernel cannot take (bf16).
         native = _native_geqrf(a)
         if native is not None:
+            obs_events.note(factor="fused", form="native")
             packed, ntaus = native
             out = dataclasses.replace(r, data=packed,
                                       mtype=MatrixType.General)
@@ -442,6 +447,7 @@ def geqrf(A: TiledMatrix, opts: OptionsLike = None, *,
             # panels until the step count fits the threshold
             cand = round_up(ceil_div(kmax, step_cap), 128)
         if ceil_div(kmax, cand) <= step_cap:
+            obs_events.note(factor="tiled", form="carry", nb=cand, ib=ib)
             packed, taus = _geqrf_carry(a, cand, kmax, ib)
             out = dataclasses.replace(r, data=packed,
                                       mtype=MatrixType.General)
@@ -450,15 +456,18 @@ def geqrf(A: TiledMatrix, opts: OptionsLike = None, *,
         # (O(1) program size; its fixed-width column blocks need the
         # blocking to divide the padded width — tile size otherwise)
         nb_scan = cand if N % cand == 0 else nb
+        obs_events.note(factor="tiled", form="scan", nb=nb_scan, ib=ib)
         a, taus = _geqrf_scan(a, nb_scan, kmax, None, ib=ib)
         out = dataclasses.replace(r, data=a,
                                   mtype=MatrixType.General)
         return QRFactors(out, taus[:min(M, N)])
     nt = ceil_div(kmax, nb)
     if grid is not None and nt > QR_SCAN_THRESHOLD and r.m >= r.n:
+        obs_events.note(factor="tiled", form="scan", nb=nb, ib=ib)
         a, taus = _geqrf_scan(a, nb, kmax, grid, ib=ib)
         out = dataclasses.replace(r, data=a, mtype=MatrixType.General)
         return QRFactors(out, taus[:min(M, N)])
+    obs_events.note(factor="tiled", form="unrolled", nb=nb, ib=ib)
     taus = jnp.zeros((min(M, N),), a.dtype)
     for k in range(nt):
         k0, k1 = k * nb, min((k + 1) * nb, kmax)
@@ -659,20 +668,131 @@ def unmlq(side: Side, A: LQFactors, C: TiledMatrix, trans: bool = False,
     return unmqr(side, F, C, trans=not trans, opts=opts)
 
 
-def cholqr(A: TiledMatrix, opts: OptionsLike = None
-           ) -> Tuple[TiledMatrix, TiledMatrix]:
-    """Cholesky QR: R = chol(A^H A), Q = A R^-1 (reference src/cholqr.cc;
-    MethodCholQR variants select how A^H A is formed — one herk here)."""
+#: power and inverse iterations behind the condition estimate: each
+#: is one (n, n) product or two triangular solves on one vector
+_COND_ITERS = 8
+
+
+@jax.jit
+def _gram_factor_cond(gram: jax.Array, rfac: jax.Array) -> jax.Array:
+    """What gels can observe of a Cholesky factor R of G = A^H A
+    before it trusts it: an estimate of cond_2(A) = sqrt(lmax(G) /
+    lmin(G)) from below, lmax by power iteration on G, lmin by
+    inverse iteration through R (G^-1 w = R^-1 R^-H w), _COND_ITERS
+    steps each from one fixed start vector, O(n^2) work a step. inf
+    where the factorization broke down (a non-positive pivot leaves
+    NaN from that column on: G is not numerically positive definite,
+    cond(A)^2 eps >= 1)."""
+    HI = jax.lax.Precision.HIGHEST
+    n = gram.shape[0]
+    rfac = jnp.triu(rfac[:n, :n])
+    d = jnp.real(jnp.diagonal(rfac))
+    ok = jnp.all(jnp.isfinite(d)) & jnp.all(d > 0)
+    # fixed, sign-alternating, not aligned with any coordinate axis
+    v0 = (jnp.cos(jnp.arange(n, dtype=d.dtype) * 0.7) + 0.5
+          ).astype(gram.dtype)[:, None]
+
+    def unit(v):
+        return v / jnp.linalg.norm(v)
+
+    def up(_, v):
+        return unit(jnp.matmul(gram, v, precision=HI))
+
+    def down(_, w):
+        z = jax.lax.linalg.triangular_solve(
+            rfac, w, left_side=True, lower=False, transpose_a=True,
+            conjugate_a=True)
+        return unit(jax.lax.linalg.triangular_solve(
+            rfac, z, left_side=True, lower=False))
+
+    v = jax.lax.fori_loop(0, _COND_ITERS, up, unit(v0))
+    w = jax.lax.fori_loop(0, _COND_ITERS, down, unit(v0))
+    lmax = jnp.linalg.norm(jnp.matmul(gram, v, precision=HI))
+    # ||R w|| = sqrt(w^H G w): the Rayleigh quotient of G at w
+    smin = jnp.linalg.norm(jnp.matmul(rfac, w, precision=HI))
+    cond = jnp.sqrt(lmax) / smin
+    return jnp.where(ok & jnp.isfinite(cond), cond, jnp.inf)
+
+
+def _gram_factor(A: TiledMatrix, opts: OptionsLike, span,
+                 explicit: bool = False):
+    """(R, cond): R = chol(A^H A), upper (reference src/cholqr.cc;
+    MethodCholQR variants select how A^H A is formed — one herk
+    here), and the condition number it shows (_gram_factor_cond),
+    inf where R is NaN, None under a jit trace, where no value can
+    reach the host. The read of `cond` is the one host
+    synchronisation of a Gram route: the device has nothing queued
+    behind it until the caller has chosen. `explicit` is the caller
+    whom an option sent here: a broken factor raises SlateError."""
+    from ..core.matrix import HermitianMatrix
     r = A.resolve()
     a = r.to_dense()
-    gram = jnp.matmul(jnp.conj(a.T), a,
-                      precision=jax.lax.Precision.HIGHEST)
-    from ..core.matrix import HermitianMatrix
-    H = HermitianMatrix(Uplo.Upper, gram, mb=r.nb)
-    R = potrf(H, opts)                      # upper triangular
-    Q = trsm(Side.Right, 1.0, R, dataclasses.replace(
-        r, mtype=MatrixType.General), opts)
-    return Q, R
+    with span("gels::gram"):
+        gram = jnp.matmul(jnp.conj(a.T), a,
+                          precision=jax.lax.Precision.HIGHEST)
+    with span("gels::potrf"):
+        R = potrf(HermitianMatrix(Uplo.Upper, gram, mb=r.nb), opts)
+    if isinstance(gram, jax.core.Tracer):
+        return R, None
+    with span("gels::select"):
+        cond = float(_gram_factor_cond(gram, R.resolve().data))
+    if explicit:
+        obs_events.note(gram_cond=cond)
+        if cond == float("inf"):
+            raise SlateError(
+                "cholqr: A^H A is not numerically positive definite "
+                "(cond(A)^2 eps >= 1), so its Cholesky factor is NaN; "
+                "use MethodGels.QR, or leave Option.MethodGels unset "
+                "and gels chooses by what it observes")
+    return R, cond
+
+
+def cholqr(A: TiledMatrix, opts: OptionsLike = None
+           ) -> Tuple[TiledMatrix, TiledMatrix]:
+    """Cholesky QR: R = chol(A^H A), Q = A R^-1 (reference
+    src/cholqr.cc). Squares the condition number: raises SlateError
+    when the Gram matrix is not numerically positive definite
+    (cond(A) >= 1/sqrt(eps)), where the reference returns info; under
+    a jit trace nothing can be observed and the factors come back as
+    computed."""
+    from ..utils.trace import phases
+    span = phases(opts)
+    R = _gram_factor(A, opts, span, explicit=True)[0]
+    return _cholqr_q(A, R, opts, span), R
+
+
+def _cholqr_q(A: TiledMatrix, R: TiledMatrix, opts: OptionsLike, span
+              ) -> TiledMatrix:
+    with span("gels::apply"):
+        return trsm(Side.Right, 1.0, R, dataclasses.replace(
+            A.resolve(), mtype=MatrixType.General), opts)
+
+
+def _cholqr_solve(A: TiledMatrix, R: TiledMatrix, B: TiledMatrix,
+                  opts: OptionsLike, span) -> TiledMatrix:
+    """X = R^-1 (Q^H B) with Q = A R^-1, then one step of refinement
+    on the residual through the same factors (the corrected
+    semi-normal equations, Bjorck 1987). Q is orthonormal only to the
+    Gram matrix's own rounding, which at 65536 rows on the TPU is
+    1e-5, not eps (a sum of m six-pass products): without the step
+    the well-conditioned answer read 1.3e-5 from the f64 solution
+    where Householder QR reads 1.5e-6 (PERF.md, PR 31). The step
+    costs one pass over A and one over Q."""
+    HI = jax.lax.Precision.HIGHEST
+    q = _cholqr_q(A, R, opts, span).to_dense()
+    a, b = A.to_dense(), B.to_dense()
+
+    def solve(rhs):
+        with span("gels::apply"):
+            qtb = jnp.matmul(jnp.conj(q.T), rhs, precision=HI)
+        with span("gels::trsm"):
+            return trsm(Side.Left, 1.0, R, TiledMatrix.from_dense(
+                qtb, B.mb, B.nb), opts).to_dense()
+
+    x = solve(b)
+    with span("gels::refine"):
+        resid = b - jnp.matmul(a, x, precision=HI)
+    return TiledMatrix.from_dense(x + solve(resid), B.mb, B.nb)
 
 
 @instrument_driver("gels")
@@ -681,19 +801,34 @@ def gels(A: TiledMatrix, B: TiledMatrix, opts: OptionsLike = None
     """Least squares / minimum-norm solve (reference src/gels.cc:99,
     router over MethodGels qr|cholqr; slate.hh:932).
 
-    m >= n: minimize ||A x - b|| via QR (or CholQR for well-separated
-    tall-skinny). m < n: minimum-norm solution via LQ."""
+    m >= n: minimize ||A x - b||. With Option.MethodGels unset the
+    answer is QR-grade (an error of order cond(A) eps) at every
+    conditioning: a tall single-device problem forms the Gram matrix
+    and its Cholesky factor, observes whether that factor exists and
+    what condition number it shows (_gram_factor_cond), and keeps
+    CholQR only where the normal equations lose no more than
+    Householder QR would (MethodGels.select); otherwise the Gram
+    factor is abandoned (counter `gels.refactors`) and blocked
+    Householder QR runs. An explicit MethodGels.CholQR means what it
+    says and raises SlateError where the Gram matrix cannot be
+    factored. m < n: minimum-norm solution via LQ."""
     m, n = A.shape
+    obs_metrics.inc("gels.solves")
     if m >= n:
         method = get_option(opts, Option.MethodGels, None)
+        grid = get_option(opts, Option.Grid, None)
         if method is None or method is MethodGels.Auto:
-            grid = get_option(opts, Option.Grid, None)
+            if grid is None and MethodGels.tall(m, n) \
+                    and not isinstance(A.data, jax.core.Tracer):
+                return _gels_observed(A, B, opts)
             method = MethodGels.select(m, n, on_grid=grid is not None)
+        obs_events.note(method=method.value, chosen="by option or shape")
         if method is MethodGels.CholQR:
             return gels_cholqr(A, B, opts)
         if method is MethodGels.TSQR:
             return gels_tsqr(A, B, opts)
         return gels_qr(A, B, opts)
+    obs_events.note(method="lq")
     # underdetermined: A = L Q, x = Q^H L^-1 b
     F = gelqf(A, opts)
     L = dataclasses.replace(F.LQ.resolve(), mtype=MatrixType.Triangular,
@@ -707,23 +842,39 @@ def gels(A: TiledMatrix, B: TiledMatrix, opts: OptionsLike = None
     return X
 
 
+def _gels_observed(A: TiledMatrix, B: TiledMatrix,
+                   opts: OptionsLike) -> TiledMatrix:
+    """gels' Auto route for a tall problem on one device: CholQR's
+    first two stages, then the choice from what they show."""
+    from ..utils.trace import phases
+    span = phases(opts)
+    R, cond = _gram_factor(A, opts, span)
+    method = MethodGels.select(*A.shape, gram_cond=cond)
+    obs_events.note(method=method.value, chosen="observed",
+                    gram_cond=cond)
+    if method is MethodGels.CholQR:
+        return _cholqr_solve(A, R, B, opts, span)
+    obs_metrics.inc("gels.refactors")
+    return gels_qr(A, B, opts)
+
+
 def gels_qr(A: TiledMatrix, B: TiledMatrix,
             opts: OptionsLike = None) -> TiledMatrix:
     """Reference slate.hh:917."""
     from ..utils.trace import phases
-    ph = phases(opts)
+    span = phases(opts)
     m, n = A.shape
-    with ph("gels::geqrf"):
+    with span("gels::geqrf"):
         F = geqrf(A, opts)
-    with ph("gels::unmqr"):
+    with span("gels::unmqr"):
         QtB = unmqr(Side.Left, F, B, trans=True, opts=opts)
     R = dataclasses.replace(F.QR.resolve(), mtype=MatrixType.Triangular,
                             uplo=Uplo.Upper, diag=Diag.NonUnit)
     Rsq = R.slice(0, n - 1, 0, n - 1)
     qtb = QtB.to_dense()[:n]
-    X = trsm(Side.Left, 1.0, Rsq,
-             TiledMatrix.from_dense(qtb, B.mb, B.nb), opts)
-    return X
+    with span("gels::trsm"):
+        return trsm(Side.Left, 1.0, Rsq,
+                    TiledMatrix.from_dense(qtb, B.mb, B.nb), opts)
 
 
 @instrument_driver("gels_tsqr")
@@ -771,11 +922,11 @@ def gels_tsqr(A: TiledMatrix, B: TiledMatrix,
 
 def gels_cholqr(A: TiledMatrix, B: TiledMatrix,
                 opts: OptionsLike = None) -> TiledMatrix:
-    """Reference slate.hh:924 / src/gels_cholqr.cc."""
-    n = A.shape[1]
-    Q, R = cholqr(A, opts)
-    qtb = jnp.matmul(jnp.conj(Q.to_dense().T), B.to_dense(),
-                     precision=jax.lax.Precision.HIGHEST)
-    X = trsm(Side.Left, 1.0, R,
-             TiledMatrix.from_dense(qtb, B.mb, B.nb), opts)
-    return X
+    """Reference slate.hh:924 / src/gels_cholqr.cc: the normal
+    equations through CholQR, for well-conditioned A (an error of
+    order cond(A)^2 eps). Raises SlateError where the Gram matrix
+    cannot be factored (see cholqr) instead of returning NaN."""
+    from ..utils.trace import phases
+    span = phases(opts)
+    R = _gram_factor(A, opts, span, explicit=True)[0]
+    return _cholqr_solve(A, R, B, opts, span)

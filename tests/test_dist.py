@@ -119,7 +119,8 @@ def test_gels_auto_routes_tsqr_on_grid(rng, grid8):
     """gels Auto on a grid routes tall-skinny to the TSQR tree
     (MethodGels.select on_grid) and still matches lstsq."""
     assert MethodGels.select(96, 8, on_grid=True) is MethodGels.TSQR
-    assert MethodGels.select(96, 8) is MethodGels.CholQR
+    assert MethodGels.select(96, 8, gram_cond=1.5) is MethodGels.CholQR
+    assert MethodGels.select(96, 8) is MethodGels.QR
     assert MethodGels.select(96, 48, on_grid=True) is MethodGels.QR
     m, n = 96, 8
     a = rng.standard_normal((m, n))

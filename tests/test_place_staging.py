@@ -317,7 +317,9 @@ def test_stage_reuse_share_is_declared_and_found():
                   "better": "higher", "source": "program_counter",
                   "layer": "mesh", "moves": "stream_solve_s",
                   "workloads": ["grid-posv"]}]
-    assert BENCH["per_layer"][-1] == m[0]
+    # appended after PR 27's grid metrics; later cells append after it
+    at = BENCH["per_layer"].index(m[0])
+    assert BENCH["per_layer"][at - 1]["name"] == "grid.idle_upload_share"
     assert callable(bench_run.load_module(
         "layer_metrics", "grid.stage_reuse_share").compute)
 
