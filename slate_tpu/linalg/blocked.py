@@ -38,8 +38,11 @@ historical record, not regenerable.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..core.tiles import ceil_div, round_up
 
@@ -137,7 +140,7 @@ def trsm_left(a: jax.Array, b: jax.Array, lower: bool, nb: int,
                     for j in range(0, b.shape[1], k_slab)]
             return jnp.concatenate(outs, axis=1)
         return direct(b)
-    if nt > CHOL_SCAN_THRESHOLD and n == nt * nb:
+    if trsm_form(n, nb) == "scan":
         return trsm_left_scan(a, b, lower, nb, unit_diagonal, precision,
                               grid)
     x = b
@@ -168,7 +171,9 @@ def trsm_left_scan(a: jax.Array, b: jax.Array, lower: bool, nb: int,
     for a v5e 2x2, the two unrolled sweeps of a potrs at n=49152,
     nt=96 kept the compiler 5 min 23 s and were refused (278.72 GB of
     HBM wanted a chip); this form compiles in 3.4 s (PERF.md, PR 27).
-    Blocks are read and written as in `cholesky_scan`."""
+    A step reads column block k of A, its diagonal block and block k
+    of X and writes block k of X through `_take_block` and
+    `_put_block`: under a grid, on the chips that own them."""
     from ..parallel.sharding import constrain
     n = a.shape[0]
     nt = n // nb
@@ -177,8 +182,9 @@ def trsm_left_scan(a: jax.Array, b: jax.Array, lower: bool, nb: int,
     def step(i, x):
         k = i if lower else nt - 1 - i
         colblk = _take_block(a, k, nb, 1, grid)
-        inv = invert_triangular(_take_block(colblk, k, nb, 0, grid),
-                                lower, unit_diagonal)
+        inv = invert_triangular(
+            _take_block(colblk, k, nb, 0, grid, COLUMN_BLOCK),
+            lower, unit_diagonal)
         xk = jnp.matmul(inv, _take_block(x, k, nb, 0, grid),
                         precision=precision)
         rest = rows >= (k + 1) * nb if lower else rows < k * nb
@@ -362,19 +368,104 @@ def chol_loop_pipelined(a: jax.Array, nb: int, diag_factor,
 CHOL_SCAN_THRESHOLD = 64
 
 
-def _take_block(a: jax.Array, k, nb: int, axis: int, grid) -> jax.Array:
+#: how a scan form holds one column block of a matrix on a grid: as
+#: `_take_block` hands it over, its rows over 'p' and whole along its
+#: nb columns (spread over 'q' too, the products against it would sum
+#: over the mesh: an all-reduce of n/p x n a step)
+COLUMN_BLOCK = P("p", None)
+
+
+def block_on_one_chip(dim: int, nb: int, parts: int) -> bool:
+    """Whether every nb-block of a dimension of `dim` spread over
+    `parts` chips lies on one of them: a chip's extent is a multiple of
+    nb (24576 = 48 x 512 at n=49152 on a 2x2; not the 388 rows a chip
+    of n=776, mb=8 on two), or the dimension is not spread at all
+    (`fitted_sharding` drops a mesh axis that does not divide it). The
+    one static predicate by which `_take_block`, `_put_block` and the
+    dispatch counters of `chol.potrf` and `chol.potrs` choose between
+    the local and the masked form."""
+    return dim % parts != 0 or (dim // parts) % nb == 0
+
+
+def grid_blocks(n: int, nb: int, grid) -> str:
+    """How the scan forms reach the blocks of an order-n operand under
+    `grid`: "local" where `block_on_one_chip` holds along both mesh
+    axes, else "masked" (a form whose accesses along one axis alone
+    fall back is counted masked)."""
+    local = all(block_on_one_chip(n, nb, parts)
+                for parts in (grid.p, grid.q))
+    return "local" if local else "masked"
+
+
+def _on_owner(a: jax.Array, nb: int, axis: int, grid, spec):
+    """What the local form of `_take_block` / `_put_block` needs of an
+    operand held as `spec` (fitted to its shape) on `grid`: that
+    spec, the same with `axis` replicated (a block's), the mesh axis
+    `axis` is spread over (None where it is not), and `where(k)`:
+    under `shard_map`, block k's start indices in a chip's shard and
+    whether the chip holds it (None where every chip does). None
+    where a block can straddle two chips."""
+    from ..parallel.sharding import fitted_sharding
+    spec = fitted_sharding(a.shape, grid, spec).spec
+    name = spec[axis]
+    names = name if isinstance(name, tuple) else (name,) if name else ()
+    parts = math.prod(grid.mesh.shape[nm] for nm in names)
+    if not block_on_one_chip(a.shape[axis], nb, parts):
+        return None
+    blk_spec = P(*(None if d == axis else e for d, e in enumerate(spec)))
+    extent = a.shape[axis] // parts
+
+    def where(k):
+        owner = (k * nb) // extent
+        start = [0] * a.ndim
+        start[axis] = k * nb - owner * extent
+        own = None if name is None \
+            else jax.lax.axis_index(name) == owner
+        return start, own
+
+    return spec, blk_spec, name, where
+
+
+def _take_block(a: jax.Array, k, nb: int, axis: int, grid,
+                spec=None) -> jax.Array:
     """Block k (k traced) of the nb-blocks of `a` along `axis`: rows
-    k*nb:(k+1)*nb for axis 0, columns for axis 1. Under a grid the
-    block is picked by a one-hot mask over the block axis and summed
-    (exact: every other term is a zero): a `dynamic_slice` at a traced
-    offset along a sharded dimension makes the SPMD partitioner
-    all-gather the WHOLE operand onto every device (9.0 GB a chip at
-    n=49152 on 2x2; PERF.md, PR 27), while the masked sum stays
-    sharded at the price of one pass over `a`."""
+    k*nb:(k+1)*nb for axis 0, columns for axis 1. With no grid a
+    `dynamic_slice`. Under a grid the same slice at a traced offset
+    along a sharded dimension makes the SPMD partitioner all-gather
+    the WHOLE operand onto every device (9.0 GB a chip at n=49152 on
+    2x2; PERF.md, PR 27), so the block is read on the chip that owns
+    it: under `shard_map`, which `a` enters as `spec` (default
+    P('p','q'); `fitted_sharding`), every chip slices its own shard
+    at the block's offset inside the owner, the chips that do not own
+    it zero what they read, and a `psum` along that mesh axis hands
+    the block to all of them (exact: every other term is a zero). A
+    chip reads the block's bytes and the mesh moves one all-reduce of
+    them. The result keeps the other axis' sharding and is replicated
+    along `axis`. Where a block straddles two chips
+    (`block_on_one_chip` is false) it is picked by a one-hot mask over
+    the block axis and summed instead, which stays sharded too but
+    passes over the whole of `a` (2.4 GB a chip to deliver 50 MB;
+    PERF.md, PR 32)."""
     if grid is None:
         start, size = [0, 0], list(a.shape)
         start[axis], size[axis] = k * nb, nb
         return jax.lax.dynamic_slice(a, start, size)
+    local = _on_owner(a, nb, axis, grid, spec)
+    if local is not None:
+        from ..parallel.smap import shard_map
+        spec, blk_spec, name, where = local
+
+        def take(shard, k):
+            start, own = where(k)
+            size = list(shard.shape)
+            size[axis] = nb
+            blk = jax.lax.dynamic_slice(shard, start, size)
+            if own is None:
+                return blk
+            return jax.lax.psum(jnp.where(own, blk, 0), name)
+
+        return shard_map(take, grid.mesh, (spec, P()), blk_spec)(
+            a, jnp.asarray(k))
     blocks = a.shape[axis] // nb
     sel = (jnp.arange(blocks) == k)[
         tuple(slice(None) if d == axis else None for d in range(3))]
@@ -383,18 +474,47 @@ def _take_block(a: jax.Array, k, nb: int, axis: int, grid) -> jax.Array:
 
 
 def _put_block(a: jax.Array, blk: jax.Array, k, nb: int, axis: int,
-               grid) -> jax.Array:
-    """`a` with block k along `axis` replaced by `blk`; under a grid a
-    select against the tiled block, for `_take_block`'s reason."""
+               grid, spec=None) -> jax.Array:
+    """`a` with block k along `axis` replaced by `blk`. With no grid a
+    `dynamic_update_slice`; under a grid, for `_take_block`'s reason,
+    the same on every chip's own shard under `shard_map`, at the
+    block's offset inside its owner: the owner writes its part of
+    `blk` and every other chip what it held there, so a step reads
+    and writes the block's bytes in place. Where a block straddles
+    two chips, a select over the whole of `a` against the tiled
+    block."""
     if grid is None:
         start = [0, 0]
         start[axis] = k * nb
         return jax.lax.dynamic_update_slice(a, blk, start)
+    local = _on_owner(a, nb, axis, grid, spec)
+    if local is not None:
+        from ..parallel.smap import shard_map
+        spec, blk_spec, _name, where = local
+
+        def put(shard, blk, k):
+            start, own = where(k)
+            if own is not None:
+                blk = jnp.where(own, blk, jax.lax.dynamic_slice(
+                    shard, start, blk.shape))
+            return jax.lax.dynamic_update_slice(shard, blk, start)
+
+        return shard_map(put, grid.mesh, (spec, blk_spec, P()), spec)(
+            a, blk, jnp.asarray(k))
     reps, here = [1, 1], [None, None]
     reps[axis] = a.shape[axis] // nb
     here[axis] = slice(None)
     here = (jnp.arange(a.shape[axis]) // nb == k)[tuple(here)]
     return jnp.where(here, jnp.tile(blk, reps), a)
+
+
+def trsm_form(n: int, nb: int) -> str:
+    """Which grid loop of `trsm_left` solves against an order-n
+    triangle in nb-blocks: "scan" above CHOL_SCAN_THRESHOLD block
+    steps where nb divides n, else "unrolled"."""
+    nt = ceil_div(n, nb)
+    scan = nt > CHOL_SCAN_THRESHOLD and n == nt * nb
+    return "scan" if scan else "unrolled"
 
 
 def chol_form(n: int, nb: int, guarded: bool = False) -> str:
@@ -414,8 +534,10 @@ def cholesky_scan(a: jax.Array, nb: int, precision=_HI,
     columns untouched), and applies one full-size trailing update.
     Program size independent of nt — the compile-time-safe form of
     chol_loop for nt > CHOL_SCAN_THRESHOLD. Blocks are read and
-    written through `_take_block` and `_put_block`, so that under a
-    grid no step gathers the matrix."""
+    written through `_take_block` and `_put_block`: under a grid each
+    on the chip that owns it, so that no step gathers the matrix or
+    passes over more of it than the update does, and the write of
+    the factored column block is the carry's last use, in place."""
     from ..parallel.sharding import constrain
     n = a.shape[0]
     nt = ceil_div(n, nb)
@@ -426,7 +548,7 @@ def cholesky_scan(a: jax.Array, nb: int, precision=_HI,
         k1 = k0 + nb
         colblk = _take_block(a, k, nb, 1, grid)
         lkk = jnp.tril(chol_diag_factor(
-            _take_block(colblk, k, nb, 0, grid)))
+            _take_block(colblk, k, nb, 0, grid, COLUMN_BLOCK)))
         # full-height panel solve: rhs rows are independent in the
         # right-side solve, so the dead rows cost only masked FLOPs
         pan = _chol_panel_solve(lkk, colblk, grid, precision)
@@ -436,7 +558,7 @@ def cholesky_scan(a: jax.Array, nb: int, precision=_HI,
         # write the factored column block: L_kk on the diagonal, the
         # panel below, existing content above (the update is zero in
         # this block's columns, so `colblk` still holds it)
-        newblk = _put_block(pan, lkk, k, nb, 0, grid)
+        newblk = _put_block(pan, lkk, k, nb, 0, grid, COLUMN_BLOCK)
         newblk = jnp.where((rows < k0)[:, None], colblk, newblk)
         return _put_block(a, newblk, k, nb, 1, grid)
 

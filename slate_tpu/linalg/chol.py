@@ -26,6 +26,7 @@ from ..core.options import (Option, OptionsLike, get_option,
                             get_option_tuned)
 from ..core.tiles import TiledMatrix, ceil_div, pad_diag_identity
 from ..obs import events as obs_events
+from ..obs import metrics as obs_metrics
 from ..obs.events import instrument_driver
 from .blas3 import trsm
 
@@ -74,6 +75,20 @@ def _grid_potrf_programs(grid):
     return (jax.jit(prep, static_argnums=(2, 3)),
             jax.jit(tiled, static_argnums=(1,),
                     static_argnames=("lookahead",)))
+
+
+def _count_block_steps(n: int, nb: int, steps: int, grid) -> str:
+    """A scan form of `steps` block steps over an order-n operand is
+    being dispatched under `grid`: count them by how the form reaches
+    its blocks (`blocked.grid_blocks`; the helpers themselves run at
+    trace time only) and return that."""
+    from .blocked import grid_blocks
+    blocks = grid_blocks(n, nb, grid)
+    if blocks == "local":
+        obs_metrics.inc("grid.block_steps_local", steps)
+    else:
+        obs_metrics.inc("grid.block_steps_masked", steps)
+    return blocks
 
 
 @functools.lru_cache(maxsize=None)
@@ -137,11 +152,15 @@ def potrf(A: TiledMatrix, opts: OptionsLike = None,
                                      n=r.n, dtype=r.dtype)
     if obs_events.enabled():
         from .blocked import chol_form
+        form = ("native" if method is MethodFactor.Fused
+                and not return_info
+                else chol_form(np_, nb, guarded=return_info))
+        # static slices but in a scan form under a grid
+        blocks = "slice" if grid is None or form != "scan" \
+            else _count_block_steps(np_, nb, np_ // nb, grid)
         obs_events.note(
-            factor=method.value, nb=nb, nt=np_ // nb,
-            form=("native" if method is MethodFactor.Fused
-                  and not return_info
-                  else chol_form(np_, nb, guarded=return_info)),
+            factor=method.value, nb=nb, nt=np_ // nb, form=form,
+            blocks=blocks,
             grid="1x1" if grid is None else "%dx%d" % (grid.p, grid.q))
     if grid is not None and not return_info:
         # across a mesh the prep and the factorization are one compiled
@@ -200,6 +219,12 @@ def potrs(A: TiledMatrix, B: TiledMatrix,
     program; `trsm` reads no other option."""
     grid = get_option(opts, Option.Grid, None)
     if grid is not None:
+        if obs_events.enabled():
+            from .blocked import trsm_form
+            r = A.resolve()
+            if trsm_form(r.n, r.nb) == "scan":
+                # the two sweeps of `_potrs`, r.n / r.nb steps each
+                _count_block_steps(r.n, r.nb, 2 * (r.n // r.nb), grid)
         return _grid_potrs_program(grid)(A, B)
     return _potrs(A, B, opts)
 

@@ -1,7 +1,10 @@
 """The grid deployment `grid2x2-posv-n49152` (PR 27) at sizes the CPU
 tier holds, on a 2x2 grid of the virtual devices: `st.posv` under
 `Option.Grid` against the benchmark's plain reference at the cell's
-route (nt=96, the scan form) and at an unrolled size, the placement
+route (nt=96, the scan form, its blocks read and written on the chips
+that own them, PR 32), at a size whose blocks straddle two chips (the
+masked form) and at an unrolled size, the two forms against each
+other and against the plain slices, the placement
 that never puts a matrix whole on one device, the spans and the route
 the per-layer metrics read, the reader of a four-chip trace on planes
 made by hand, and a rehearsal of the cell."""
@@ -34,7 +37,7 @@ CELL = "grid-posv"
 GRID_METRICS = ["grid.h2d_gb", "grid.upload_s", "grid.collective_share",
                 "grid.busy_imbalance", "grid.launches_per_solve",
                 "grid.solve_roofline", "idle_share.grid",
-                "grid.idle_upload_share"]
+                "grid.idle_upload_share", "grid.block_local_share"]
 
 
 @pytest.fixture(scope="module")
@@ -78,21 +81,34 @@ def solve_on(grid, a, b, mb):
 #: n=96, 2.7e-7 - 4.9e-7 and 7.4e-8 - 8.1e-8 against 1.26e-6 - 1.59e-6
 #: and 5.7e-7 - 7.9e-7. Each limit sits between: two f32 Cholesky
 #: solves differ by the order of their sums, a few eps times the
-#: growth; a product at `high` is wrong by 2^-18 of its terms.
-LIMITS = {(768, 8): (2.3e-6, 7.0e-7), (96, 8): (8.0e-7, 2.2e-7)}
+#: growth; a product at `high` is wrong by 2^-18 of its terms. At n=776
+#: (388 rows a device, so a block of 8 can straddle two: the masked
+#: form, PR 32) the same seeds read 8.9e-7 - 1.03e-6 and 1.6e-7 -
+#: 2.4e-7 against 3.86e-6 - 6.11e-6 and 1.19e-6 - 1.43e-6: n=768's
+#: limits lie between.
+LIMITS = {(768, 8): (2.3e-6, 7.0e-7), (96, 8): (8.0e-7, 2.2e-7),
+          (776, 8): (2.3e-6, 7.0e-7)}
 
 
-@pytest.mark.parametrize("n,mb,form", [(768, 8, "scan"),
-                                       (96, 8, "unrolled")])
-def test_grid_posv_agrees_with_the_plain_reference(grid, bus, n, mb, form):
+@pytest.mark.parametrize("n,mb,form,blocks", [
+    (768, 8, "scan", "local"), (96, 8, "unrolled", "slice"),
+    (776, 8, "scan", "masked")])
+def test_grid_posv_agrees_with_the_plain_reference(grid, bus, n, mb, form,
+                                                   blocks):
     a, b = system(3000000019, n)
     rows = refcheck.factor_sample(n, gen.rng(3000000019, "sample"), 32)
     obs.enable()
     L, X = solve_on(grid, a, b, mb)
     route = [e for e in obs.bus_events(cat="driver")
              if e.name == "potrf"][-1].args
-    assert (route["form"], route["nt"], route["grid"]) == \
-        (form, n // mb, "2x2")
+    assert (route["form"], route["nt"], route["grid"], route["blocks"]) \
+        == (form, n // mb, "2x2", blocks)
+    # the block steps of the scan forms dispatched, by how they reach
+    # their blocks: nt for the factor, nt for each of the two sweeps
+    counters = obs.snapshot()["metrics"]["counters"]
+    steps = {k.rsplit("_", 1)[1]: v for k, v in counters.items()
+             if k.startswith("grid.block_steps_")}
+    assert steps == ({} if form == "unrolled" else {blocks: 3 * (n // mb)})
     assert len(X.data.sharding.device_set) == 4
     x, l = X.to_numpy(), np.tril(np.asarray(L.data))[rows]
     assert x.dtype == np.float32
@@ -120,6 +136,70 @@ def test_grid_posv_agrees_with_the_plain_reference(grid, bus, n, mb, form):
         lr = np.asarray(L.data)[rows]
         assert refcheck.factor_resid(a[np.ix_(rows, rows)], lr, rows) \
             <= reh["tolerance"]["factor_residual_rms"]
+
+
+# -- a block on the chip that owns it ---------------------------------------
+
+def test_local_form_is_bitwise_the_masked_form(grid, monkeypatch):
+    """At the rehearsal's size every block lies on one device: L and X
+    by the per-device slices and sums are bit for bit what the masked
+    sums over the whole matrix give (both add exact zeros)."""
+    from slate_tpu.linalg import blocked
+    n, mb = 768, 8
+    assert blocked.grid_blocks(n, mb, grid) == "local"
+    a, b = system(23, n)
+    L, X = solve_on(grid, a, b, mb)
+    local = np.asarray(L.data), X.to_numpy()
+    def forget():
+        # the programs are kept by grid and shape, whatever the form
+        chol._grid_potrf_programs.cache_clear()
+        chol._grid_potrs_program.cache_clear()
+
+    monkeypatch.setattr(blocked, "block_on_one_chip", lambda *a: False)
+    assert blocked.grid_blocks(n, mb, grid) == "masked"
+    forget()
+    try:
+        L, X = solve_on(grid, a, b, mb)
+        masked = np.asarray(L.data), X.to_numpy()
+    finally:
+        forget()
+    assert local[0].tobytes() == masked[0].tobytes()
+    assert local[1].tobytes() == masked[1].tobytes()
+
+
+def test_a_straddling_block_is_seen_from_the_shapes():
+    from slate_tpu.linalg.blocked import block_on_one_chip
+    assert block_on_one_chip(49152, 512, 2)         # 48 blocks a chip
+    assert block_on_one_chip(768, 8, 2)
+    assert not block_on_one_chip(776, 8, 2)         # 388 rows a chip
+    assert block_on_one_chip(776, 8, 1)
+    assert block_on_one_chip(770, 8, 4)             # not spread at all
+
+
+@pytest.mark.parametrize("p,q", [(2, 2), (1, 4), (4, 1)])
+@pytest.mark.parametrize("axis", [0, 1])
+def test_blocks_round_trip_on_their_owners(p, q, axis):
+    """Every block of both axes, taken and put back changed, against
+    the plain slices; the taken block is whole along its axis."""
+    from slate_tpu.linalg.blocked import _put_block, _take_block
+    g = st.make_grid(p, q, devices=jax.devices()[:4])
+    n, nb = 64, 8
+    a = np.arange(n * n, dtype=np.float32).reshape(n, n)
+    A = place(a, g, (n, n))
+    take = jax.jit(lambda a, k: _take_block(a, k, nb, axis, g))
+    put = jax.jit(lambda a, blk, k: _put_block(a, blk, k, nb, axis, g))
+    for k in range(n // nb):
+        at = [slice(None), slice(None)]
+        at[axis] = slice(k * nb, (k + 1) * nb)
+        at = tuple(at)
+        blk = take(A, k)
+        np.testing.assert_array_equal(np.asarray(blk), a[at])
+        assert {s.data.shape[axis] for s in blk.addressable_shards} == {nb}
+        want = a.copy()
+        want[at] = -a[at] - 1
+        got = put(A, -blk - 1, k)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        assert got.sharding.is_equivalent_to(A.sharding, 2)
 
 
 # -- placement -------------------------------------------------------------
@@ -206,6 +286,9 @@ def test_no_whole_matrix_on_one_device(grid):
         text = lowered.compile().as_text()
         assert "f32[%d,%d]" % (n // 2, n // 2) in text, name
         assert _beyond_a_block(text, n) == [], name
+        # nor a device's block viewed by its blocks, which the masked
+        # form rewrote at every step (PR 32)
+        assert "[%d,%d,%d]" % (n // 2, n // mb // 2, mb) not in text, name
     # the check can see a gather: a column block at a traced offset by
     # `dynamic_slice`, as the scan form took it
     from slate_tpu.linalg.blocked import _take_block
@@ -343,6 +426,16 @@ def test_grid_metrics_by_hand(monkeypatch):
     run = _run(t, counters={"grid.h2d_bytes": 5 * 9676259328},
                spans={"grid::place": 7.5})
     assert h2d(run) == 9.676259328 and up(run) == 1.5
+    # five solves of 96 + 192 block steps on their owners; then one
+    # operand of them all whose blocks straddle
+    local = bench_run.load_module("layer_metrics",
+                                  "grid.block_local_share").compute
+    assert local(_run(t, counters={"grid.block_steps_local": 5 * 288})) \
+        == 100.0
+    assert local(_run(t, counters={"grid.block_steps_local": 4 * 288,
+                                   "grid.block_steps_masked": 288})) == 80.0
+    assert local(_run(t, counters={"grid.block_steps_masked": 288})) == 0.0
+    assert local(_run(t, counters={"grid.h2d_bytes": 7})) is None
 
 
 @pytest.mark.parametrize("name", GRID_METRICS)
