@@ -13,7 +13,7 @@ from bench import _slope, emit  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from slate_tpu.linalg.polar import polar_unitary, _chol_halley_step  # noqa: E402
+from slate_tpu.linalg.polar import polar_unitary, _chol_halley  # noqa: E402
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -100,7 +100,7 @@ def m_chstep():
     c = jnp.asarray(3.0, jnp.float32)
 
     def f(d, aux):
-        return _chol_halley_step(d, a, b, c) * (1.0 - 1e-30)
+        return _chol_halley(d, a, b, c)[0] * (1.0 - 1e-30)
     t = _slope(f, hs, hs, est_hint=0.12, reps=3, target=0.4)
     emit({"metric": "chol_step_8192_ms", "value": round(t * 1e3, 1)})
 
@@ -193,7 +193,7 @@ def m_polar_batched():
     c = jnp.asarray(3.0, jnp.float32)
 
     def f(d, aux):
-        return jax.vmap(lambda u: _chol_halley_step(u, a, b, c))(d) \
+        return jax.vmap(lambda u: _chol_halley(u, a, b, c)[0])(d) \
             * (1.0 - 1e-30)
     t = _slope(f, hb, hb, est_hint=0.03, reps=3, target=0.4)
     emit({"metric": "chol_step_vmap2x4096_ms", "value": round(t * 1e3, 1)})

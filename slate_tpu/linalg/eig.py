@@ -23,6 +23,7 @@ the band for stage 2, heev.cc:115).
 from __future__ import annotations
 
 import dataclasses
+import sys
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -51,12 +52,25 @@ def heev(A: TiledMatrix, opts: OptionsLike = None,
     syev alias :1115).
 
     MethodEig routes the solve (reference heev.cc:150-162 choosing
-    steqr2 vs stedc): the default/DC path is XLA's QDWH spectral
-    divide & conquer — one fused matmul-dominant program (module doc);
-    QRIteration runs the full reference pipeline he2hb -> hb2st ->
-    steqr2 with the two back-transforms. When the caller leaves the
-    method on Auto, a measured tune-cache entry (tune/select.py) may
-    route it instead; cold cache keeps today's Auto behavior."""
+    steqr2 vs stedc): the default path is spectral divide & conquer —
+    on the chip, for a concrete real matrix above SPECTRAL_DC_MIN_N,
+    the in-house one (linalg/spectral_dc.py: a host agenda over
+    per-bucket programs, which returns once the last split's sizes
+    are read), else XLA's QDWH eigh (module doc: smaller or complex
+    matrices, a caller's jit, and other backends unless a tune entry
+    written there says otherwise); QRIteration runs the full reference pipeline
+    he2hb -> hb2st -> steqr2 with the two back-transforms, DC the
+    same with the Cuppen tridiagonal solver. When the caller leaves
+    the method on Auto, a measured tune-cache entry (tune/select.py)
+    may route it instead; cold cache keeps today's Auto behavior.
+
+    Spans `heev::prep`, `heev::split` (bucket, size), `heev::agenda`
+    (the host's read of a split's sizes), `heev::leaf`,
+    `heev::vectors`; the root span carries the route (`method`,
+    `form`, `leaf`, `buckets`); counters `heev.solves`,
+    `heev.splits`, `heev.leaves`, `heev.polar_iters`,
+    `heev.unconverged`, `heev.split_rows_true`,
+    `heev.split_rows_padded`."""
     slate_assert(A.mtype in (MatrixType.Hermitian, MatrixType.Symmetric,
                              MatrixType.HermitianBand),
                  "heev: A must be Hermitian/symmetric")
@@ -74,62 +88,68 @@ def heev(A: TiledMatrix, opts: OptionsLike = None,
         # staged pipeline with the Cuppen divide & conquer tridiagonal
         # solver (reference stedc); Auto stays on the fused QDWH path
         return _heev_two_stage(A, opts, want_vectors, use_dc=True)
-    a = A.to_dense()
-    from ..ops.pallas_kernels import _on_tpu
+    from ..obs import metrics as obs_metrics
+    from ..obs.events import note
     from ..tune.select import tuned_int
-    # routing threshold and leaf size are tunable (tune/select.py);
-    # their frozen defaults are the module constants, so an empty
-    # cache reproduces today's routing exactly
-    dc_min_n = tuned_int("heev", "spectral_dc_min_n",
-                         SPECTRAL_DC_MIN_N, opts=opts,
-                         n=a.shape[0], dtype=a.dtype)
-    if (_on_tpu() and a.shape[0] > dc_min_n
-            and not jnp.issubdtype(a.dtype, jnp.complexfloating)):
+    from ..utils.trace import phases
+    ph = phases(opts)
+    obs_metrics.inc("heev.solves")
+    with ph("heev::prep"):
+        a = A.to_dense()
+        # routing threshold and leaf size are tunable (tune/select.py);
+        # their frozen defaults are the module constants, so an empty
+        # cache reproduces today's routing exactly. Off the chip the
+        # default is never (LAPACK's and XLA's own eigh are the
+        # measured routes there): only a tune entry, whose key names
+        # the backend it was written on, sends another backend down
+        # this route (a rehearsal's and tier-1's do, in memory)
+        from ..ops.pallas_kernels import _on_tpu
+        dc_min_n = tuned_int(
+            "heev", "spectral_dc_min_n",
+            SPECTRAL_DC_MIN_N if _on_tpu() else sys.maxsize,
+            opts=opts, n=a.shape[0], dtype=a.dtype)
+    if (a.shape[0] > dc_min_n
+            and not jnp.issubdtype(a.dtype, jnp.complexfloating)
+            and not isinstance(a, jax.core.Tracer)):
         # the in-house spectral D&C (linalg/spectral_dc.py): same
         # QDWH-family algorithm as jax's eigh but with the all-
         # Cholesky polar and no padded-copy agenda — measured faster
         # on v5e above the threshold (PERF.md "Round-5: in-house
-        # spectral divide & conquer"). Real dtypes
-        # only: the TPU backend's Jacobi leaf solver does not
-        # implement complex.
-        from .spectral_dc import LEAF, eigh_dc
+        # spectral divide & conquer"). Real dtypes only: the TPU
+        # backend's Jacobi leaf solver does not implement complex.
+        # Concrete arrays only: a host agenda dispatches its splits
+        # and reads their sizes, so it returns when the last split
+        # has been read and not at once; under a caller's jit the
+        # solve is XLA's eigh below.
+        from .spectral_dc import LEAF, _bucket_ladder, eigh_dc
         leaf = tuned_int("heev", "dc_leaf", LEAF, opts=opts,
                          n=a.shape[0], dtype=a.dtype)
+        note(method="spectral_dc",
+             form="agenda" if a.shape[0] > leaf else "leaf", leaf=leaf,
+             buckets=",".join(
+                 str(b) for b in _bucket_ladder(a.shape[0], leaf)))
         w, v, dc_ok = eigh_dc(a, leaf=leaf)     # ascending already
-        # materializing dc_ok would force the whole O(n^3) solve to
-        # finish inside heev (losing async dispatch overlap), so the
-        # eager check is opt-in; callers that need the flag without
-        # the env switch call spectral_dc.eigh_dc directly
-        import os
-        if os.environ.get("SLATE_TPU_CHECK_POLAR") == "1":
-            try:
-                ok_concrete = bool(dc_ok)  # raises under jit tracing
-            except Exception:
-                ok_concrete = True
-            else:
-                # the flag reaches the metrics registry only inside
-                # this opt-in gate: the bool() above already paid the
-                # synchronization, so recording it is free — obs being
-                # enabled must never force the solve by itself
-                from ..obs import metrics as obs_metrics
-                obs_metrics.flag_concrete("polar.unconverged",
-                                          not ok_concrete)
-            if not ok_concrete:
-                import warnings
-                warnings.warn(
-                    "heev: a spectral-D&C split's polar (sign) "
-                    "iteration hit its iteration cap without "
-                    "converging; eigenpairs may be degraded "
-                    "(polar.py capped-weight schedule)", stacklevel=2)
+        # the agenda has read every split's converged flag on the
+        # host by now (it reads each split's sizes anyway), so an
+        # unconverged sign iteration is always surfaced
+        if not dc_ok:
+            import warnings
+            warnings.warn(
+                "heev: a spectral-D&C split's polar (sign) "
+                "iteration hit its iteration cap without "
+                "converging; eigenpairs may be degraded "
+                "(polar.py capped-weight schedule)", stacklevel=2)
     else:
+        note(method="xla_eigh", form="native")
         v, w = jax.lax.linalg.eigh(a)  # QDWH D&C (see module doc)
         order = jnp.argsort(w)
         w = w[order]
         v = v[:, order]
     if not want_vectors:
         return EigResult(w, None)
-    r = A.resolve()
-    V = TiledMatrix.from_dense(v, r.mb, r.nb)
+    with ph("heev::vectors"):
+        r = A.resolve()
+        V = TiledMatrix.from_dense(v, r.mb, r.nb)
     return EigResult(w, V)
 
 

@@ -120,30 +120,49 @@ def _lift_estimate(sg, a, b, c):
         * (1.0 - 1e-5)
 
 
-def _chol_halley_step(u, a, b, c, want_sigma_est=False, it=0):
+#: widest block of right-hand-side columns one forward substitution
+#: takes (`_forward_solve`)
+FORWARD_SOLVE_COLS = 1280
+
+
+def _forward_solve(r, b):
+    """r^{-1} b for lower-triangular r, a block of b's columns at a
+    time under one `fori_loop`. The columns are independent, so this
+    is the same arithmetic as one solve; but the TPU's forward
+    substitution with an (n, n) right-hand side counts temporaries
+    that grow with n/128 blocks times its width (3.7 n^2 words at
+    n=2176, 14.7 at 4096, 35 at 8192: 9.5 GB, compiled for a described
+    v5e, PR 33), where the back substitution counts none. In blocks
+    of at most FORWARD_SOLVE_COLS columns it counts an eighth of that
+    at n=8192, in one copy of the solve's code."""
+    n, m = b.shape
+    chunks = 1
+    while m // chunks > FORWARD_SOLVE_COLS and m % (2 * chunks) == 0:
+        chunks *= 2
+    if chunks == 1:
+        return jax.lax.linalg.triangular_solve(
+            r, b, left_side=True, lower=True)
+    w = m // chunks
+    zero = jnp.zeros((), jnp.int32)
+
+    def body(i, out):
+        at = (zero, i * w)
+        t = jax.lax.linalg.triangular_solve(
+            r, jax.lax.dynamic_slice(b, at, (n, w)), left_side=True,
+            lower=True)
+        return jax.lax.dynamic_update_slice(out, t, at)
+
+    return jax.lax.fori_loop(0, chunks, body, jnp.zeros_like(b))
+
+
+def _chol_halley(u, a, b, c):
     """One weighted Halley iteration in the Cholesky form:
     u <- (b/c) u + (a - b/c) u (I + c u^H u)^{-1} (SISC 2013 eq. 5.5
     family: the inverse applied via Cholesky of I + c u^H u and two
-    triangular solves).
-
-    With want_sigma_est, also returns an estimate of sigma_min(u)
-    (the PRE-map iterate's smallest singular value) from the Cholesky
-    factor already in hand: power iteration on x^{-1} = (r r^H)^{-1}
-    via per-step triangular solves with a thin block of vectors
-    (O(n^2 k) — noise next to the step's 4.3 n^3). The Rayleigh-type
-    ratio ||x^{-1} v|| / ||v|| lower-bounds lambda_max(x^{-1}), so
-    1/ratio UPPER-bounds lambda_min(x) = 1 + c sigma_min(u)^2 and the
-    derived sigma_est is an over-estimate — callers must apply a
-    safety factor before using it as a schedule lower bound. The
-    returned `reliable` flag additionally requires the power iteration
-    itself to have CONVERGED (relative ratio delta between the last
-    two steps below 5%): 4 steps from a ~1/sqrt(n) overlap can leave
-    the ratio far below lambda_max(x^{-1}) when small singular values
-    cluster, inflating sigma_est beyond what the 0.7 safety factor
-    absorbs (ADVICE r5). `it` (the schedule iteration counter) is
-    folded into the estimator PRNG key so a start block that happens
-    to be orthogonal to the small-eigenvector subspace is not retried
-    identically every iteration."""
+    triangular solves). Returns (u_new, r) with r the lower Cholesky
+    factor of x = I + c u^H u, which `_sigma_min_estimate` rides on:
+    an iteration is ONE Gram product, ONE factorization and two
+    solves whether or not the schedule asks for the estimate."""
     n = u.shape[0]
     dt = u.dtype
     e = b / c
@@ -152,15 +171,35 @@ def _chol_halley_step(u, a, b, c, want_sigma_est=False, it=0):
     r = jax.lax.linalg.cholesky(x, symmetrize_input=False)
     # z = u x^{-1}: with x = r r^H, solve r t = u^H, then r^H s = t,
     # giving s = x^{-1} u^H and z = s^H
-    z = jax.lax.linalg.triangular_solve(
-        r, u.conj().T, left_side=True, lower=True)
+    z = _forward_solve(r, u.conj().T)
     z = jax.lax.linalg.triangular_solve(
         r, z, left_side=True, lower=True, transpose_a=True,
         conjugate_a=True).conj().T
-    unew = e.astype(dt) * u + (a - e).astype(dt) * z
-    if not want_sigma_est:
-        return unew
-    # ---- sigma_min estimator (module doc of polar_unitary) ----
+    return e.astype(dt) * u + (a - e).astype(dt) * z, r
+
+
+def _sigma_min_estimate(r, c, it=0):
+    """Estimate of sigma_min(u), the PRE-map iterate's smallest
+    singular value, from the Cholesky factor r of x = I + c u^H u that
+    `_chol_halley` already holds: power iteration on x^{-1} =
+    (r r^H)^{-1} via per-step triangular solves with a thin block of
+    vectors (O(n^2 k) — noise next to the step's 4.3 n^3). The
+    Rayleigh-type ratio ||x^{-1} v|| / ||v|| lower-bounds
+    lambda_max(x^{-1}), so 1/ratio UPPER-bounds lambda_min(x) =
+    1 + c sigma_min(u)^2 and the derived sigma_est is an
+    over-estimate — callers must apply a safety factor before using it
+    as a schedule lower bound. The returned `reliable` flag
+    additionally requires the power iteration itself to have CONVERGED
+    (relative ratio delta between the last two steps below 5%): 4
+    steps from a ~1/sqrt(n) overlap can leave the ratio far below
+    lambda_max(x^{-1}) when small singular values cluster, inflating
+    sigma_est beyond what the 0.7 safety factor absorbs (ADVICE r5).
+    `it` (the schedule iteration counter) is folded into the estimator
+    PRNG key so a start block that happens to be orthogonal to the
+    small-eigenvector subspace is not retried identically every
+    iteration. Returns (sigma_est f32, reliable)."""
+    n = r.shape[0]
+    dt = r.dtype
     # start block: e_j at the weakest Cholesky pivot (strongly aligned
     # with the small eigenvector) + fixed pseudo-random columns
     k = 4
@@ -196,7 +235,7 @@ def _chol_halley_step(u, a, b, c, want_sigma_est=False, it=0):
     pw_ok = jnp.abs(ratio - ratio_prev) <= 0.05 * ratio
     reliable = (lam_min_x - 1.0 > 0.5) & pw_ok
     sig = jnp.sqrt(jnp.maximum(sig2, 0.0))
-    return unew, sig.astype(jnp.float32), reliable
+    return sig.astype(jnp.float32), reliable
 
 
 @partial(jax.jit, static_argnames=("max_iterations", "newton_schulz"))
@@ -244,26 +283,26 @@ def polar_unitary(x: jax.Array, l0: Optional[float] = None,
     def body_f(state):
         u, l, k, _ = state
         a, b, c, lnew = _capped_params(l, c_max)
+        u2, r = _chol_halley(u, a, b, c)
 
-        def with_est(u):
-            u2, sig, rel = _chol_halley_step(u, a, b, c,
-                                             want_sigma_est=True,
-                                             it=k)
+        def lifted(r):
             # bound the NEW iterate's sigma_min from the (pre-step,
             # safety-deflated) estimate via the INTERVAL minimum of
             # this step's scalar map (_lift_estimate — f is
             # non-monotone under capped weights, so f(sg) alone is
             # not a bound); estimator over-estimates (docstring), so
             # only lift the schedule, never finish it outright
-            sg = 0.7 * sig
-            lest = _lift_estimate(sg, a, b, c)
+            sig, rel = _sigma_min_estimate(r, c, k)
+            lest = _lift_estimate(0.7 * sig, a, b, c)
             lest = jnp.clip(lest, 0.0, 0.98)
-            return u2, jnp.where(rel, jnp.maximum(lnew, lest), lnew)
+            return jnp.where(rel, jnp.maximum(lnew, lest), lnew)
 
-        def without_est(u):
-            return _chol_halley_step(u, a, b, c), lnew
-
-        u2, lnew = jax.lax.cond(l < est_gate, with_est, without_est, u)
+        # only the estimator's thin solves sit under the branch: the
+        # Gram product, the factorization and the two full solves are
+        # in the program once (they were in it twice, one copy a
+        # branch, which doubled every bucket's share of the
+        # eigensolver's executable; PR 33)
+        lnew = jax.lax.cond(l < est_gate, lifted, lambda r: lnew, r)
         diff = jnp.sqrt(jnp.sum(jnp.abs(u2 - u) ** 2))
         return u2, lnew, k + 1, diff
 

@@ -43,17 +43,43 @@ execution (design, not translation — written fresh):
 Shapes shrink down the recursion through the same bucket ladder idea
 as the stock implementation (multiplier ~1.98, granularity 128), with
 subproblem true sizes handled by masking.
+
+A split is four steps: `dc_take` (the subproblem out of the
+workspace, and whether it is already diagonal), `dc_sign` (the polar
+iteration: half of a split's code), `dc_basis` (the subspace QRs and
+the compression: the other half) and `dc_put` (the eigenvector
+compose and the children back into the workspace); a subproblem at
+or under the leaf size is one `dc_leaf`; `dc_vectors` sorts.
+
+Each step is a compiled program of its own at each bucket size,
+dispatched from a host AGENDA that reads three numbers a split (k,
+the polar's converged flag and its iteration count) and threads the
+donated workspaces from program to program. Splits are dispatched as
+soon as their sizes are known and their sizes read oldest first, so
+the device waits for the host only where the tree is one node wide.
+The root and a lopsided split's full-size fallback share their two
+heavy programs (`dc_sign`, `dc_basis` see a (B, B) block and its true
+size, not where it came from). No program holds more than half a
+bucket: as ONE program (a `while_loop` over a `lax.switch` with a
+branch a bucket) the solve at n=4096 was a 240 MB executable that
+took 258-275 s to compile and that the compile cache refused, so
+every process compiled it again (PR 22; PR 33 made the agenda). The
+sizes are read on the host, so the input is a concrete array: under a
+caller's `jit` `eigh_dc` raises, and `st.heev` takes XLA's own `eigh`
+(linalg/eig.py).
 """
 
 from __future__ import annotations
 
-from functools import partial
-from typing import NamedTuple
+import functools
+from collections import deque
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import events as obs_events
+from ..obs import metrics as obs_metrics
 from .polar import sign_hermitian
 
 HI = jax.lax.Precision.HIGHEST
@@ -73,7 +99,17 @@ def _round_up(x, g):
 def _bucket_ladder(n: int, leaf: int):
     """Static padded sizes for subproblems: n/1.98 rounded up to 128,
     then halving, ending at the leaf size. The 1.98 (not 2) absorbs
-    off-median splits without falling back into the parent bucket."""
+    off-median splits without falling back into the parent bucket.
+
+    Rungs a factor of two apart are what a compile cache of 192 MiB
+    holds at n=8192: a split's two heavy programs cost 11 KB of cache
+    a row of their bucket, whatever the bucket, so the ladder under
+    the root may have about as many rows as the root. A lopsided
+    split's larger child (over n/1.98 rows) therefore runs at the full
+    size again (`_bucket_of`). Rungs 1.5 times 4224 and 2176 (6272,
+    3200) held such children and cut the solve of PR 33's cell from
+    4.8 to 2.95 s, but made the programs 288 MB, and every run
+    compiled all of them again, 570 s (PERF.md, PR 33)."""
     buckets = [leaf]
     if n > leaf:
         i = int(n / 1.98)
@@ -97,40 +133,42 @@ def _mask_cols(x, c0, c1, fill=0.0):
     return jnp.where((j >= c0) & (j < c1), x, jnp.asarray(fill, x.dtype))
 
 
-class _Split(NamedTuple):
-    Q: jax.Array        # (B, B) orthogonal: cols [0,k) span the lower
-    #                     invariant subspace, [k, m) the upper
-    W: jax.Array        # (B, B) compressed Q^H H Q (block diagonal up
-    #                     to the split tolerance)
-    k: jax.Array        # rank of the lower block (int32)
-    ok: jax.Array       # polar/sign iteration converged (bool) — the
-    #                     flag polar.py returns, no longer discarded
-    #                     (ADVICE r5: l can overshoot, so an
-    #                     unconverged sign matrix must be surfaced)
+def _sign_split(H, m, l0):
+    """sign(H - sigma I) of the masked (m, m) Hermitian block H,
+    padded to static (B, B), at sigma = the median of its diagonal.
+    Returns (S, polar iterations, converged)."""
+    B = H.shape[0]
+    dt = H.dtype
+    diag = jnp.real(jnp.diagonal(H))
+    ids = jnp.arange(B)
+    sigma = jnp.nanmedian(jnp.where(ids < m, diag, jnp.nan))
+    Hs = H - sigma.astype(dt) * _eye_m(B, m, dt)
+    return sign_hermitian(Hs, l0=l0)
 
 
-def _split_spectrum(H, m, l0):
-    """One spectral split of the masked (m, m) Hermitian block H,
-    padded to static (B, B): sign(H - sigma I) at sigma = median of
-    the diagonal, projector subspaces via column-norm-sorted complete
-    QR with subspace-iteration refinement (the rank-revealing scheme
-    of SISC 2013 §3; same scheme as the stock implementation,
-    re-written)."""
+def _eye_m(B, m, dt):
+    ids = jnp.arange(B)
+    return jnp.where((ids < m)[:, None] & (ids < m)[None, :],
+                     jnp.eye(B, dtype=dt), jnp.zeros((), dt))
+
+
+def _split_basis(H, S, m):
+    """The split of the masked (m, m) block H by its sign matrix S:
+    projector subspaces via column-norm-sorted complete QR with
+    subspace-iteration refinement (the rank-revealing scheme of SISC
+    2013 §3; same scheme as the stock implementation, re-written).
+    Returns (Q, W, k): Q (B, B) orthogonal, cols [0, k) spanning the
+    lower invariant subspace and [k, m) the upper; W = Q^H H Q, block
+    diagonal up to the split tolerance; k the rank of the lower
+    block."""
     B = H.shape[0]
     dt = H.dtype
     rdt = jnp.float32 if dt != jnp.float64 else jnp.float64
     eps = jnp.finfo(rdt).eps
-
-    diag = jnp.real(jnp.diagonal(H))
     ids = jnp.arange(B)
-    sigma = jnp.nanmedian(jnp.where(ids < m, diag, jnp.nan))
-
-    eye_m = jnp.where((ids < m)[:, None] & (ids < m)[None, :],
-                      jnp.eye(B, dtype=dt), jnp.zeros((), dt))
-    Hs = H - sigma.astype(dt) * eye_m
+    eye_m = _eye_m(B, m, dt)
 
     hnorm = jnp.sqrt(jnp.sum(jnp.abs(H) ** 2))
-    S, _, conv = sign_hermitian(Hs, l0=l0)
     P_lo = 0.5 * (eye_m - S)
     k = jnp.round(jnp.trace(jnp.real(P_lo))).astype(jnp.int32)
     k = jnp.clip(k, 1, jnp.maximum(m - 1, 1))
@@ -161,22 +199,26 @@ def _split_spectrum(H, m, l0):
             V1, precision=HI)
         return Q, jnp.sqrt(jnp.sum(jnp.abs(err_blk) ** 2))
 
-    Q, err = qr_pass(X)
-
     def refine_cond(state):
         _, err, it = state
-        return (err > thresh) & (it < SUBSPACE_MAXITER)
+        return (it == 0) | ((err > thresh) & (it < SUBSPACE_MAXITER))
 
+    def refreshed(Q):
+        Y = jnp.matmul(P, _mask_cols(Q, 0, r), precision=HI)
+        # re-complete the basis from the refreshed leading block
+        return Y + _mask_cols(Q, r, B)
+
+    # ONE QR in the program: the first pass is the loop's iteration 0
+    # (on P's sorted columns) and not a second copy of the QR in
+    # front of the loop; only the operand is chosen under the branch
     def refine_body(state):
         Q, _, it = state
-        X = jnp.matmul(P, _mask_cols(Q, 0, r), precision=HI)
-        # re-complete the basis from the refreshed leading block
-        X = X + _mask_cols(Q, r, B)
-        Q, err = qr_pass(X)
+        Q, err = qr_pass(jax.lax.cond(it == 0, lambda q: X, refreshed, Q))
         return Q, err, it + 1
 
     Q, err, _ = jax.lax.while_loop(
-        refine_cond, refine_body, (Q, err, jnp.ones((), jnp.int32)))
+        refine_cond, refine_body,
+        (X, jnp.asarray(jnp.inf, rdt), jnp.zeros((), jnp.int32)))
 
     # un-swap: we want cols [0, k) = lower subspace. Column rolls use
     # a doubled-array dynamic_slice (traced shift amounts).
@@ -198,7 +240,7 @@ def _split_spectrum(H, m, l0):
 
     HQ = jnp.matmul(H, Q, precision=HI)
     W = jnp.matmul(Q.conj().T, HQ, precision=HI)
-    return _Split(Q=Q, W=W, k=k, ok=conv)
+    return Q, W, k
 
 
 def _masked_merge_block(work, blk, off_r, off_c, rows, cols):
@@ -216,196 +258,292 @@ def _masked_merge_block(work, blk, off_r, off_c, rows, cols):
     return jax.lax.dynamic_update_slice(work, t, (off_r, off_c))
 
 
-class _State(NamedTuple):
-    offs: jax.Array      # (cap,) int32 agenda offsets
-    szs: jax.Array       # (cap,) int32 agenda sizes
-    sp: jax.Array        # stack pointer
-    blocks: jax.Array    # (2n, n) subproblem workspace, left-aligned;
-    #                      column 0 doubles as the eigenvalue store
-    vecs: jax.Array      # (n, 2n) accumulated eigenvector workspace
-    h0norm: jax.Array    # Frobenius norm of the input (noise cutoff)
-    ok: jax.Array        # AND of every split's polar converged flag
+def _info(k, ok, iters):
+    """What the host agenda reads of one split, as one transfer:
+    [k, converged, polar iterations]; k = 0 says the block was
+    (near-)diagonal and has no children."""
+    return jnp.stack([jnp.asarray(k, jnp.int32),
+                      jnp.asarray(ok, jnp.int32),
+                      jnp.asarray(iters, jnp.int32)])
 
 
-def _push2(st: _State, o1, s1, o2, s2) -> _State:
-    offs = st.offs.at[st.sp].set(o1).at[st.sp + 1].set(o2)
-    szs = st.szs.at[st.sp].set(s1).at[st.sp + 1].set(s2)
-    return st._replace(offs=offs, szs=szs, sp=st.sp + 2)
+def _window(work, off, axis, B):
+    """The (B, B) block window of `blocks` at row `off` (axis 0) or
+    the (n, B) column window of `vecs` at column `off` (axis 1)."""
+    zero = jnp.zeros((), jnp.int32)
+    off = jnp.asarray(off, jnp.int32)
+    if axis == 0:
+        return jax.lax.dynamic_slice(work, (off, zero), (B, B))
+    return jax.lax.dynamic_slice(work, (zero, off), (work.shape[0], B))
 
 
-def _apply_split(st: _State, spl: _Split, off, sz, n: int,
-                 compose: bool) -> _State:
+def _masked_block(blocks, off, sz, B):
+    """The (sz, sz) Hermitian subproblem at row `off` of `blocks`,
+    zero-padded to (B, B) and symmetrized."""
+    H = _window(blocks, off, 0, B)
+    ids = jnp.arange(B)
+    inside = (ids < sz)[:, None] & (ids < sz)[None, :]
+    H = jnp.where(inside, H, jnp.zeros((), H.dtype))
+    return 0.5 * (H + H.conj().T), inside
+
+
+def _nearly_diagonal(H, h0norm):
+    """A (near-)diagonal or noise-level block: its diagonal entries
+    are the eigenvalues and the accumulated vectors already stand."""
+    eps = float(jnp.finfo(H.dtype).eps)
+    hn = jnp.sqrt(jnp.sum(jnp.abs(H) ** 2))
+    d = jnp.real(jnp.diagonal(H)).astype(H.dtype)
+    offd = jnp.sqrt(jnp.sum(jnp.abs(H - jnp.diagflat(d)) ** 2))
+    return (offd <= 5.0 * eps * hn) | (hn < eps * h0norm)
+
+
+# ---- the steps: each a compiled program of the agenda form ---------
+
+def dc_take_root(h):
+    """The input as the root subproblem: symmetrized, with its
+    Frobenius norm (the noise cutoff of every later block) and
+    whether it is diagonal already. Returns (H, h0norm, nearly)."""
+    h = 0.5 * (h + h.conj().T)
+    h0norm = jnp.sqrt(jnp.sum(jnp.abs(h) ** 2))
+    return h, h0norm, _nearly_diagonal(h, h0norm)
+
+
+def dc_take(blocks, at, h0norm, B: int):
+    """The subproblem `at` = [offset, size] out of the workspace,
+    padded to the bucket's static (B, B). Returns (H, nearly)."""
+    H, _ = _masked_block(blocks, at[0], at[1], B)
+    return H, _nearly_diagonal(H, h0norm)
+
+
+def dc_sign(H, m, nearly, l0=None):
+    """The polar iteration of one split (skipped on a block that is
+    diagonal already). Returns (S, flags) with flags = [nearly,
+    converged, iterations]."""
+    def skip(H):
+        return H, jnp.zeros((), jnp.int32), jnp.ones((), jnp.bool_)
+
+    S, iters, conv = jax.lax.cond(
+        nearly, skip, lambda H: _sign_split(H, m, l0), H)
+    return S, _info(nearly, conv, iters)
+
+
+def dc_basis(H, S, m, flags):
+    """The subspace bases and the compression of one split. Returns
+    (Q, W, info). On a block that is diagonal already info's k is 0,
+    Q = I, and W holds the diagonal (the eigenvalues) in its column 0:
+    `_put` then writes the block's eigenvalue column and leaves its
+    vectors as they stand, by the writes it makes for any split."""
+    def skip(H, S):
+        d = jnp.real(jnp.diagonal(H)).astype(H.dtype)
+        return (jnp.eye(H.shape[0], dtype=H.dtype),
+                jnp.zeros_like(H).at[:, 0].set(d),
+                jnp.zeros((), jnp.int32))
+
+    Q, W, k = jax.lax.cond(flags[0] > 0, skip,
+                           lambda H, S: _split_basis(H, S, m), H, S)
+    return Q, W, _info(k, flags[1], flags[2])
+
+
+def _put(blocks, vecs, off, sz, Q, W, k, compose: bool):
     """Write a split's compressed children + composed eigenvector
-    columns into the workspaces and push the children. `compose` is
-    False only for the root call, whose V0 is the identity (stock
-    implementations pay 2 n^3 composing against it)."""
-    B = spl.Q.shape[0]
-    k = spl.k
+    columns into the workspaces. `compose` is False only for the root
+    call, whose V0 is the identity (stock implementations pay 2 n^3
+    composing against it). No branch here: a conditional over the
+    workspaces makes the compiler copy one (0.54 GB a launch at
+    n=8192); `dc_basis` shapes a finished block's Q and W so that
+    these same writes finish it."""
+    n = vecs.shape[0]
+    B = Q.shape[0]
     if compose:
-        V0 = jax.lax.dynamic_slice(
-            st.vecs, (jnp.zeros((), jnp.int32), jnp.asarray(off, jnp.int32)),
-            (n, B))
-        Vnew = jnp.matmul(V0, spl.Q, precision=HI)
+        Vnew = jnp.matmul(_window(vecs, off, 1, B), Q, precision=HI)
     else:
-        Vnew = spl.Q
+        Vnew = Q
     # Q is padded-identity beyond (m, m), so columns of Vnew past sz
     # reproduce V0 exactly; the merge mask still bounds the write
-    vecs = _masked_merge_block(st.vecs, Vnew, 0, off, n, sz)
+    vecs = _masked_merge_block(vecs, Vnew, 0, off, n, sz)
     # children, left-aligned: W[:k, :k] at (off, 0); W[k:sz, k:sz]
     # at (off + k, 0). The second extraction slides a (B, B) window
     # to (k, k), so pad W locally (a B^2 pad, not the stock
     # implementation's full-workspace pad).
-    Wp = jnp.pad(spl.W, ((0, B), (0, B)))
-    W22 = jax.lax.dynamic_slice(
-        Wp, (jnp.asarray(k, jnp.int32), jnp.asarray(k, jnp.int32)), (B, B))
-    blocks = _masked_merge_block(st.blocks, spl.W, off, 0, k, k)
+    Wp = jnp.pad(W, ((0, B), (0, B)))
+    W22 = jax.lax.dynamic_slice(Wp, (k, k), (B, B))
+    blocks = _masked_merge_block(blocks, W, off, 0, k, k)
     blocks = _masked_merge_block(blocks, W22, off + k, 0,
                                  sz - k, sz - k)
-    st = st._replace(blocks=blocks, vecs=vecs, ok=st.ok & spl.ok)
-    return _push2(st, off, k, off + k, sz - k)
+    return blocks, vecs
 
 
-def _write_diag_case(st: _State, off, sz, B: int) -> _State:
-    """(Near-)diagonal or noise-level block: its diagonal entries are
-    the eigenvalues and the accumulated V0 columns are already the
-    vectors — only the eigenvalue column needs writing."""
-    H = jax.lax.dynamic_slice(
-        st.blocks, (jnp.asarray(off, jnp.int32), jnp.zeros((), jnp.int32)),
-        (B, B))
-    d = jnp.real(jnp.diagonal(H))[:, None].astype(st.blocks.dtype)
-    blocks = _masked_merge_block(st.blocks, d, off, 0, sz, 1)
-    return st._replace(blocks=blocks)
+def dc_put_root(Q, W, info):
+    """Makes the two workspaces and writes the root split into them:
+    `blocks` (2n, n), the subproblems left-aligned, whose column 0
+    doubles as the eigenvalue store, and `vecs` (n, 2n), the
+    accumulated eigenvectors; each carries a bucket-sized margin so
+    every later window is an in-bounds dynamic_slice /
+    dynamic_update_slice on the touched window only."""
+    n = Q.shape[0]
+    zero = jnp.zeros((), jnp.int32)
+    return _put(jnp.zeros((2 * n, n), Q.dtype),
+                jnp.zeros((n, 2 * n), Q.dtype), zero,
+                jnp.asarray(n, jnp.int32), Q, W, info[0], compose=False)
 
 
-@partial(jax.jit, static_argnames=("leaf", "l0"))
+def dc_put(blocks, vecs, at, Q, W, info):
+    """Writes the split of the subproblem `at` into the workspaces."""
+    return _put(blocks, vecs, at[0], at[1], Q, W, info[0], compose=True)
+
+
+def dc_leaf(blocks, vecs, at, B: int):
+    """Solve the subproblem `at` = [offset, size <= B] outright and
+    compose its vectors. Returns (blocks, vecs)."""
+    n = vecs.shape[0]
+    dt = blocks.dtype
+    off, sz = at[0], at[1]
+    H, inside = _masked_block(blocks, off, sz, B)
+    # pad with a sentinel diagonal ABOVE the leaf's spectral
+    # radius (<= its Frobenius norm): any sorted eigh then leaves
+    # the real eigenpairs in the leading sz positions and the
+    # padding eigenpairs (exact e_i vectors — the matrix is block
+    # diagonal) at the tail, so no backend-specific no-sort
+    # behavior is relied on (works on CPU LAPACK and TPU Jacobi)
+    sent = 2.0 * jnp.sqrt(jnp.sum(jnp.abs(H) ** 2)) + 1.0
+    H = H + jnp.where(inside, jnp.zeros((), dt),
+                      sent.astype(dt) * jnp.eye(B, dtype=dt))
+    V, w = jax.lax.linalg.eigh(H, symmetrize_input=False)
+    Vnew = jnp.matmul(_window(vecs, off, 1, B), V, precision=HI)
+    vecs = _masked_merge_block(vecs, Vnew, 0, off, n, sz)
+    blocks = _masked_merge_block(blocks, w[:, None].astype(dt),
+                                 off, 0, sz, 1)
+    return blocks, vecs
+
+
+def dc_vectors(blocks, vecs):
+    """(w ascending, V with V[:, i] the eigenvector of w[i]) out of
+    the finished workspaces."""
+    n = vecs.shape[0]
+    w = jnp.real(blocks[:n, 0])
+    order = jnp.argsort(w)
+    return w[order], vecs[:, :n][:, order]
+
+
+def dc_small(h):
+    """A matrix no larger than the leaf: one sorted eigh."""
+    v, w = jax.lax.linalg.eigh(h, symmetrize_input=True)
+    order = jnp.argsort(w)
+    return w[order], v[:, order]
+
+
+#: the steps by name; `_programs` compiles each at a bucket size
+_STEPS = {"take_root": dc_take_root, "put_root": dc_put_root,
+          "take": dc_take, "sign": dc_sign, "basis": dc_basis,
+          "put": dc_put, "leaf": dc_leaf}
+_JIT = {"take": dict(static_argnames=("B",)),
+        "sign": dict(static_argnames=("l0",)),
+        "put": dict(donate_argnums=(0, 1)),
+        "leaf": dict(static_argnames=("B",), donate_argnums=(0, 1))}
+
+
+def _split_steps(step, blocks, vecs, at, h0norm, B: int, l0):
+    """The four steps of one split dispatched in a row (`step` =
+    `_programs(B)`). Returns (blocks, vecs, info)."""
+    H, nearly = step["take"](blocks, at, h0norm, B=B)
+    S, flags = step["sign"](H, at[1], nearly, l0=l0)
+    Q, W, info = step["basis"](H, S, at[1], flags)
+    return step["put"](blocks, vecs, at, Q, W, info) + (info,)
+
+
+def _root_steps(step, h, l0):
+    """The root split at the concrete size: no masking overhead, no
+    identity compose. Returns (blocks, vecs, h0norm, info)."""
+    H, h0norm, nearly = step["take_root"](h)
+    m = np.int32(h.shape[0])
+    S, flags = step["sign"](H, m, nearly, l0=l0)
+    Q, W, info = step["basis"](H, S, m, flags)
+    return step["put_root"](Q, W, info) + (h0norm, info)
+
+
+def _bucket_of(ladder, n: int, sz: int) -> int:
+    """The smallest bucket that holds `sz`; the full size n for a
+    lopsided split's larger child that outgrew the ladder."""
+    return next((b for b in ladder if b >= sz), n)
+
+
+# ---- the agenda -----------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _programs(B: int):
+    """The compiled steps at bucket size B, each named for its bucket
+    (`jit_dc_sign_4224`): a device trace then says which size ran."""
+    def program(name, step):
+        named = functools.partial(step)
+        functools.update_wrapper(named, step)
+        named.__name__ = "%s_%d" % (step.__name__, B)
+        return jax.jit(named, **_JIT.get(name, {}))
+
+    return {name: program(name, step) for name, step in _STEPS.items()}
+
+
+_vectors_program = jax.jit(dc_vectors)
+_small_program = jax.jit(dc_small)
+
+
+def _eigh_dc_agenda(h, leaf: int, l0):
+    """Host agenda over the compiled steps (module doc). The queue
+    holds the splits dispatched and not yet read, oldest first; a
+    read (`heev::agenda`) waits for that split alone while the device
+    runs on through what was dispatched after it."""
+    n = h.shape[0]
+    ladder = _bucket_ladder(n, leaf)
+    span, inc = obs_events.span, obs_metrics.inc
+    leaves = _programs(leaf)
+    with span("heev::split", cat="phase", bucket=n, size=n):
+        blocks, vecs, h0norm, info = _root_steps(_programs(n), h, l0)
+    pending = deque([(0, n, n, info)])
+    ok = True
+    while pending:
+        off, sz, B, info = pending.popleft()
+        with span("heev::agenda", cat="phase"):
+            k, conv, iters = (int(x) for x in np.asarray(info))
+        ok = ok and bool(conv)
+        inc("heev.splits")
+        inc("heev.split_rows_true", sz)
+        inc("heev.split_rows_padded", B)
+        inc("heev.polar_iters", iters)
+        if not conv:
+            inc("heev.unconverged")
+        if k == 0:                      # finished as a diagonal block
+            continue
+        for c_off, c_sz in ((off, k), (off + k, sz - k)):
+            at = np.array([c_off, c_sz], np.int32)
+            if c_sz <= leaf:
+                with span("heev::leaf", cat="phase", size=c_sz):
+                    blocks, vecs = leaves["leaf"](blocks, vecs, at, B=leaf)
+                inc("heev.leaves")
+                continue
+            Bc = _bucket_of(ladder, n, c_sz)
+            with span("heev::split", cat="phase", bucket=Bc, size=c_sz):
+                blocks, vecs, info = _split_steps(
+                    _programs(Bc), blocks, vecs, at, h0norm, Bc, l0)
+            pending.append((c_off, c_sz, Bc, info))
+    with span("heev::vectors", cat="phase"):
+        w, v = _vectors_program(blocks, vecs)
+    return w, v, ok
+
+
 def eigh_dc(h: jax.Array, leaf: int = LEAF, l0=None):
     """Full Hermitian eigendecomposition by spectral divide & conquer
     (module doc). Returns (w ascending, V with V[:, i] the
-    eigenvector of w[i], ok) where `ok` is the AND of every split's
-    polar converged flag — False means at least one sign iteration
-    hit its cap without meeting tolerance and the results may be
-    degraded (the driver surfaces this; ADVICE r5)."""
-    n = h.shape[0]
-    dt = h.dtype
-    if n <= leaf:
-        v, w = jax.lax.linalg.eigh(h, symmetrize_input=True)
-        order = jnp.argsort(w)
-        return w[order], v[:, order], jnp.ones((), jnp.bool_)
-
-    h = 0.5 * (h + h.conj().T)
-    ladder = _bucket_ladder(n, leaf)
-    # agenda bound: every stacked entry has size >= 1 and pending
-    # sizes sum to <= n, so n + 8 can never overflow even under
-    # degenerate k=1 split chains (review r5 finding)
-    cap = n + 8
-
-    h0norm = jnp.sqrt(jnp.sum(jnp.abs(h) ** 2))
-    eps = float(jnp.finfo(dt).eps)
-
-    st = _State(
-        offs=jnp.zeros((cap,), jnp.int32),
-        szs=jnp.zeros((cap,), jnp.int32),
-        sp=jnp.zeros((), jnp.int32),
-        blocks=jnp.zeros((2 * n, n), dt),
-        vecs=jnp.zeros((n, 2 * n), dt),
-        h0norm=h0norm,
-        ok=jnp.ones((), jnp.bool_),
-    )
-
-    def root_diag(st):
-        blocks = _masked_merge_block(
-            st.blocks, jnp.real(jnp.diagonal(h))[:, None].astype(dt),
-            0, 0, n, 1)
-        vecs = _masked_merge_block(st.vecs, jnp.eye(n, dtype=dt),
-                                   0, 0, n, n)
-        return st._replace(blocks=blocks, vecs=vecs)
-
-    def root_split(st):
-        # root split at the concrete size: no masking overhead, and
-        # compose=False skips the stock loop's 2 n^3 identity compose
-        spl = _split_spectrum(h, jnp.asarray(n, jnp.int32), l0)
-        return _apply_split(st, spl, jnp.zeros((), jnp.int32),
-                            jnp.asarray(n, jnp.int32), n,
-                            compose=False)
-
-    d0 = jnp.real(jnp.diagonal(h)).astype(dt)
-    offd0 = jnp.sqrt(jnp.sum(jnp.abs(h - jnp.diagflat(d0)) ** 2))
-    st = jax.lax.cond(offd0 <= 5.0 * eps * h0norm,
-                      root_diag, root_split, st)
-
-    # ---- agenda loop over shrinking buckets
-    def leaf_case(Bc, off, sz, st):
-        H = jax.lax.dynamic_slice(
-            st.blocks,
-            (jnp.asarray(off, jnp.int32), jnp.zeros((), jnp.int32)),
-            (Bc, Bc))
-        ids = jnp.arange(Bc)
-        inside = (ids < sz)[:, None] & (ids < sz)[None, :]
-        H = jnp.where(inside, H, jnp.zeros((), dt))
-        H = 0.5 * (H + H.conj().T)
-        # pad with a sentinel diagonal ABOVE the leaf's spectral
-        # radius (<= its Frobenius norm): any sorted eigh then leaves
-        # the real eigenpairs in the leading sz positions and the
-        # padding eigenpairs (exact e_i vectors — the matrix is block
-        # diagonal) at the tail, so no backend-specific no-sort
-        # behavior is relied on (works on CPU LAPACK and TPU Jacobi)
-        sent = 2.0 * jnp.sqrt(jnp.sum(jnp.abs(H) ** 2)) + 1.0
-        H = H + jnp.where(inside, jnp.zeros((), dt),
-                          sent.astype(dt) * jnp.eye(Bc, dtype=dt))
-        V, w = jax.lax.linalg.eigh(H, symmetrize_input=False)
-        V0 = jax.lax.dynamic_slice(
-            st.vecs, (jnp.zeros((), jnp.int32), jnp.asarray(off, jnp.int32)),
-            (n, Bc))
-        Vnew = jnp.matmul(V0, V, precision=HI)
-        vecs = _masked_merge_block(st.vecs, Vnew, 0, off, n, sz)
-        blocks = _masked_merge_block(
-            st.blocks, w[:, None].astype(dt), off, 0, sz, 1)
-        return st._replace(blocks=blocks, vecs=vecs)
-
-    def recursive_case(Bc, off, sz, st):
-        H = jax.lax.dynamic_slice(
-            st.blocks,
-            (jnp.asarray(off, jnp.int32), jnp.zeros((), jnp.int32)),
-            (Bc, Bc))
-        ids = jnp.arange(Bc)
-        inside = (ids < sz)[:, None] & (ids < sz)[None, :]
-        H = jnp.where(inside, H, jnp.zeros((), dt))
-        H = 0.5 * (H + H.conj().T)
-        hn = jnp.sqrt(jnp.sum(jnp.abs(H) ** 2))
-        d = jnp.real(jnp.diagonal(H)).astype(dt)
-        offd = jnp.sqrt(jnp.sum(jnp.abs(H - jnp.diagflat(d)) ** 2))
-        nearly = (offd <= 5.0 * eps * hn) | (hn < eps * st.h0norm)
-
-        def diag_branch(st):
-            return _write_diag_case(st, off, sz, Bc)
-
-        def split_branch(st):
-            spl = _split_spectrum(H, sz, l0)
-            return _apply_split(st, spl, off, sz, n, compose=True)
-
-        return jax.lax.cond(nearly, diag_branch, split_branch, st)
-
-    branches = [partial(leaf_case, ladder[0])]
-    for b in ladder[1:]:
-        branches.append(partial(recursive_case, b))
-    branches.append(partial(recursive_case, n))   # lopsided fallback
-    bucket_arr = jnp.asarray(ladder + [n], jnp.int32)
-
-    def loop_cond(st):
-        return st.sp > 0
-
-    def loop_body(st):
-        sp = st.sp - 1
-        off = st.offs[sp]
-        sz = st.szs[sp]
-        st = st._replace(sp=sp)
-        which = jnp.where(bucket_arr < sz, jnp.iinfo(jnp.int32).max,
-                          bucket_arr)
-        choice = jnp.argmin(which)
-        return jax.lax.switch(choice, branches, off, sz, st)
-
-    st = jax.lax.while_loop(loop_cond, loop_body, st)
-
-    w = jnp.real(st.blocks[:n, 0])
-    order = jnp.argsort(w)
-    return w[order], st.vecs[:, :n][:, order], st.ok
+    eigenvector of w[i], ok) where `ok`, a Python bool, is the AND of
+    every split's polar converged flag — False means at least one
+    sign iteration hit its cap without meeting tolerance and the
+    results may be degraded (the driver surfaces this; ADVICE r5).
+    `h` is a concrete array: the agenda reads each split's sizes on
+    the host, which a tracer does not allow."""
+    if isinstance(h, jax.core.Tracer):
+        raise TypeError(
+            "eigh_dc: a host agenda dispatches the splits and reads "
+            "their sizes, so it cannot run under a caller's jit; "
+            "call it on a concrete array (st.heev under jit takes "
+            "XLA's eigh)")
+    if h.shape[0] <= leaf:
+        return _small_program(h) + (True,)
+    return _eigh_dc_agenda(jnp.asarray(h), leaf, l0)

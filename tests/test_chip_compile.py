@@ -183,3 +183,44 @@ def test_getrf_carry_finish_compiles_at_the_cells_shape(one_chip):
     ma = compiled.memory_analysis()
     assert ma.output_size_in_bytes >= n * n * 4
     assert ma.temp_size_in_bytes < n * n * 4 // 8, ma.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("step", ["put", "leaf", "sign", "basis"])
+def test_heev_steps_compile_at_the_cells_workspaces(one_chip, step):
+    """A step of the spectral divide and conquer's agenda (PR 33) at
+    incore-heev's workspaces, n=8192, in the ladder's second bucket.
+    `dc_put` and `dc_leaf` take the donated (2n, n) and (n, 2n)
+    workspaces and must write them in place: a conditional over them
+    made the compiler copy one, 0.54 GB a launch. `dc_sign` holds ONE
+    Cholesky form and `dc_basis` ONE QR (the parent held two of each):
+    every bucket's programs stay a small part of what the compile
+    cache takes an entry."""
+    from slate_tpu.linalg import spectral_dc as dc
+    n, B, leaf = 8192, 384, 256
+    one = jnp.float32
+
+    def S(*shape, dtype=one):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ws = (S(2 * n, n), S(n, 2 * n), S(2, dtype=jnp.int32))
+    sq, flags = S(B, B), S(3, dtype=jnp.int32)
+    if step == "put":
+        compiled = dc._programs(B)["put"].lower(
+            *ws, sq, sq, flags).compile()
+    elif step == "leaf":
+        compiled = dc._programs(leaf)["leaf"].lower(*ws, B=leaf).compile()
+    elif step == "sign":
+        compiled = dc._programs(B)["sign"].lower(
+            sq, S(dtype=jnp.int32), S(dtype=jnp.bool_)).compile()
+    else:
+        compiled = dc._programs(B)["basis"].lower(
+            sq, sq, S(dtype=jnp.int32), flags).compile()
+    ma = compiled.memory_analysis()
+    if step in ("put", "leaf"):
+        assert ma.output_size_in_bytes >= 2 * n * 2 * n * 4
+        assert ma.temp_size_in_bytes < n * n * 4 // 8, ma.temp_size_in_bytes
+    else:
+        # 4.7 and 4.3 MB of code (sandbox, PR 33); two copies of the
+        # Cholesky form or of the QR are 8 and more
+        assert ma.generated_code_size_in_bytes < 6 << 20, \
+            ma.generated_code_size_in_bytes

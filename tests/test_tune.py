@@ -430,7 +430,7 @@ def test_polar_lift_is_interval_minimum():
 def test_polar_estimator_key_varies_with_iteration():
     """The estimator start block folds the iteration counter into its
     PRNG key (no fixed-PRNGKey(7) retry loop)."""
-    from slate_tpu.linalg.polar import _chol_halley_step
+    from slate_tpu.linalg.polar import _chol_halley, _sigma_min_estimate
     n = 32
     rng = np.random.default_rng(3)
     x = rng.standard_normal((n, n)).astype(np.float32)
@@ -438,10 +438,9 @@ def test_polar_estimator_key_varies_with_iteration():
     a = jnp.float32(3.0)
     b = jnp.float32(1.0)
     c = jnp.float32(3.0)
-    _, sig0, _ = _chol_halley_step(u, a, b, c, want_sigma_est=True,
-                                   it=0)
-    _, sig1, _ = _chol_halley_step(u, a, b, c, want_sigma_est=True,
-                                   it=1)
+    _, r = _chol_halley(u, a, b, c)
+    sig0, _ = _sigma_min_estimate(r, c, it=0)
+    sig1, _ = _sigma_min_estimate(r, c, it=1)
     # different fold leads to a (generically) different estimate;
     # both remain finite and nonnegative
     assert np.isfinite(float(sig0)) and np.isfinite(float(sig1))
