@@ -491,9 +491,10 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
         # the factor's host buffer, mapped and not touched
         # (np.zeros_like would fill all n^2 elements before the first
         # panel is staged): the strictly upper blocks are never
-        # written and stay the exact zeros the cached full-height
-        # panels mirror; the writer's _d2h threads first touch the
-        # rest, and a full-height read the zeros above its block
+        # written and never read (a factor panel is staged from its
+        # diagonal block down and zero-embedded on the device, PR
+        # 34), so their pages are never faulted in; the writer's
+        # _d2h threads first touch the rest
         with obs_events.span("ooc::alloc", cat="staging",
                              bytes=int(a.nbytes)):
             out = np.zeros(a.shape, a.dtype)
@@ -526,14 +527,15 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
         j1 = min(j0 + panel_cols, n)
         if eng.caching:
             # cached entries are full-height columns (rows above the
-            # diagonal block are exact zeros in the lower factor),
-            # served sliced to rows k0: — the same (n-k0, wj) block
-            # the upload path ships
+            # diagonal block are exact zeros in the lower factor:
+            # staged from the diagonal block down, embedded on the
+            # device), served sliced to rows k0: — the same
+            # (n-k0, wj) block the upload path ships
             with _ledger.frame("stage"):
                 Lj = eng.fetch("L", j,
                                lambda j0=j0, j1=j1:
-                               ld(out[:, j0:j1]),
-                               view=(k0, n - k0))
+                               ld(out[j0:, j0:j1]),
+                               view=(k0, n - k0), embed=(j0, n))
         else:
             with _ledger.frame("stage"):
                 Lj = eng.fetch(
@@ -545,7 +547,7 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
             if eng.caching:
                 eng.prefetch("L", j + 1,
                              lambda j2=j2, j3=j3:
-                             ld(out[:, j2:j3]))
+                             ld(out[j2:, j2:j3]), embed=(j2, n))
             else:
                 eng.prefetch("L", j + 1,
                              lambda j2=j2, j3=j3:
@@ -644,25 +646,39 @@ def _chol_back_visit(S: jax.Array, Pk: jax.Array, k0) -> jax.Array:
     return jax.lax.dynamic_update_slice(S, X, (k0, 0))
 
 
-def _solve_sweep(eng, buf, mat, w, n, X, order, kernel, prep=None):
+def _solve_sweep(eng, buf, mat, w, n, X, order, kernel, prep=None,
+                 lower=False):
     """One streamed triangular-solve sweep shared by the OOC solves:
-    for each panel start in `order`, fetch the full factor column
+    for each panel start in `order`, fetch the factor column
     `mat[:, k0:k0+w]` through the engine (prefetching the next one),
-    then advance the device-resident RHS with `kernel(X, Pk, k0)`.
+    then advance the device-resident RHS with `kernel(X, Pk, k0)`,
+    which is handed the full-height (n, w) panel either way.
     Forward and backward sweeps differ only in `order`/`kernel`.
     `prep` transforms the host slice before staging (the mixed path's
     stream.demote_host — half the sweep's H2D bytes; None is the
-    identity, the full-precision path bit-identically)."""
+    identity, the full-precision path bit-identically). `lower` says
+    the factor is lower triangular by blocks (potrs_ooc's L): the
+    column is staged from its diagonal block down, `mat[k0:, ...]`,
+    and zero-embedded on the device (stream.fetch `embed`; an offset
+    of 0 is a plain upload). The default stages the whole column,
+    which getrs_ooc's packed LU needs: U lies above the diagonal
+    block."""
     if prep is None:
         prep = lambda sl: sl                              # noqa: E731
+
+    def column(k0):
+        """The loader and `embed` of the panel whose columns start
+        at k0."""
+        off = k0 if lower else 0
+        return (lambda: prep(mat[off:, k0:min(k0 + w, n)])), (off, n)
+
     for i, k0 in enumerate(order):
-        Pk = eng.fetch(buf, k0 // w,
-                       lambda k0=k0: prep(mat[:, k0:min(k0 + w, n)]))
+        load, embed = column(k0)
+        Pk = eng.fetch(buf, k0 // w, load, embed=embed)
         if i + 1 < len(order):
             p0 = order[i + 1]
-            eng.prefetch(buf, p0 // w,
-                         lambda p0=p0:
-                         prep(mat[:, p0:min(p0 + w, n)]))
+            load, embed = column(p0)
+            eng.prefetch(buf, p0 // w, load, embed=embed)
         X = kernel(X, Pk, k0)
     return X
 
@@ -677,9 +693,12 @@ def potrs_ooc(l: np.ndarray, b: np.ndarray,
     unit=False) and the conjugate-transposed backward sweep. B stays
     device-resident (nrhs << n), so HBM holds one (n, w) factor panel
     plus the RHS block (reference src/potrs.cc solves from the
-    distributed factor the same two-sweep way). With a cache budget
-    the backward sweep re-serves the panels the forward sweep
-    uploaded (reverse order hits whatever stayed resident).
+    distributed factor the same two-sweep way). A panel is staged
+    from its diagonal block down and zero-embedded on the device
+    (_solve_sweep's `lower`): nothing above the diagonal block of `l`
+    is read. With a cache budget the backward sweep re-serves the
+    panels the forward sweep uploaded (reverse order hits whatever
+    stayed resident).
     ``precision`` "bf16" (ISSUE 12) stages the factor panels in bf16
     and runs the mixed sweep kernels — the lo solve of the
     refinement loop (posv_ooc), which corrects what the demotion
@@ -704,9 +723,11 @@ def potrs_ooc(l: np.ndarray, b: np.ndarray,
     try:
         X = _h2d(np.asarray(b))
         X = _solve_sweep(                    # forward: L y = b
-            eng, "L", l, w, n, X, panels, fwd, prep=prep)
+            eng, "L", l, w, n, X, panels, fwd, prep=prep,
+            lower=True)
         X = _solve_sweep(                    # backward: L^H x = y
-            eng, "L", l, w, n, X, panels[::-1], bwd, prep=prep)
+            eng, "L", l, w, n, X, panels[::-1], bwd, prep=prep,
+            lower=True)
         return np.asarray(X)
     finally:
         eng.finish()

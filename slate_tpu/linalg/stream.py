@@ -247,11 +247,17 @@ def _suffix_rows(P: jax.Array, off, *, rows: int) -> jax.Array:
 @functools.partial(jax.jit, static_argnames=("n",))
 def _embed_rows(P: jax.Array, off, *, n: int) -> jax.Array:
     """Zero-embed a (rows, w) panel at row offset `off` of an (n, w)
-    frame — how a just-factored potrf panel (rows k0:) enters the
-    cache at the full-height normal form every later visit slices
-    from. Rows above the offset are exact zeros, matching the
-    zeros-initialized host factor those rows mirror, so a cached
-    entry is bit-identical to the uploaded column it replaces."""
+    frame — how a lower-Cholesky factor panel (rows k0:, from its
+    diagonal block down) becomes the full-height normal form every
+    later visit slices from: potrf_ooc's just-factored panel on its
+    way into the cache, and every factor panel an engine stages
+    trimmed (``fetch(embed=)``; PR 34). Rows above the offset are
+    exact zeros, which is what the lower factor holds there (the
+    host buffer's strictly upper blocks are never written), so the
+    frame is bit-identical to the full-height column it stands for,
+    without those zeros being read on the host or sent over the
+    link. The frame has the panel's dtype (bf16 under the mixed
+    mode)."""
     import jax.numpy as jnp
     frame = jnp.zeros((n, P.shape[1]), P.dtype)
     return jax.lax.dynamic_update_slice(frame, P, (off, 0))
@@ -609,10 +615,13 @@ class StreamEngine:
 
     @property
     def caching(self) -> bool:
-        """Call sites switch loaders on this: cached mode wants the
-        full-height panel (the insertable normal form), uncached mode
-        wants exactly the rows the kernel consumes (the pre-engine
-        upload, bit-identical by construction)."""
+        """Call sites switch loaders on this: cached mode wants an
+        entry every later visit can slice from, the full-height
+        normal form (staged whole, or, for a lower-Cholesky factor
+        panel, from its diagonal block down and zero-embedded on the
+        device: ``fetch(embed=)``); uncached mode wants exactly the
+        rows the kernel consumes (the pre-engine upload,
+        bit-identical by construction)."""
         return self.cache.enabled
 
     # -- H2D side ---------------------------------------------------
@@ -635,7 +644,13 @@ class StreamEngine:
                 f.result()
         _ledger.credit("cache", time.perf_counter() - t0)
 
-    def _upload(self, buf: str, idx: int, loader: Callable) -> Any:
+    def _upload(self, buf: str, idx: int, loader: Callable,
+                embed: Optional[Tuple[int, int]] = None) -> Any:
+        """Stage what `loader` returns. With `embed=(off, n)` that is
+        rows off: of a panel whose rows above are zeros (fetch doc):
+        the full-height frame is made on the device, and the bytes
+        the trim left on the host are counted beside the bytes
+        sent."""
         self._wait_write(buf, idx)
         arr = _guard_transfer("h2d", lambda: _h2d(loader()),
                               buf=buf, idx=idx)
@@ -643,16 +658,24 @@ class StreamEngine:
         # take the cache lock like every other counter mutation
         with self.cache._lock:
             self.cache.uploaded_bytes += _nbytes(arr)
+        off, n = embed or (0, 0)
+        if off > 0:
+            if obs_events.enabled():
+                obs_metrics.inc("ooc.h2d_trimmed_bytes",
+                                off * _nbytes(arr) // arr.shape[0])
+            arr = _embed_rows(arr, off, n=n)
         return arr
 
     def prefetch(self, buf: str, idx: int, loader: Callable,
-                 cache: bool = True) -> None:
+                 cache: bool = True,
+                 embed: Optional[Tuple[int, int]] = None) -> None:
         """Queue `buf[idx]`'s upload on the transfer thread (no-op
         when already cached, already pending, or prefetch is off).
         The loader runs ON the worker — it must read host state that
         is stable until the matching fetch (drivers only prefetch
         within a fixup-free window; a stale pending entry is fenced
-        by the epoch in its key)."""
+        by the epoch in its key). `embed` as in fetch: the pending
+        entry is the embedded full-height panel."""
         if self._h2d_pool is None:
             return
         key = self.cache.key(buf, idx)
@@ -669,7 +692,7 @@ class StreamEngine:
             t0 = time.perf_counter()
             with obs_events.span("ooc::prefetch", cat="staging",
                                  buf=buf, idx=idx):
-                arr = self._upload(buf, idx, loader)
+                arr = self._upload(buf, idx, loader, embed)
             self.prefetch_upload_seconds += time.perf_counter() - t0
             return arr
 
@@ -680,14 +703,26 @@ class StreamEngine:
 
     def fetch(self, buf: str, idx: int, loader: Callable,
               view: Optional[Tuple[Any, int]] = None,
-              cache: bool = True) -> Any:
+              cache: bool = True,
+              embed: Optional[Tuple[int, int]] = None) -> Any:
         """The visiting panel `buf[idx]`: cache hit, pending prefetch,
         or synchronous upload — in that order. `view=(offset, rows)`
         slices the served full-height entry down to the rows the
         kernel consumes (potrf's shrinking visits, gels' R prefix);
         None serves the entry as-is. With the cache off the loader is
         expected to return the exact kernel input and `view` is
-        ignored for uploads."""
+        ignored for uploads.
+
+        `embed=(off, n)` says the loader returns a TRIMMED panel:
+        rows off: of an (n, w) panel whose rows above `off` are exact
+        zeros (a lower-Cholesky factor panel above its diagonal
+        block). The upload zero-embeds it on the device
+        (_embed_rows), so the cache, `view` and the caller see the
+        same full-height array a whole-column upload gives, and
+        `off * w * itemsize` bytes are neither read on the host nor
+        sent (``ooc.h2d_trimmed_bytes``). `off == 0` is a plain
+        upload. It is the caller's knowledge of its matrix, never a
+        default: an LU panel carries U above its diagonal block."""
         key = self.cache.key(buf, idx)
         use_cache = cache and self.cache.enabled
         if use_cache:
@@ -717,7 +752,7 @@ class StreamEngine:
         with _ledger.frame("stage"), \
                 obs_events.span("ooc::wait_stage", cat="staging",
                                 buf=buf, idx=idx, kind="sync"):
-            arr = self._upload(buf, idx, loader)
+            arr = self._upload(buf, idx, loader, embed)
         self.sync_upload_seconds += time.perf_counter() - t0
         if use_cache:
             self.cache.put(key, arr)

@@ -178,6 +178,43 @@ def test_bf16_residency_cuts_staged_bytes(rng, obs_on):
         > c1.get("ooc.cast_demote_bytes", 0)
 
 
+def test_bf16_trimmed_upload_is_the_resident_panel(rng, obs_on):
+    """A budget of three bf16 panels makes the mixed stream stage
+    evicted factor panels again, from their diagonal block down
+    (demote_host of fewer rows, the frame made in bf16 on the device):
+    the factor equals the all-resident budget's, which uploads no
+    factor panel at all, bit for bit, and potrs_ooc's lo sweeps read
+    the same X from both."""
+    n, w = 256, 32
+    a, _ = _spd(rng, n)
+    b = rng.standard_normal((n, 2)).astype(np.float32)
+    want = ooc.potrf_ooc(a, panel_cols=w, precision="bf16",
+                         cache_budget_bytes=8 * n * w * 2)
+    c0 = _counters()
+    assert "ooc.h2d_trimmed_bytes" not in c0    # nothing was re-staged
+    got = ooc.potrf_ooc(a, panel_cols=w, precision="bf16",
+                        cache_budget_bytes=3 * n * w * 2)
+    c1 = _counters()
+    assert got.tobytes() == want.tobytes()
+    trimmed = c1["ooc.h2d_trimmed_bytes"]
+    assert trimmed > 0 and trimmed % (w * w * 2) == 0   # bf16 blocks
+    # the demotion reads only the rows it stages: f32 bytes in, half out
+    staged_l = c1["ooc.h2d_bytes"] - c0["ooc.h2d_bytes"] \
+        - (n // w) * (n // w + 1) // 2 * w * w * 4      # less the A panels
+    demoted_host = c1["ooc.cast_demote_bytes"] \
+        - 2 * c0["ooc.cast_demote_bytes"]       # less the puts' demotions
+    assert demoted_host == 2 * staged_l
+    x0 = ooc.potrs_ooc(want, b, panel_cols=w, precision="bf16",
+                       cache_budget_bytes=8 * n * w * 2)
+    x1 = ooc.potrs_ooc(got, b, panel_cols=w, precision="bf16",
+                       cache_budget_bytes=3 * n * w * 2)
+    assert x1.tobytes() == x0.tobytes()
+    c2 = _counters()
+    # forward L0..L7 and backward L4..L0 at a budget of three: all
+    # but L0's two uploads are trimmed
+    assert c2["ooc.h2d_trimmed_bytes"] > trimmed
+
+
 def test_bf16_residency_fits_2x_panels():
     """Budget accounting: at an equal byte budget the cache holds
     ~2x the panels when residents are demoted — pinned directly on

@@ -6,6 +6,7 @@ measurably cutting the left-looking H2D revisit volume (the ISSUE 4
 acceptance: >= 40% reduction at nt >= 8 with a budget holding >= nt/2
 panels, read from the obs metrics snapshot)."""
 
+import os
 import sys
 import threading
 import time
@@ -680,12 +681,17 @@ def test_stream_rerun_compiles_nothing(rng, obs_on, driver):
 # -- what one posv_ooc stages, from its schedule --------------------------
 
 def _posv_staged_panels(nt, resident):
-    """Host model of posv_ooc's staging in full-height panels
-    (n x w): the schedule of linalg/ooc.py restated, with the panel
-    cache as a list. The cache holds `resident` full-height panels;
-    when full it gives up the most recently used one that is not one
-    of the last two touched (policy mru, two pins)."""
-    held, pins, staged = [], [], 0.0
+    """Host model of posv_ooc's staging, in blocks of w x w (a
+    full-height panel is nt of them): the schedule of linalg/ooc.py
+    restated, with the panel cache as a list. Returns (staged,
+    trimmed): what crosses the link,
+    and the rows above a factor panel's diagonal block that a trimmed
+    upload leaves on the host (PR 34: a cache miss of L[:, j] and
+    every upload of potrs_ooc's sweeps stage rows j0: only). The cache
+    holds `resident` full-height panels; when full it gives up the
+    most recently used one that is not one of the last two touched
+    (policy mru, two pins)."""
+    held, pins, staged, trimmed = [], [], 0, 0
 
     def touch(j):
         """A fetch or a put of panel j; True when it was resident."""
@@ -705,45 +711,225 @@ def _posv_staged_panels(nt, resident):
         return hit
 
     for k in range(nt):                     # potrf_ooc, left-looking
-        below = (nt - k) / nt               # rows k0: of a panel
+        below = nt - k                      # rows k0: of a panel
         staged += below                     # A[k0:, k], never cached
         for j in range(k):                  # the visits of L[:, j]
             if not resident:
                 staged += below             # uncached: rows k0: only
             elif not touch(j):
-                staged += 1.0               # cached: full height
+                staged += nt - j            # cached: rows j0:, embedded
+                trimmed += j
         if resident:
             touch(k)                        # the factored panel is put
     held, pins = [], []                     # potrs_ooc: an engine of its own
     for j in [*range(nt), *reversed(range(nt))]:
         if not resident or not touch(j):    # forward, then backward
-            staged += 1.0
-    return staged
+            staged += nt - j
+            trimmed += j
+    return staged, trimmed
 
 
-@pytest.mark.parametrize("nt,resident,panels", [
-    (8, 5, 20.5), (8, 0, 31.0), (8, 8, 12.5), (6, 3, 21.5), (4, 2, 9.5)])
+@pytest.mark.parametrize("nt,resident,blocks,left", [
+    (8, 5, 109, 55), (8, 0, 192, 56), (8, 8, 72, 28), (6, 3, 95, 34),
+    (4, 2, 25, 13)])
 def test_posv_ooc_staged_bytes_match_schedule(rng, obs_on, nt, resident,
-                                              panels):
+                                              blocks, left):
     """ooc.h2d_bytes of one posv_ooc is what its schedule says: the
     input panels below their diagonal, the visits a cache of `resident`
-    full-height panels misses, potrs_ooc's two sweeps, and the
-    right-hand side. `panels` is the model's answer, written down so
-    that a change of schedule shows in the diff; (8, 5) is the streamed
-    cell's shape, 20.5 panels and the right-hand side, which at n=32768,
-    w=4096, nrhs=8 is the 11.006902272 GB the benchmark reads as
-    stream.h2d_gb."""
+    full-height panels misses and potrs_ooc's two sweeps, each from the
+    panel's diagonal block down, and the right-hand side;
+    ooc.h2d_trimmed_bytes is the rows above those diagonal blocks,
+    which the parent staged too (164 blocks at (8, 5), PR 26).
+    `blocks` and `left` are the model's answers, written down so that a
+    change of schedule shows in the diff; (8, 5) is the streamed cell's
+    shape: 109 blocks of w x w and the right-hand side, which at
+    n=32768, w=4096, nrhs=8 is the 7.315914752 GB the benchmark reads
+    as stream.h2d_gb, and 55 blocks, 3.69098752 GB, left on the host
+    (stream.h2d_trim_share 33.5%)."""
     from slate_tpu.obs import metrics
-    assert _posv_staged_panels(nt, resident) == panels
-    assert int(_posv_staged_panels(8, 5) * 32768 * 4096 * 4) \
-        + 32768 * 8 * 4 == 11006902272
+    assert _posv_staged_panels(nt, resident) == (blocks, left)
+    assert 109 * 4096 * 4096 * 4 + 32768 * 8 * 4 == 7315914752
+    assert 55 * 4096 * 4096 * 4 == 3690987520
     w, nrhs = 32, 8
     n = nt * w
     a, b = _spd(rng, n, np.float32), _f32(rng, n, nrhs)
     ooc.posv_ooc(a, b, panel_cols=w,
                  cache_budget_bytes=resident * n * w * 4)
-    got = metrics.snapshot()["counters"]["ooc.h2d_bytes"]
-    assert got == int(panels * n * w * 4) + n * nrhs * 4
+    c = metrics.snapshot()["counters"]
+    assert c["ooc.h2d_bytes"] == blocks * w * w * 4 + n * nrhs * 4
+    assert c["ooc.h2d_trimmed_bytes"] == left * w * w * 4
+
+
+# -- factor panels staged from their diagonal block down (PR 34) ----------
+
+@pytest.mark.parametrize("n,w,staged,trimmed", [
+    (512, 64, 109 * 64 * 64, 55 * 64 * 64),
+    # no multiple of w: 8 panels, the last 52 columns wide and tall
+    (500, 64, None, None)])
+def test_posv_ooc_trimmed_uploads_bitwise_the_uncached_route(
+        rng, obs_on, n, w, staged, trimmed):
+    """The streamed cell's shape in small (8 panels, a budget of 5): a
+    factor panel reaches the device from its diagonal block down and is
+    zero-embedded there, L and X equal the budget-0 route's bit for bit
+    (whose potrf_ooc stages rows k0: as it always did), and the counters
+    are the cell's block arithmetic: 109 blocks sent, 55 left behind."""
+    from slate_tpu.obs import metrics
+    a, b = _spd(rng, n, np.float32), _f32(rng, n, 3)
+    L0, X0 = ooc.posv_ooc(a, b, panel_cols=w, cache_budget_bytes=0)
+    metrics.reset()
+    L, X = ooc.posv_ooc(a, b, panel_cols=w,
+                        cache_budget_bytes=5 * n * w * 4)
+    c = metrics.snapshot()["counters"]
+    assert L.tobytes() == L0.tobytes() and X.tobytes() == X0.tobytes()
+    assert not np.triu(L, 1).any()
+    if staged is None:
+        # by hand: panel j is (n - j w) x min(w, n - j w); the same
+        # uploads as at n=512 (8 A; L2, L5, L2, L3, L6; L0..L7; L4,
+        # L3, L2)
+        ws = [min(w, n - j * w) for j in range(8)]
+        ups = [2, 5, 2, 3, 6, *range(8), 4, 3, 2]
+        staged = sum((n - j * w) * ws[j] for j in [*range(8), *ups])
+        trimmed = sum(j * w * ws[j] for j in ups)
+    assert c["ooc.h2d_bytes"] == staged * 4 + b.nbytes
+    assert c["ooc.h2d_trimmed_bytes"] == trimmed * 4
+    assert c["ooc.cache.misses"] == 16          # the L uploads, as before
+
+
+@pytest.mark.parametrize("budget_panels", [0, 3, 8])
+def test_potrs_ooc_never_reads_above_the_diagonal_block(rng, budget_panels):
+    """Both sweeps stage rows k0: of panel k: a factor whose strictly
+    upper blocks are NaN gives the clean factor's X, bit for bit."""
+    n, w = 256, 32
+    L = ooc.potrf_ooc(_spd(rng, n, np.float32), panel_cols=w)
+    b = _f32(rng, n, 3)
+    dirty = L.copy()
+    for j in range(1, n // w):
+        dirty[:j * w, j * w:(j + 1) * w] = np.nan
+    bud = budget_panels * n * w * 4
+    want = ooc.potrs_ooc(L, b, panel_cols=w, cache_budget_bytes=bud)
+    got = ooc.potrs_ooc(dirty, b, panel_cols=w, cache_budget_bytes=bud)
+    assert np.isfinite(want).all()
+    assert got.tobytes() == want.tobytes()
+
+
+def test_getrs_ooc_sweeps_stay_full_height(rng, obs_on):
+    """An LU panel carries U above its diagonal block: getrs_ooc's two
+    sweeps stage 2 nt whole columns with the cache off, and nothing is
+    trimmed anywhere in gesv_ooc."""
+    from slate_tpu.obs import metrics
+    n, w, nrhs = 256, 32, 3
+    nt = n // w
+    g, b = _f32(rng, n, n), _f32(rng, n, nrhs)
+    lu, ipiv = ooc.getrf_ooc(g, panel_cols=w)
+    metrics.reset()
+    x = ooc.getrs_ooc(lu, ipiv, b, panel_cols=w, cache_budget_bytes=0)
+    c = metrics.snapshot()["counters"]
+    assert c["ooc.h2d_bytes"] == 2 * nt * n * w * 4 + b.nbytes
+    assert "ooc.h2d_trimmed_bytes" not in c
+    assert np.abs(g @ x - b).max() < 1e-2
+    ooc.gesv_ooc(g, b, panel_cols=w, cache_budget_bytes=3 * n * w * 4)
+    assert "ooc.h2d_trimmed_bytes" not in metrics.snapshot()["counters"]
+
+
+def test_trimmed_uploads_add_no_embed_program(rng):
+    """_embed_rows is compiled for every (n - k0, w) of a solve by
+    potrf_ooc's writeback: the trimmed uploads of a warm solve find
+    their programs there, and a second solve adds none."""
+    n, w = 512, 64
+    a, b = _spd(rng, n, np.float32), _f32(rng, n, 3)
+    bud = 5 * n * w * 4
+    ooc.potrf_ooc(a, panel_cols=w, cache_budget_bytes=8 * n * w * 4)
+    after_writebacks = stream._embed_rows._cache_size()
+    ooc.posv_ooc(a, b, panel_cols=w, cache_budget_bytes=bud)
+    assert stream._embed_rows._cache_size() == after_writebacks
+    ooc.posv_ooc(a, b, panel_cols=w, cache_budget_bytes=bud)
+    assert stream._embed_rows._cache_size() == after_writebacks
+
+
+def test_engine_embed_is_the_full_height_panel(rng, obs_on):
+    """fetch(embed=(off, n)) and prefetch(embed=) hand the cache, view=
+    and the caller the array a whole-column upload gives; off == 0 is a
+    plain upload and counts nothing."""
+    from slate_tpu.obs import metrics
+    n, w, off = 96, 16, 32
+    col = np.zeros((n, w), np.float32)
+    col[off:] = _f32(rng, n - off, w)
+    host = np.zeros((n, 3 * w), np.float32)
+    host[:, w:2 * w] = col                      # a strided source
+    with StreamEngine(budget_bytes=4 * n * w * 4) as eng:
+        whole = eng.fetch("W", 0, lambda: host[:, w:2 * w])
+        got = eng.fetch("T", 0, lambda: host[off:, w:2 * w],
+                        embed=(off, n))
+        assert got.shape == (n, w) and got.dtype == whole.dtype
+        assert np.asarray(got).tobytes() == np.asarray(whole).tobytes()
+        # the cached entry is the embedded one, and view= slices it
+        tail = eng.fetch("T", 0, lambda: 1 / 0, view=(off + 8, 24))
+        assert np.array_equal(np.asarray(tail), col[off + 8:off + 32])
+        eng.prefetch("P", 0, lambda: host[off:, w:2 * w], embed=(off, n))
+        assert np.array_equal(np.asarray(
+            eng.fetch("P", 0, lambda: 1 / 0, embed=(off, n))), col)
+        c = metrics.snapshot()["counters"]
+        assert c["ooc.h2d_trimmed_bytes"] == 2 * off * w * 4
+        assert c["ooc.h2d_bytes"] == (n + 2 * (n - off)) * w * 4
+        plain = eng.fetch("Z", 0, lambda: host[:, w:2 * w], embed=(0, n))
+        assert np.array_equal(np.asarray(plain), col)
+        assert metrics.snapshot()["counters"]["ooc.h2d_trimmed_bytes"] \
+            == c["ooc.h2d_trimmed_bytes"]
+        assert eng.cache.stats()["uploaded_bytes"] \
+            == (2 * n + 2 * (n - off)) * w * 4
+
+
+# -- the benchmark's metric ------------------------------------------------
+
+def _trim_share():
+    from benchmarks import run as bench_run
+    return bench_run, bench_run.load_module(
+        "layer_metrics", "stream.h2d_trim_share").compute
+
+
+def test_h2d_trim_share_is_declared_and_found():
+    bench_run, compute = _trim_share()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    per_layer = bench_run.load_json(
+        os.path.join(root, "BENCHMARK.json"))["per_layer"]
+    m = [x for x in per_layer if x["name"] == "stream.h2d_trim_share"]
+    assert m == [{"name": "stream.h2d_trim_share", "unit": "%",
+                  "better": "higher", "source": "program_counter",
+                  "layer": "stream engine", "moves": "stream_solve_s",
+                  "workloads": ["stream-posv"]}]
+    # appended after PR 33's heev metrics; later PRs append after it
+    at = per_layer.index(m[0])
+    assert per_layer[at - 1]["name"] == "heev.pad_rows_share"
+    assert callable(compute)
+
+
+@pytest.mark.parametrize("counted,want", [
+    # the streamed cell by its schedule: 55 blocks of 164
+    ({"ooc.h2d_trimmed_bytes": 3690987520,
+      "ooc.h2d_bytes": 7315914752}, 100.0 * 3690987520 / 11006902272),
+    ({"ooc.h2d_trimmed_bytes": 100, "ooc.h2d_bytes": 300}, 25.0),
+    ({"ooc.h2d_bytes": 11006902272}, None),     # the parent: no trim
+    ({}, None),
+    ({"grid.h2d_bytes": 9, "ooc.h2d_stage_reuse_bytes": 5}, None),
+])
+def test_h2d_trim_share_by_hand(counted, want):
+    assert _trim_share()[1]({"counters": counted}) == want
+
+
+def test_h2d_trim_share_from_a_solve(rng, obs_on):
+    """One posv_ooc at the cell's shape reads 55 / 164.5 blocks (the
+    right-hand side is half a block here); getrs_ooc, whose panels stay
+    whole, reads nothing."""
+    from slate_tpu.obs import metrics
+    compute = _trim_share()[1]
+    n, w = 256, 32
+    a, g, b = _spd(rng, n, np.float32), _f32(rng, n, n), _f32(rng, n, 2)
+    ooc.gesv_ooc(g, b, panel_cols=w, cache_budget_bytes=5 * n * w * 4)
+    assert compute({"counters": metrics.snapshot()["counters"]}) is None
+    metrics.reset()
+    ooc.posv_ooc(a, b, panel_cols=w, cache_budget_bytes=5 * n * w * 4)
+    got = compute({"counters": metrics.snapshot()["counters"]})
+    assert got == 100.0 * 55 / 164.5 and 33.0 < got < 34.0
 
 
 def test_writebacks_in_flight_are_bounded(monkeypatch, obs_on):
