@@ -30,6 +30,7 @@ the padded diagonal block to identity; helpers here provide that.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Optional, Tuple
 
 import jax
@@ -42,6 +43,9 @@ from .exceptions import DimensionError, slate_assert
 
 
 _warned_downcast = False
+#: what a host array's `matrix::h2d` and `matrix::h2d_ready` spans
+#: share (counted with the obs bus on only)
+_upload_seq = itertools.count()
 
 
 def _asarray_warn_downcast(a, grid=None, shape=None):
@@ -57,12 +61,20 @@ def _asarray_warn_downcast(a, grid=None, shape=None):
         out = place(a if hasattr(a, "nbytes") else np.asarray(a), grid,
                     shape)
     elif obs_events.enabled() and isinstance(a, np.ndarray):
-        # a host array: the upload a solve's wall contains (the
-        # hand-over to the runtime; the transfer itself is not waited
-        # for)
+        # a host array: the upload a solve's wall contains.
+        # `matrix::h2d` is the hand-over to the runtime; the transfer
+        # itself is not waited for here: `matrix::h2d_ready` (same
+        # `seq`) stays open on the obs-ready thread until it is over.
+        # Under a profiler session a large one launches a clock beacon
+        # first, from this thread, so that it runs ahead of every
+        # program that waits for `a`
+        seq, nbytes = next(_upload_seq), int(a.nbytes)
+        obs_events.clock_beacon(nbytes)
         with obs_events.span("matrix::h2d", cat="staging",
-                             bytes=int(a.nbytes)):
+                             bytes=nbytes, seq=seq):
             out = jnp.asarray(a)
+        obs_events.watch_ready("matrix::h2d_ready", out, bytes=nbytes,
+                               seq=seq)
     else:
         out = jnp.asarray(a)
     global _warned_downcast

@@ -172,27 +172,33 @@ def _h2d(x: np.ndarray) -> jax.Array:
     with obs_events.span("ooc::h2d", cat="staging", bytes=nbytes):
         # the host-side copy against the hand-over to the runtime; the
         # transfer is not waited for (observing must not change the
-        # program)
+        # program): `ooc::h2d_ready` below stays open on the obs-ready
+        # thread until it is over
         if x.flags.c_contiguous:
+            reused = None           # taken as it lies: in neither counter
             with obs_events.span("ooc::h2d_pack", cat="staging"):
                 packed = np.ascontiguousarray(x)
             with obs_events.span("ooc::h2d_put", cat="staging"):
-                return jnp.asarray(packed)
-        slot, reused = _ring.acquire(nbytes)
-        arr = None
-        try:
-            with obs_events.span("ooc::h2d_pack", cat="staging"):
-                packed = slot.buf[:nbytes].view(x.dtype).reshape(x.shape)
-                np.copyto(packed, x)
-            with obs_events.span("ooc::h2d_put", cat="staging"):
-                arr = jnp.array(packed) if _aliases_host() \
-                    else jnp.asarray(packed)
-        finally:
-            _ring.release(slot, arr)
-    if on and reused:
-        obs_metrics.inc("ooc.h2d_stage_reuse_bytes", nbytes)
-    elif on:
-        obs_metrics.inc("ooc.h2d_stage_fresh_bytes", nbytes)
+                arr = jnp.asarray(packed)
+        else:
+            slot, reused = _ring.acquire(nbytes)
+            arr = None
+            try:
+                with obs_events.span("ooc::h2d_pack", cat="staging"):
+                    packed = slot.buf[:nbytes].view(x.dtype) \
+                        .reshape(x.shape)
+                    np.copyto(packed, x)
+                with obs_events.span("ooc::h2d_put", cat="staging"):
+                    arr = jnp.array(packed) if _aliases_host() \
+                        else jnp.asarray(packed)
+            finally:
+                _ring.release(slot, arr)
+    if on:
+        obs_events.watch_ready("ooc::h2d_ready", arr, bytes=nbytes)
+        if reused:
+            obs_metrics.inc("ooc.h2d_stage_reuse_bytes", nbytes)
+        elif reused is not None:
+            obs_metrics.inc("ooc.h2d_stage_fresh_bytes", nbytes)
     return arr
 
 
@@ -213,8 +219,14 @@ def _d2h(x: jax.Array, out: Optional[np.ndarray] = None,
                             * int(np.prod(x.shape))))
     if out is None:
         out = np.empty(x.shape, np.dtype(x.dtype))
+    # `ooc::d2h` counts what the process's resident set grows by under
+    # it: `out` where nothing has written it yet, and the fetched
+    # chunks' own fresh arrays, which are let go after it closes.
+    # Around the chunk threads and not on each, because the count is
+    # the whole process's, and the writer runs these one at a time
     if m < 2048:
-        with obs_events.span("ooc::d2h", cat="staging"):
+        with obs_events.span("ooc::d2h", cat="staging",
+                             resident="ooc.d2h_touched_bytes"):
             out[...] = np.asarray(x)
         return out
     step = ceil_div(m, threads)
@@ -228,7 +240,8 @@ def _d2h(x: jax.Array, out: Optional[np.ndarray] = None,
         with obs_events.span("ooc::d2h_chunk", cat="staging"):
             out[i:j] = np.asarray(x[i:j])
 
-    with obs_events.span("ooc::d2h", cat="staging"):
+    with obs_events.span("ooc::d2h", cat="staging",
+                         resident="ooc.d2h_touched_bytes"):
         with cf.ThreadPoolExecutor(len(bounds)) as ex:
             list(ex.map(fetch, bounds))
     return out

@@ -286,7 +286,10 @@ def place(a, grid: ProcessGrid,
     several devices. The bytes copied into a touched slot add to
     `grid.stage_reuse_bytes`, those into a slot's first or regrown
     buffer to `grid.stage_fresh_bytes`; contiguous blocks are in
-    neither."""
+    neither. What the process's resident set grows by under a host
+    array's `matrix::h2d` (the staging threads' packs into pages
+    nothing has touched, and whatever the runtime maps meanwhile)
+    adds to `grid.pack_touched_bytes`."""
     from ..obs import events as obs_events, metrics as obs_metrics
     sharding = fitted_sharding(a.shape, grid)
     with obs_events.span("grid::place", cat="staging",
@@ -296,7 +299,8 @@ def place(a, grid: ProcessGrid,
         else:
             with obs_events.span("matrix::h2d", cat="staging",
                                  bytes=int(a.nbytes),
-                                 devices=grid.nprocs):
+                                 devices=grid.nprocs,
+                                 resident="grid.pack_touched_bytes"):
                 out = _place_host(a, sharding)
             if obs_events.enabled():
                 obs_metrics.inc("grid.h2d_bytes", sum(

@@ -93,8 +93,12 @@ def test_bus_merges_sources_and_threads(rng, obs_clean, host_plane):
     evs = obs.bus_events()
     names = {e.name for e in evs}
     assert spans | {"tune::fake=1 [frozen]"} <= names
-    tids = {e.tid for e in evs}
-    assert len(tids) == 2                        # main + worker
+    # main + worker; the constructor's upload is watched on a third,
+    # the bus's own (PR 36)
+    tids = {e.tid for e in evs if e.thread != "obs-ready"}
+    assert len(tids) == 2
+    assert {e.name for e in evs if e.thread == "obs-ready"} \
+        <= {"matrix::h2d_ready"}
     # the off-thread block is on the profiler's timeline too
     assert {e[2] for e in seen} == spans
     assert [e for e in evs if e.cat == "driver"]

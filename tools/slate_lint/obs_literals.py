@@ -45,6 +45,8 @@ WRITERS = {
     "observe_concrete": "histogram",
     "set_gauge": "gauge",
     "span": "span",
+    "watch_ready": "span",         # events.watch_ready(): a span the
+                                   # obs-ready thread opens and closes
     "instant": "instant",
     "sample": "series",            # obs/series.py time-series samples
 }
@@ -97,6 +99,14 @@ def collect(repo: str) -> Dict[Tuple[str, str], Entry]:
                     pat = astutil.name_pattern(node.args[0])
                     if pat is not None:
                         add(kind, pat[0], pat[1], rel, node.lineno)
+                    # span(..., resident="<counter>"): the counter
+                    # the resident set's growth under the span adds to
+                    for kw in node.keywords:
+                        pat = astutil.name_pattern(kw.value) \
+                            if kw.arg == "resident" else None
+                        if kind == "span" and pat is not None:
+                            add("counter", pat[0], pat[1], rel,
+                                node.lineno)
             elif is_metrics and isinstance(node, ast.Assign):
                 for t in node.targets:
                     if isinstance(t, ast.Subscript) \
