@@ -33,10 +33,15 @@ over PCIe. This module is those two moves for the host<->HBM stream:
   async, so the worker only serializes the host-side staging memcpy)
   and a background D2H writer (panel k's writeback into the host
   factor overlaps panel k+1's visit stream — SLATE's lookahead mapped
-  onto host<->HBM transfers). Writeback futures are keyed like cache
-  entries, so a later cache MISS that must re-read a panel from host
-  memory first waits for that panel's writeback — never for the whole
-  queue. At most ``WRITES_IN_FLIGHT`` writebacks are unfinished at
+  onto host<->HBM transfers). The writer fetches a panel in row
+  chunks small enough that the arrays the runtime allocates for them
+  come from heap pages the allocator has touched and keeps
+  (``FETCH_CHUNK_BYTES``): in eighths of a panel each arrived in a
+  fresh mapping, 2 GB of first touches a solve at n=32768 (PERF.md,
+  PRs 36 and 37). Writeback futures are keyed like cache entries, so
+  a later cache MISS that must re-read a panel from host memory first
+  waits for that panel's writeback — never for the whole queue. At
+  most ``WRITES_IN_FLIGHT`` writebacks are unfinished at
   once: queuing one more waits for the oldest, which is the engine's
   only backpressure from the device on the driver's main thread.
 * ``StreamEngine.stash`` — the multi-shard extension (ISSUE 7): a
@@ -139,6 +144,17 @@ AUTO_BUDGET_FRACTION = 0.9
 #: held it to 6.5 GB
 WRITES_IN_FLIGHT = 2
 
+#: most bytes `_d2h` fetches as one numpy array. glibc hands out a
+#: request over its mmap threshold as a fresh mapping and unmaps it
+#: when freed; the threshold follows the largest such block freed, up
+#: to 32 MiB and no further, so a chunk under that is served from heap
+#: pages the allocator has touched and keeps, and one over it faults
+#: every page in again (d2h_probe.py on the v5e host, PR 37: a
+#: 470 MB panel in chunks of 64 and 32 MiB at 2.7 and 2.9 GB/s, of 24
+#: and 16 MiB at 11.2 and 11.1, of 8 and 4 MiB at 8.9 and 5.5). Half
+#: the ceiling: a block's header and an aligned request stay under it
+FETCH_CHUNK_BYTES = 16 << 20
+
 #: most recent finished engine's stats (bench.py --ooc extras); a
 #: plain module slot, last-writer-wins — the bench runs one driver at
 #: a time
@@ -202,48 +218,65 @@ def _h2d(x: np.ndarray) -> jax.Array:
     return arr
 
 
+def _chunk_rows(m: int, nbytes: int, threads: int) -> int:
+    """Rows `_d2h` fetches at a time of an (m, ...) block of `nbytes`:
+    an eighth of it (one a thread), or ``FETCH_CHUNK_BYTES`` worth
+    where an eighth is more."""
+    return max(min(ceil_div(m, threads),
+                   FETCH_CHUNK_BYTES * m // max(nbytes, 1)), 1)
+
+
 def _d2h(x: jax.Array, out: Optional[np.ndarray] = None,
          threads: int = 8) -> np.ndarray:
     """Device-to-host copy of a big block, chunked over rows and
-    issued from a thread pool (8 chunk reads: whether the chunking
-    still pays is not measured on the current machine).
+    issued from a thread pool, eight chunk reads at a time.
 
     ``out`` — a caller-provided preallocated slice (any writable
     ndarray view of x's shape) that chunks are written into directly,
     dropping the full extra host copy a concatenate would cost per
-    panel writeback. Without it a fresh writable array is returned."""
+    panel writeback. Without it a fresh writable array is returned.
+
+    Where a chunk's bytes land on the way decides the cost. Each
+    ``np.asarray`` arrives in an array the runtime allocates and lets
+    go again, and an allocation over the allocator's mmap threshold is
+    a mapping of its own, every page of it touched for the first time
+    at 0.65 s a GB on the v5e host: fetched in eighths, a 470 MB panel
+    came over at 2.7 GB/s where the link does 10, 2 GB of first
+    touches a solve at n=32768; in chunks of ``FETCH_CHUNK_BYTES``,
+    which come from heap pages the allocator has touched and keeps,
+    at 11 (benchmarks/tools/d2h_probe.py; PERF.md, PR 37). The slice
+    is dropped as soon as it is copied: the runtime keeps the host
+    copy of an array it has fetched for as long as the array lives."""
     m = x.shape[0]
+    nbytes = _nbytes(x)
     if obs_events.enabled():
-        obs_metrics.inc("ooc.d2h_bytes",
-                        int(np.dtype(x.dtype).itemsize
-                            * int(np.prod(x.shape))))
+        obs_metrics.inc("ooc.d2h_bytes", nbytes)
     if out is None:
         out = np.empty(x.shape, np.dtype(x.dtype))
     # `ooc::d2h` counts what the process's resident set grows by under
-    # it: `out` where nothing has written it yet, and the fetched
-    # chunks' own fresh arrays, which are let go after it closes.
-    # Around the chunk threads and not on each, because the count is
-    # the whole process's, and the writer runs these one at a time
+    # it: `out` where nothing has written it yet, and whatever the
+    # fetched chunks' own arrays map afresh and still hold when it
+    # closes. Around the chunk threads and not on each, because the
+    # count is the whole process's, and the writer runs these one at a
+    # time
     if m < 2048:
         with obs_events.span("ooc::d2h", cat="staging",
                              resident="ooc.d2h_touched_bytes"):
             out[...] = np.asarray(x)
         return out
-    step = ceil_div(m, threads)
-    bounds = [(i, min(i + step, m)) for i in range(0, m, step)]
+    step = _chunk_rows(m, nbytes, threads)
 
-    def fetch(b):
+    def fetch(i):
         # per-chunk staging span: these run on POOL THREADS — the
         # shared bus (obs/events.py) is what makes them visible at
         # finish/export time (the old thread-local trace lost them)
-        i, j = b
         with obs_events.span("ooc::d2h_chunk", cat="staging"):
-            out[i:j] = np.asarray(x[i:j])
+            out[i:i + step] = np.asarray(x[i:i + step])
 
     with obs_events.span("ooc::d2h", cat="staging",
                          resident="ooc.d2h_touched_bytes"):
-        with cf.ThreadPoolExecutor(len(bounds)) as ex:
-            list(ex.map(fetch, bounds))
+        with cf.ThreadPoolExecutor(threads) as ex:
+            list(ex.map(fetch, range(0, m, step)))
     return out
 
 

@@ -157,6 +157,85 @@ def test_d2h_writes_into_preallocated_slice(rng):
     np.testing.assert_array_equal(h2, np.asarray(d)[:64])
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows,cap,chunks", [
+    # cap: FETCH_CHUNK_BYTES, in rows of six f32 (twice as many bf16);
+    # chunks: fetched, f32 and bf16
+    (64, None, (0, 0)), (2047, None, (0, 0)),   # under 2048: one copy
+    (2048, None, (8, 8)), (2309, None, (8, 8)),     # the last one short
+    (4096, 300, (14, 8)),       # eighths are 512 rows: the cap holds f32
+    (2309, 100, (24, 12)),
+])
+def test_d2h_fills_a_strided_slice_bit_identically(rng, obs_on, monkeypatch,
+                                                   rows, cap, chunks, dtype):
+    """The write-back's copy: under and over the 2048 rows where the
+    chunk threads start, a row count the chunks do not divide, both
+    resident dtypes, `out` given and not, and chunks held to
+    FETCH_CHUNK_BYTES where an eighth of the block is more (PR 37: at
+    the streamed cell's shapes an eighth is up to 67 MB)."""
+    import jax.numpy as jnp
+    from slate_tpu.obs import metrics
+    if cap is not None:
+        monkeypatch.setattr(stream, "FETCH_CHUNK_BYTES", cap * 6 * 4)
+    chunks = chunks[dtype == "bfloat16"]
+    d = jnp.asarray(rng.standard_normal((rows, 6)), dtype=dtype)
+    want = np.array(d)
+    host = np.zeros((rows + 3, 10), want.dtype)
+    got = stream._d2h(d, out=host[3:, 2:8])
+    assert got.base is host
+    assert host[3:, 2:8].tobytes() == want.tobytes()
+    assert not host[:3].any() and not host[:, :2].any() \
+        and not host[:, 8:].any()
+    fresh = stream._d2h(d)                      # out=None
+    assert fresh.flags.writeable and fresh.tobytes() == want.tobytes()
+    assert metrics.snapshot()["counters"]["ooc.d2h_bytes"] \
+        == 2 * want.nbytes
+    spans = [e.name for e in obs_on.bus_events(cat="staging")]
+    assert spans.count("ooc::d2h") == 2
+    assert spans.count("ooc::d2h_chunk") == 2 * chunks
+
+
+def test_chunk_rows_at_the_streamed_cells_shapes():
+    """The cap where it matters, by the rule `_d2h` applies: the cell's
+    panels are (32768 - 4096 k, 4096) f32; an eighth of the four
+    tallest is over the allocator's 32 MiB ceiling and the fifth is at
+    it, and every chunk is now 1024 rows, 16 MiB; a bf16 panel of the
+    mixed mode is cut at the same bytes, and a narrow block (the
+    right-hand side) stays in eighths."""
+    assert stream.FETCH_CHUNK_BYTES == 16 << 20
+
+    def rows(m, w, item):
+        return stream._chunk_rows(m, m * w * item, 8)
+
+    assert [rows(32768 - 4096 * k, 4096, 4) for k in range(8)] \
+        == [1024] * 7 + [512]
+    assert [32768 * 4096 * 4 // 8 > 32 << 20, 16384 * 4096 * 4 // 8] \
+        == [True, 32 << 20]
+    assert rows(32768, 4096, 2) == 2048 and rows(8192, 4096, 2) == 1024
+    assert rows(32768, 8, 4) == 4096
+    assert rows(4096, 0, 4) == 512          # nothing to divide by
+
+
+def test_two_writebacks_in_flight_land_each_in_its_own_slice(rng):
+    """WRITES_IN_FLIGHT panels queued at once, different data, one
+    writer: each lands in its own slice, also once the panels
+    themselves are gone."""
+    import jax.numpy as jnp
+    host = np.zeros((2, 2304, 10), np.float32)
+    panels = [jnp.asarray(rng.standard_normal((2304, 6)), jnp.float32)
+              for _ in range(4)]
+    want = [np.array(p) for p in panels]
+    with StreamEngine() as eng:
+        for rnd in (0, 2):
+            for k in (0, 1):
+                eng.write("L", k, panels[rnd + k], host[k][:, 2:8])
+            eng.wait_writes()
+            for k in (0, 1):
+                assert host[k][:, 2:8].tobytes() == want[rnd + k].tobytes()
+    del panels
+    assert host[1][:, 2:8].tobytes() == want[3].tobytes()
+
+
 # -- cache-on == cache-off, driver by driver ------------------------------
 
 def test_ooc_drivers_cache_bit_identical_under_eviction(rng):
@@ -930,6 +1009,35 @@ def test_h2d_trim_share_from_a_solve(rng, obs_on):
     ooc.posv_ooc(a, b, panel_cols=w, cache_budget_bytes=5 * n * w * 4)
     got = compute({"counters": metrics.snapshot()["counters"]})
     assert got == 100.0 * 55 / 164.5 and 33.0 < got < 34.0
+
+
+def test_writeback_spans_nest_over_a_solve(rng, obs_on):
+    """posv_ooc at the cell's shape in small (8 panels, 5 resident,
+    panels tall enough for the chunk threads): every byte written back
+    is counted once, and the writer's spans open one inside the
+    other."""
+    from slate_tpu.obs import metrics
+    n, w = 4096, 512
+    g = _f32(rng, n, n)
+    a = g @ g.T / 64 + np.eye(n, dtype=np.float32)
+    ooc.posv_ooc(a, _f32(rng, n, 8), panel_cols=w,
+                 cache_budget_bytes=5 * n * w * 4)
+    assert metrics.snapshot()["counters"]["ooc.d2h_bytes"] \
+        == 36 * w * w * 4
+    wb, d2h, chunks = ([e for e in obs_on.bus_events(cat="staging")
+                        if e.name == name]
+                       for name in ("ooc::writeback", "ooc::d2h",
+                                    "ooc::d2h_chunk"))
+
+    def inside(e, outers):
+        return any(o.t0 <= e.t0 and e.t1 <= o.t1 for o in outers)
+
+    assert len(wb) == len(d2h) == 8
+    assert all(inside(d, [o for o in wb if o.thread == d.thread])
+               for d in d2h)
+    assert all(inside(ch, d2h) for ch in chunks)
+    # panel k has 4096 - 512 k rows: 2048 or more for k <= 4
+    assert len(chunks) == 5 * 8
 
 
 def test_writebacks_in_flight_are_bounded(monkeypatch, obs_on):
