@@ -23,7 +23,6 @@ the band for stage 2, heev.cc:115).
 from __future__ import annotations
 
 import dataclasses
-import sys
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -38,6 +37,7 @@ from ..obs.events import instrument_driver
 from ..ops.householder import reflect as _reflect
 from .blas3 import _store, trsm
 from .chol import potrf
+from .spectral_dc import SPECTRAL_DC_MIN_N  # noqa: F401  (its old home)
 
 
 class EigResult(NamedTuple):
@@ -90,27 +90,16 @@ def heev(A: TiledMatrix, opts: OptionsLike = None,
         return _heev_two_stage(A, opts, want_vectors, use_dc=True)
     from ..obs import metrics as obs_metrics
     from ..obs.events import note
-    from ..tune.select import tuned_int
     from ..utils.trace import phases
+    from . import spectral_dc
     ph = phases(opts)
     obs_metrics.inc("heev.solves")
     with ph("heev::prep"):
         a = A.to_dense()
-        # routing threshold and leaf size are tunable (tune/select.py);
-        # their frozen defaults are the module constants, so an empty
-        # cache reproduces today's routing exactly. Off the chip the
-        # default is never (LAPACK's and XLA's own eigh are the
-        # measured routes there): only a tune entry, whose key names
-        # the backend it was written on, sends another backend down
-        # this route (a rehearsal's and tier-1's do, in memory)
-        from ..ops.pallas_kernels import _on_tpu
-        dc_min_n = tuned_int(
-            "heev", "spectral_dc_min_n",
-            SPECTRAL_DC_MIN_N if _on_tpu() else sys.maxsize,
-            opts=opts, n=a.shape[0], dtype=a.dtype)
-    if (a.shape[0] > dc_min_n
-            and not jnp.issubdtype(a.dtype, jnp.complexfloating)
-            and not isinstance(a, jax.core.Tracer)):
+        # the routing threshold and the leaf size are `svd`'s too, so
+        # `spectral_dc.route` reads them for both drivers
+        leaf = spectral_dc.route(a, opts)
+    if leaf is not None:
         # the in-house spectral D&C (linalg/spectral_dc.py): same
         # QDWH-family algorithm as jax's eigh but with the all-
         # Cholesky polar and no padded-copy agenda — measured faster
@@ -121,14 +110,8 @@ def heev(A: TiledMatrix, opts: OptionsLike = None,
         # and reads their sizes, so it returns when the last split
         # has been read and not at once; under a caller's jit the
         # solve is XLA's eigh below.
-        from .spectral_dc import LEAF, _bucket_ladder, eigh_dc
-        leaf = tuned_int("heev", "dc_leaf", LEAF, opts=opts,
-                         n=a.shape[0], dtype=a.dtype)
-        note(method="spectral_dc",
-             form="agenda" if a.shape[0] > leaf else "leaf", leaf=leaf,
-             buckets=",".join(
-                 str(b) for b in _bucket_ladder(a.shape[0], leaf)))
-        w, v, dc_ok = eigh_dc(a, leaf=leaf)     # ascending already
+        note(method="spectral_dc", **spectral_dc.route_note(a.shape[0], leaf))
+        w, v, dc_ok = spectral_dc.eigh_dc(a, leaf=leaf)  # ascending already
         # the agenda has read every split's converged flag on the
         # host by now (it reads each split's sizes anyway), so an
         # unconverged sign iteration is always surfaced
@@ -386,12 +369,6 @@ def _householder_tridiag(a: jax.Array, want_q: bool = True
 #: panel count above which he2hb switches to the fixed-shape fori_loop
 #: form (O(1) program size in nt; see blocked.CHOL_SCAN_THRESHOLD)
 HE2HB_SCAN_THRESHOLD = 64
-
-#: above this n, heev's Auto path on TPU routes to the in-house
-#: spectral D&C (spectral_dc.eigh_dc) instead of jax.lax.linalg.eigh
-#: (measured crossover, PERF.md "Round-5: in-house spectral divide &
-#: conquer")
-SPECTRAL_DC_MIN_N = 2048
 
 
 def _he2hb_scan(a: jax.Array, n: int, nb: int, want_q: bool):

@@ -319,10 +319,14 @@ def polar_unitary(x: jax.Array, l0: Optional[float] = None,
     return u, k, converged
 
 
-def sign_hermitian(h: jax.Array, l0: Optional[float] = None):
+def sign_hermitian(h: jax.Array, l0: Optional[float] = None,
+                   general=False):
     """Matrix sign of a Hermitian matrix (the spectral-split operator:
     sign(H - sigma I) separates the spectrum at sigma). The sign of a
     Hermitian matrix is Hermitian; symmetrizing removes the skew part
-    left by finite iteration."""
+    left by finite iteration. Where `general` (a traced flag) is set,
+    h is any square matrix and its polar factor is returned as the
+    iteration left it: one program serves both uses
+    (spectral_dc.dc_sign)."""
     u, k, conv = polar_unitary(h, l0=l0)
-    return 0.5 * (u + u.conj().T), k, conv
+    return jnp.where(general, u, 0.5 * (u + u.conj().T)), k, conv

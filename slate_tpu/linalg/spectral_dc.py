@@ -72,6 +72,7 @@ caller's `jit` `eigh_dc` raises, and `st.heev` takes XLA's own `eigh`
 from __future__ import annotations
 
 import functools
+import sys
 from collections import deque
 
 import jax
@@ -83,6 +84,12 @@ from ..obs import metrics as obs_metrics
 from .polar import sign_hermitian
 
 HI = jax.lax.Precision.HIGHEST
+
+#: above this n, on the chip, `st.heev` and `st.svd` with no method set
+#: take this module's agenda instead of XLA's one-program eigh / svd
+#: (measured crossover, PERF.md "Round-5: in-house spectral divide &
+#: conquer"); `route` reads it
+SPECTRAL_DC_MIN_N = 2048
 
 #: subproblems at or below this size stop recursing and solve with the
 #: TPU Jacobi eigh custom call (scales poorly upward, fine here)
@@ -133,17 +140,23 @@ def _mask_cols(x, c0, c1, fill=0.0):
     return jnp.where((j >= c0) & (j < c1), x, jnp.asarray(fill, x.dtype))
 
 
-def _sign_split(H, m, l0):
+def _sign_split(H, m, general, l0):
     """sign(H - sigma I) of the masked (m, m) Hermitian block H,
-    padded to static (B, B), at sigma = the median of its diagonal.
-    Returns (S, polar iterations, converged)."""
+    padded to static (B, B), at sigma = the median of its diagonal;
+    or, where the traced flag `general` is set, the orthogonal polar
+    factor of H as it stands (no shift, and the result is not
+    symmetrized): the SVD's polar step is the eigensolver's sign step
+    at sigma = 0 on a matrix that need not be Hermitian, and ONE
+    program a bucket serves both (`dc_sign`). Returns (S, polar
+    iterations, converged)."""
     B = H.shape[0]
     dt = H.dtype
     diag = jnp.real(jnp.diagonal(H))
     ids = jnp.arange(B)
     sigma = jnp.nanmedian(jnp.where(ids < m, diag, jnp.nan))
+    sigma = jnp.where(general, jnp.zeros((), sigma.dtype), sigma)
     Hs = H - sigma.astype(dt) * _eye_m(B, m, dt)
-    return sign_hermitian(Hs, l0=l0)
+    return sign_hermitian(Hs, l0=l0, general=general)
 
 
 def _eye_m(B, m, dt):
@@ -315,15 +328,18 @@ def dc_take(blocks, at, h0norm, B: int):
     return H, _nearly_diagonal(H, h0norm)
 
 
-def dc_sign(H, m, nearly, l0=None):
+def dc_sign(H, m, nearly, general, l0=None):
     """The polar iteration of one split (skipped on a block that is
     diagonal already). Returns (S, flags) with flags = [nearly,
-    converged, iterations]."""
+    converged, iterations]. `general` (traced) asks for the polar
+    factor of a general H instead of the sign of the shifted Hermitian
+    one (`_sign_split`): `st.svd` dispatches the executable the
+    eigensolver compiled, at the root's bucket (`polar_general`)."""
     def skip(H):
         return H, jnp.zeros((), jnp.int32), jnp.ones((), jnp.bool_)
 
     S, iters, conv = jax.lax.cond(
-        nearly, skip, lambda H: _sign_split(H, m, l0), H)
+        nearly, skip, lambda H: _sign_split(H, m, general, l0), H)
     return S, _info(nearly, conv, iters)
 
 
@@ -442,11 +458,15 @@ _JIT = {"take": dict(static_argnames=("B",)),
         "leaf": dict(static_argnames=("B",), donate_argnums=(0, 1))}
 
 
+#: `dc_sign`'s traced `general` flag, as its two callers pass it
+_HERMITIAN, _GENERAL = np.False_, np.True_
+
+
 def _split_steps(step, blocks, vecs, at, h0norm, B: int, l0):
     """The four steps of one split dispatched in a row (`step` =
     `_programs(B)`). Returns (blocks, vecs, info)."""
     H, nearly = step["take"](blocks, at, h0norm, B=B)
-    S, flags = step["sign"](H, at[1], nearly, l0=l0)
+    S, flags = step["sign"](H, at[1], nearly, _HERMITIAN, l0=l0)
     Q, W, info = step["basis"](H, S, at[1], flags)
     return step["put"](blocks, vecs, at, Q, W, info) + (info,)
 
@@ -456,7 +476,7 @@ def _root_steps(step, h, l0):
     identity compose. Returns (blocks, vecs, h0norm, info)."""
     H, h0norm, nearly = step["take_root"](h)
     m = np.int32(h.shape[0])
-    S, flags = step["sign"](H, m, nearly, l0=l0)
+    S, flags = step["sign"](H, m, nearly, _HERMITIAN, l0=l0)
     Q, W, info = step["basis"](H, S, m, flags)
     return step["put_root"](Q, W, info) + (h0norm, info)
 
@@ -527,6 +547,54 @@ def _eigh_dc_agenda(h, leaf: int, l0):
     with span("heev::vectors", cat="phase"):
         w, v = _vectors_program(blocks, vecs)
     return w, v, ok
+
+
+def polar_general(a: jax.Array, l0=None):
+    """The orthogonal polar factor of the square matrix `a` by the
+    eigensolver's own `dc_sign` program at the bucket `a.shape[0]`
+    (the executable `eigh_dc`'s root split runs: a second polar
+    program at n=8192 would be another 41 MB of compile cache).
+    Returns (U_p, flags) on the device, flags = [0, converged,
+    iterations]; nothing is read here."""
+    n = a.shape[0]
+    # `nearly` as the root split passes it, an array on the device: the
+    # call then finds the executable `eigh_dc` loaded, in this process
+    # too
+    return _programs(n)["sign"](a, np.int32(n), jax.device_put(np.False_),
+                                _GENERAL, l0=l0)
+
+
+def route(a, opts=None):
+    """The leaf size at which `a` (dense, square) takes this module's
+    agenda through `st.heev` or `st.svd` with no method set, or None
+    where it keeps XLA's own program: a tracer (the agenda reads sizes
+    on the host), a complex matrix (the TPU's Jacobi leaf solver is
+    real), or one of no more rows than the routing threshold. The
+    threshold and the leaf size are tunable (tune/select.py); their
+    frozen defaults are the module constants, so an empty cache
+    reproduces today's routing exactly. Off the chip the default is
+    never (LAPACK's and XLA's own are the measured routes there): only
+    a tune entry, whose key names the backend it was written on, sends
+    another backend down this route (a rehearsal's and tier-1's do, in
+    memory). Both drivers read the one entry, `heev`'s."""
+    from ..ops.pallas_kernels import _on_tpu
+    from ..tune.select import tuned_int
+    if isinstance(a, jax.core.Tracer) \
+            or jnp.issubdtype(a.dtype, jnp.complexfloating):
+        return None
+    n = a.shape[0]
+    min_n = tuned_int("heev", "spectral_dc_min_n",
+                      SPECTRAL_DC_MIN_N if _on_tpu() else sys.maxsize,
+                      opts=opts, n=n, dtype=a.dtype)
+    if n <= min_n:
+        return None
+    return tuned_int("heev", "dc_leaf", LEAF, opts=opts, n=n, dtype=a.dtype)
+
+
+def route_note(n: int, leaf: int):
+    """What a driver span records of the agenda route at size n."""
+    return dict(form="agenda" if n > leaf else "leaf", leaf=leaf,
+                buckets=",".join(str(b) for b in _bucket_ladder(n, leaf)))
 
 
 def eigh_dc(h: jax.Array, leaf: int = LEAF, l0=None):

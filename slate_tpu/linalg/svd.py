@@ -4,16 +4,34 @@ unmbr_ge2tb.cc, unmbr_tb2bd.cc; SURVEY §3.5).
 TPU-native design. The reference pipeline is ge2tb (dense -> triangular
 band) -> tb2bd (band -> bidiagonal wavefront bulge chase) -> bdsqr
 (bidiagonal QR iteration on 1D-distributed U/VT rows) -> two
-back-transforms. The production `svd` path is XLA's QDWH-SVD
-(`jax.lax.linalg.svd`) — polar decomposition + Hermitian eig, all MXU
-matmuls, SPMD-partitionable — because the bulge chase's tiny
-sequential dispatches are the anti-pattern on TPU. The staged names
-are REAL algorithms, not aliases: ge2tb is a blocked two-sided QR/LQ
-reduction (fused Pallas panels, fixed-shape scan form at huge nt),
-tb2bd runs the windowed bulge chase (band.tb2bd_band) on the CPU/host
-path, and bdsqr runs the shifted implicit-QR iteration with deflation
-(bdsqr_qr) there — each with the TPU fallback documented at its
-definition.
+back-transforms. The production `svd` path is a QDWH-SVD — polar
+decomposition + Hermitian eig, all MXU matmuls — because the bulge
+chase's tiny sequential dispatches are the anti-pattern on TPU.
+
+On the chip, for a concrete real f32 square matrix above the routing
+threshold `st.heev` uses (`spectral_dc.route`), that QDWH-SVD is this
+library's own (`_svd_qdwh_dc`, PR 39): A = U_p H with U_p from the
+eigensolver's polar program at the root's bucket (`spectral_dc.dc_sign`
+serves a general matrix under a traced flag: ONE executable a bucket
+for both drivers), H = sym(U_p^T A) = V diag(s) V^T by the
+eigensolver's host agenda over per-bucket programs
+(`spectral_dc.eigh_dc`), U = U_p V. The SVD adds two small programs of
+its own (the form and the compose) and compiles nothing heavy:
+every heavy program is one `st.heev` compiled. Like `heev` on that
+route it returns once the host has read the last split's sizes (and
+the polar's flags), not at once. Everywhere else (a caller's jit,
+rectangular, complex, f64 or smaller input, another backend) `svd` is
+XLA's fused QDWH-SVD, `jax.lax.linalg.svd`: one program holding a
+polar iteration and a whole spectral divide and conquer, which at
+n=8192 is 1.4 GB of code, 10 GB of temporaries and half an hour of
+compile that no cache holds (PERF.md, PR 39).
+
+The staged names are REAL algorithms, not aliases: ge2tb is a blocked
+two-sided QR/LQ reduction (fused Pallas panels, fixed-shape scan form
+at huge nt), tb2bd runs the windowed bulge chase (band.tb2bd_band) on
+the CPU/host path, and bdsqr runs the shifted implicit-QR iteration
+with deflation (bdsqr_qr) there — each with the TPU fallback
+documented at its definition.
 """
 
 from __future__ import annotations
@@ -38,6 +56,94 @@ class SVDResult(NamedTuple):
     Vh: Optional[TiledMatrix]
 
 
+@jax.jit
+def _svd_form(up, a):
+    """H = sym(U_p^T A): the Hermitian polar factor of A, whose
+    eigenpairs are A's singular values and right singular vectors.
+    One product at the full size. Nothing is donated: `a` is the
+    caller's matrix and the compose needs U_p."""
+    h = jnp.matmul(up.T, a, precision=jax.lax.Precision.HIGHEST)
+    return 0.5 * (h + h.T)
+
+
+def _svd_compose(w, v, up, want_vh: bool = True):
+    """(s, U, Vh) out of H's eigenpairs (w ascending, V) and the polar
+    factor: s = w descending, clipped at 0 (H is positive semidefinite
+    up to rounding); U = U_p V and Vh = V^T with their columns (rows)
+    reversed to match. `v` None: no factor is wanted; `up` None: no U."""
+    s = jnp.maximum(w[::-1], jnp.zeros((), w.dtype))
+    if v is None:
+        return s, None, None
+    vr = v[:, ::-1]
+    u = None if up is None else jnp.matmul(
+        up, vr, precision=jax.lax.Precision.HIGHEST)
+    return s, u, (vr.T if want_vh else None)
+
+
+#: the compose as a program: with both factors wanted (the usual call)
+#: both workspaces are donated and U, Vh take their buffers
+_svd_compose_both = jax.jit(_svd_compose, donate_argnums=(1, 2))
+_svd_compose_part = jax.jit(_svd_compose, static_argnames=("want_vh",))
+
+
+def _svd_qdwh_dc(A: TiledMatrix, a, leaf: int, span, want_u: bool,
+                 want_vh: bool) -> SVDResult:
+    """`svd`'s own route on the chip (module doc): A = U_p H by the
+    eigensolver's polar program, H = V diag(s) V^T by its agenda,
+    U = U_p V."""
+    import warnings
+    import numpy as np
+    from ..obs import metrics as obs_metrics
+    from . import spectral_dc
+    with span("svd::polar"):
+        up, flags = spectral_dc.polar_general(a)
+    with span("svd::form"):
+        h = _svd_form(up, a)
+    with span("svd::eig"):
+        w, v, ok = spectral_dc.eigh_dc(h, leaf=leaf)
+    with span("svd::compose"):
+        if want_u and want_vh:
+            s, u, vh = _svd_compose_both(w, v, up)
+        else:
+            s, u, vh = _svd_compose_part(
+                w, v if (want_u or want_vh) else None,
+                up if want_u else None, want_vh=want_vh)
+    # the polar's flags were computed before the eigensolver's root
+    # split ran, and everything after them is dispatched by now: the
+    # device does not wait for this read
+    with span("svd::agenda"):
+        _, conv, iters = (int(x) for x in np.asarray(flags))
+    obs_metrics.inc("svd.polar_iters", iters)
+    if not conv:
+        obs_metrics.inc("svd.unconverged")
+    if not (conv and ok):
+        warnings.warn(
+            "svd: the polar iteration of %s hit its iteration cap "
+            "without converging; singular triplets may be degraded "
+            "(polar.py capped-weight schedule)"
+            % ("A" if not conv else "a split of A's Hermitian factor"),
+            stacklevel=3)
+    r = A.resolve()
+    return SVDResult(
+        s, None if u is None else TiledMatrix.from_dense(u, r.mb, r.nb),
+        None if vh is None else TiledMatrix.from_dense(vh, r.mb, r.nb))
+
+
+def agenda_leaf(a, opts=None):
+    """The leaf size at which `svd` with no method set takes its own
+    route on `a` (an array, or anything with its shape and dtype), or
+    None where it is jax's fused program: the route needs a concrete
+    real f32 square matrix (the shared polar program is the
+    eigensolver's, and its cache entries are f32's) of more rows than
+    `heev`'s threshold and than a leaf (`spectral_dc.route`)."""
+    from . import spectral_dc
+    if len(a.shape) != 2 or a.shape[0] != a.shape[1] \
+            or a.dtype != jnp.float32:
+        return None
+    leaf = spectral_dc.route(a, opts)
+    return leaf if leaf is not None and a.shape[0] > leaf else None
+
+
 @instrument_driver("svd")
 def svd(A: TiledMatrix, opts: OptionsLike = None,
         want_u: bool = True, want_vh: bool = True) -> SVDResult:
@@ -46,20 +152,40 @@ def svd(A: TiledMatrix, opts: OptionsLike = None,
 
     Option.MethodSVD routes the solve (reference svd.cc:216-322, one
     routed driver), mirroring heev's MethodEig routing:
-    - Auto: the fused QDWH-SVD (polar decomposition + Hermitian eig —
-      all MXU matmuls, SPMD-partitionable; module doc).
+    - Auto: a QDWH-SVD, polar decomposition + Hermitian eig, all MXU
+      matmuls. On the chip, for a concrete real square matrix of more
+      rows than `heev`'s own threshold (`spectral_dc.route`: 2048, the
+      one tune entry both drivers read), it is this library's:
+      A = U_p H by the eigensolver's polar program at the root's
+      bucket, H = V diag(s) V^T by its host agenda
+      (`spectral_dc.eigh_dc`), U = U_p V; it returns once the last
+      split's sizes and the polar's flags are read, not at once.
+      Everything else (a caller's jit, a complex, rectangular or
+      smaller matrix, another backend without a tune entry written
+      there) is jax's fused one-program `jax.lax.linalg.svd`.
     - QRIteration: the staged reference pipeline ge2tb -> tb2bd ->
       bdsqr with both back-transforms composed (each stage's TPU/host
       split documented at its definition).
-    - DC: documented delegation to the fused path — jax's SVD IS a
-      divide & conquer (QDWH polar split + D&C Hermitian eig), so the
-      reference's DC slot maps to the same kernel as Auto."""
+    - DC: the same route as Auto: a QDWH-SVD IS a divide & conquer
+      (polar split + D&C Hermitian eig), so the reference's DC slot
+      maps to it.
+
+    `want_u` / `want_vh` false skip their products of the compose
+    (`svd_vals` takes the same route: the polar and the eigensolver
+    are what give s, and its programs are the ones already compiled).
+
+    Spans `svd::prep`, `svd::polar`, `svd::form`, `svd::eig` (the
+    `heev::*` spans and `heev.*` counters open inside it),
+    `svd::compose`, `svd::agenda` (the host's read of the polar's
+    flags); the root span carries the route (`method` `qdwh_dc` or
+    `xla_svd`, `form` `agenda` or `native`, `leaf`, `buckets`);
+    counters `svd.solves`, `svd.polar_iters`, `svd.unconverged`."""
     from ..core.methods import MethodSVD
     from ..core.options import Option, get_option
     method = get_option(opts, Option.MethodSVD, MethodSVD.Auto)
     if method is MethodSVD.Auto:
         # measured Auto routing from the tune cache (mirrors heev's
-        # MethodEig); cold cache keeps the fused QDWH-SVD default
+        # MethodEig); cold cache keeps the default below
         from ..tune.select import tuned_method
         cached = tuned_method("svd", "svd", opts=opts,
                               option=Option.MethodSVD,
@@ -85,7 +211,21 @@ def svd(A: TiledMatrix, opts: OptionsLike = None,
         res = bdsqr(Bd, opts)
         return SVDResult(res.s, res.U if want_u else None,
                          res.Vh if want_vh else None)
-    a = A.to_dense()
+    from ..obs import metrics as obs_metrics
+    from ..obs.events import note
+    from ..utils.trace import phases
+    from . import spectral_dc
+    # named `span` so that the lint's registry of published names
+    # (docs/OBS_REFERENCE.md) reads these sites
+    span = phases(opts)
+    obs_metrics.inc("svd.solves")
+    with span("svd::prep"):
+        a = A.to_dense()
+        leaf = agenda_leaf(a, opts)
+    if leaf is not None:
+        note(method="qdwh_dc", **spectral_dc.route_note(a.shape[0], leaf))
+        return _svd_qdwh_dc(A, a, leaf, span, want_u, want_vh)
+    note(method="xla_svd", form="native")
     if want_u or want_vh:
         u, s, vh = jax.lax.linalg.svd(a, full_matrices=False)
         r = A.resolve()

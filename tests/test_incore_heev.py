@@ -288,8 +288,7 @@ def test_heev_routes_on_the_platform_without_a_tune_entry(
     every size; only a tune entry written on that backend (the
     rehearsal's, `tuned`) sends it down the chip's route."""
     import slate_tpu.ops.pallas_kernels as pk
-    from slate_tpu.linalg import eig
-    monkeypatch.setattr(eig, "SPECTRAL_DC_MIN_N", 128)
+    monkeypatch.setattr(spectral_dc, "SPECTRAL_DC_MIN_N", 128)
     monkeypatch.setattr(pk, "_on_tpu", lambda: on_chip)
     a = matrix("wigner", 353)
     obs.enable()
@@ -340,7 +339,7 @@ def test_an_unconverged_split_is_always_reported(bus, tuned, monkeypatch):
     was dropped, then read only under SLATE_TPU_CHECK_POLAR=1. The
     agenda reads it with each split's sizes, so `st.heev` warns with
     no switch set."""
-    def two_steps(h, l0=None):
+    def two_steps(h, l0=None, general=False):
         u, k, conv = polar.polar_unitary(h, l0=l0, max_iterations=2)
         return 0.5 * (u + u.conj().T), k, conv
 
@@ -591,7 +590,8 @@ def test_configuration_is_as_the_issue_states_it():
     assert entry["source"] == CFG["source"] and len(entry["source"]) <= 200
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         (CONFIG, "repeat", 1) and len(cell["why"]) <= 200
-    assert BENCH["workloads"][-1] is cell and BENCH["configs"][-1] is entry
+    # appended behind PR 31's, and PR 39's appended behind them
+    assert BENCH["workloads"][5] is cell and BENCH["configs"][5] is entry
     assert (CFG["n"], CFG["mb"], CFG["dtype"], CFG["vectors"]) == \
         (8192, 512, "float32", "all")
     assert CFG["matrix"]["cond"] == 1e4 and CFG["routine"] == "heev"
