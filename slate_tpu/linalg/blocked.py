@@ -360,10 +360,12 @@ def chol_loop_pipelined(a: jax.Array, nb: int, diag_factor,
 
 #: block-step count above which the Tiled Cholesky switches from the
 #: Python-unrolled shrinking-slice loop (minimal FLOPs, program size
-#: O(nt)) to the fixed-shape fori_loop (O(1) program; every step
-#: updates the whole matrix, 2 n^3 FLOPs against n^3/3: six times)
-#: — compile time stays bounded for huge-n distributed runs
-#: (reference task emission scales to nt=512, potrf.cc:85).
+#: O(nt)) to the fori_loop form (`cholesky_scan`: one step body a
+#: stage, program size O(CHOL_SCAN_STAGES); a stage's steps update the
+#: whole of the stage's trailing square, so the form does some
+#: 2.8 times the n^3/3 FLOPs of a factorization at four stages, six
+#: times in one) — compile time stays bounded for huge-n distributed
+#: runs (reference task emission scales to nt=512, potrf.cc:85).
 #: `trsm_left`'s grid loop switches to its scan form at the same count
 CHOL_SCAN_THRESHOLD = 64
 
@@ -508,6 +510,133 @@ def _put_block(a: jax.Array, blk: jax.Array, k, nb: int, axis: int,
     return jnp.where(here, jnp.tile(blk, reps), a)
 
 
+def _shared_runs(length: int, at_src: int, ext_src: int, at_dst: int,
+                 ext_dst: int, parts: int) -> list:
+    """One dimension of `_move_rect`: a run of `length` from `at_src`
+    of a dimension held in `parts` parts of `ext_src` to `at_dst` of
+    one held in parts of `ext_dst`, as (source part, destination
+    part, start inside the source part, start inside the destination
+    part, length) for every pair of parts that share some of it."""
+    runs = []
+    for j in range(parts):
+        for i in range(parts):
+            lo = max(j * ext_src - at_src, i * ext_dst - at_dst, 0)
+            hi = min((j + 1) * ext_src - at_src,
+                     (i + 1) * ext_dst - at_dst, length)
+            if lo < hi:
+                runs.append((j, i, lo + at_src - j * ext_src,
+                             lo + at_dst - i * ext_dst, hi - lo))
+    return runs
+
+
+#: bytes of the largest piece one round of `_move_rect` hands from
+#: chip to chip: a rectangle is moved in column strips this small, one
+#: round after the other, so that a move holds a few hundred MB
+#: besides its operands whatever their size
+MOVE_PIECE_BYTES = 256 << 20
+
+
+def _move_rect(src: jax.Array, dst, size, at_src, at_dst,
+               grid) -> jax.Array:
+    """`dst` with the `size` rectangle of `src` at `at_src` written at
+    `at_dst`, all static; `dst` given as a shape is a new array of
+    zeros. With no grid a slice and a `dynamic_update_slice`. Under a
+    grid both are held as P('p','q') over orders that differ, so a
+    chip's part of the rectangle lies on other chips of `dst` (the
+    partitioner answers the same slice and update with copies of the
+    operands at full height, 5.1 GB of temporaries a chip at n=49152
+    in four stages, and gathers `dst` whole where the rectangle
+    straddles two chips' columns; compiled for a v5e 2x2, PERF.md,
+    PR 41). So under `shard_map`, in rounds: for each column strip of
+    at most MOVE_PIECE_BYTES a piece and each shift (d, d') of the
+    mesh that some chip sends it along, every chip cuts the piece it
+    owes out of its shard (`lax.switch` on its place in the mesh: the
+    pieces are static and differ from chip to chip), zero-padded to
+    the largest of the round, one `ppermute` an axis hands it over,
+    and the receiver writes the part that is meant into a window of
+    its shard in place. A round starts when the one before it has
+    written (`optimization_barrier`): a chip sends what the other
+    needs and holds one round's pieces besides the operands."""
+    h, w = size
+    new = not hasattr(dst, "shape")
+    if grid is None:
+        rect = jax.lax.slice(src, at_src, (at_src[0] + h, at_src[1] + w))
+        return jax.lax.dynamic_update_slice(
+            jnp.zeros(dst, src.dtype) if new else dst, rect, at_dst)
+    from ..parallel.smap import shard_map
+    p, q = grid.p, grid.q
+    shape = tuple(dst) if new else dst.shape
+    assert all(d % parts == 0 for arr in (src.shape, shape)
+               for d, parts in zip(arr, (p, q)))
+    held = (src.shape[0] // p, src.shape[1] // q)
+    local = (shape[0] // p, shape[1] // q)
+    rows = _shared_runs(h, at_src[0], held[0], at_dst[0], local[0], p)
+
+    def shifts(c, cw):
+        """The pieces of columns c:c+cw of the rectangle, by shift."""
+        cols = _shared_runs(cw, at_src[1] + c, held[1], at_dst[1] + c,
+                            local[1], q)
+        by = {}
+        for j, i, sr, dr, rh in rows:
+            for j2, i2, sc, dc, pw in cols:
+                by.setdefault(((j - i) % p, (j2 - i2) % q), []).append(
+                    (j * q + j2, i * q + i2, (sr, sc), (dr, dc), (rh, pw)))
+        return by
+
+    strip = MOVE_PIECE_BYTES // (src.dtype.itemsize * min(h, held[0]))
+    strip = max(strip // 128 * 128, 128)
+    rounds = [rnd for c in range(0, w, strip)
+              for rnd in sorted(shifts(c, min(strip, w - c)).items())]
+    if new:
+        # a new array starts as what every chip keeps of its own, in
+        # one piece of the array's size: nothing to blend into yet
+        rounds = [((0, 0), shifts(0, w).get((0, 0), []))] \
+            + [rnd for rnd in rounds if rnd[0] != (0, 0)]
+
+    def cut(sr, sc, rh, cw, pad):
+        return lambda shard: jnp.pad(shard[sr:sr + rh, sc:sc + cw], pad)
+
+    def move(shard, out=None):
+        here = jax.lax.axis_index("p") * q + jax.lax.axis_index("q")
+        for (d, d2), pieces in rounds:
+            big = local if out is None else tuple(
+                max(x[4][k] for x in pieces) for k in (0, 1))
+            send = [lambda shard: jnp.zeros(big, shard.dtype)] * (p * q)
+            # per receiving chip: the window's start, the piece's
+            # start inside the window, the piece's size
+            meant = [[0] * 6 for _ in range(p * q)]
+            for sender, receiver, at, to, (rh, cw) in pieces:
+                win = [min(to[k], local[k] - big[k]) for k in (0, 1)]
+                off = [to[k] - win[k] for k in (0, 1)]
+                send[sender] = cut(*at, rh, cw, (
+                    (off[0], big[0] - off[0] - rh),
+                    (off[1], big[1] - off[1] - cw)))
+                meant[receiver] = [*win, *off, rh, cw]
+            if out is None:
+                out = jax.lax.switch(here, send, shard)
+                continue
+            shard, out = jax.lax.optimization_barrier((shard, out))
+            got = jax.lax.switch(here, send, shard)
+            if d:
+                got = jax.lax.ppermute(
+                    got, "p", [(j, (j - d) % p) for j in range(p)])
+            if d2:
+                got = jax.lax.ppermute(
+                    got, "q", [(j, (j - d2) % q) for j in range(q)])
+            r0, c0, ro, co, rh, cw = jnp.asarray(meant, jnp.int32)[here]
+            rr, cc = jnp.arange(big[0]), jnp.arange(big[1])
+            inside = ((rr >= ro) & (rr < ro + rh))[:, None] \
+                & ((cc >= co) & (cc < co + cw))[None, :]
+            got = jnp.where(inside, got,
+                            jax.lax.dynamic_slice(out, (r0, c0), big))
+            out = jax.lax.dynamic_update_slice(out, got, (r0, c0))
+        return out
+
+    spec = P("p", "q")
+    args = (src,) if new else (src, dst)
+    return shard_map(move, grid.mesh, (spec,) * len(args), spec)(*args)
+
+
 def trsm_form(n: int, nb: int) -> str:
     """Which grid loop of `trsm_left` solves against an order-n
     triangle in nb-blocks: "scan" above CHOL_SCAN_THRESHOLD block
@@ -525,44 +654,108 @@ def chol_form(n: int, nb: int, guarded: bool = False) -> str:
     return "scan" if scan else "unrolled"
 
 
+#: stages `cholesky_scan` runs in (fewer where the order has fewer
+#: boundaries to offer). Read on a v5e 2x2 at n=49152, nb=512 (the
+#: factor alone, `tools/stage_probe.py`, PR 41): 2.2996 s in one stage,
+#: 1.3346 in four, 1.2697 in five, 1.2445 in six, 1.2054 in eight. A
+#: stage holds its square beside the next one's while that is made, so
+#: the program's temporaries grow with the count (3.1, 3.5, 4.1, 5.3
+#: GB a chip there); compiled for n=65536 four stages count 12.96 GB a
+#: chip, what `potrs`'s program counts there already, and six 15.94,
+#: over a chip's 15.75: four, for the last 0.13 s
+CHOL_SCAN_STAGES = 4
+
+
+def chol_scan_stages(n: int, nb: int, grid=None) -> tuple:
+    """The stages of `cholesky_scan` on an order-n matrix in nb-blocks:
+    ((r, w), ...), stage s factoring the w_s columns from r_s on the
+    trailing square a[r_s:, r_s:]. Read from n, nb and the grid alone:
+    a boundary is a multiple of nb * lcm(p, q), so that every trailing
+    square keeps `block_on_one_chip` true along both mesh axes (1024
+    rows at n=49152, nb=512 on a 2x2: 48 units; nb with no grid), and
+    the units are dealt to CHOL_SCAN_STAGES stages as evenly as whole
+    units go. Where the order is no multiple of the unit (n=776, nb=8
+    on a 2x2: no trailing square's blocks would lie on one chip) the
+    form keeps ONE stage, the whole matrix at every step."""
+    unit = nb * (math.lcm(grid.p, grid.q) if grid is not None else 1)
+    units = n // unit
+    if n % unit or units < 2:
+        return ((0, n),)
+    stages = min(CHOL_SCAN_STAGES, units)
+    cuts = [unit * (s * units // stages) for s in range(stages + 1)]
+    return tuple((r, r1 - r) for r, r1 in zip(cuts, cuts[1:]))
+
+
+def chol_scan_update_flops(n: int, nb: int, grid=None) -> int:
+    """FLOPs of the trailing updates `cholesky_scan` dispatches on an
+    order-n matrix: a stage of w columns on a trailing square of order
+    m runs w / nb steps of 2 m^2 nb each (n^3/3 is what a Cholesky
+    needs)."""
+    return sum(2 * (n - r) ** 2 * w for r, w in chol_scan_stages(n, nb, grid))
+
+
 def cholesky_scan(a: jax.Array, nb: int, precision=_HI,
                   grid=None) -> jax.Array:
-    """Lower Cholesky as ONE compiled block step iterated by fori_loop:
-    every step takes a fixed (N, nb) column block at a traced offset,
-    factors the diagonal block, forms the panel full-height (rows above
-    the panel masked to zero so the trailing matmul leaves factored
-    columns untouched), and applies one full-size trailing update.
-    Program size independent of nt — the compile-time-safe form of
-    chol_loop for nt > CHOL_SCAN_THRESHOLD. Blocks are read and
-    written through `_take_block` and `_put_block`: under a grid each
-    on the chip that owns it, so that no step gathers the matrix or
-    passes over more of it than the update does, and the write of
+    """Lower Cholesky as a compiled block step iterated by fori_loop,
+    in the few static stages of `chol_scan_stages`: stage s runs the
+    step over its trailing square t = a[r_s:, r_s:], a static slice
+    spread over the grid again as P('p','q') so that every chip shares
+    every stage's updates evenly (what `chol_loop` does at every step,
+    here a handful of times: the square crosses the links once a
+    stage), then its w_s factored columns go into the result at
+    (r_s, r_s) and the next stage takes t[w_s:, w_s:]. A step takes a
+    fixed (m, nb) column block of the square at a traced offset,
+    factors the diagonal block, forms the panel full-height (rows
+    above the panel masked to zero so the trailing matmul leaves
+    factored columns untouched), and applies one trailing update of
+    the square's size: the stages' squares shrink, so the form does
+    `chol_scan_update_flops` for the 2 n^3 of a single stage. Program
+    size goes with the stages, not with nt — the compile-time-safe
+    form of chol_loop for nt > CHOL_SCAN_THRESHOLD. Blocks are read
+    and written through `_take_block` and `_put_block`: under a grid
+    each on the chip that owns it, so that no step gathers the square
+    or passes over more of it than the update does, and the write of
     the factored column block is the carry's last use, in place."""
     from ..parallel.sharding import constrain
+
+    def stage(t, steps):
+        rows = jnp.arange(t.shape[0])
+
+        def step(k, t):
+            k0 = k * nb
+            k1 = k0 + nb
+            colblk = _take_block(t, k, nb, 1, grid)
+            lkk = jnp.tril(chol_diag_factor(
+                _take_block(colblk, k, nb, 0, grid, COLUMN_BLOCK)))
+            # full-height panel solve: rhs rows are independent in the
+            # right-side solve, so the dead rows cost only masked FLOPs
+            pan = _chol_panel_solve(lkk, colblk, grid, precision)
+            pan = jnp.where((rows >= k1)[:, None], pan, 0)
+            upd = jnp.matmul(pan, jnp.conj(pan.T), precision=precision)
+            t = constrain(t - upd, grid)
+            # write the factored column block: L_kk on the diagonal,
+            # the panel below, existing content above (the update is
+            # zero in this block's columns, so `colblk` still holds it)
+            newblk = _put_block(pan, lkk, k, nb, 0, grid, COLUMN_BLOCK)
+            newblk = jnp.where((rows < k0)[:, None], colblk, newblk)
+            return _put_block(t, newblk, k, nb, 1, grid)
+
+        return jax.lax.fori_loop(0, steps, step, t)
+
     n = a.shape[0]
-    nt = ceil_div(n, nb)
-    rows = jnp.arange(n)
-
-    def step(k, a):
-        k0 = k * nb
-        k1 = k0 + nb
-        colblk = _take_block(a, k, nb, 1, grid)
-        lkk = jnp.tril(chol_diag_factor(
-            _take_block(colblk, k, nb, 0, grid, COLUMN_BLOCK)))
-        # full-height panel solve: rhs rows are independent in the
-        # right-side solve, so the dead rows cost only masked FLOPs
-        pan = _chol_panel_solve(lkk, colblk, grid, precision)
-        pan = jnp.where((rows >= k1)[:, None], pan, 0)
-        upd = jnp.matmul(pan, jnp.conj(pan.T), precision=precision)
-        a = constrain(a - upd, grid)
-        # write the factored column block: L_kk on the diagonal, the
-        # panel below, existing content above (the update is zero in
-        # this block's columns, so `colblk` still holds it)
-        newblk = _put_block(pan, lkk, k, nb, 0, grid, COLUMN_BLOCK)
-        newblk = jnp.where((rows < k0)[:, None], colblk, newblk)
-        return _put_block(a, newblk, k, nb, 1, grid)
-
-    return jax.lax.fori_loop(0, nt, step, a)
+    out = t = a
+    for r, w in chol_scan_stages(n, nb, grid):
+        m = n - r
+        t = stage(t, ceil_div(w, nb))
+        # the first stage's square is the matrix itself
+        out = t if r == 0 else _move_rect(t, out, (m, w), (0, 0), (r, r),
+                                          grid)
+        if w < m:
+            t = _move_rect(t, (m - w, m - w), (m - w, m - w), (w, w),
+                           (0, 0), grid)
+            # the next stage starts when this one's columns are home
+            out, t = jax.lax.optimization_barrier((out, t))
+    return out
 
 
 def cholesky_blocked(a: jax.Array, nb: int,
@@ -579,8 +772,8 @@ def cholesky_blocked(a: jax.Array, nb: int,
     lookahead >= 1 (Option.Lookahead, reference default 1) takes the
     software-pipelined loop whose wide trailing update is dataflow-
     independent of the next panel; 0 forces the plain right-looking
-    order. The huge-nt scan form has a fixed one-step body and ignores
-    the knob (its fori_loop carries no cross-step independence to
+    order. The huge-nt scan form has one step body a stage and ignores
+    the knob (its fori_loops carry no cross-step independence to
     exploit)."""
     if chol_form(a.shape[0], nb) == "scan":
         return cholesky_scan(a, nb, precision, grid)

@@ -151,16 +151,25 @@ def potrf(A: TiledMatrix, opts: OptionsLike = None,
         lookahead = get_option_tuned(opts, Option.Lookahead, "potrf",
                                      n=r.n, dtype=r.dtype)
     if obs_events.enabled():
-        from .blocked import chol_form
+        from .blocked import (chol_form, chol_scan_stages,
+                              chol_scan_update_flops)
         form = ("native" if method is MethodFactor.Fused
                 and not return_info
                 else chol_form(np_, nb, guarded=return_info))
-        # static slices but in a scan form under a grid
-        blocks = "slice" if grid is None or form != "scan" \
-            else _count_block_steps(np_, nb, np_ // nb, grid)
+        # static slices but in a scan form under a grid; the scan form
+        # runs in stages, whose update FLOPs are counted under a grid
+        # beside the n^3/3 a Cholesky needs
+        blocks, stages = "slice", 0
+        if form == "scan":
+            stages = len(chol_scan_stages(np_, nb, grid))
+            if grid is not None:
+                blocks = _count_block_steps(np_, nb, np_ // nb, grid)
+                obs_metrics.inc("grid.update_flops",
+                                chol_scan_update_flops(np_, nb, grid))
+                obs_metrics.inc("grid.update_flops_needed", np_ ** 3 // 3)
         obs_events.note(
             factor=method.value, nb=nb, nt=np_ // nb, form=form,
-            blocks=blocks,
+            blocks=blocks, stages=stages,
             grid="1x1" if grid is None else "%dx%d" % (grid.p, grid.q))
     if grid is not None and not return_info:
         # across a mesh the prep and the factorization are one compiled

@@ -2,9 +2,11 @@
 checks: ||b - A x|| / (||A|| ||x|| n eps))."""
 
 import numpy as np
+import pytest
 
 import slate_tpu as st
 from slate_tpu import TiledMatrix, Uplo
+from slate_tpu.linalg.blocked import CHOL_SCAN_STAGES
 
 
 def spd(rng, n, complex_=False):
@@ -124,18 +126,59 @@ def test_potrf_tiled_matches_fused(rng):
     np.testing.assert_allclose(Lf @ Lf.T, a, rtol=1e-9, atol=1e-10)
 
 
-def test_cholesky_scan_matches_blocked(rng):
-    """Fixed-shape fori_loop Cholesky (compile-time-safe form for huge
-    nt) must match the unrolled blocked loop numerically."""
+@pytest.mark.parametrize("n,nb,stages", [
+    (16, 16, 1), (32, 16, 2), (192, 16, CHOL_SCAN_STAGES),
+    (176, 16, CHOL_SCAN_STAGES)])
+def test_cholesky_scan_matches_blocked(rng, n, nb, stages):
+    """The fori_loop Cholesky (compile-time-safe form for huge nt), in
+    one stage, in two and in as many as it takes at most (even ones,
+    and 11 blocks dealt 2, 3, 3, 3), must match the unrolled blocked
+    loop numerically."""
     import jax.numpy as jnp
-    from slate_tpu.linalg.blocked import cholesky_blocked, cholesky_scan
-    n, nb = 192, 16
+    from slate_tpu.linalg.blocked import (chol_scan_stages, cholesky_blocked,
+                                          cholesky_scan)
+    assert len(chol_scan_stages(n, nb)) == stages
     a = spd(rng, n)
     aj = jnp.asarray(a)
     Ls = np.tril(np.asarray(cholesky_scan(aj, nb)))
     np.testing.assert_allclose(Ls @ Ls.T, a, rtol=1e-10, atol=1e-10)
     Lb = np.tril(np.asarray(cholesky_blocked(aj, nb)))
     np.testing.assert_allclose(Ls, Lb, rtol=1e-9, atol=1e-10)
+
+
+@pytest.mark.parametrize("p,q", [(2, 2), (1, 4), (4, 1)])
+def test_scan_stage_plan(p, q):
+    """The stages of the scan form, from n, nb and the grid alone: they
+    cover the order exactly, every boundary is a multiple of
+    nb * lcm(p, q), and every trailing square keeps its blocks on one
+    chip along both mesh axes."""
+    import math
+    import types
+    from slate_tpu.linalg.blocked import (block_on_one_chip,
+                                          chol_scan_stages,
+                                          chol_scan_update_flops)
+    grid = types.SimpleNamespace(p=p, q=q)
+    for n, nb in ((49152, 512), (768, 8), (65536, 512), (1056, 8)):
+        plan = chol_scan_stages(n, nb, grid)
+        unit = nb * math.lcm(p, q)
+        assert len(plan) == min(CHOL_SCAN_STAGES, n // unit)
+        assert plan[0][0] == 0 and sum(w for _, w in plan) == n
+        for (r, w), (r1, _) in zip(plan, plan[1:] + ((n, 0),)):
+            assert r + w == r1 and w > 0 and r % unit == 0
+            assert all(block_on_one_chip(n - r, nb, parts)
+                       for parts in (p, q))
+        # as even as whole units go
+        widths = {w // unit for _, w in plan}
+        assert max(widths) - min(widths) <= 1
+        flops = chol_scan_update_flops(n, nb, grid)
+        assert flops == sum(2 * (n - r) ** 2 * w for r, w in plan)
+        assert 2 * n ** 3 / 3 < flops < 2 * n ** 3
+    # no boundary keeps a block of 8 on one of two chips at 388 rows
+    # a chip, and a single unit has nothing to cut: one stage, the
+    # whole matrix at every step
+    for n, nb in ((776, 8), (8 * math.lcm(p, q), 8)):
+        assert chol_scan_stages(n, nb, grid) == ((0, n),)
+        assert chol_scan_update_flops(n, nb, grid) == 2 * n ** 3
 
 
 def test_cholesky_scan_threshold_route(rng, monkeypatch):

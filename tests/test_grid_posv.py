@@ -24,6 +24,7 @@ from slate_tpu import obs
 from slate_tpu.core.methods import MethodFactor
 from slate_tpu.core.options import Option
 from slate_tpu.linalg import chol
+from slate_tpu.linalg.blocked import CHOL_SCAN_STAGES, chol_scan_stages
 from slate_tpu.obs import events as obs_events
 from slate_tpu.obs import metrics as obs_metrics
 from slate_tpu.parallel.sharding import place
@@ -37,7 +38,8 @@ CELL = "grid-posv"
 GRID_METRICS = ["grid.h2d_gb", "grid.upload_s", "grid.collective_share",
                 "grid.busy_imbalance", "grid.launches_per_solve",
                 "grid.solve_roofline", "idle_share.grid",
-                "grid.idle_upload_share", "grid.block_local_share"]
+                "grid.idle_upload_share", "grid.block_local_share",
+                "grid.update_work_ratio"]
 
 
 @pytest.fixture(scope="module")
@@ -90,25 +92,40 @@ LIMITS = {(768, 8): (2.3e-6, 7.0e-7), (96, 8): (8.0e-7, 2.2e-7),
           (776, 8): (2.3e-6, 7.0e-7)}
 
 
-@pytest.mark.parametrize("n,mb,form,blocks", [
-    (768, 8, "scan", "local"), (96, 8, "unrolled", "slice"),
-    (776, 8, "scan", "masked")])
+@pytest.mark.parametrize("n,mb,form,blocks,stages", [
+    (768, 8, "scan", "local", CHOL_SCAN_STAGES),
+    (96, 8, "unrolled", "slice", 0), (776, 8, "scan", "masked", 1)])
 def test_grid_posv_agrees_with_the_plain_reference(grid, bus, n, mb, form,
-                                                   blocks):
+                                                   blocks, stages):
     a, b = system(3000000019, n)
     rows = refcheck.factor_sample(n, gen.rng(3000000019, "sample"), 32)
     obs.enable()
     L, X = solve_on(grid, a, b, mb)
     route = [e for e in obs.bus_events(cat="driver")
              if e.name == "potrf"][-1].args
-    assert (route["form"], route["nt"], route["grid"], route["blocks"]) \
-        == (form, n // mb, "2x2", blocks)
+    assert (route["form"], route["nt"], route["grid"], route["blocks"],
+            route["stages"]) == (form, n // mb, "2x2", blocks, stages)
     # the block steps of the scan forms dispatched, by how they reach
     # their blocks: nt for the factor, nt for each of the two sweeps
     counters = obs.snapshot()["metrics"]["counters"]
     steps = {k.rsplit("_", 1)[1]: v for k, v in counters.items()
              if k.startswith("grid.block_steps_")}
     assert steps == ({} if form == "unrolled" else {blocks: 3 * (n // mb)})
+    # and the update FLOPs of the factor's stages beside the n^3/3 a
+    # Cholesky needs: the whole matrix at every step in one stage, the
+    # trailing squares of the rehearsal's four (48 units of 16 rows,
+    # 12 a stage: 1 + (3/4)^2 + (1/2)^2 + (1/4)^2 quarters of 2 n^3)
+    flops = {k.rsplit(".", 1)[1]: v for k, v in counters.items()
+             if k.startswith("grid.update_flops")}
+    if form == "unrolled":
+        assert flops == {}
+    else:
+        assert flops["update_flops_needed"] == n ** 3 // 3
+        assert flops["update_flops"] == (
+            2 * n ** 3 if stages == 1 else
+            sum(2 * (n - r) ** 2 * (n // stages)
+                for r in range(0, n, n // stages)))
+        assert (stages > 1) == (blocks == "local")
     assert len(X.data.sharding.device_set) == 4
     x, l = X.to_numpy(), np.tril(np.asarray(L.data))[rows]
     assert x.dtype == np.float32
@@ -202,6 +219,39 @@ def test_blocks_round_trip_on_their_owners(p, q, axis):
         assert got.sharding.is_equivalent_to(A.sharding, 2)
 
 
+@pytest.mark.parametrize("p,q", [(2, 2), (1, 4), (4, 1), (0, 0)])
+def test_a_rectangle_moves_between_orders(p, q, rng, monkeypatch):
+    """What a stage boundary of the scan form does: a static rectangle
+    of one spread matrix written into another of another order, in
+    place or into a new one, against the plain slices (no grid: (0,
+    0)); in strips of a few columns too."""
+    from slate_tpu.linalg import blocked
+    g = st.make_grid(p, q, devices=jax.devices()[:4]) if p else None
+
+    def on(a):
+        return place(a, g, a.shape) if g else jax.numpy.asarray(a)
+
+    src = rng.standard_normal((48, 64)).astype(np.float32)
+    dst = rng.standard_normal((32, 40)).astype(np.float32)
+    cases = [((32, 32), (16, 16), (0, 0)),      # a trailing square
+             ((32, 8), (16, 0), (0, 0)), ((17, 23), (30, 5), (11, 17)),
+             ((1, 1), (47, 63), (31, 39)), ((32, 40), (3, 9), (0, 0))]
+    for piece_bytes in (blocked.MOVE_PIECE_BYTES, 1):
+        monkeypatch.setattr(blocked, "MOVE_PIECE_BYTES", piece_bytes)
+        for (h, w), (r0, c0), (r1, c1) in cases:
+            for new in (False, True):
+                want = np.zeros_like(dst) if new else dst.copy()
+                want[r1:r1 + h, c1:c1 + w] = src[r0:r0 + h, c0:c0 + w]
+                got = jax.jit(
+                    lambda s, d: blocked._move_rect(
+                        s, dst.shape if new else d, (h, w), (r0, c0),
+                        (r1, c1), g))(on(src), on(dst))
+                np.testing.assert_array_equal(np.asarray(got), want)
+                if g:
+                    assert got.sharding.is_equivalent_to(
+                        on(dst).sharding, 2)
+
+
 # -- placement -------------------------------------------------------------
 
 def test_placement_sends_each_device_its_own_block(grid, bus):
@@ -289,6 +339,17 @@ def test_no_whole_matrix_on_one_device(grid):
         # nor a device's block viewed by its blocks, which the masked
         # form rewrote at every step (PR 32)
         assert "[%d,%d,%d]" % (n // 2, n // mb // 2, mb) not in text, name
+    # the factor runs in stages: after the first, a device's share of
+    # the update is its block of the stage's trailing square, spread
+    # over the four again, and no larger value is made on the way
+    # (the partitioner's own answer to the slice copies full-height
+    # strips of its source, and gathers the matrix where a stage's
+    # columns straddle two devices': PERF.md, PR 41)
+    text = programs["factor"].compile().as_text()
+    plan = chol_scan_stages(n, mb, grid)
+    assert len(plan) == CHOL_SCAN_STAGES
+    for r, _w in plan[1:]:
+        assert "f32[%d,%d]" % ((n - r) // 2, (n - r) // 2) in text, r
     # the check can see a gather: a column block at a traced offset by
     # `dynamic_slice`, as the scan form took it
     from slate_tpu.linalg.blocked import _take_block
@@ -436,6 +497,20 @@ def test_grid_metrics_by_hand(monkeypatch):
                                    "grid.block_steps_masked": 288})) == 80.0
     assert local(_run(t, counters={"grid.block_steps_masked": 288})) == 0.0
     assert local(_run(t, counters={"grid.h2d_bytes": 7})) is None
+    # five solves in four even stages: 6 (1/4) (1 + 9/16 + 1/4 + 1/16)
+    # of the n^3/3; in one stage 6; a program that counts neither
+    work = bench_run.load_module("layer_metrics",
+                                 "grid.update_work_ratio").compute
+    n = 49152
+    assert work(_run(t, counters={
+        "grid.update_flops": 5 * sum(2 * (n * j // 4) ** 2 * (n // 4)
+                                     for j in (4, 3, 2, 1)),
+        "grid.update_flops_needed": 5 * (n ** 3 // 3)})) == \
+        pytest.approx(2.8125)
+    assert work(_run(t, counters={
+        "grid.update_flops": 2 * n ** 3,
+        "grid.update_flops_needed": n ** 3 // 3})) == pytest.approx(6.0)
+    assert work(_run(t, counters={"grid.block_steps_local": 288})) is None
 
 
 @pytest.mark.parametrize("name", GRID_METRICS)
