@@ -380,23 +380,67 @@ def pbsv(A: TiledMatrix, B: TiledMatrix, opts: OptionsLike = None):
 
 # -- mixed precision ------------------------------------------------------
 
+def _lo_potrs(ctx, factors, rhs: jax.Array) -> jax.Array:
+    """The lo solve the mixed Cholesky drivers hand `refine`'s
+    programs: `potrs` on dense arrays. `factors` = (the lo factor's
+    padded data, ones on its padding's diagonal,), `ctx` = (lower, nb,
+    grid); rhs (n, k) in the factor's dtype, and so is the answer. The
+    factor is read where it lies, its conjugate transpose too
+    (`refine.tri_sweep`)."""
+    from .refine import lo_work_dtype, tri_sweep
+    (f,) = factors
+    lower, nb, grid = ctx
+    n = rhs.shape[0]
+    if grid is not None:
+        from .blocked import trsm_dense
+        f = f[:n, :n]
+        fh = jnp.conj(f.T)
+        first, second = (f, fh) if lower else (fh, f)
+        y = trsm_dense(first, rhs, left=True, lower=True, nb=nb, grid=grid)
+        return trsm_dense(second, y, left=True, lower=False, nb=nb,
+                          grid=grid)
+    y = jnp.pad(rhs, ((0, f.shape[0] - n), (0, 0))).astype(
+        lo_work_dtype(f.dtype))
+    # lower: L y = b, L^H x = y; upper: U^H y = b, U x = y
+    for adjoint in ((False, True) if lower else (True, False)):
+        y = tri_sweep(f, y, lower=lower, nb=nb, adjoint=adjoint)
+    return y[:n].astype(rhs.dtype)
+
+
+def _mixed_factor(name: str, A: TiledMatrix, opts: OptionsLike):
+    """What `posv_mixed` and `posv_mixed_gmres` share: A demoted once
+    (`<name>::demote`), factored by `potrf` (`<name>::factor`).
+    Returns (L, ctx, factors) for `_lo_potrs`."""
+    from ..utils.trace import phases
+    from .refine import demote
+    A_lo = demote(name, A, opts)
+    with phases(opts)(name + "::factor"):
+        L = potrf(A_lo, opts)
+        rl = L.resolve()
+        # the padded factor's diagonal kept nonsingular, as an LU's
+        # is: once here, not in every lo solve
+        data = pad_diag_identity(rl.data, rl.m, rl.n)
+    obs_events.note(lo=str(A_lo.dtype))
+    return L, (rl.uplo is Uplo.Lower, rl.nb,
+               get_option(opts, Option.Grid, None)), (data,)
+
+
 @instrument_driver("posv_mixed")
 def posv_mixed(A: TiledMatrix, B: TiledMatrix, opts: OptionsLike = None):
     """Mixed-precision Cholesky with iterative refinement (reference
     src/posv_mixed.cc, slate.hh:694). Returns (factor_lo, X, iters);
-    iters < 0 means the full-precision fallback produced X."""
-    from .refine import iterative_refinement, lo_dtype, lo_rhs_solver
+    iters < 0 means the full-precision fallback produced X, which the
+    host decided on the refinement's verdict (`refine.py`)."""
+    from .refine import iterative_refinement
     from .blas3 import _store
-    r = A.resolve()
-    lo = lo_dtype(r.dtype)
-    A_lo = dataclasses.replace(r, data=r.data.astype(lo))
-    L = potrf(A_lo, opts)
-    solve_lo = lo_rhs_solver(B, lo, lambda rhs: potrs(L, rhs, opts))
+    L, ctx, factors = _mixed_factor("posv_mixed", A, opts)
+    obs_events.note(refine="ir")
 
     def full_solve():
         return potrs(potrf(A, opts), B, opts).to_dense()
 
-    x, iters = iterative_refinement(A, B, solve_lo, full_solve, opts)
+    x, iters = iterative_refinement(A, B, _lo_potrs, ctx, factors,
+                                    full_solve, opts, name="posv_mixed")
     return L, _store(B, x), iters
 
 
@@ -405,19 +449,17 @@ def posv_mixed_gmres(A: TiledMatrix, B: TiledMatrix,
                      opts: OptionsLike = None):
     """Mixed-precision FGMRES-IR Cholesky (reference
     src/posv_mixed_gmres.cc, slate.hh:738). Single RHS."""
-    from .refine import fgmres_ir, lo_dtype, lo_rhs_solver
+    from .refine import fgmres_ir
     from .blas3 import _store
     slate_assert(B.shape[1] == 1,
                  "posv_mixed_gmres supports one right-hand side")
-    r = A.resolve()
-    lo = lo_dtype(r.dtype)
-    A_lo = dataclasses.replace(r, data=r.data.astype(lo))
-    L = potrf(A_lo, opts)
-    solve_lo = lo_rhs_solver(B, lo, lambda rhs: potrs(L, rhs, opts))
+    L, ctx, factors = _mixed_factor("posv_mixed_gmres", A, opts)
+    obs_events.note(refine="fgmres")
 
     def full_solve():
         return potrs(potrf(A, opts), B, opts).to_dense()
 
-    x, iters = fgmres_ir(A, B, solve_lo, full_solve,
-                         restart_cap=max(r.mb - 1, 1), opts=opts)
+    x, iters = fgmres_ir(A, B, _lo_potrs, ctx, factors, full_solve,
+                         restart_cap=max(A.resolve().mb - 1, 1),
+                         opts=opts, name="posv_mixed_gmres")
     return L, _store(B, x), iters

@@ -52,6 +52,11 @@ class LUFactors(NamedTuple):
     #: are not retroactively permuted across blocks — such factors must
     #: be solved by gbtrs's interleaved sweeps, never by plain getrs
     band: bool = False
+    #: the swaps composed to one permutation of the padded rows (the
+    #: factor is that of A[perm]), where the route that made the
+    #: factor composed it on the way: the lo carry form does, and a
+    #: mixed solve then replays no swap sequence
+    perm: Optional[jax.Array] = None
 
 
 # -- pivot machinery ------------------------------------------------------
@@ -199,6 +204,111 @@ def lu_panel_fori(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return a, piv
 
 
+#: base-block width of `lu_panel_blocked`: the (ib, m) block one
+#: column step rewrites. Read on the chip at 16384 x 1024 (PR 42): a
+#: block costs 0.29 ms (the solve against the factored columns, the
+#: matmul under it) and a column 10.7 us whatever ib is from 16 to 64
+#: (its operations are latency-bound): 30.7 ms a panel at 16, 20.2 at
+#: 32, 15.4 at 64, 15.2 at 128, where a pass over the block is 8 MB
+LU_BLOCKED_IB = 64
+
+
+def _blocked_ib(w: int) -> int:
+    """Widest base block of `lu_panel_blocked` that divides `w`
+    (0: none does, the caller keeps the fori kernel)."""
+    return next((ib for ib in (LU_BLOCKED_IB, 32, 16, 8) if w % ib == 0), 0)
+
+
+def lu_panel_blocked(a: jax.Array, ib: int = LU_BLOCKED_IB
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Partial-pivot LU of a tall (m, w) panel, w a multiple of `ib`,
+    for heights XLA's native LU refuses (NATIVE_LU_MAX_M): left-
+    looking over ib-wide column blocks, one program whatever w / ib
+    is. `lu_panel_fori` does a rank-1 update of the WHOLE (m, w) panel
+    at every column (two passes over it a column: 0.50 s for the 32
+    tall panels of a solve at n=16384, PERF.md); here a column step
+    rewrites only its own block, held transposed as (ib, m) so that m
+    runs along the lanes and a narrow block pads nothing, and the
+    other columns meet the block's update and its pivots once a
+    block: one matmul, and an exchange of the at most 2 ib rows its
+    swaps touched.
+
+    Block i first takes what the factored columns left of it owe it
+    (the solve against their unit-lower square gives its U rows and
+    its updated rows inside the top w x w, the matmul the rows under
+    that), then factors itself column by column: masked argmax, the
+    two rows exchanged, the multipliers, and the rank-1 update of the
+    block's later columns as ONE elementwise pass. A device operation
+    inside the loop costs a microsecond or more however small (read
+    on the chip, PR 42: 4.4 us a column for two scalar updates of a
+    permutation vector), so the block's permutation rides in the same
+    pass as one more row of the block: the row positions, exchanged
+    with the rows. Same pivots as `lu_panel_fori` (ties aside).
+    Returns (packed LU, local swap targets (w,), their composed
+    permutation (m,))."""
+    m, w = a.shape
+    slate_assert(m < 1 << 24, "row positions ride in an f32 row")
+    rows = jnp.arange(m, dtype=jnp.int32)
+    cols = jnp.arange(w, dtype=jnp.int32)
+    sub = jnp.arange(ib + 1, dtype=jnp.int32)[:, None]
+    zero = jnp.zeros((), jnp.int32)
+    positions = rows.astype(a.dtype)[None, :]
+
+    def block(i, carry):
+        a, gperm, piv = carry
+        j0 = jnp.asarray(i, jnp.int32) * ib
+        blk = jax.lax.dynamic_slice(a, (zero, j0), (m, ib))
+        done = cols < j0
+        # the factored columns' unit-lower square, identity past them:
+        # row r < j0 of the solve is U's, row r >= j0 the updated one
+        top = jax.lax.linalg.triangular_solve(
+            jnp.where(done[None, :], a[:w], 0), blk[:w], left_side=True,
+            lower=True, unit_diagonal=True)
+        if m > w:
+            below = blk[w:] - jnp.matmul(
+                a[w:], jnp.where(done[:, None], top, 0), precision=_HIP)
+            blk = jnp.concatenate([top, below], axis=0)
+        else:
+            blk = top
+
+        def column(jj, c):
+            tb, pv = c
+            jj = jnp.asarray(jj, jnp.int32)
+            j = j0 + jj
+            col = jax.lax.dynamic_slice(tb, (jj, zero), (1, m))
+            p = jnp.argmax(jnp.where(rows[None, :] >= j, jnp.abs(col),
+                                     -jnp.inf)).astype(jnp.int32)
+            at_j = jax.lax.dynamic_slice(tb, (zero, j), (ib + 1, 1))
+            at_p = jax.lax.dynamic_slice(tb, (zero, p), (ib + 1, 1))
+            # rows j <-> p of the block (p == j: at_p is at_j)
+            tb = jnp.where(rows[None, :] == j, at_p,
+                           jnp.where(rows[None, :] == p, at_j, tb))
+            pivval = at_p[jj, 0]
+            safe = jnp.where(pivval == 0, jnp.ones((), tb.dtype), pivval)
+            col = jax.lax.dynamic_slice(tb, (jj, zero), (1, m))
+            mult = jnp.where(rows[None, :] > j, col / safe, 0)
+            urow = jnp.where((sub > jj) & (sub < ib), at_p, 0)
+            tb = jnp.where((sub == jj) & (rows[None, :] > j), mult,
+                           tb - urow * mult)
+            return tb, pv.at[jj].set(p)
+
+        tb, pv = jax.lax.fori_loop(
+            0, ib, column, (jnp.concatenate([blk.T, positions], axis=0),
+                            jnp.zeros((ib,), jnp.int32)))
+        # the other columns into the block's row order: only the rows
+        # its swaps touched moved (twice named, a row gets one content)
+        touched = jnp.concatenate([j0 + sub[:ib, 0], pv])
+        came_from = tb[ib].astype(jnp.int32)[touched]
+        a = a.at[touched].set(a[came_from])
+        a = jax.lax.dynamic_update_slice(a, tb[:ib].T, (zero, j0))
+        return (a, gperm.at[touched].set(gperm[came_from]),
+                jax.lax.dynamic_update_slice(piv, pv, (j0,)))
+
+    a, perm, piv = jax.lax.fori_loop(
+        0, w // ib, block, (a, rows, jnp.zeros((w,), jnp.int32)))
+    return a, piv, perm
+
+
 # -- factorizations -------------------------------------------------------
 
 def _tnt_swap_sequence(rows: jax.Array, m: int
@@ -287,6 +397,34 @@ def _carry_panel(trail: jax.Array, w: int, method: MethodLUPanel):
     return lu, piv, _compose_swaps(piv, trail.shape[0])
 
 
+def _lo_panel_route(m: int, w: int) -> str:
+    """How the lo carry form factors an (m, w) panel, from what it can
+    observe: XLA's native LU of the f32 copy where that compiles at the
+    height, `lu_panel_blocked` above it, the fori kernel where no base
+    block divides the width."""
+    if MethodFactor.native_lu_ok(jnp.float32, m):
+        return "native"
+    return "blocked" if m >= w and _blocked_ib(w) else "fori"
+
+
+@functools.partial(jax.jit, static_argnames=("w", "route"))
+def _carry_panel_lo(trail: jax.Array, w: int, route: str):
+    """`_carry_panel` for a factor stored below f32 (the mixed solves'
+    bf16): XLA's LU takes no such operand, so the panel alone is
+    raised to f32, factored there (`route`: `_lo_panel_route`) and
+    stored rounded, as HPL-MxP codes factor the panel above the
+    update's precision. The pivot search sees f32 values."""
+    panel = trail[:, :w].astype(jnp.float32)
+    if route == "native":
+        lu, piv, perm = jax.lax.linalg.lu(panel)
+    elif route == "blocked":
+        lu, piv, perm = lu_panel_blocked(panel, _blocked_ib(w))
+    else:
+        lu, piv = lu_panel_fori(panel)
+        perm = _compose_swaps(piv, trail.shape[0])
+    return lu.astype(trail.dtype), piv.astype(jnp.int32), perm
+
+
 @functools.partial(jax.jit, static_argnames="w")
 def _carry_swap(trail: jax.Array, perm: jax.Array, w: int) -> jax.Array:
     """The columns right of a step's panel, in the panel's row order.
@@ -299,6 +437,25 @@ def _carry_update(lu: jax.Array, rest: jax.Array
                   ) -> Tuple[jax.Array, jax.Array]:
     """One step's U12 strip and the trailing matrix it leaves."""
     w = lu.shape[1]
+    if lu.dtype.itemsize < 4:
+        # the lo factor: the strip solved in f32 against the STORED
+        # (rounded) diagonal block and rounded once; the update one
+        # pass of lo x lo products accumulated in f32, the trailing
+        # matrix rounded as it is written
+        # (by `tri_sweep` in blocks of 128: XLA's TriangularSolve
+        # expander unrolls w / 128 steps into 10.7 MB of code for this
+        # one strip, a program a step; the sweep is 2.1 MB. Compiled
+        # for a described v5e at n=16384, w=1024, PR 42)
+        from .refine import tri_sweep
+        u12 = tri_sweep(lu[:w].astype(jnp.float32),
+                        rest[:w].astype(jnp.float32), lower=True,
+                        nb=128 if w % 128 == 0 else w,
+                        unit_diagonal=True).astype(lu.dtype)
+        if lu.shape[0] == w:
+            return u12, rest[w:]
+        return u12, (rest[w:].astype(jnp.float32) - jnp.matmul(
+            lu[w:], u12, preferred_element_type=jnp.float32)
+        ).astype(lu.dtype)
     u12 = jax.lax.linalg.triangular_solve(
         lu[:w], rest[:w], left_side=True, lower=True,
         unit_diagonal=True)
@@ -308,9 +465,10 @@ def _carry_update(lu: jax.Array, rest: jax.Array
         lu[w:], u12, precision=jax.lax.Precision.HIGHEST)
 
 
-@functools.partial(jax.jit, static_argnames=("nb", "kmax", "M", "N"))
+@functools.partial(jax.jit,
+                   static_argnames=("nb", "kmax", "M", "N", "compose"))
 def _carry_finish(panels, perms, urows, pivs, *, nb: int, kmax: int,
-                  M: int, N: int) -> Tuple[jax.Array, jax.Array]:
+                  M: int, N: int, compose: bool = False):
     """The end of `_getrf_carry` as one program: every panel into its
     final row order, the packed factor, the global pivots.
 
@@ -336,10 +494,18 @@ def _carry_finish(panels, perms, urows, pivs, *, nb: int, kmax: int,
     out = assemble_packed(reordered, urows, nb, kmax, M, N,
                           panels[0].dtype)
     pivots = jnp.concatenate([k * nb + p for k, p in enumerate(pivs)])
-    return out, pivots
+    if not compose:
+        return out, pivots
+    # the steps' permutations as one of all M rows (`LUFactors.perm`):
+    # step k reorders the rows from k * nb down, nt short gathers
+    # where `lu_pivots_to_permutation` replays kmax swaps one by one
+    perm = jnp.arange(M, dtype=jnp.int32)
+    for k, pk in enumerate(perms):
+        perm = jnp.concatenate([perm[:k * nb], perm[k * nb:][pk]])
+    return out, pivots, perm
 
 
-def _getrf_carry(a: jax.Array, nb: int) -> Tuple[jax.Array, jax.Array]:
+def _getrf_carry(a: jax.Array, nb: int, lo: bool = False):
     """Single-device blocked LU that carries the SHRINKING trailing
     matrix as the loop state instead of updating the full matrix in
     place. Functional slice-updates of a big matrix materialize
@@ -356,7 +522,12 @@ def _getrf_carry(a: jax.Array, nb: int) -> Tuple[jax.Array, jax.Array]:
     `_carry_finish` brings them all into the final order at the end.
 
     The host dispatches three compiled programs a step (panel, swap,
-    update) and one at the end, each under the span that names it."""
+    update) and one at the end, each under the span that names it.
+
+    `lo`: the factor of a mixed solve, stored below f32. The same
+    steps on the same spans with `_carry_panel_lo` for the panel, and
+    the finish also hands back the composed row permutation: returns
+    (packed LU, pivots, perm)."""
     M, N = a.shape
     kmax = min(M, N)
     nt = ceil_div(kmax, nb)
@@ -374,12 +545,16 @@ def _getrf_carry(a: jax.Array, nb: int) -> Tuple[jax.Array, jax.Array]:
         # this consumer too
         with span("getrf::panel", cat="step", k=k):
             m = trail.shape[0]
-            method = MethodLUPanel.resolve(m, w, trail.dtype)
-            if method is MethodLUPanel.Fori:
-                # its one-shot instant, which a compiled panel would
-                # raise only while it is traced
-                _surface_fori_fallback(m, w, trail.dtype)
-            lu, piv, perm = _carry_panel(trail, w, method)
+            if lo:
+                lu, piv, perm = _carry_panel_lo(
+                    trail, w, _lo_panel_route(m, w))
+            else:
+                method = MethodLUPanel.resolve(m, w, trail.dtype)
+                if method is MethodLUPanel.Fori:
+                    # its one-shot instant, which a compiled panel
+                    # would raise only while it is traced
+                    _surface_fori_fallback(m, w, trail.dtype)
+                lu, piv, perm = _carry_panel(trail, w, method)
         with span("getrf::pivots", cat="step", k=k):
             pivs.append(piv)
             perms.append(perm)
@@ -391,9 +566,8 @@ def _getrf_carry(a: jax.Array, nb: int) -> Tuple[jax.Array, jax.Array]:
                 u12, trail = _carry_update(lu, rest)
                 urows.append(u12)
     with span("getrf::reorder", cat="step", nt=nt):
-        out, pivots = _carry_finish(panels, perms, urows, pivs, nb=nb,
-                                    kmax=kmax, M=M, N=N)
-    return out, pivots
+        return _carry_finish(panels, perms, urows, pivs, nb=nb,
+                             kmax=kmax, M=M, N=N, compose=lo)
 
 
 def _getrf_pipelined(a: jax.Array, nb: int, grid=None
@@ -607,6 +781,25 @@ def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
     return a, ipiv
 
 
+def _lo_route(opts: OptionsLike, tile_nb: int, shape, dtype) -> dict:
+    """The lo carry form's route, as `getrf` notes it on its span and
+    the mixed drivers on theirs: the blocking `getrf` resolves, how
+    the first (tallest) panel is factored, what is stored and what an
+    update multiplies."""
+    nb = _lu_nb(opts, tile_nb, shape, None, dtype=dtype)
+    return dict(form="carry", nb=nb, store=str(dtype),
+                panel=_lo_panel_route(shape[0], min(nb, *shape)),
+                panel_dtype="float32",
+                update="one pass %s x %s -> float32" % (dtype, dtype))
+
+
+def _stored_lo(dtype) -> bool:
+    """A real factor dtype below f32 (bf16, the chip's lo of f32; f16):
+    what XLA's LU takes no operand of."""
+    d = jnp.dtype(dtype)
+    return jnp.issubdtype(d, jnp.floating) and d.itemsize < 4
+
+
 def _nopiv_panel(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """LU panel without pivoting (reference getrf_nopiv)."""
     m, w = a.shape
@@ -770,6 +963,7 @@ def getrf(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
     with obs_events.span("getrf::prep", cat="step"):
         r, a = _prep(A)
     grid = get_option(opts, Option.Grid, None)
+    perm = None
     dtype_ok = MethodFactor.native_lu_dtype_ok(a.dtype)
     fmethod = get_option(opts, Option.MethodFactor, MethodFactor.Auto)
     if fmethod is MethodFactor.Auto:
@@ -816,6 +1010,15 @@ def getrf(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
         # convention
         lu, ipiv, _ = jax.lax.linalg.lu(a)
         ipiv = ipiv.astype(jnp.int32)
+    elif grid is None and _stored_lo(a.dtype):
+        # the lo factor of the mixed solves on one device: the carry
+        # form with each panel raised to f32 (`_carry_panel_lo`), the
+        # factor stored in its own dtype, the update one lo pass. nb
+        # as resolved: no panel here runs the fori kernel's O(w)
+        # full-panel passes that the f32 route narrows nb for
+        route = _lo_route(opts, r.nb, a.shape, a.dtype)
+        obs_events.note(**route)
+        lu, ipiv, perm = _getrf_carry(a, route["nb"], lo=True)
     else:
         lu, ipiv = _getrf_dense(
             a, _lu_nb(opts, r.nb, a.shape, grid, dtype=a.dtype),
@@ -826,7 +1029,7 @@ def getrf(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
         info = lu_info(lu, r.m, r.n)
     return LUFactors(dataclasses.replace(r, data=lu,
                                          mtype=MatrixType.General), ipiv,
-                     info)
+                     info, perm=perm)
 
 
 def getrf_nopiv(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
@@ -932,25 +1135,73 @@ def getri(F: LUFactors, opts: OptionsLike = None) -> TiledMatrix:
 
 # -- mixed precision ------------------------------------------------------
 
+def _lo_getrs(ctx, factors, rhs: jax.Array) -> jax.Array:
+    """The lo solve the mixed LU drivers hand `refine`'s programs:
+    `getrs` on dense arrays. `factors` = (packed lo LU (padded), its
+    composed row permutation), `ctx` = (nb, grid); rhs (n, k) in the
+    factor's dtype, and so is the answer. The triangles are read
+    where they lie in the packed factor, no masked copy made; on one
+    device by `refine.tri_sweep`, whose program does not grow with n."""
+    from .refine import lo_work_dtype, tri_sweep
+    lu, perm = factors
+    nb, grid = ctx
+    n = rhs.shape[0]
+    y = _permute_rows(jnp.pad(rhs, ((0, lu.shape[0] - n), (0, 0))), perm)
+    if grid is not None:
+        from .blocked import trsm_dense
+        y = trsm_dense(lu, y, left=True, lower=True, nb=nb,
+                       unit_diagonal=True, grid=grid)
+        return trsm_dense(lu, y, left=True, lower=False, nb=nb,
+                          grid=grid)[:n]
+    y = y.astype(lo_work_dtype(lu.dtype))
+    y = tri_sweep(lu, y, lower=True, nb=nb, unit_diagonal=True)
+    return tri_sweep(lu, y, lower=False, nb=nb)[:n].astype(rhs.dtype)
+
+
+def _mixed_factor(name: str, A: TiledMatrix, opts: OptionsLike):
+    """What `gesv_mixed` and `gesv_mixed_gmres` share: A demoted once
+    (`<name>::demote`), factored through `getrf`'s own routing
+    (`<name>::factor`), and the lo solve's operands with the swaps
+    composed to a permutation once a call. Returns (F, ctx, factors)
+    and notes the route on the driver span."""
+    from ..utils.trace import phases
+    from .refine import demote
+    A_lo = demote(name, A, opts)
+    with phases(opts)(name + "::factor"):
+        F = getrf(A_lo, opts)
+        perm = F.perm if F.perm is not None else _compose_swaps(
+            F.pivots, F.LU.data.shape[0])
+    obs_events.note(lo=str(A_lo.dtype))
+    if F.perm is not None and obs_events.enabled():
+        # the lo carry form made it: its route on this span too
+        obs_events.note(factor=MethodFactor.Tiled.value, **_lo_route(
+            opts, F.LU.nb, F.LU.data.shape, F.LU.dtype))
+    return F, (F.LU.nb, get_option(opts, Option.Grid, None)), \
+        (F.LU.data, perm)
+
+
 @instrument_driver("gesv_mixed")
 def gesv_mixed(A: TiledMatrix, B: TiledMatrix, opts: OptionsLike = None):
     """Mixed-precision LU with iterative refinement (reference
     src/gesv_mixed.cc:24-40: lo-precision factor + hi-precision residual
     refinement, fallback to full precision on non-convergence).
 
+    The first lo solve and the refinement are two compiled programs
+    reused by every later call at the shape (`refine.py`); the host
+    reads their verdict once and decides: converged, or the f32
+    `getrf` / `getrs` called as a caller would.
+
     Returns (factors_lo, X, iters) where iters < 0 means the fallback
     full-precision solve produced X (reference info semantics)."""
-    from .refine import iterative_refinement, lo_dtype, lo_rhs_solver
-    r = A.resolve()
-    lo = lo_dtype(r.dtype)
-    A_lo = dataclasses.replace(r, data=r.data.astype(lo))
-    F = getrf(A_lo, opts)
-    solve_lo = lo_rhs_solver(B, lo, lambda rhs: getrs(F, rhs, opts))
+    from .refine import iterative_refinement
+    F, ctx, factors = _mixed_factor("gesv_mixed", A, opts)
+    obs_events.note(refine="ir")
 
     def full_solve():
         return getrs(getrf(A, opts), B, opts).to_dense()
 
-    x, iters = iterative_refinement(A, B, solve_lo, full_solve, opts)
+    x, iters = iterative_refinement(A, B, _lo_getrs, ctx, factors,
+                                    full_solve, opts, name="gesv_mixed")
     return F, _store(B, x), iters
 
 
@@ -961,21 +1212,19 @@ def gesv_mixed_gmres(A: TiledMatrix, B: TiledMatrix,
     restarted FGMRES, restart=min(30, itermax, mb-1), right-
     preconditioned by the lo-precision LU solve). Single-RHS like the
     reference."""
-    from .refine import fgmres_ir, lo_dtype, lo_rhs_solver
-    r = A.resolve()
+    from .refine import fgmres_ir
     slate_assert(B.shape[1] == 1,
                  "gesv_mixed_gmres supports one right-hand side "
                  "(reference gesv_mixed_gmres.cc nrhs==1 limitation)")
-    lo = lo_dtype(r.dtype)
-    A_lo = dataclasses.replace(r, data=r.data.astype(lo))
-    F = getrf(A_lo, opts)
-    solve_lo = lo_rhs_solver(B, lo, lambda rhs: getrs(F, rhs, opts))
+    F, ctx, factors = _mixed_factor("gesv_mixed_gmres", A, opts)
+    obs_events.note(refine="fgmres")
 
     def full_solve():
         return getrs(getrf(A, opts), B, opts).to_dense()
 
-    x, iters = fgmres_ir(A, B, solve_lo, full_solve,
-                         restart_cap=max(r.mb - 1, 1), opts=opts)
+    x, iters = fgmres_ir(A, B, _lo_getrs, ctx, factors, full_solve,
+                         restart_cap=max(A.resolve().mb - 1, 1),
+                         opts=opts, name="gesv_mixed_gmres")
     return F, _store(B, x), iters
 
 

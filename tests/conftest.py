@@ -87,8 +87,6 @@ SLOW_TESTS = {
     "test_harness.py::test_condest_early_exit",
     "test_harness.py::test_tester_cli_quick",
     "test_info.py::test_hetrf_info",
-    "test_lu.py::test_gesv_mixed",
-    "test_lu.py::test_gesv_mixed_gmres",
     "test_lu.py::test_gesv_rbt",
     "test_lu.py::test_getrf_carry_rectangular",
     "test_lu.py::test_getrf_lookahead_pipelined_matches_plain",

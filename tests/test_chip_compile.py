@@ -282,3 +282,43 @@ def test_svd_programs_compile_at_the_cells_size(one_chip, program):
         # U and Vh take the donated buffers: no third n x n output
         assert ma.alias_size_in_bytes >= 2 * n * n * 4, \
             ma.alias_size_in_bytes
+
+
+@pytest.mark.parametrize("program", ["panel", "solve0", "sweeps", "update"])
+def test_mixed_programs_compile_at_the_cells_size(one_chip, program):
+    """The programs PR 42 adds for `incore-gesv-mixed`, n=16384 with
+    a bf16 factor in panels of 1024: the tall panel by
+    `lu_panel_blocked` (XLA's LU refuses 16384 rows), the first lo
+    solve and the refinement (XLA's TriangularSolve on the whole
+    factor kept this compiler over five minutes for one of them;
+    `refine.tri_sweep` is O(1) in n), the first step's one-pass
+    update. Each in seconds, its temporaries a small part of a chip."""
+    from slate_tpu.linalg import lu, refine
+    n, nb = 16384, 1024
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    fac = ((n, n), bf), ((n,), i32)
+
+    def solve0(lu_data, perm, b):
+        return refine._ir_solve0(lu._lo_getrs, (512, None),
+                                 (lu_data, perm), b)
+
+    def sweeps(lu_data, perm, a, b, x):
+        return refine._ir_sweeps(lu._lo_getrs, (512, None),
+                                 (lu_data, perm), a, b, x, 30)
+
+    fn, shapes, static = {
+        "panel": (lu._carry_panel_lo, [((n, n), bf)],
+                  {"w": nb, "route": "blocked"}),
+        "solve0": (jax.jit(solve0), [*fac, ((n, 1), f32)], {}),
+        "sweeps": (jax.jit(sweeps), [*fac, ((n, n), f32), ((n, 1), f32),
+                                     ((n, 1), f32)], {}),
+        "update": (lu._carry_update, [((n, nb), bf), ((n, n - nb), bf)], {}),
+    }[program]
+    compiled = _compile(fn, one_chip, *shapes, kernel=False, limit_s=60.0,
+                        **static)
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 1 << 30, ma.temp_size_in_bytes
+    if program == "update":
+        # the trailing matrix is written back in bf16
+        assert "bf16[15360,15360]" in compiled.as_text()
+        assert ma.output_size_in_bytes < 2 * n * n + (1 << 20)
