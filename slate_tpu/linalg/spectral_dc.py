@@ -44,16 +44,41 @@ Shapes shrink down the recursion through the same bucket ladder idea
 as the stock implementation (multiplier ~1.98, granularity 128), with
 subproblem true sizes handled by masking.
 
+Where a block is split is free in the algorithm (backward stable at
+any shift) and decides the tree. The median of a block's diagonal
+concentrates at the MEAN of its eigenvalues, not at their median, so
+on a decaying spectrum every split is lopsided, and a child of more
+than n/1.98 rows runs in the root's bucket again at (n/rows)^3 of its
+cost: at n=8192 the two benchmark cells ran THREE splits at the full
+size (8192, 5824 and 4709 rows; 8192, 6217 and 4487), 3.7 of 4.4 busy
+seconds (PERF.md, PR 33, PR 39). A balanced ROOT would need the split
+point to 1.6% of the rows (4224 of 8192), which nothing cheap
+resolves inside a cluster of eigenvalues. But the larger child's OWN
+split only has to leave both of its children under the top rung: any
+point between the 27th and the 72nd percentile of a 5824-row block.
+So from SHIFT_MIN_LEAVES leaves to a bucket up, `dc_sign` first
+estimates the block's spectral distribution (`_spectral_measure`: a
+short Lanczos recurrence from a few probe vectors, B^2 work beside
+the split's 50 B^3) and `_estimated_shift` chooses sigma from it:
+the diagonal's median where the estimate says both children clear the
+rung, else the estimated median where that clears it with room, else
+a point deliberately off the rung's edge, so that which bucket a child
+lands in does not turn on the estimate's last percent. The larger
+child of a lopsided split is then halved once: two full-size splits a
+solve for three (PR 43). Smaller buckets keep the diagonal's median.
+
 A split is four steps: `dc_take` (the subproblem out of the
-workspace, and whether it is already diagonal), `dc_sign` (the polar
-iteration: half of a split's code), `dc_basis` (the subspace QRs and
-the compression: the other half) and `dc_put` (the eigenvector
-compose and the children back into the workspace); a subproblem at
-or under the leaf size is one `dc_leaf`; `dc_vectors` sorts.
+workspace, and whether it is already diagonal), `dc_sign` (the shift
+and the polar iteration: half of a split's code), `dc_basis` (the
+subspace QRs and the compression: the other half) and `dc_put` (the
+eigenvector compose and the children back into the workspace); a
+subproblem at or under the leaf size is one `dc_leaf`; `dc_vectors`
+sorts.
 
 Each step is a compiled program of its own at each bucket size,
-dispatched from a host AGENDA that reads three numbers a split (k,
-the polar's converged flag and its iteration count) and threads the
+dispatched from a host AGENDA that reads four numbers a split (k,
+the polar's converged flag, its iteration count and where the shift
+came from) and threads the
 donated workspaces from program to program. Splits are dispatched as
 soon as their sizes are known and their sizes read oldest first, so
 the device waits for the host only where the tree is one node wide.
@@ -116,7 +141,12 @@ def _bucket_ladder(n: int, leaf: int):
     size again (`_bucket_of`). Rungs 1.5 times 4224 and 2176 (6272,
     3200) held such children and cut the solve of PR 33's cell from
     4.8 to 2.95 s, but made the programs 288 MB, and every run
-    compiled all of them again, 570 s (PERF.md, PR 33)."""
+    compiled all of them again, 570 s (PERF.md, PR 33). What the
+    ladder cannot hold the shift avoids instead: a full-size child's
+    own split is aimed inside the window that leaves both of its
+    children under the top rung (`_estimated_shift`, PR 43), wide
+    because a child of r rows only needs a split between rows
+    r - 4224 and 4224."""
     buckets = [leaf]
     if n > leaf:
         i = int(n / 1.98)
@@ -140,23 +170,169 @@ def _mask_cols(x, c0, c1, fill=0.0):
     return jnp.where((j >= c0) & (j < c1), x, jnp.asarray(fill, x.dtype))
 
 
-def _sign_split(H, m, general, l0):
+#: the estimate of a block's spectral distribution (`_spectral_measure`):
+#: steps of the Lanczos recurrence, and probe vectors run side by side
+#: (eight fill the sublanes of one f32 tile: they cost what four do)
+SHIFT_STEPS = 32
+SHIFT_PROBES = 8
+
+#: a split estimates from this many leaves to a bucket up: the estimate
+#: is B^2 work in some tens of short sequential steps and a split is
+#: B^3, so at 384 and 640 rows under leaves of 256 (27 splits of the
+#: cells' 46, 51 ms in all) it would cost what the split does
+SHIFT_MIN_LEAVES = 8
+
+#: how far from a rung, as a share of the block's rows, the rule aims a
+#: child it cannot place on the rung's near side: the estimate's bias on
+#: a smooth spectrum (2.4% read) and its noise from one matrix to the
+#: next (under 1% at eight probes)
+SHIFT_ROOM = 0.03
+
+#: how many rows under the rung the estimate has to put both children
+#: of the diagonal's median for that median to be kept: the estimate
+#: repeats to 10 rows at 2048 and 25 at 8192 from one matrix of a
+#: spectrum to the next, and a balanced root has 128 to spare
+SHIFT_KEEP_ROWS = 32
+
+
+def _spectral_measure(apply, m, B: int, dt, key=None):
+    """Nodes and weights, each (SHIFT_PROBES, SHIFT_STEPS), of Gauss
+    quadratures of the spectral measure of the Hermitian operator
+    `apply` (rows in, rows out: X -> X H^T) on the leading m of B
+    coordinates: SHIFT_STEPS steps of the Lanczos recurrence from each
+    of SHIFT_PROBES Gaussian vectors, every new vector orthogonalized
+    twice against all kept ones, then the eigenvalues of each
+    tridiagonal matrix (nodes, ascending) and the squared first
+    components of its eigenvectors (weights, summing to 1 a probe).
+    The key is fixed: the same matrix gives the same nodes. A probe
+    that exhausts an invariant subspace goes on in its complement with
+    a coupling at rounding level, so its later nodes weigh nothing."""
+    rdt = jnp.zeros((), dt).real.dtype
+    P, S = SHIFT_PROBES, SHIFT_STEPS
+    tiny = jnp.finfo(rdt).tiny
+    live = jnp.arange(B) < m
+    key = jax.random.PRNGKey(43) if key is None else key
+    q = jnp.where(live, jax.random.normal(key, (P, B), rdt), 0).astype(dt)
+    q = q / jnp.sqrt(jnp.sum(jnp.abs(q) ** 2, axis=1))[:, None]
+
+    def step(j, carry):
+        Q, q, alpha, beta = carry
+        # a scatter, NOT `lax.dynamic_update_slice(Q, q[None], (j, 0,
+        # 0))`: on the chip that form left `Q` something else than
+        # what was written (beta 1.6e13 at the FIRST step, compiled
+        # alone or under a `cond`; PERF.md, PR 43, call C), where this
+        # one, a mask over the steps and the unrolled loop all read
+        # the spectrum
+        Q = Q.at[j].set(q)
+        w = apply(q)
+        a = jnp.real(jnp.sum(q.conj() * w, axis=1))
+        for _ in range(2):
+            c = jnp.einsum("spb,pb->sp", Q.conj(), w, precision=HI)
+            w = w - jnp.einsum("sp,spb->pb", c, Q, precision=HI)
+        b = jnp.sqrt(jnp.sum(jnp.abs(w) ** 2, axis=1))
+        q = w / jnp.maximum(b, tiny)[:, None].astype(dt)
+        return Q, q, alpha.at[j].set(a), beta.at[j].set(b)
+
+    _, _, alpha, beta = jax.lax.fori_loop(
+        0, S, step, (jnp.zeros((S, P, B), dt), q,
+                     jnp.zeros((S, P), rdt), jnp.zeros((S, P), rdt)))
+    nodes, vecs = jnp.linalg.eigh(jax.vmap(
+        lambda a, o: jnp.diag(a) + jnp.diag(o, 1) + jnp.diag(o, -1))(
+            alpha.T, beta[:-1].T))
+    return nodes, vecs[:, 0, :] ** 2
+
+
+def _share_under(nodes, weights, x):
+    """The estimated share of the eigenvalues under x (any shape): a
+    Gauss quadrature's sums bracket the measure at its nodes (all the
+    weight under a node at least, that node's weight more at most), so
+    each probe's estimate runs through the brackets' middles, straight
+    between nodes, and the probes are averaged."""
+    mid = jnp.cumsum(weights, axis=1) - 0.5 * weights
+    return jnp.mean(jax.vmap(
+        lambda t, c: jnp.interp(x, t, c, left=0.0, right=1.0))(nodes, mid),
+        axis=0)
+
+
+def _shift_for(nodes, weights, share):
+    """The shift the estimate puts `share` of the eigenvalues under."""
+    xs = jnp.sort(nodes.ravel())
+    return jnp.interp(share, _share_under(nodes, weights, xs), xs)
+
+
+def _estimated_shift(apply, m, B: int, dt, rung, sigma_d, bound, key=None):
+    """The split point of a block of m rows in the bucket B whose next
+    rung down is `rung`, from an estimate of where its eigenvalues lie
+    (module doc). `bound` is any upper bound of the block's spectral
+    radius: a Ritz value 1% beyond it, or one that is not a number, says
+    the recurrence failed, and the diagonal's median is kept (a first
+    form of the recurrence read NaN and Ritz values of 1e13 on the
+    chip where the sandbox's CPU read the spectrum: PERF.md, PR 43).
+    Returns (sigma, moved):
+
+    (a) sigma_d, the median of the diagonal, where the estimate puts
+        both of ITS children SHIFT_KEEP_ROWS under the rung or lower: a
+        spectrum the mean balances is not moved (the estimate is the
+        coarser of the two there);
+    (b) otherwise the estimated median, where the larger child then
+        clears the rung by SHIFT_ROOM of the block; where it does not
+        (the root: 4224 of 8192 rows is 1.6% over a half), the shift
+        that puts the larger child SHIFT_ROOM of the block over the
+        rung, on sigma_d's side: that child is in this bucket again
+        whatever the estimate's error, its own split has the wide
+        window of (a) or (b), and the tree is the same from one matrix
+        of a spectrum to the next."""
+    nodes, weights = _spectral_measure(apply, m, B, dt, key)
+    rows = jnp.asarray(m, nodes.dtype)
+    rung = jnp.asarray(rung, nodes.dtype)
+    under_d = _share_under(nodes, weights, sigma_d)
+    sane = jnp.all(jnp.abs(nodes) <= 1.01 * bound)
+    keep = ~sane | (jnp.maximum(under_d, 1.0 - under_d) * rows
+                    <= rung - SHIFT_KEEP_ROWS)
+    larger = jnp.where((0.5 + SHIFT_ROOM) * rows <= rung, 0.5,
+                       rung / rows + SHIFT_ROOM)
+    share = jnp.where(under_d < 0.5, 1.0 - larger, larger)
+    sigma_e = _shift_for(nodes, weights, share).astype(sigma_d.dtype)
+    return jnp.where(keep, sigma_d, sigma_e), ~keep
+
+
+def _sign_split(H, m, general, rung, l0):
     """sign(H - sigma I) of the masked (m, m) Hermitian block H,
-    padded to static (B, B), at sigma = the median of its diagonal;
-    or, where the traced flag `general` is set, the orthogonal polar
-    factor of H as it stands (no shift, and the result is not
+    padded to static (B, B). sigma is the median of the diagonal in a
+    bucket that does not estimate (`rung` None: under
+    SHIFT_MIN_LEAVES leaves; the program is then the code of before
+    PR 43, with no estimate in it), and `_estimated_shift`'s choice
+    between that median and a point of the block's estimated spectral
+    distribution where `rung` (traced) is the next bucket down. Where
+    the traced flag `general` is set the result is the orthogonal
+    polar factor of H as it stands (no shift, no estimate: the
+    estimate sits under the flag's branch; and the result is not
     symmetrized): the SVD's polar step is the eigensolver's sign step
     at sigma = 0 on a matrix that need not be Hermitian, and ONE
     program a bucket serves both (`dc_sign`). Returns (S, polar
-    iterations, converged)."""
+    iterations, converged, shift) with shift 0 where nothing was
+    estimated, 1 where the estimate kept the diagonal's median and 2
+    where it moved sigma."""
     B = H.shape[0]
     dt = H.dtype
     diag = jnp.real(jnp.diagonal(H))
     ids = jnp.arange(B)
     sigma = jnp.nanmedian(jnp.where(ids < m, diag, jnp.nan))
+    shift = jnp.zeros((), jnp.int32)
+    if rung is not None:
+        def estimated(sigma_d):
+            sigma, moved = _estimated_shift(
+                lambda X: jax.lax.dot_general(
+                    X, H, (((1,), (1,)), ((), ())), precision=HI),
+                m, B, dt, rung, sigma_d,
+                jnp.max(jnp.sum(jnp.abs(H), axis=0)))
+            return sigma, 1 + moved.astype(jnp.int32)
+
+        sigma, shift = jax.lax.cond(
+            general, lambda sigma_d: (sigma_d, shift), estimated, sigma)
     sigma = jnp.where(general, jnp.zeros((), sigma.dtype), sigma)
     Hs = H - sigma.astype(dt) * _eye_m(B, m, dt)
-    return sign_hermitian(Hs, l0=l0, general=general)
+    return sign_hermitian(Hs, l0=l0, general=general) + (shift,)
 
 
 def _eye_m(B, m, dt):
@@ -271,13 +447,14 @@ def _masked_merge_block(work, blk, off_r, off_c, rows, cols):
     return jax.lax.dynamic_update_slice(work, t, (off_r, off_c))
 
 
-def _info(k, ok, iters):
+def _info(k, ok, iters, shift):
     """What the host agenda reads of one split, as one transfer:
-    [k, converged, polar iterations]; k = 0 says the block was
-    (near-)diagonal and has no children."""
-    return jnp.stack([jnp.asarray(k, jnp.int32),
-                      jnp.asarray(ok, jnp.int32),
-                      jnp.asarray(iters, jnp.int32)])
+    [k, converged, polar iterations, shift]; k = 0 says the block was
+    (near-)diagonal and has no children; shift is `_sign_split`'s: 0
+    where nothing was estimated, 1 where the estimate kept the
+    diagonal's median, 2 where it moved sigma."""
+    return jnp.stack([jnp.asarray(x, jnp.int32)
+                      for x in (k, ok, iters, shift)])
 
 
 def _window(work, off, axis, B):
@@ -328,19 +505,23 @@ def dc_take(blocks, at, h0norm, B: int):
     return H, _nearly_diagonal(H, h0norm)
 
 
-def dc_sign(H, m, nearly, general, l0=None):
-    """The polar iteration of one split (skipped on a block that is
-    diagonal already). Returns (S, flags) with flags = [nearly,
-    converged, iterations]. `general` (traced) asks for the polar
-    factor of a general H instead of the sign of the shifted Hermitian
-    one (`_sign_split`): `st.svd` dispatches the executable the
+def dc_sign(H, m, nearly, general, rung, l0=None):
+    """The shift and the polar iteration of one split (skipped on a
+    block that is diagonal already). Returns (S, flags) with flags =
+    [nearly, converged, iterations, shift]. `rung` (traced) is the
+    next bucket under this one where the split estimates its block's
+    spectral distribution before it shifts, and None where it takes
+    the median of the diagonal (`_sign_split`). `general` (traced) asks
+    for the polar factor of a general H instead of the sign of the
+    shifted Hermitian one: `st.svd` dispatches the executable the
     eigensolver compiled, at the root's bucket (`polar_general`)."""
     def skip(H):
-        return H, jnp.zeros((), jnp.int32), jnp.ones((), jnp.bool_)
+        zero = jnp.zeros((), jnp.int32)
+        return H, zero, jnp.ones((), jnp.bool_), zero
 
-    S, iters, conv = jax.lax.cond(
-        nearly, skip, lambda H: _sign_split(H, m, general, l0), H)
-    return S, _info(nearly, conv, iters)
+    S, iters, conv, shift = jax.lax.cond(
+        nearly, skip, lambda H: _sign_split(H, m, general, rung, l0), H)
+    return S, _info(nearly, conv, iters, shift)
 
 
 def dc_basis(H, S, m, flags):
@@ -357,7 +538,7 @@ def dc_basis(H, S, m, flags):
 
     Q, W, k = jax.lax.cond(flags[0] > 0, skip,
                            lambda H, S: _split_basis(H, S, m), H, S)
-    return Q, W, _info(k, flags[1], flags[2])
+    return Q, W, _info(k, flags[1], flags[2], flags[3])
 
 
 def _put(blocks, vecs, off, sz, Q, W, k, compose: bool):
@@ -462,21 +643,21 @@ _JIT = {"take": dict(static_argnames=("B",)),
 _HERMITIAN, _GENERAL = np.False_, np.True_
 
 
-def _split_steps(step, blocks, vecs, at, h0norm, B: int, l0):
+def _split_steps(step, blocks, vecs, at, h0norm, B: int, rung, l0):
     """The four steps of one split dispatched in a row (`step` =
     `_programs(B)`). Returns (blocks, vecs, info)."""
     H, nearly = step["take"](blocks, at, h0norm, B=B)
-    S, flags = step["sign"](H, at[1], nearly, _HERMITIAN, l0=l0)
+    S, flags = step["sign"](H, at[1], nearly, _HERMITIAN, rung, l0=l0)
     Q, W, info = step["basis"](H, S, at[1], flags)
     return step["put"](blocks, vecs, at, Q, W, info) + (info,)
 
 
-def _root_steps(step, h, l0):
+def _root_steps(step, h, rung, l0):
     """The root split at the concrete size: no masking overhead, no
     identity compose. Returns (blocks, vecs, h0norm, info)."""
     H, h0norm, nearly = step["take_root"](h)
     m = np.int32(h.shape[0])
-    S, flags = step["sign"](H, m, nearly, _HERMITIAN, l0=l0)
+    S, flags = step["sign"](H, m, nearly, _HERMITIAN, rung, l0=l0)
     Q, W, info = step["basis"](H, S, m, flags)
     return step["put_root"](Q, W, info) + (h0norm, info)
 
@@ -485,6 +666,17 @@ def _bucket_of(ladder, n: int, sz: int) -> int:
     """The smallest bucket that holds `sz`; the full size n for a
     lopsided split's larger child that outgrew the ladder."""
     return next((b for b in ladder if b >= sz), n)
+
+
+def _rung_under(ladder, B: int, leaf: int):
+    """What a split in the bucket B passes `dc_sign` as its `rung`:
+    the next bucket down, whose edge its children should clear, where
+    the split estimates its block's spectral distribution (a bucket
+    of SHIFT_MIN_LEAVES leaves or more); None where it does not (the
+    program of such a bucket holds no estimate)."""
+    if B < SHIFT_MIN_LEAVES * leaf:
+        return None
+    return np.int32(max(b for b in ladder if b < B))
 
 
 # ---- the agenda -----------------------------------------------------
@@ -516,18 +708,25 @@ def _eigh_dc_agenda(h, leaf: int, l0):
     span, inc = obs_events.span, obs_metrics.inc
     leaves = _programs(leaf)
     with span("heev::split", cat="phase", bucket=n, size=n):
-        blocks, vecs, h0norm, info = _root_steps(_programs(n), h, l0)
+        blocks, vecs, h0norm, info = _root_steps(
+            _programs(n), h, _rung_under(ladder, n, leaf), l0)
     pending = deque([(0, n, n, info)])
     ok = True
     while pending:
         off, sz, B, info = pending.popleft()
         with span("heev::agenda", cat="phase"):
-            k, conv, iters = (int(x) for x in np.asarray(info))
+            k, conv, iters, shift = (int(x) for x in np.asarray(info))
         ok = ok and bool(conv)
         inc("heev.splits")
         inc("heev.split_rows_true", sz)
         inc("heev.split_rows_padded", B)
         inc("heev.polar_iters", iters)
+        if B == n:
+            inc("heev.full_size_splits")
+        if shift:
+            inc("heev.shift_estimated")
+        if shift > 1:
+            inc("heev.shift_moved")
         if not conv:
             inc("heev.unconverged")
         if k == 0:                      # finished as a diagonal block
@@ -542,26 +741,29 @@ def _eigh_dc_agenda(h, leaf: int, l0):
             Bc = _bucket_of(ladder, n, c_sz)
             with span("heev::split", cat="phase", bucket=Bc, size=c_sz):
                 blocks, vecs, info = _split_steps(
-                    _programs(Bc), blocks, vecs, at, h0norm, Bc, l0)
+                    _programs(Bc), blocks, vecs, at, h0norm, Bc,
+                    _rung_under(ladder, Bc, leaf), l0)
             pending.append((c_off, c_sz, Bc, info))
     with span("heev::vectors", cat="phase"):
         w, v = _vectors_program(blocks, vecs)
     return w, v, ok
 
 
-def polar_general(a: jax.Array, l0=None):
+def polar_general(a: jax.Array, leaf: int = LEAF, l0=None):
     """The orthogonal polar factor of the square matrix `a` by the
     eigensolver's own `dc_sign` program at the bucket `a.shape[0]`
-    (the executable `eigh_dc`'s root split runs: a second polar
-    program at n=8192 would be another 41 MB of compile cache).
-    Returns (U_p, flags) on the device, flags = [0, converged,
-    iterations]; nothing is read here."""
+    (the executable the root split of `eigh_dc(., leaf)` runs: a
+    second polar program at n=8192 would be another 41 MB of compile
+    cache). Returns (U_p, flags) on the device, flags = [0, converged,
+    iterations, 0]; nothing is read here."""
     n = a.shape[0]
-    # `nearly` as the root split passes it, an array on the device: the
-    # call then finds the executable `eigh_dc` loaded, in this process
-    # too
+    # `nearly` and `rung` as the root split passes them (an array on
+    # the device; a rung or none by the root's bucket): the call then
+    # finds the executable `eigh_dc` loaded, in this process too. The
+    # flag's branch estimates nothing, whatever the rung
+    rung = _rung_under(_bucket_ladder(n, leaf), n, leaf)
     return _programs(n)["sign"](a, np.int32(n), jax.device_put(np.False_),
-                                _GENERAL, l0=l0)
+                                _GENERAL, rung, l0=l0)
 
 
 def route(a, opts=None):

@@ -96,7 +96,7 @@ def _svd_qdwh_dc(A: TiledMatrix, a, leaf: int, span, want_u: bool,
     from ..obs import metrics as obs_metrics
     from . import spectral_dc
     with span("svd::polar"):
-        up, flags = spectral_dc.polar_general(a)
+        up, flags = spectral_dc.polar_general(a, leaf)
     with span("svd::form"):
         h = _svd_form(up, a)
     with span("svd::eig"):
@@ -112,7 +112,7 @@ def _svd_qdwh_dc(A: TiledMatrix, a, leaf: int, span, want_u: bool,
     # split ran, and everything after them is dispatched by now: the
     # device does not wait for this read
     with span("svd::agenda"):
-        _, conv, iters = (int(x) for x in np.asarray(flags))
+        _, conv, iters, _ = (int(x) for x in np.asarray(flags))
     obs_metrics.inc("svd.polar_iters", iters)
     if not conv:
         obs_metrics.inc("svd.unconverged")

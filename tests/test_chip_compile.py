@@ -210,7 +210,7 @@ def test_heev_steps_compile_at_the_cells_workspaces(one_chip, step):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     ws = (S(2 * n, n), S(n, 2 * n), S(2, dtype=jnp.int32))
-    sq, flags = S(B, B), S(3, dtype=jnp.int32)
+    sq, flags = S(B, B), S(4, dtype=jnp.int32)
     if step == "put":
         compiled = dc._programs(B)["put"].lower(
             *ws, sq, sq, flags).compile()
@@ -219,7 +219,7 @@ def test_heev_steps_compile_at_the_cells_workspaces(one_chip, step):
     elif step == "sign":
         compiled = dc._programs(B)["sign"].lower(
             sq, S(dtype=jnp.int32), S(dtype=jnp.bool_),
-            S(dtype=jnp.bool_)).compile()
+            S(dtype=jnp.bool_), None).compile()
     else:
         compiled = dc._programs(B)["basis"].lower(
             sq, sq, S(dtype=jnp.int32), flags).compile()
@@ -254,8 +254,8 @@ def test_svd_programs_compile_at_the_cells_size(one_chip, program):
     if program == "polar":
         # 343 s beside another compile (sandbox, PR 39)
         ma = _compile(dc._programs(n)["sign"], one_chip, sq,
-                      ((), jnp.int32), flag, flag, kernel=False,
-                      limit_s=1500.0).memory_analysis()
+                      ((), jnp.int32), flag, flag, ((), jnp.int32),
+                      kernel=False, limit_s=1500.0).memory_analysis()
         # 304.0 MB of code and 2.887 GB of temporaries, the parent's
         # numbers to the megabyte; two Cholesky forms are 600 and more
         assert ma.generated_code_size_in_bytes < 340e6, \

@@ -8,8 +8,12 @@ of a lopsided split; the route, the
 spans and the counters the per-layer metrics read; an unconverged
 split reported; ADVICE.md's two findings on the polar iteration; the
 kind's `check()` against sound and unsound answers; the readers on
-planes made by hand; and a rehearsal of the cell `incore-heev`."""
+planes made by hand; a rehearsal of the cell `incore-heev`; and PR 43's
+choice of a split's shift: the estimate of a block's spectral
+distribution against known spectra, the rule walked on the host over
+the two cells' multisets, and the counters it publishes."""
 
+import functools
 import json
 import os
 import subprocess
@@ -157,10 +161,14 @@ PARENT = {
 
 
 @pytest.mark.parametrize("law,seed", sorted(PARENT))
-def test_agenda_reads_the_parents_digits(law, seed):
+def test_agenda_reads_the_parents_digits(law, seed, monkeypatch):
     """The agenda's per-bucket programs run the steps the parent's one
     program ran: the same answer to rounding (the same bytes where the
-    frozen digits were read)."""
+    frozen digits were read). The parent split every block at the
+    median of its diagonal, so no bucket estimates here (PR 43): with
+    no rung `dc_sign` is that parent's arithmetic."""
+    monkeypatch.setattr(spectral_dc, "SHIFT_MIN_LEAVES", 1 << 20)
+    spectral_dc._programs.cache_clear()     # traced with no rung
     a = jnp.asarray(matrix(law, seed))
     w, v, ok = spectral_dc.eigh_dc(a, leaf=LEAF)
     assert ok is True
@@ -228,6 +236,290 @@ def test_blocks_that_need_no_split(what):
     assert all(got[k] <= LIMITS[k] for k in LIMITS), got
 
 
+# -- where a split takes its shift from (PR 43) ----------------------------
+
+def spectrum_of(law, n, seed=360):
+    """A sorted multiset of n eigenvalues in [-1, 1]."""
+    r = np.random.default_rng(seed)
+    if law == "heev":               # incore-heev's
+        return np.sort(heevgen.spectrum(r, n, CFG["matrix"]["cond"],
+                                        CFG["matrix"]["sign_seed"]))
+    if law == "svd":                # incore-svd's eigen-stage
+        from benchmarks.lib import svdgen
+        return np.sort(svdgen.spectrum(r, n, CFG["matrix"]["cond"]))
+    if law == "uniform":
+        return np.linspace(-1.0, 1.0, n)
+    if law == "normal":
+        x = np.sort(r.standard_normal(n))
+        return x / np.abs(x).max()
+    # `clusters`: a third of the eigenvalues about -0.5, the rest
+    # about 0.5
+    return np.sort(np.concatenate([r.normal(-0.5, 0.05, n // 3),
+                                   r.normal(0.5, 0.05, n - n // 3)]))
+
+
+@pytest.mark.parametrize("law,form,err", [
+    ("uniform", "diagonal", 0.015), ("uniform", "rotated", 0.015),
+    ("normal", "rotated", 0.02), ("clusters", "rotated", 0.03),
+    ("svd", "rotated", 0.12), ("heev", "rotated", 0.20)])
+def test_the_estimate_reads_the_share_under_a_shift(law, form, err):
+    """`_spectral_measure` and `_share_under` on an f32 matrix of known
+    spectrum, padded to a bucket as a split's block is: the share of
+    the eigenvalues under sigma to 2% of the block where the density
+    is smooth, to 3% between two clusters, and to the weight of the
+    node that stands for a cluster where hundreds of eigenvalues lie
+    closer together than the recurrence resolves (the decaying laws of
+    the two cells: 12% and 20%, which is why the rule aims off a
+    rung's edge and not at it)."""
+    m, B = 480, 512
+    lam = spectrum_of(law, m)
+    if form == "diagonal":
+        a = np.diag(lam)
+    else:
+        q, _ = np.linalg.qr(np.random.default_rng(361)
+                            .standard_normal((m, m)))
+        a = (q * lam) @ q.T
+    h = np.zeros((B, B), np.float32)
+    h[:m, :m] = (a + a.T) / 2
+    h = jnp.asarray(h)
+    nodes, weights = jax.jit(lambda h: spectral_dc._spectral_measure(
+        lambda x: x @ h.T, np.int32(m), B, h.dtype))(h)
+    assert nodes.shape == weights.shape == (spectral_dc.SHIFT_PROBES,
+                                            spectral_dc.SHIFT_STEPS)
+    assert nodes.dtype == weights.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(weights).sum(axis=1), 1.0,
+                               atol=1e-4)
+    assert (np.diff(np.asarray(nodes), axis=1) >= 0).all()
+    # the Ritz values lie inside the spectrum, and reach its ends
+    assert lam[0] - 1e-4 <= float(nodes.min()) <= lam[0] + 0.05
+    assert lam[-1] - 0.05 <= float(nodes.max()) <= lam[-1] + 1e-4
+    sigmas = np.quantile(lam, [0.1, 0.25, 0.4, 0.5, 0.6, 0.75, 0.9])
+    got = np.asarray(spectral_dc._share_under(
+        nodes, weights, jnp.asarray(sigmas, jnp.float32)))
+    true = np.array([(lam < x).mean() for x in sigmas])
+    assert np.abs(got - true).max() <= err, (got, true)
+    assert (np.diff(got) >= 0).all() and 0 <= got.min() <= got.max() <= 1
+    # and the shift it names for a share is where it reads that share
+    for share in (0.3, 0.5, 0.7):
+        sigma = spectral_dc._shift_for(nodes, weights, share)
+        assert abs(float(spectral_dc._share_under(nodes, weights, sigma))
+                   - share) <= 1e-3
+
+
+def test_a_probe_that_exhausts_its_subspace_weighs_nothing_more():
+    """Three distinct eigenvalues: the recurrence ends after three
+    steps, the later nodes carry no weight, and the estimate still
+    reads the three shares."""
+    m = B = 256
+    lam = np.repeat([-0.5, 0.1, 0.8], [64, 128, 64])
+    q, _ = np.linalg.qr(np.random.default_rng(362).standard_normal((m, m)))
+    h = jnp.asarray(((q * lam) @ q.T).astype(np.float32))
+    nodes, weights = spectral_dc._spectral_measure(
+        lambda x: x @ h.T, np.int32(m), B, h.dtype)
+    assert np.isfinite(np.asarray(nodes)).all()
+    got = np.asarray(spectral_dc._share_under(
+        nodes, weights, jnp.asarray([-0.2, 0.5], jnp.float32)))
+    assert np.abs(got - [0.25, 0.75]).max() <= 0.03, got
+
+
+@functools.lru_cache(maxsize=None)
+def _rule_at(B):
+    """`_estimated_shift` on diag(lam), compiled for the bucket B."""
+    return jax.jit(lambda lam, m, rung, sigma_d, key:
+                   spectral_dc._estimated_shift(
+                       lambda x: x * lam[None, :], m, B, lam.dtype, rung,
+                       sigma_d, jnp.abs(lam).max(), key))
+
+
+def walk(lam, leaf, key=None):
+    """[(rows, bucket, rows under the shift, moved or None)] of every
+    split of `eigh_dc`'s tree on a matrix of eigenvalues `lam`, oldest
+    first, by the solver's own ladder, fallback and rule: in the
+    eigenbasis the block is diag(lam) and the probes are as Gaussian
+    as in any other, so `_estimated_shift` runs as on the device; the
+    median of a block's diagonal is the mean of its eigenvalues
+    (`svdgen.mean_split_sizes`'s arithmetic, which the traced sizes
+    follow to a few rows). `key` = None walks the parent's tree: every
+    split at the mean."""
+    n = lam.size
+    ladder = spectral_dc._bucket_ladder(n, leaf)
+    todo, out = [(np.sort(lam), n)], []
+    while todo:
+        blk, B = todo.pop(0)
+        m = blk.size
+        rung = spectral_dc._rung_under(ladder, B, leaf)
+        sigma, moved = np.float32(blk.mean()), None
+        if rung and key is not None:
+            pad = np.zeros(B, np.float32)
+            pad[:m] = blk
+            key, sub = jax.random.split(key)
+            sigma, moved = _rule_at(B)(jnp.asarray(pad), np.int32(m), rung,
+                                       sigma, sub)
+            moved = bool(moved)
+        k = min(max(int((blk < float(sigma)).sum()), 1), m - 1)
+        out.append((m, B, k, moved))
+        todo += [(c, spectral_dc._bucket_of(ladder, n, c.size))
+                 for c in (blk[:k], blk[k:]) if c.size > leaf]
+    return out
+
+
+@pytest.mark.parametrize("law,full_before", [("heev", 3), ("svd", 3)])
+def test_the_rule_halves_the_larger_child_once_on_the_cells_laws(
+        law, full_before):
+    """ISSUE 43: at n=8192 the parent's tree runs three splits in the
+    root's bucket on either cell's multiset; with the estimated shift
+    it runs two, and on six probe keys (in the eigenbasis a probe key
+    is a seed's rotation) the splits that estimate run the same
+    sequence of buckets, none of their children within 30 rows of the
+    edge of a rung that costs."""
+    n, leaf = 8192, spectral_dc.LEAF
+    lam = spectrum_of(law, n)
+    before = walk(lam, leaf)
+    assert sum(B == n for _, B, _, _ in before) == full_before
+    if law == "heev":
+        assert [m for m, B, _, _ in before if B == n] == [8192, 5824, 4709]
+    ladder = spectral_dc._bucket_ladder(n, leaf)
+    seen = set()
+    for i in range(6):
+        tree = walk(lam, leaf, jax.random.PRNGKey(4300 + i))
+        est = [(m, B, k) for m, B, k, moved in tree if moved is not None]
+        full = [(m, k) for m, B, k in est if B == n]
+        assert len(full) == 2 and full[0][0] == n
+        seen.add(tuple(B for _, B, _ in est))
+        # the second full-size split leaves both children under 4224
+        m, k = full[1]
+        assert max(k, m - k) <= ladder[-1] - 30, full
+        # and no child of an estimating split is within 30 rows of the
+        # rungs at 2176 and 4224 (a flip there is 0.15 s and more; one
+        # at 1152 is 0.02 s, the configurations' `assumed` say so)
+        for m, B, k in est:
+            for child in (k, m - k):
+                assert min(abs(child - b) for b in ladder[-2:]) >= 30, \
+                    (m, B, k)
+        assert len(tree) <= len(before) + 4
+    assert len(seen) == 1, seen
+
+
+@pytest.mark.parametrize("law", ["uniform", "normal", "clusters"])
+def test_the_rule_leaves_a_spectrum_the_mean_balances(law):
+    """The controls: where the median of the diagonal already halves
+    the root, the estimate keeps it (its own reading of the share
+    under a shift is the coarser of the two), and no law runs more
+    splits in the root's bucket than the parent's tree does."""
+    n, leaf = 8192, spectral_dc.LEAF
+    lam = spectrum_of(law, n)
+    parent = walk(lam, leaf)
+    before = sum(B == n for _, B, _, _ in parent)
+    for i in range(3):
+        tree = walk(lam, leaf, jax.random.PRNGKey(4310 + i))
+        assert sum(B == n for _, B, _, _ in tree) <= before
+        if law != "clusters":
+            assert before == 1 and tree[0][3] is False
+            assert tree[0][2] == parent[0][2]
+
+
+@pytest.mark.parametrize("fault", ["nan", "blown_up"])
+def test_an_estimate_that_failed_keeps_the_diagonals_median(fault):
+    """On the chip the recurrence, compiled alone, read NaN and Ritz
+    values of 1e13 where the same code inside `dc_sign` reads the
+    spectrum (PERF.md, PR 43): whatever an estimate reads beyond the
+    bound of the block's spectral radius, the split keeps the median
+    of its diagonal, the parent's shift."""
+    m = B = 256
+    lam = jnp.asarray(spectrum_of("svd", m), jnp.float32)
+    sigma_d = jnp.float32(np.mean(np.asarray(lam)))
+
+    def broken(x):
+        y = x * lam[None, :]
+        return y * jnp.float32(jnp.nan) if fault == "nan" else 1e6 * y + x
+
+    args = (np.int32(m), B, lam.dtype, np.int32(128), sigma_d,
+            jnp.float32(1.0))
+    sigma, moved = spectral_dc._estimated_shift(broken, *args)
+    assert not bool(moved) and float(sigma) == float(sigma_d)
+    sigma, moved = spectral_dc._estimated_shift(
+        lambda x: x * lam[None, :], *args)
+    assert bool(moved) and np.isfinite(float(sigma))
+    assert float(sigma) != float(sigma_d)
+
+
+@pytest.mark.parametrize("bucket,leaf,rung", [
+    (8192, 256, 4224), (4224, 256, 2176), (2176, 256, 1152),
+    (1152, 256, None), (640, 256, None), (384, 256, None),
+    (256, 32, 128), (128, 32, None)])
+def test_a_bucket_estimates_from_eight_leaves_up(bucket, leaf, rung):
+    """ISSUE 43: the cells' 8192, 4224 and 2176 and the rehearsal's
+    root estimate; under eight leaves `dc_sign` gets no rung and is
+    the parent's code, with no estimate compiled into it."""
+    n = 8192 if leaf == 256 else 256
+    ladder = spectral_dc._bucket_ladder(n, leaf)
+    got = spectral_dc._rung_under(ladder, bucket, leaf)
+    assert got == rung and (rung is None or got.dtype == np.int32)
+
+
+@pytest.mark.parametrize("law,seed", [("geo", 363), ("wigner", 364),
+                                      ("repeated", 365)])
+def test_eigh_dc_with_the_estimate_in_every_large_bucket(bus, law, seed):
+    """n=512 with leaves of 32: the root and the buckets of 384 and 256
+    rows all estimate (256 rows is eight leaves). Every law stays inside
+    `test_eigh_dc_is_a_backward_stable_eigendecomposition`'s limits,
+    and the agenda counts what the device says it did."""
+    n = 512
+    assert spectral_dc._bucket_ladder(n, LEAF) == [32, 128, 256, 384]
+    a = matrix(law, seed, n=n)
+    obs.enable()
+    w, v, ok = spectral_dc.eigh_dc(jnp.asarray(a), leaf=LEAF)
+    obs.disable()
+    assert ok is True
+    got = graded(a, np.asarray(w), np.asarray(v))
+    assert all(got[k] <= LIMITS[k] for k in LIMITS), got
+    c = obs.snapshot()["metrics"]["counters"]
+    sizes = [e.args for e in obs.bus_events(cat="phase")
+             if e.name == "heev::split"]
+    big = sum(s["bucket"] >= spectral_dc.SHIFT_MIN_LEAVES * LEAF
+              for s in sizes)
+    assert 1 <= c["heev.shift_estimated"] <= big
+    assert c.get("heev.shift_moved", 0) <= c["heev.shift_estimated"]
+    assert c["heev.full_size_splits"] == \
+        sum(s["bucket"] == n for s in sizes) >= 1
+
+
+def test_sign_split_says_where_its_shift_came_from():
+    """`dc_sign`'s fourth flag: 0 with no rung (and the parent's sign
+    matrix, to the bit: a program of its own, with no estimate in
+    it), 1 or 2 with one; a rung under the `general` flag estimates
+    nothing and shifts nothing, in the program the root split runs."""
+    a = jnp.asarray(matrix("geo", 366))
+    sign = spectral_dc._programs(N)["sign"]
+    no, m = jax.device_put(np.False_), np.int32(N)
+    s0, f0 = sign(a, m, no, np.False_, None, l0=None)
+    assert "eigh" not in str(jax.make_jaxpr(
+        lambda a: spectral_dc.dc_sign(a, m, no, np.False_, None))(a))
+    sigma = np.median(np.diag(np.asarray(a)))
+    want, _, _ = polar.sign_hermitian(
+        a - jnp.float32(sigma) * jnp.eye(N, dtype=jnp.float32))
+    assert int(f0[3]) == 0 and np.array_equal(np.asarray(s0),
+                                              np.asarray(want))
+    s1, f1 = sign(a, m, no, np.False_, np.int32(128), l0=None)
+    assert int(f1[3]) in (1, 2)
+    # the sign matrix counts the eigenvalues under the shift it took
+    w64 = np.linalg.eigvalsh(np.asarray(a, np.float64))
+    under = (N - float(np.trace(np.asarray(s1)))) / 2
+    if int(f1[3]) == 2:     # 128 of 256 is no room: the larger child
+        assert abs(max(under, N - under) / N     # goes over the rung
+                   - (0.5 + spectral_dc.SHIFT_ROOM)) <= 0.2
+    else:
+        assert abs(under - (w64 < sigma).sum()) <= 1
+    g0, fg0 = sign(a, m, no, np.True_, None, l0=None)
+    g1, fg1 = sign(a, m, no, np.True_, np.int32(128), l0=None)
+    assert int(fg0[3]) == int(fg1[3]) == 0
+    assert np.array_equal(np.asarray(g0), np.asarray(g1))
+    assert np.array_equal(np.asarray(g0),
+                          np.asarray(polar.polar_unitary(a)[0]))
+    # two programs: with the estimate and without
+    assert sign._cache_size() == 2
+
+
 # -- route, spans, counters ------------------------------------------------
 
 def test_heev_takes_the_route_on_size_and_dtype(bus, tuned):
@@ -258,6 +550,12 @@ def test_heev_takes_the_route_on_size_and_dtype(bus, tuned):
     assert all(s["size"] <= s["bucket"] for s in sizes)
     assert sum(s["size"] for s in sizes) == c["heev.split_rows_true"]
     assert 3 <= c["heev.polar_iters"] / c["heev.splits"] <= 14
+    # PR 43: the splits in the root's bucket, and those that estimated
+    # (the rehearsal's root alone: 256 rows is eight leaves)
+    assert c["heev.full_size_splits"] == \
+        sum(s["bucket"] == N for s in sizes) >= 1
+    assert c["heev.shift_estimated"] == c["heev.full_size_splits"]
+    assert c.get("heev.shift_moved", 0) <= c["heev.shift_estimated"]
     # under the threshold, and for a complex matrix, XLA's own eigh
     obs_events.clear()
     heev(matrix("wigner", 341, n=64), mb=32)
@@ -566,6 +864,31 @@ def test_heev_metrics_by_hand():
     assert heevtrace.pad_rows_share(run) == 25.0
 
 
+@pytest.mark.parametrize("solves", ["heev.solves", "svd.solves"])
+def test_full_size_splits_by_hand(solves):
+    """PR 43's metric, from its own file: the window's splits in the
+    root's bucket over its solves, under either driver's count; left
+    out where the program publishes no such counter (the parent)."""
+    entry = next(m for m in BENCH["per_layer"]
+                 if m["name"] == "heev.full_size_splits")
+    assert entry == {
+        "name": "heev.full_size_splits", "unit": "count",
+        "better": "lower", "source": "program_counter", "layer": "kernels",
+        "moves": "stream_solve_s",
+        "workloads": ["incore-heev", "incore-svd"]}
+    assert BENCH["per_layer"][-1] is entry
+    compute = bench_run.load_module("layer_metrics",
+                                    "heev.full_size_splits").compute
+    run = _run(None)
+    assert compute(run) is None
+    run["counters"] = {solves: 9, "heev.splits": 414}
+    assert compute(run) is None             # the parent's counters
+    run["counters"]["heev.full_size_splits"] = 18
+    assert compute(run) == 2.0
+    run["counters"] = {"heev.full_size_splits": 18}
+    assert compute(run) is None
+
+
 @pytest.mark.parametrize("name", METRICS)
 def test_heev_metric_is_found_and_silent_without_a_trace(name):
     entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
@@ -637,6 +960,7 @@ def test_rehearsal_takes_the_cells_route_and_publishes_its_spans(tmp_path):
     assert window["compiles_in_window"]["programs"] == 0
     assert 3 <= last["metrics"]["heev.polar_iters_per_split"]["value"] <= 14
     assert 0 <= last["metrics"]["heev.pad_rows_share"]["value"] < 100
+    assert 1 <= last["metrics"]["heev.full_size_splits"]["value"] <= 4
     xplane = next(ln["xplane"] for ln in lines if ln.get("phase") == "trace")
     seen = {e[2]: e for e in heevtrace.host_events(
         reduce_trace.load(xplane))}

@@ -182,7 +182,7 @@ def test_one_executable_a_bucket_serves_both_callers(tuned):
     assert traced >= 1
     h = (a + a.T) * np.float32(0.5)
     st.heev(st.HermitianMatrix(st.Uplo.Lower, h, mb=64))
-    up, flags = spectral_dc.polar_general(jnp.asarray(a))
+    up, flags = spectral_dc.polar_general(jnp.asarray(a), LEAF)
     assert sign._cache_size() == traced
     # and what the general caller gets is the orthogonal polar factor
     up, flags = np.asarray(up, np.float64), np.asarray(flags)
@@ -194,7 +194,7 @@ def test_one_executable_a_bucket_serves_both_callers(tuned):
     assert not np.array_equal(up, up.T)
     # the Hermitian caller's answer is symmetric
     s_h, _ = sign(jnp.asarray(h), np.int32(N), jax.device_put(np.False_),
-                  np.False_, l0=None)
+                  np.False_, np.int32(128), l0=None)
     assert np.array_equal(np.asarray(s_h), np.asarray(s_h).T)
     assert sign._cache_size() == traced
 
