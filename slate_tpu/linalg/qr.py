@@ -23,6 +23,7 @@ trading a small recompute for not storing mt*nb^2 of T tiles in HBM).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -556,12 +557,97 @@ def _unmqr_scan(a: jax.Array, taus: jax.Array, nb: int, kmax: int,
     return jax.lax.fori_loop(0, nt, step, c)
 
 
+@functools.partial(jax.jit, static_argnames=("nb", "kmax", "left", "trans"))
+def _unmqr_apply(a: jax.Array, taus: jax.Array, c_log: jax.Array, *,
+                 nb: int, kmax: int, left: bool, trans: bool
+                 ) -> jax.Array:
+    """The packed-factor apply of unmqr as ONE compiled program: C is
+    padded to the factor's padded extent on the applied side, the
+    ceil(kmax / nb) compact-WY panel updates run unrolled over the
+    SHRINKING panels a[k0:, k0:k1] (reflector k has no rows above its
+    diagonal, so panel k touches rows, or columns, k0: of C only),
+    and the result is cropped back to C's logical shape. Called
+    eagerly it is one dispatch where the same loop run from Python
+    is some 50 a panel, each a gap on the device (PERF.md, PR 44: 398
+    of tall-gels' 440 launches, the chip idle 0.09 s of a 0.52 s
+    solve between them); under a caller's trace it inlines. Static
+    on what shapes the program: the panel width, the reflector
+    count, the side and the op."""
+    HI = jax.lax.Precision.HIGHEST
+    cm, cn = c_log.shape
+    nt = ceil_div(kmax, nb)
+    c = _pad_applied_side(c_log, a.shape[0], left)
+    # Left Q^H C and right C Q consume panels forward; the other two in
+    # reverse (Q = Q_1 Q_2 ... Q_nt from geqrf).
+    forward = trans if left else not trans
+    for k in (range(nt) if forward else reversed(range(nt))):
+        k0, k1 = k * nb, min((k + 1) * nb, kmax)
+        V = _panel_V(a[k0:, k0:k1], 0)
+        T = _larft(V, taus[k0:k1])
+        Tm = jnp.conj(T.T) if trans else T
+        if left:
+            Ck = c[k0:, :]
+            W = jnp.matmul(jnp.conj(V.T), Ck, precision=HI)
+            W = jnp.matmul(Tm, W, precision=HI)
+            c = c.at[k0:, :].set(Ck - jnp.matmul(V, W, precision=HI))
+        else:
+            Ck = c[:, k0:]
+            W = jnp.matmul(Ck, V, precision=HI)
+            W = jnp.matmul(W, Tm, precision=HI)
+            c = c.at[:, k0:].set(
+                Ck - jnp.matmul(W, jnp.conj(V.T), precision=HI))
+    return c[:cm, :cn]
+
+
+def _pad_applied_side(c: jax.Array, M: int, left: bool) -> jax.Array:
+    """C padded to the factor's padded extent M on the applied side:
+    V's padded rows are zero, so the extra rows (columns) stay zero
+    through the updates."""
+    cm, cn = c.shape
+    return jnp.pad(c, ((0, M - cm), (0, 0)) if left
+                   else ((0, 0), (0, M - cn)))
+
+
+def _unmqr_form(A: QRFactors) -> Tuple[str, int]:
+    """(form, nt): which of unmqr's three applies these factors take,
+    and over how many panels. `explicit`: one product with the
+    factors' own Q (the mesh TSQR's). `scan`: one compiled panel step
+    under fori_loop (_unmqr_scan), past QR_SCAN_THRESHOLD panels.
+    `compiled`: one program unrolled over the shrinking panels
+    (_unmqr_apply), everywhere else."""
+    if A.Q is not None:
+        return "explicit", 1
+    r = A.QR.resolve()
+    nt = ceil_div(max(min(r.m, r.n), 1), r.nb)
+    # M >= nt*nb guarantees every rolled panel keeps its unit diagonal
+    # inside live rows (always true for square tiles; odd mb<nb pads
+    # fall back to the unrolled form)
+    if nt > QR_SCAN_THRESHOLD and r.data.shape[0] >= nt * r.nb:
+        return "scan", nt
+    return "compiled", nt
+
+
 def unmqr(side: Side, A: QRFactors, C: TiledMatrix, trans: bool = True,
           opts: OptionsLike = None) -> TiledMatrix:
     """Multiply C by Q or Q^H from geqrf (reference src/unmqr.cc,
-    slate.hh:960). trans=True applies Q^H (the gels case). Explicit-Q
-    factors (the Fused path) apply by one matmul."""
-    if A.Q is not None:
+    slate.hh:960). trans=True applies Q^H (the gels case).
+
+    Three applies (_unmqr_form). Explicit-Q factors (the mesh TSQR's)
+    apply by one matmul. Packed factors apply as ONE compiled program,
+    _unmqr_apply: the compact-WY panel loop unrolled over the
+    shrinking panels, the pad of C and the crop with it, so an eager
+    caller dispatches once and the device does not wait between some
+    50 small operations a panel.
+    Past QR_SCAN_THRESHOLD panels the program's size is bounded
+    instead by _unmqr_scan, one panel step under fori_loop. The scan
+    form is NOT the default: a fixed-shape step rolls every panel to
+    the full height M and updates all of C, so it multiplies
+    nt * M * nb * w where the shrinking panels need half of that on a
+    square factor, and it carries two rolls of C a step; it exists
+    for the step counts at which an unrolled program would not
+    compile in reasonable time or memory."""
+    form, nt = _unmqr_form(A)
+    if form == "explicit":
         HI = jax.lax.Precision.HIGHEST
         q = A.Q.to_dense()
         # square Q: the classical orthogonal apply. A THIN (M, K) Q
@@ -588,49 +674,17 @@ def unmqr(side: Side, A: QRFactors, C: TiledMatrix, trans: bool = True,
         return _store(C, fit(y, cn, 1))
     r = A.QR.resolve()
     a = r.data
-    M = a.shape[0]
     nb = r.nb
     kmax = max(min(r.m, r.n), 1)     # number of reflectors (logical)
-    nt = ceil_div(kmax, nb)
     c_log = C.to_dense()
-    cm, cn = c_log.shape
     left = side is Side.Left
-    # pad C to the factor's padded extent on the applied side; V's padded
-    # rows are zero so the extra rows/cols stay zero through the updates
-    if left:
-        c = jnp.pad(c_log, ((0, M - cm), (0, 0)))
-    else:
-        c = jnp.pad(c_log, ((0, 0), (0, M - cn)))
-    # Left Q^H C and right C Q consume panels forward; the other two in
-    # reverse (Q = Q_1 Q_2 ... Q_nt from geqrf).
-    forward = trans if left else not trans
-    # M >= nt*nb guarantees every rolled panel keeps its unit diagonal
-    # inside live rows (always true for square tiles; odd mb<nb pads
-    # fall back to the unrolled form)
-    if nt > QR_SCAN_THRESHOLD and M >= nt * nb:
-        c = _unmqr_scan(a, A.taus, nb, kmax, c, left, trans, forward)
-        return _store(C, c[:cm, :cn])
-    order = range(nt) if forward else reversed(range(nt))
-    for k in order:
-        k0, k1 = k * nb, min((k + 1) * nb, kmax)
-        panel = a[k0:, k0:k1]
-        V = _panel_V(panel, 0)
-        T = _larft(V, A.taus[k0:k1])
-        Tm = jnp.conj(T.T) if trans else T
-        if left:
-            Ck = c[k0:, :]
-            W = jnp.matmul(jnp.conj(V.T), Ck,
-                           precision=jax.lax.Precision.HIGHEST)
-            W = jnp.matmul(Tm, W, precision=jax.lax.Precision.HIGHEST)
-            c = c.at[k0:, :].set(
-                Ck - jnp.matmul(V, W, precision=jax.lax.Precision.HIGHEST))
-        else:
-            Ck = c[:, k0:]
-            W = jnp.matmul(Ck, V, precision=jax.lax.Precision.HIGHEST)
-            W = jnp.matmul(W, Tm, precision=jax.lax.Precision.HIGHEST)
-            c = c.at[:, k0:].set(
-                Ck - jnp.matmul(W, jnp.conj(V.T),
-                                precision=jax.lax.Precision.HIGHEST))
+    if form == "compiled":
+        return _store(C, _unmqr_apply(a, A.taus, c_log, nb=nb, kmax=kmax,
+                                      left=left, trans=trans))
+    cm, cn = c_log.shape
+    c = _unmqr_scan(a, A.taus, nb, kmax,
+                    _pad_applied_side(c_log, a.shape[0], left), left, trans,
+                    forward=trans if left else not trans)
     return _store(C, c[:cm, :cn])
 
 
@@ -867,6 +921,8 @@ def gels_qr(A: TiledMatrix, B: TiledMatrix,
     with span("gels::geqrf"):
         F = geqrf(A, opts)
     with span("gels::unmqr"):
+        form, nt = _unmqr_form(F)
+        obs_events.note(unmqr=form, unmqr_nt=nt)
         QtB = unmqr(Side.Left, F, B, trans=True, opts=opts)
     R = dataclasses.replace(F.QR.resolve(), mtype=MatrixType.Triangular,
                             uplo=Uplo.Upper, diag=Diag.NonUnit)

@@ -178,6 +178,46 @@ def host_plane(tmp_path):
     return run
 
 
+@pytest.fixture
+def dispatches_under(tmp_path):
+    """`dispatches_under(body, names)`: run `body()` under a profiler
+    session and return {span name: [the jitted functions dispatched
+    while that span was open, in order], one list a span, in start
+    order}. The profiler's host plane has a `PjitFunction(name)` event
+    for each dispatch of a jitted function and, with the bus on, each
+    bus span on the same clock: how a test counts the compiled
+    programs a step holds once nothing is left to trace."""
+    def run(body, names):
+        from benchmarks.lib import reduce_trace
+        from benchmarks.lib.tracer import Tracer
+        tr = Tracer(str(tmp_path / "trace"))
+        tr.start()
+        try:
+            body()
+        finally:
+            tr.stop()
+        spans, calls = [], []
+        for plane in reduce_trace.load(tr.xplane()).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    ev = (e.start_ns, e.start_ns + e.duration_ns, e.name)
+                    if e.name in names:
+                        spans.append(ev)
+                    elif e.name.startswith("PjitFunction("):
+                        calls.append(ev[:2] + (e.name[13:-1],))
+        # the runtime marks a dispatch twice, one event inside the other
+        calls.sort()
+        calls = [c for prev, c in zip([(0, 0, "")] + calls, calls)
+                 if prev[1] < c[1]]
+        held = {n: [] for n in names}
+        for s0, s1, name in sorted(spans):
+            held[name].append([c for t, _, c in calls if s0 <= t < s1])
+        return held
+    return run
+
+
 @pytest.fixture(scope="session")
 def grid8():
     import slate_tpu as st

@@ -438,51 +438,27 @@ def test_getrf_rerun_compiles_nothing(rng, obs_on):
         > before["jit.backend_compile_seconds"]
 
 
-def test_getrf_carry_spans_hold_one_program_each(rng, obs_on, tmp_path):
+def test_getrf_carry_spans_hold_one_program_each(rng, obs_on,
+                                                 dispatches_under):
     """On a second call (nothing left to trace) every step span of
     the carry form still opens at run time, and the host dispatches
     one compiled program under each: `getrf::reorder` holds
     `_carry_finish` alone, where it held some thirty eager index
-    operations a panel. Read from the profiler's host plane, where
-    each dispatch of a jitted function is a `PjitFunction(name)`
-    event and each bus span an annotation on the same clock."""
-    from benchmarks.lib import reduce_trace
-    from benchmarks.lib.tracer import Tracer
+    operations a panel. Read from the profiler's host plane
+    (conftest's `dispatches_under`)."""
     A, opts = _carry_case(rng)        # 256 x 200 at nb 64: 4 steps
     st.getrf(A, opts)
     obs_on.clear()
-    tr = Tracer(str(tmp_path / "trace"))
-    tr.start()
-    try:
-        F = st.getrf(A, opts)
-        F.LU.data.block_until_ready()
-    finally:
-        tr.stop()
-    steps = [e.name for e in obs_on.bus_events(cat="step")]
+
+    def factor():
+        st.getrf(A, opts).LU.data.block_until_ready()
+
     want = {"getrf::panel": 4, "getrf::pivots": 4, "getrf::update": 3,
             "getrf::reorder": 1}
+    held = dispatches_under(factor, set(want))
+    steps = [e.name for e in obs_on.bus_events(cat="step")]
     assert {n: steps.count(n) for n in want} == want
-    spans, calls = [], []
-    for plane in reduce_trace.load(tr.xplane()).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name in want:
-                    spans.append((e.start_ns, e.start_ns + e.duration_ns,
-                                  e.name))
-                elif e.name.startswith("PjitFunction("):
-                    calls.append((e.start_ns, e.start_ns + e.duration_ns,
-                                  e.name[13:-1]))
-    # the runtime marks a dispatch twice, one event inside the other
-    calls.sort()
-    calls = [c for prev, c in zip([(0, 0, "")] + calls, calls)
-             if prev[1] < c[1]]
-    assert sorted(n for _, _, n in spans) == sorted(
-        n for n, c in want.items() for _ in range(c))
-    held = {n: [] for n in want}
-    for s0, s1, name in sorted(spans):
-        held[name].append([c for t, _, c in calls if s0 <= t < s1])
+    assert {n: len(held[n]) for n in want} == want
     assert held["getrf::reorder"] == [["_carry_finish"]]
     assert held["getrf::panel"] == [["_carry_panel"]] * 4
     # the last step of a tall matrix has no columns to its right

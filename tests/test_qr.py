@@ -192,6 +192,55 @@ def test_unmqr_scan_matches_unrolled(rng, monkeypatch):
                                rtol=1e-8, atol=1e-9)
 
 
+@pytest.mark.parametrize("shape", [(64, 48), (72, 44)],
+                         ids=["whole_panels", "ragged_last_panel"])
+@pytest.mark.parametrize("dtype", [np.float64, np.complex128],
+                         ids=["real", "complex"])
+@pytest.mark.parametrize("trans", [True, False], ids=["QH", "Q"])
+@pytest.mark.parametrize("side", [Side.Left, Side.Right],
+                         ids=["left", "right"])
+def test_unmqr_compiled_apply_matches_explicit_q(rng, side, trans, dtype,
+                                                 shape):
+    """The packed-factor apply is ONE compiled program (qr._unmqr_apply:
+    the pad of C, the unrolled loop over the shrinking panels, the
+    crop). Every side and op, real and complex, with kmax a multiple
+    of nb (48 = 3 x 16) and not (44: a last panel 12 wide), gives
+    what the explicit Q gives: Q from the same apply on the identity,
+    itself held to Q R = A and Q^H Q = I. The tolerance is
+    test_unmqr_scan_matches_unrolled's. A caller already under a
+    trace gets the same numbers (the program inlines)."""
+    import jax
+
+    from slate_tpu.linalg import qr as qrmod
+    m, n = shape
+    nb, r = 16, 5
+
+    def draw(*dims):
+        x = rng.standard_normal(dims)
+        if dtype is np.complex128:
+            x = x + 1j * rng.standard_normal(dims)
+        return x.astype(dtype)
+
+    a = draw(m, n)
+    F = st.geqrf(M(a, nb))
+    assert qrmod._unmqr_form(F) == ("compiled", -(-n // nb))
+    Q = st.unmqr(Side.Left, F, M(np.eye(m, dtype=dtype), nb),
+                 trans=False).to_numpy()
+    np.testing.assert_allclose(Q.conj().T @ Q, np.eye(m), atol=1e-12)
+    np.testing.assert_allclose(Q[:, :n] @ np.triu(F.QR.to_numpy()[:n]), a,
+                               rtol=1e-11, atol=1e-12)
+    op = Q.conj().T if trans else Q
+    c = draw(m, r) if side is Side.Left else draw(r, m)
+    want = op @ c if side is Side.Left else c @ op
+    got = st.unmqr(side, F, M(c, nb), trans=trans)
+    assert got.to_numpy().shape == c.shape
+    np.testing.assert_allclose(got.to_numpy(), want, rtol=1e-11, atol=1e-12)
+    traced = jax.jit(lambda f, x: st.unmqr(side, f, M(x, nb),
+                                           trans=trans).data)(F, c)
+    np.testing.assert_allclose(np.asarray(traced), np.asarray(got.data),
+                               rtol=1e-11, atol=1e-12)
+
+
 def test_geqrf_fused_packed(rng):
     """MethodFactor.Fused geqrf = one whole-matrix native geqrf with
     the PACKED Householder contract (the explicit-Q form was retired:

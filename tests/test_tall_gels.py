@@ -222,6 +222,31 @@ def test_gels_spans_reach_the_host_plane(bus, host_plane):
     assert max(e[1] for e in by_name["matrix::h2d"]) <= root[0]
 
 
+def test_unmqr_span_holds_one_compiled_program(bus, dispatches_under):
+    """On a second call (nothing left to trace) the span `gels::unmqr`
+    of a rehearsal-sized solve holds ONE dispatch of the compiled
+    apply, `_unmqr_apply`, and beside it only the slice that takes
+    B's logical columns and the pad that stores the result: at most
+    nt + 2 dispatches, where the panel loop run from Python held some
+    dozens a panel (PR 44; after test_lu's
+    test_getrf_carry_spans_hold_one_program_each)."""
+    rh = CFG["rehearsal"]
+    m, n, mb = rh["m"], rh["n"], rh["mb"]
+    assert (m, n, mb) == (4096, 256, 64)
+    nt = n // mb
+    a, b = problem(39, m, n, CFG["matrix"]["cond"], nrhs=rh["nrhs"])
+    gels(a, b, mb=mb)
+    obs.enable()
+    (held,) = dispatches_under(lambda: gels(a, b, mb=mb),
+                               {"gels::unmqr"})["gels::unmqr"]
+    route = driver_span("gels")
+    assert route["method"] == "qr"
+    assert route["unmqr"] == "compiled" and route["unmqr_nt"] == nt
+    assert held.count("_unmqr_apply") == 1, held
+    assert len(held) <= nt + 2, held
+    assert set(held) <= {"_unmqr_apply", "dynamic_slice", "_pad"}, held
+
+
 # -- the comparison that decides `correct` ---------------------------------
 
 def rehearsal_cell(seed):
