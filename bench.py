@@ -32,7 +32,7 @@ the run uses the backend jax finds and fails where it finds none.
 
 Flags (combinable with the default sweep unless noted): ``--micro``
 ``--tune`` ``--ooc`` ``--serve`` ``--serve-daemon`` ``--shard``
-``--faults`` ``--graph`` ``--lint``
+``--faults`` ``--lint``
 run their own suites; ``--obs`` enables the observability bus for the
 whole run, ships the metrics/driver/analysis snapshot in the headline
 extras, AND runs the **regression leg** (ISSUE 14): the current run's
@@ -1532,188 +1532,6 @@ def bench_shard():
     return 0
 
 
-def bench_graph():
-    """`--graph`: the task-graph runtime (ISSUE 17) — scheduler
-    "graph" vs the FROZEN "walk" on the same problems, single-engine
-    and sharded. Reports per-leg wall, node counts, and the pure
-    issue-loop overhead per node (sched.issue_overhead_seconds /
-    sched.nodes_issued — the scheduling cost the construct-then-
-    execute route adds over the hand-written loops). GATES on (a)
-    bitwise equality of every graph/walk pair, (b) the sharded graph
-    leg staging exactly the ownership schedule's (depth-invariant)
-    byte prediction, and (c) >= 95% of the graph sharded potrf wall
-    attributed to named ledger phases — the flight-recorder contract
-    carried onto the graph route (node kinds map 1:1 onto PHASES).
-    Walls are REPORTED, not gated (2-core-box flap; the TPU round
-    judges them)."""
-    import numpy as np
-    from slate_tpu import obs
-    import slate_tpu as st
-    from slate_tpu.dist import shard_ooc
-    from slate_tpu.linalg import ooc
-    from slate_tpu.obs import metrics as om
-
-    obs.enable()
-    try:
-        n = int(os.environ.get("SLATE_GRAPH_N", "1024"))
-    except ValueError:
-        n = 1024
-    w = max(n // 8, 32)
-    nt = (n + w - 1) // w
-    grid = st.make_grid()
-    rng = np.random.default_rng(0)
-    x = rng.standard_normal((n, n)).astype(np.float32)
-    a = x @ x.T / n + 4.0 * np.eye(n, dtype=np.float32)
-    g = x + 0.2 * n * np.eye(n, dtype=np.float32)
-    budget = 64 * n * w * 4
-    extras = {"n": n, "panel_cols": w, "nt": nt,
-              "grid": [grid.p, grid.q],
-              "cache_budget_bytes": budget}
-
-    def counters():
-        return dict(om.snapshot()["counters"])
-
-    results = {}
-
-    def run(name, fn):
-        c0 = counters()
-        t0 = time.perf_counter()
-        try:
-            out = fn()
-        except Exception as e:
-            extras["%s_error" % name] = str(e)[:160]
-            emit({"graph": name, "error": str(e)[:160]})
-            return None
-        wall = time.perf_counter() - t0
-        c1 = counters()
-        nodes = int(c1.get("sched.nodes_issued", 0)
-                    - c0.get("sched.nodes_issued", 0))
-        over = float(c1.get("sched.issue_overhead_seconds", 0)
-                     - c0.get("sched.issue_overhead_seconds", 0))
-        rec = {"wall_s": round(wall, 4),
-               "h2d_bytes": int(c1.get("ooc.h2d_bytes", 0)
-                                - c0.get("ooc.h2d_bytes", 0)),
-               "nodes_issued": nodes,
-               "issue_overhead_s": round(over, 6),
-               "issue_overhead_per_node_us":
-                   round(1e6 * over / nodes, 3) if nodes else 0.0}
-        extras[name] = rec
-        emit(dict({"graph": name}, **rec))
-        results[name] = out
-        return out
-
-    # single-engine pairs (same budget, walk then graph)
-    run("potrf_walk",
-        lambda: ooc.potrf_ooc(a, panel_cols=w,
-                              cache_budget_bytes=budget,
-                              scheduler="walk"))
-    run("potrf_graph",
-        lambda: ooc.potrf_ooc(a, panel_cols=w,
-                              cache_budget_bytes=budget,
-                              scheduler="graph"))
-    run("geqrf_walk",
-        lambda: ooc.geqrf_ooc(g, panel_cols=w,
-                              cache_budget_bytes=budget,
-                              scheduler="walk"))
-    run("geqrf_graph",
-        lambda: ooc.geqrf_ooc(g, panel_cols=w,
-                              cache_budget_bytes=budget,
-                              scheduler="graph"))
-    run("getrf_walk",
-        lambda: ooc.getrf_tntpiv_ooc(g, panel_cols=w,
-                                     cache_budget_bytes=budget,
-                                     scheduler="walk"))
-    run("getrf_graph",
-        lambda: ooc.getrf_tntpiv_ooc(g, panel_cols=w,
-                                     cache_budget_bytes=budget,
-                                     scheduler="graph"))
-    # sharded pair at lookahead 1 (the depth where the graph's
-    # slot-keyed issue order actually interleaves work)
-    run("potrf_shard_walk",
-        lambda: shard_ooc.shard_potrf_ooc(
-            a, grid, panel_cols=w, cache_budget_bytes=budget,
-            lookahead=1, scheduler="walk"))
-    run("potrf_shard_graph",
-        lambda: shard_ooc.shard_potrf_ooc(
-            a, grid, panel_cols=w, cache_budget_bytes=budget,
-            lookahead=1, scheduler="graph"))
-
-    ok = True
-    for base in ("potrf", "geqrf", "getrf"):
-        wv, gv = results.get(base + "_walk"), \
-            results.get(base + "_graph")
-        if wv is None or gv is None:
-            ok = False
-            continue
-        if base == "potrf":
-            bit = bool(np.array_equal(wv, gv))
-        else:
-            bit = bool(np.array_equal(np.asarray(wv[0]),
-                                      np.asarray(gv[0]))
-                       and np.array_equal(np.asarray(wv[1]),
-                                          np.asarray(gv[1])))
-        extras["%s_graph_bitwise" % base] = bit
-        ok &= bit
-    if results.get("potrf_shard_walk") is not None \
-            and results.get("potrf_shard_graph") is not None:
-        bit = bool(np.array_equal(results["potrf_shard_walk"],
-                                  results["potrf_shard_graph"]))
-        extras["potrf_shard_graph_bitwise"] = bit
-        ok &= bit
-        sched = shard_ooc.CyclicSchedule(nt, grid)
-        expect = sched.staged_bytes(
-            {k: n - k * w for k in range(nt)}, w,
-            n - (nt - 1) * w, 4, depth=1)
-        exact = extras["potrf_shard_graph"]["h2d_bytes"] == expect
-        extras["potrf_shard_graph_h2d_exact_schedule"] = exact
-        ok &= exact
-    else:
-        ok = False
-    # walk-vs-graph wall + per-node overhead summary (reported)
-    for pair in (("potrf", "potrf"), ("potrf_shard", "potrf_shard")):
-        wrec = extras.get(pair[0] + "_walk")
-        grec = extras.get(pair[1] + "_graph")
-        if wrec and grec and wrec["wall_s"] > 0:
-            extras["%s_graph_wall_ratio" % pair[1]] = round(
-                grec["wall_s"] / wrec["wall_s"], 4)
-
-    # ledger attribution on the GRAPH route (ISSUE 17 acceptance):
-    # node frames land in the same closed phase columns as the walk,
-    # so >= 95% of the sharded graph wall stays attributed
-    from slate_tpu.obs import ledger as obs_ledger
-    from slate_tpu.obs import xprof as obs_xprof
-    try:
-        obs_ledger.reset()
-        obs_ledger.enable()
-        t0 = time.perf_counter()
-        shard_ooc.shard_potrf_ooc(a, grid, panel_cols=w,
-                                  cache_budget_bytes=budget,
-                                  lookahead=1, scheduler="graph")
-        wall = time.perf_counter() - t0
-        att = obs_xprof.attribute_run(
-            records=obs_ledger.records("shard_potrf_ooc"))
-        frac = att["total_wall_s"] / wall if wall > 0 else 0.0
-        rec = {"wall_s": round(wall, 4),
-               "ledger_records": att["records"],
-               "attributed_s": att["total_wall_s"],
-               "fraction_attributed": round(frac, 4),
-               "buckets": att["buckets"]}
-        extras["graph_ledger_attribution"] = rec
-        emit(dict({"graph": "ledger_attribution"}, **rec))
-        ok &= frac >= 0.95
-    except Exception as e:
-        extras["graph_ledger_attribution_error"] = str(e)[:160]
-        ok = False
-    finally:
-        obs_ledger.disable()
-        obs_ledger.reset()
-
-    emit({"metric": "graph", "value": 1 if ok else 0,
-          "unit": "suite", "vs_baseline": 1 if ok else 0,
-          "extras": extras})
-    return 0
-
-
 def bench_elastic():
     """`--elastic`: the elastic mesh (ISSUE 19) — throughput-driven
     panel re-ownership under a seeded straggler, on a REAL 2-process
@@ -2519,7 +2337,6 @@ def main():
     serve_daemon = "--serve-daemon" in sys.argv[1:]
     shard = "--shard" in sys.argv[1:]
     with_faults = "--faults" in sys.argv[1:]
-    with_graph = "--graph" in sys.argv[1:]
     with_elastic = "--elastic" in sys.argv[1:]
     with_obs = "--obs" in sys.argv[1:]
 
@@ -2527,7 +2344,7 @@ def main():
         # pure AST — runs (and must stay green) with no backend at all
         return bench_lint()
 
-    if (shard or with_faults or with_graph or with_elastic) and (
+    if (shard or with_faults or with_elastic) and (
             os.environ.get("JAX_PLATFORMS", "").startswith("cpu")):
         # the sharded-OOC suite needs a mesh: on the CPU tier pin 8
         # virtual devices BEFORE the in-process backend initializes
@@ -2554,8 +2371,6 @@ def main():
         return bench_shard()
     if with_faults:
         return bench_faults()
-    if with_graph:
-        return bench_graph()
     if with_elastic:
         return bench_elastic()
 

@@ -474,42 +474,6 @@ class MethodLUPivot(enum.Enum):
         return MethodLUPivot.Partial if m is MethodLUPivot.Auto else m
 
 
-class MethodScheduler(enum.Enum):
-    """Issue-loop scheduler of the streaming OOC drivers (ISSUE 17):
-
-      * ``Walk``: the hand-written static schedules — the
-        single-engine left-looking loops in linalg/ooc.py and the
-        ``_BcastPipeline`` walk in dist/shard_ooc.py, untouched;
-      * ``Graph``: construct-then-execute through the task-graph
-        runtime (slate_tpu/sched/) — the same loop bodies as typed
-        dependency-graph nodes, issued by sched/runtime.py in an
-        order that is a linear extension of the walk's (bitwise-equal
-        results, pinned per op / per lookahead depth, single-engine
-        and sharded).
-
-    ``Auto`` resolves through the tune cache (the ``ooc/scheduler``
-    tunable; FROZEN default "walk"), so a COLD CACHE keeps the legacy
-    walks bit-identically — the graph route is an earned (measured,
-    ``bench.py --graph``) or explicit decision, pinned by tests."""
-    Auto = "auto"
-    Walk = "walk"
-    Graph = "graph"
-
-    @staticmethod
-    def resolve(n: int, dtype) -> "MethodScheduler":
-        """The tuned/frozen ``ooc/scheduler`` route (unknown values
-        from a newer cache demote to the frozen Walk, never an
-        error)."""
-        from ..tune.select import resolve as _resolve
-        try:
-            m = str2method("scheduler", str(_resolve(
-                "ooc", "scheduler", n=n, dtype=dtype)))
-        except KeyError:
-            m = MethodScheduler.Walk
-        return MethodScheduler.Walk if m is MethodScheduler.Auto \
-            else m
-
-
 class MethodOwnership(enum.Enum):
     """Panel-ownership policy of the sharded OOC stream (ISSUE 19):
 
@@ -572,8 +536,7 @@ def str2method(family: str, s: str):
         "factor": MethodFactor, "eig": MethodEig, "svd": MethodSVD,
         "lu_panel": MethodLUPanel, "ooc": MethodOOC,
         "lu_pivot": MethodLUPivot, "precision": MethodPrecision,
-        "batch": MethodBatchStrategy, "scheduler": MethodScheduler,
-        "ownership": MethodOwnership,
+        "batch": MethodBatchStrategy, "ownership": MethodOwnership,
     }[family]
     for mem in fam:
         if mem.value.lower() == s.lower() or mem.name.lower() == s.lower():

@@ -56,7 +56,7 @@ same jitted kernels on bitwise-equal operands (fresh frames from the
 broadcast, or durable-mirror replays that the resil contract already
 pins bitwise), so elastic output equals static output even when
 remaps fire; with uniform throughput the planner never fires and the
-execution is the static graph route panel for panel.
+execution is the static stream's panel for panel.
 
 Shrink-to-fit resume (:func:`shrink_to_fit`): a ``WorkerLost`` from a
 multiproc launch no longer means a full-mesh abort — the survivors
@@ -450,15 +450,14 @@ def run_elastic(ctrl: ElasticController, *, op: str, bc, st,
     ``applied_through`` pruning the updates earlier segments already
     applied and ``trailing_to`` extending the trailing sweep over the
     whole stream — so within a segment every trailing panel absorbs
-    exactly the segment's update steps, in the walk's ascending
-    order, through the walk's closures (bitwise). At each boundary
+    exactly the segment's update steps, in ascending order, through
+    the driver's closures (bitwise). At each boundary
     the controller measures, agrees, and maybe remaps; panels moved
     away are dropped from this host's working set (their next owner
     stages them fresh and catches up through durable-mirror replays),
     panels moved here need nothing — the next segment's graph simply
-    contains their catch-up nodes. Elastic always runs the graph
-    route: ownership is a graph-construction input here, which is
-    the whole mechanism."""
+    contains their catch-up nodes: ownership is a graph-construction
+    input here, which is the whole mechanism."""
     from ..sched import policies as _policies
     from ..sched.runtime import execute as _execute
     panels = list(factor_panels)

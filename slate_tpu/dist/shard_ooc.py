@@ -58,34 +58,36 @@ arbitrate through core/methods.MethodOOC — the FROZEN
 ``ooc/shard_method`` default is "stream", so a cold cache keeps the
 single-device path bit-identically even when a grid is supplied.
 
-Lookahead v2 (ISSUE 11): the schedule above is step-synchronous —
-every host idles while panel k's broadcast completes, then idles
-again while the owner of k+1 factors it. SLATE's defining perf trick
-(PAPER.md: the lookahead parameter overlapping critical-path panel
-work with trailing updates; BLASX is the multi-accelerator
-communication/computation-overlap precedent) has an exact mesh-scale
-analogue built here as ``_BcastPipeline``: at step k, after frame k
-completes, the owner of panel k+1 applies its OWN k-update first
+Issue order and lookahead: the step-synchronous schedule above idles
+every host while panel k's broadcast completes, then again while the
+owner of k+1 factors it. SLATE's lookahead (PAPER.md: critical-path
+panel work overlapped with trailing updates; BLASX is the
+multi-accelerator precedent) has an exact mesh-scale analogue: at step
+k the owner of panel k+1 applies its OWN k-update first
 (``CyclicSchedule.update_order`` — owned-next-panel-first), factors
-k+1 immediately, and every host dispatches the k+1 broadcast
-asynchronously (``PanelBroadcaster.broadcast_async`` — a second
-in-flight frame buffer, the way linalg/stream.py double-buffers H2D)
-BEFORE running its remaining k-updates; the frame is completed
-(``PanelBroadcaster.complete`` -> dist/tree.complete_schedule) only
-at step k+1, so the collective's wall hides under the update sweep.
-The reordering changes only WHEN identical jitted kernels run, never
-their operands — each trailing panel still receives updates
+k+1, and every host dispatches the k+1 broadcast asynchronously
+(``PanelBroadcaster.broadcast_async``, a second in-flight frame)
+BEFORE its remaining k-updates; the frame is completed only at step
+k+1, so the collective's wall hides under the update sweep. With
+broadcasts in flight the order in which a host may issue its work is
+a partial order, not a loop, so the drivers do not write one: each
+hands ``_run_stream`` its closures, ``sched/policies.sharded_stream``
+builds the stream as a dependency graph in which the lookahead depth
+only moves the slot a panel's factor and broadcast are keyed at, and
+``sched/runtime.execute`` issues whatever is ready in a deterministic
+order. The depth changes only WHEN identical jitted kernels run,
+never their operands — each trailing panel still receives updates
 0..k-1 in ascending order through the same compiled programs — so
 every depth is BITWISE equal to the synchronous schedule (pinned for
-all three drivers, single-engine and on the real 2-process gloo
-mesh). Depth rides the FROZEN ``ooc/shard_lookahead`` = 0 tunable
-(the synchronous schedule bit-identically; depth 1 is the
-earned/explicit setting), the per-step broadcast wait is published
-as the ``shard::bcast_wait`` span + ``ooc.shard.bcast_wait_seconds``
-counter so the overlap fraction is directly attributable, and the
-checkpoint epoch commit trails the deepest in-flight panel (a crash
-with two panels live resumes bitwise — the in-flight panel was never
-claimed durable).
+all three drivers, on one process and on the 2-process gloo mesh).
+Depth rides the FROZEN ``ooc/shard_lookahead`` = 0 tunable, the
+per-step broadcast wait is published as the ``shard::bcast_wait``
+span + ``ooc.shard.bcast_wait_seconds`` counter so the overlap
+fraction is directly attributable, and the checkpoint epoch commit
+trails the deepest in-flight panel (a crash with two panels live
+resumes bitwise — the in-flight panel was never claimed durable).
+The elastic route (dist/elastic.py) builds the same graph once per
+re-ownership segment.
 
 Mixed-precision frames (ISSUE 12): under the ``ooc/precision`` bf16
 mode (FROZEN "f32" — the cold cache keeps every schedule here
@@ -144,6 +146,8 @@ from ..parallel.smap import shard_map
 from ..resil import checkpoint as _ckpt
 from ..resil import faults as _faults
 from ..resil import guard as _guard
+from ..sched.policies import sharded_stream
+from ..sched.runtime import execute
 from . import tree as _tree
 
 
@@ -203,11 +207,10 @@ class CyclicSchedule:
         prefix property is exactly why the lookahead reordering is
         bitwise-safe and why :meth:`staged_bytes`'s walk is
         depth-invariant, and this query is where it is stated and
-        tested rather than assumed. ``_BcastPipeline.updates`` runs
-        the sweep in this order; the prologue's promotion set is the
-        window-∩-owned prefix (computed by ``advance`` as it chains
-        issues). Panels below ``epoch`` are durable on resume and
-        never re-updated (resil/ contract)."""
+        tested rather than assumed. ``sched/policies.sharded_stream``
+        keys the sweep's nodes in this order; the promoted panels
+        are the window-∩-owned prefix. Panels below ``epoch`` are
+        durable on resume and never re-updated (resil/ contract)."""
         todo = [j for j in self.my_panels() if j > k and j >= epoch]
         if depth <= 0:
             return todo
@@ -564,14 +567,12 @@ class _ShardState:
     host-side scratch (`ws`, allocated lazily — only spilled panels
     ever cost host scratch).
 
-    ``upto`` is the in-flight-frame bookkeeping (ISSUE 11): the next
-    update step each owned panel has NOT yet absorbed. The lookahead
-    prologue promotes a panel through its pending frames and marks
-    them applied, so the step's own update sweep skips it — with two
-    panels live at once this is what keeps every panel's per-step
-    update sequence exactly the synchronous walk's (bitwise pin), and
-    what keeps prefetch exact (a promoted panel is `staged`, so the
-    sweep's lookahead never re-stages it)."""
+    ``upto`` is the elastic route's segment bookkeeping
+    (dist/elastic.py run_elastic): the next update step each owned
+    panel has NOT yet absorbed, set at a segment boundary so the next
+    segment's graph prunes the updates already applied. Within one
+    graph a record's consumers are explicit edges and nothing writes
+    it."""
 
     def __init__(self, eng, loader: Callable[[int], Callable],
                  scratch: Callable[[int], Tuple[int, ...]],
@@ -582,14 +583,11 @@ class _ShardState:
         self.dtype = dtype
         self.ws: Dict[int, np.ndarray] = {}
         self.staged: set = set()
-        #: panel -> next update step it still needs (in-flight slot)
+        #: panel -> next update step it still needs
         self.upto: Dict[int, int] = {}
 
     def applied_through(self, j: int) -> int:
         return self.upto.get(j, 0)
-
-    def mark_applied(self, j: int, step: int) -> None:
-        self.upto[j] = step + 1
 
     def spill_view(self, k: int) -> Callable[[], np.ndarray]:
         def view():
@@ -608,18 +606,10 @@ class _ShardState:
         """Exact lookahead by panel index: stage k's first-touch input
         unless it is already staged (re-stages of spilled states
         contend with their own spill writes and stay synchronous).
-        The graph policy binds the prefetch target statically at
-        construction (sched/policies.py), the walk derives it from the
-        live todo list via prefetch_next — same H2D either way."""
+        The graph binds each sweep's target when it is built
+        (sched/policies.py)."""
         if k is not None and k not in self.staged:
             self.eng.prefetch("S", k, self._loader(k), cache=False)
-
-    def prefetch_next(self, todo: List[int], i: int) -> None:
-        """Exact lookahead: stage the next FIRST-TOUCH input this host
-        will need."""
-        self.prefetch_panel(
-            next((j for j in todo[i + 1:] if j not in self.staged),
-                 None))
 
     def stash(self, k: int, arr) -> None:
         self.eng.stash("S", k, arr, self.spill_view(k))
@@ -627,178 +617,6 @@ class _ShardState:
     def discard(self, k: int) -> None:
         self.eng.discard("S", k)
         self.ws.pop(k, None)
-
-
-class _BcastPipeline:
-    """The lookahead-overlapped broadcast schedule (ISSUE 11 tentpole;
-    module doc). Depth 0 IS the step-synchronous schedule — no frame
-    is ever dispatched ahead, bit-identical to the pre-lookahead
-    drivers. Each driver supplies four closures over its own kernels
-    and bookkeeping:
-
-      * ``payload_shape(k)`` -> (shape, dtype) of panel k's broadcast
-        frame (potrf: (n, wk); geqrf/getrf: (m+1, wk) — the extra
-        payload row);
-      * ``make_payload(k, S)`` -> the owner-side device payload from
-        the fully-updated panel state S (factor kernels +
-        guard.check_panel live here);
-      * ``complete(k, replicated)`` -> the step's update record
-        (host-side bookkeeping — taus/pivot materialization, the
-        local factor-mirror write — runs HERE, exactly once per
-        panel, in strictly ascending panel order);
-      * ``replay(k)`` -> the update record from the durable per-host
-        mirror (resume panels below the agreed epoch — no factor
-        work, no broadcast);
-      * ``apply(S, rec, j)`` -> panel j's state after absorbing the
-        record's update (the SAME jitted visit kernel at every
-        depth).
-
-    Step k runs three phases: ``obtain(k)`` (phase 1 — the completed
-    record for panel k: popped from ``done``, completed from
-    ``pending``, replayed, or — synchronous path — factored +
-    broadcast + completed inline), ``advance(k, rec)`` (phase 2 — the
-    lookahead prologue: for each panel in ``(k, k+depth]`` this
-    process owns, promote it through its pending frames via the SAME
-    apply closure (``CyclicSchedule.update_order``'s head — the
-    owned-next-panel-first rule), factor it, and dispatch its
-    broadcast WITHOUT completing it; chaining past depth 1 completes
-    the intermediate frame first, since panel i's factor needs frame
-    i-1's values), then ``updates(k, rec)`` (phase 3 — the trailing
-    sweep over the remaining owned panels, which overlaps every
-    in-flight collective). The per-panel ``step`` fault check fires
-    exactly once per panel, at the slot that PROCESSES it (issue
-    time for ahead panels) — the same ascending once-each sequence as
-    the synchronous walk, so seeded plans stay deterministic across
-    depths while a kill mid-prologue leaves the in-flight panel
-    un-committed (the checkpoint epoch trails it)."""
-
-    def __init__(self, op: str, sched: CyclicSchedule,
-                 bc: PanelBroadcaster, st: _ShardState, depth: int,
-                 epoch: int, factor_panels: List[int],
-                 payload_shape: Callable, make_payload: Callable,
-                 complete: Callable, replay: Callable,
-                 apply: Callable) -> None:
-        self.op = op
-        self.sched = sched
-        self.bc = bc
-        self.st = st
-        self.depth = max(int(depth), 0)
-        self.epoch = int(epoch)
-        self.last = factor_panels[-1] if factor_panels else -1
-        self._payload_shape = payload_shape
-        self._make_payload = make_payload
-        self._complete = complete
-        self._replay = replay
-        self._apply = apply
-        self.pending: Dict[int, _InflightFrame] = {}
-        self.done: Dict[int, Any] = {}
-        self.issued = -1
-        self._checked: set = set()
-
-    def _check(self, k: int) -> None:
-        if k not in self._checked:
-            self._checked.add(k)
-            _faults.check("step", op=self.op, step=k,
-                          mine=bool(self.sched.is_mine(k)))
-
-    def _issue(self, k: int, ahead: bool) -> _InflightFrame:
-        """Dispatch panel k's factor + broadcast. The owner's panel
-        state must already hold updates 0..k-1 (phase-1 history or
-        the prologue's promotion)."""
-        if self.sched.is_mine(k):
-            with _ledger.frame("stage"):
-                S = self.st.take(k)
-            with obs_events.span("shard::factor", cat="shard",
-                                 panel=k, ahead=ahead), \
-                    _ledger.frame("factor"):
-                payload = self._make_payload(k, S)
-            self.st.discard(k)
-        else:
-            payload = None
-        shape, dtype = self._payload_shape(k)
-        return self.bc.broadcast_async(payload,
-                                       self.sched.owner_flat(k),
-                                       shape, dtype, panel=k,
-                                       ahead=ahead)
-
-    def _finish(self, fr: _InflightFrame):
-        return self._complete(fr.panel, self.bc.complete(fr))
-
-    def obtain(self, k: int):
-        """Phase 1: the completed update record for panel k."""
-        self._check(k)
-        if k in self.done:
-            return self.done.pop(k)
-        if k < self.epoch:
-            return self._replay(k)
-        fr = self.pending.pop(k, None)
-        if fr is None:              # synchronous path (depth 0 /
-            fr = self._issue(k, ahead=False)   # the first panel)
-        return self._finish(fr)
-
-    def _promote(self, i: int, k: int, rec) -> None:
-        """Apply every frame panel i has not yet absorbed (steps
-        upto(i)..i-1, ascending — the synchronous walk's per-panel
-        order, bitwise) so its factor sees the finished state."""
-        for s in range(self.st.applied_through(i), i):
-            r = rec if s == k else self.done[s]
-            with _ledger.frame("stage"):
-                S = self.st.take(i)
-            with obs_events.span("shard::update", cat="shard",
-                                 panel=i, step=s, ahead=True), \
-                    _ledger.frame("update"):
-                S = self._apply(S, r, i)
-            self.st.mark_applied(i, s)
-            self.st.stash(i, S)
-
-    def advance(self, k: int, rec) -> None:
-        """Phase 2: pull the issue cursor up to ``min(k + depth,
-        last)`` — the lookahead prologue."""
-        if self.issued < k:
-            self.issued = k
-        limit = min(k + self.depth, self.last)
-        while self.issued < limit:
-            i = self.issued + 1
-            prev = i - 1
-            if prev > k and prev not in self.done:
-                # chain link: panel i's factor (and, for LU/QR, its
-                # host bookkeeping) needs frame i-1 realized first
-                self._check(prev)
-                if prev < self.epoch:
-                    self.done[prev] = self._replay(prev)
-                else:
-                    self.done[prev] = self._finish(
-                        self.pending.pop(prev))
-            if i < self.epoch:
-                # durable on resume: replays at its own step, no
-                # broadcast to pipeline
-                self.issued = i
-                continue
-            self._check(i)
-            if self.sched.is_mine(i):
-                self._promote(i, k, rec)
-            self.pending[i] = self._issue(i, ahead=True)
-            self.issued = i
-
-    def updates(self, k: int, rec) -> None:
-        """Phase 3: the trailing sweep on this host's remaining owned
-        panels — the work every in-flight broadcast hides under."""
-        todo = [j for j in self.sched.update_order(k, self.depth,
-                                                   self.epoch)
-                if self.st.applied_through(j) <= k]
-        t0 = time.perf_counter()
-        for i, j in enumerate(todo):
-            with _ledger.frame("stage"):
-                S_j = self.st.take(j)
-            self.st.prefetch_next(todo, i)
-            with obs_events.span("shard::update", cat="shard",
-                                 panel=j, step=k), \
-                    _ledger.frame("update"):
-                S_j = self._apply(S_j, rec, j)
-            self.st.mark_applied(j, k)
-            self.st.stash(j, S_j)
-        obs_metrics.inc("ooc.shard.update_seconds",
-                        time.perf_counter() - t0)
 
 
 def _publish_overlap(op: str, bc: PanelBroadcaster,
@@ -818,26 +636,41 @@ def _publish_overlap(op: str, bc: PanelBroadcaster,
                        overlap=round(bc.overlap_fraction(), 4))
 
 
-def _run_stream(op: str, use_graph: bool, *, sched, bc, st, depth,
-                epoch, factor_panels, tail_panels, payload_shape,
+def _run_stream(op: str, *, sched, bc, st, depth, epoch,
+                factor_panels, tail_panels, payload_shape,
                 make_payload, complete, replay, apply, tail_step,
                 led, ck, eng, step_obs, nt, elastic=None) -> None:
-    """One issue loop for all three sharded drivers (ISSUE 17): the
-    legacy ``_BcastPipeline`` walk (``scheduler="walk"`` — the frozen
-    cold route, bit-identical to the PR 11-16 drivers), or the
-    task-graph route (``sched/policies.sharded_stream`` constructed
-    once, then ``sched/runtime.execute`` issues ready nodes through
-    the SAME closures). The drivers supply the same five pipeline
-    closures either way plus ``tail_step(k)`` — the m<n tail-panel
-    body (None for potrf, whose every panel factors).
+    """The issue loop of all three sharded drivers: the stream is
+    built once as a dependency graph (``sched/policies.
+    sharded_stream``) and ``sched/runtime.execute`` issues its ready
+    nodes. Each driver supplies five closures over its own kernels
+    and bookkeeping:
+
+      * ``payload_shape(k)`` -> (shape, dtype) of panel k's broadcast
+        frame (potrf: (n, wk); geqrf/getrf: (m+1, wk) — the extra
+        payload row);
+      * ``make_payload(k, S)`` -> the owner-side device payload from
+        the fully-updated panel state S (factor kernels +
+        guard.check_panel live here);
+      * ``complete(k, replicated)`` -> the step's update record
+        (host-side bookkeeping — taus/pivot materialization, the
+        local factor-mirror write — runs HERE, exactly once per
+        panel, in strictly ascending panel order);
+      * ``replay(k)`` -> the update record from the durable per-host
+        mirror (resume panels below the agreed epoch — no factor
+        work, no broadcast);
+      * ``apply(S, rec, j)`` -> panel j's state after absorbing the
+        record's update (the SAME jitted visit kernel at every
+        depth);
+
+    plus ``tail_step(k)`` — the m<n tail-panel body (None for potrf,
+    whose every panel factors).
 
     ``elastic`` (ISSUE 19): an :class:`~.elastic.ElasticController`
     routes the stream through the segmented re-ownership loop
-    (dist/elastic.py run_elastic — graph construction per remap
-    segment, ownership re-derived from measured throughput at each
-    boundary). Elastic always constructs graphs regardless of the
-    ``ooc/scheduler`` row: ownership is a graph-construction input,
-    which is the whole re-label-and-rebuild mechanism."""
+    (dist/elastic.py run_elastic — one such graph per remap segment,
+    ownership re-derived from measured throughput at each
+    boundary)."""
     if elastic is not None:
         from . import elastic as _elastic
         _elastic.run_elastic(
@@ -849,77 +682,33 @@ def _run_stream(op: str, use_graph: bool, *, sched, bc, st, depth,
             step_obs=step_obs, nt=nt)
         return
     last = factor_panels[-1] if len(factor_panels) else -1
-    if use_graph:
-        from ..sched import policies as _policies
-        from ..sched.runtime import execute as _execute
-        g = _policies.sharded_stream(
-            op, sched=sched, bc=bc, st=st, depth=depth, epoch=epoch,
-            factor_panels=factor_panels, tail_panels=tail_panels,
-            payload_shape=payload_shape, make_payload=make_payload,
-            complete=complete, replay=replay, apply=apply,
-            tail=tail_step)
+    g = sharded_stream(
+        op, sched=sched, bc=bc, st=st, depth=depth, epoch=epoch,
+        factor_panels=factor_panels, tail_panels=tail_panels,
+        payload_shape=payload_shape, make_payload=make_payload,
+        complete=complete, replay=replay, apply=apply,
+        tail=tail_step)
 
-        def _begin(k):
-            if led is not None:
-                led.begin(k, owner=sched.owner_process(k),
-                          epoch=epoch)
-
-        def _end(k):
-            if k <= last:
-                step_obs(k)
-            if ck is not None and k >= epoch and ck.due(k):
-                eng.wait_writes()   # every panel <= k is durable;
-                ck.commit(k + 1)    # the in-flight panel is NOT
-            if led is not None:
-                led.commit()
-
-        _execute(g, op=op, nt=nt, begin_step=_begin, end_step=_end)
-        # deep lookahead keys every node below slot nt-1, so the
-        # trailing slots never open and their due() commits never
-        # fire from _end — land the walk's final complete
-        # checkpoint explicitly
-        if ck is not None and ck.epoch < nt:
-            eng.wait_writes()
-            ck.commit(nt)
-        return
-    pipe = _BcastPipeline(op, sched, bc, st, depth, epoch,
-                          list(factor_panels), payload_shape,
-                          make_payload, complete, replay, apply)
-    for k in factor_panels:
+    def _begin(k):
         if led is not None:
             led.begin(k, owner=sched.owner_process(k), epoch=epoch)
-        _health.heartbeat(op, k, nt)
-        rec = pipe.obtain(k)
-        # lookahead prologue BEFORE the trailing sweep: the next
-        # panel's broadcast rides the second frame buffer while this
-        # host applies its remaining k-updates (module doc);
-        # per-panel update order is unchanged (bitwise pin)
-        pipe.advance(k, rec)
-        pipe.updates(k, rec)
-        step_obs(k)
+
+    def _end(k):
+        if k <= last:
+            step_obs(k)
         if ck is not None and k >= epoch and ck.due(k):
             eng.wait_writes()   # every panel <= k is durable;
             ck.commit(k + 1)    # the in-flight panel is NOT
         if led is not None:
             led.commit()
-    for k in tail_panels:
-        # columns past kmax (m < n): all updates applied, the state
-        # IS the final U block — one broadcast replicates it so every
-        # host's packed factor is complete (synchronous: no factor
-        # depends on these, nothing to overlap)
-        if led is not None:
-            led.begin(k, owner=sched.owner_process(k), epoch=epoch)
-        _health.heartbeat(op, k, nt)
-        _faults.check("step", op=op, step=k,
-                      mine=bool(sched.is_mine(k)))
-        if k < epoch:
-            continue            # durable already
-        tail_step(k)
-        if ck is not None and ck.due(k):
-            eng.wait_writes()
-            ck.commit(k + 1)
-        if led is not None:
-            led.commit()
+
+    execute(g, op=op, nt=nt, begin_step=_begin, end_step=_end)
+    # deep lookahead keys every node below slot nt-1, so the trailing
+    # slots never open and their due() commits never fire from _end:
+    # land the final complete checkpoint explicitly
+    if ck is not None and ck.epoch < nt:
+        eng.wait_writes()
+        ck.commit(nt)
 
 
 @instrument_driver("shard_potrf_ooc")
@@ -931,7 +720,6 @@ def shard_potrf_ooc(a: np.ndarray, grid: ProcessGrid,
                     ckpt_path: Optional[str] = None,
                     ckpt_every: Optional[int] = None,
                     precision=None,
-                    scheduler=None,
                     ownership=None) -> np.ndarray:
     """Sharded out-of-core lower Cholesky (module doc): panels owned
     2D-block-cyclically, each host staging only its shard, factor
@@ -971,10 +759,6 @@ def shard_potrf_ooc(a: np.ndarray, grid: ProcessGrid,
     promoted mirror back (an exact roundtrip) so a resumed stream
     applies bitwise the frames the uninterrupted one did.
 
-    ``scheduler`` (ISSUE 17): ``"walk"`` (FROZEN ``ooc/scheduler``
-    default — the legacy pipeline loop) or ``"graph"`` (the task-graph
-    runtime; bitwise-pinned against the walk at every depth).
-
     ``ownership`` (ISSUE 19): ``"static"`` (FROZEN ``mesh/ownership``
     default — the pure cyclic map) or ``"elastic"`` (throughput-
     driven re-ownership, dist/elastic.py — bitwise vs static; with
@@ -982,15 +766,13 @@ def shard_potrf_ooc(a: np.ndarray, grid: ProcessGrid,
     from ..linalg import stream
     from ..linalg.ooc import (_panel_apply, _panel_apply_mx,
                               _panel_cols, _panel_factor,
-                              _precision_meta, _resolve_precision,
-                              _resolve_scheduler)
+                              _precision_meta, _resolve_precision)
     from .elastic import ElasticController, _resolve_ownership
     a = np.asarray(a)
     n = a.shape[0]
     w = min(_panel_cols(panel_cols, n, a.dtype), n)
     nt = ceil_div(n, w)
     lo = _resolve_precision(precision, n, a.dtype)
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     depth = _shard_lookahead(lookahead, n, a.dtype)
     ctrl = ElasticController("shard_potrf_ooc", grid, nt,
                              n=n, dtype=a.dtype) \
@@ -1073,8 +855,8 @@ def shard_potrf_ooc(a: np.ndarray, grid: ProcessGrid,
     led = _ledger.recorder("shard_potrf_ooc", nt=nt,
                            spill_dir=_host_ckpt_path(ckpt_path))
     try:
-        _run_stream("shard_potrf_ooc", use_graph, sched=sched, bc=bc,
-                    st=st, depth=depth, epoch=epoch,
+        _run_stream("shard_potrf_ooc", sched=sched, bc=bc, st=st,
+                    depth=depth, epoch=epoch,
                     factor_panels=list(range(nt)), tail_panels=[],
                     payload_shape=payload_shape,
                     make_payload=make_payload, complete=complete,
@@ -1103,7 +885,6 @@ def shard_geqrf_ooc(a: np.ndarray, grid: ProcessGrid,
                     ckpt_path: Optional[str] = None,
                     ckpt_every: Optional[int] = None,
                     precision=None,
-                    scheduler=None,
                     ownership=None):
     """Sharded out-of-core Householder QR: same ownership walk,
     broadcast tree, and lookahead pipeline as shard_potrf_ooc,
@@ -1129,7 +910,7 @@ def shard_geqrf_ooc(a: np.ndarray, grid: ProcessGrid,
     from ..linalg.ooc import (_panel_cols, _precision_meta,
                               _qr_apply_fresh, _qr_panel_factor,
                               _qr_visit, _qr_visit_mx,
-                              _resolve_precision, _resolve_scheduler)
+                              _resolve_precision)
     from .elastic import ElasticController, _resolve_ownership
     a = np.asarray(a)
     m, n = a.shape
@@ -1137,7 +918,6 @@ def shard_geqrf_ooc(a: np.ndarray, grid: ProcessGrid,
     w = min(_panel_cols(panel_cols, n, a.dtype), n)
     nt = ceil_div(n, w)
     lo = _resolve_precision(precision, n, a.dtype)
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     depth = _shard_lookahead(lookahead, n, a.dtype)
     ctrl = ElasticController("shard_geqrf_ooc", grid, nt,
                              n=n, dtype=a.dtype) \
@@ -1260,8 +1040,8 @@ def shard_geqrf_ooc(a: np.ndarray, grid: ProcessGrid,
     led = _ledger.recorder("shard_geqrf_ooc", nt=nt,
                            spill_dir=_host_ckpt_path(ckpt_path))
     try:
-        _run_stream("shard_geqrf_ooc", use_graph, sched=sched, bc=bc,
-                    st=st, depth=depth, epoch=epoch,
+        _run_stream("shard_geqrf_ooc", sched=sched, bc=bc, st=st,
+                    depth=depth, epoch=epoch,
                     factor_panels=factor_panels,
                     tail_panels=tail_panels,
                     payload_shape=payload_shape,
@@ -1292,7 +1072,6 @@ def shard_getrf_ooc(a: np.ndarray, grid: ProcessGrid,
                     ckpt_path: Optional[str] = None,
                     ckpt_every: Optional[int] = None,
                     precision=None,
-                    scheduler=None,
                     ownership=None):
     """Sharded out-of-core tournament-pivot LU (module doc — the PR 7
     deferral, closed): same ownership walk and broadcast tree as
@@ -1340,13 +1119,12 @@ def shard_getrf_ooc(a: np.ndarray, grid: ProcessGrid,
     from ..linalg.lu import tnt_swaps_host
     from ..linalg.ooc import (_lu_visit_orig, _lu_visit_orig_mx,
                               _panel_cols, _precision_meta,
-                              _resolve_precision, _resolve_scheduler,
-                              _tnt_factor, _tnt_select,
-                              _tnt_tail_cols, _finalize_lapack_order)
+                              _resolve_precision, _tnt_factor,
+                              _tnt_select, _tnt_tail_cols,
+                              _finalize_lapack_order)
     a = np.asarray(a)
     m, n = a.shape
     lo = _resolve_precision(precision, n, a.dtype)
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     # the pivot payload row(s) ride the FRAME dtype: row indices must
     # sit inside its exact-integer window or np.rint decodes WRONG
     # rows silently — make it a loud error instead. The mixed mode's
@@ -1431,7 +1209,7 @@ def shard_getrf_ooc(a: np.ndarray, grid: ProcessGrid,
     def make_payload(k, S):
         # the owner's tournament runs against the CURRENT `perm`,
         # which the strictly ascending completion order has advanced
-        # through frame k-1 by the time the pipeline issues panel k —
+        # through frame k-1 by the time panel k is issued —
         # lookahead or not, the same host simulation on the same
         # values
         k0, _k1, wk, wf = bounds(k)
@@ -1538,8 +1316,8 @@ def shard_getrf_ooc(a: np.ndarray, grid: ProcessGrid,
     led = _ledger.recorder("shard_getrf_ooc", nt=nt,
                            spill_dir=_host_ckpt_path(ckpt_path))
     try:
-        _run_stream("shard_getrf_ooc", use_graph, sched=sched, bc=bc,
-                    st=st, depth=depth, epoch=epoch,
+        _run_stream("shard_getrf_ooc", sched=sched, bc=bc, st=st,
+                    depth=depth, epoch=epoch,
                     factor_panels=factor_panels,
                     tail_panels=tail_panels,
                     payload_shape=payload_shape,

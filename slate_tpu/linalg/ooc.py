@@ -88,11 +88,6 @@ from ..obs.events import instrument_driver
 from ..resil import checkpoint as _rckpt
 from ..resil import faults as _rfaults
 from ..resil import guard as _rguard
-# the task-graph runtime (ISSUE 17): drivers construct-then-execute
-# their schedules as dependency graphs behind the frozen
-# ooc/scheduler="walk" arbitration (_resolve_scheduler)
-from ..sched import policies as _sched_policies
-from ..sched.runtime import execute as _sched_execute
 # the expander-temps estimate and cap are shared with the in-core
 # trsm safety valve (blocked.py)
 from .blocked import SOLVE_TEMP_CAP
@@ -142,23 +137,6 @@ def _resolve_precision(precision, n: int, dtype):
     from .refine import lo_dtype
     lo = np.dtype(lo_dtype(dtype))
     return None if lo == np.dtype(dtype) else lo
-
-
-def _resolve_scheduler(scheduler, n: int, dtype) -> bool:
-    """Issue-loop arbitration for the streaming drivers (ISSUE 17):
-    explicit ``scheduler`` argument > measured ``ooc/scheduler`` tune
-    entry > FROZEN "walk" (core/methods.MethodScheduler — a COLD
-    CACHE keeps the hand-written walks bit-identically; the
-    task-graph runtime is earned or explicit, pinned by the bitwise
-    pin suite). Returns True for the graph route
-    (slate_tpu/sched/ construct-then-execute)."""
-    from ..core.methods import MethodScheduler, str2method
-    m = scheduler if scheduler is not None else MethodScheduler.Auto
-    if isinstance(m, str):
-        m = str2method("scheduler", m)
-    if m is MethodScheduler.Auto:
-        m = MethodScheduler.resolve(n, dtype)
-    return m is MethodScheduler.Graph
 
 
 def _herm_operand(a: np.ndarray) -> np.ndarray:
@@ -417,7 +395,7 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
               cache_budget_bytes=None, grid=None,
               method=None, ckpt_path: Optional[str] = None,
               ckpt_every: Optional[int] = None,
-              precision=None, scheduler=None) -> np.ndarray:
+              precision=None) -> np.ndarray:
     """Lower Cholesky of a host-resident Hermitian matrix (lower
     triangle read), streaming one column panel through the accelerator
     at a time. Returns the host-resident lower factor; n is bounded by
@@ -475,12 +453,11 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                 a, grid, panel_cols=panel_cols,
                 cache_budget_bytes=cache_budget_bytes,
                 ckpt_path=ckpt_path, ckpt_every=ckpt_every,
-                precision=precision, scheduler=scheduler),
+                precision=precision),
             lambda: potrf_ooc(a, panel_cols, cache_budget_bytes,
                               ckpt_path=ckpt_path,
                               ckpt_every=ckpt_every,
-                              precision=precision,
-                              scheduler=scheduler),
+                              precision=precision),
             "potrf_ooc", grid)
     ck = _rckpt.maybe_checkpointer(
         ckpt_path, "potrf_ooc", a, panel_cols, nt, every=ckpt_every,
@@ -506,11 +483,10 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     ld = stream.host_demoter(lo)
     visit = _panel_apply if lo is None else _panel_apply_mx
     epoch0 = ck.epoch if ck is not None else 0
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     led = _ledger.recorder("potrf_ooc", nt=nt, spill_dir=ckpt_path)
-    # the panel loop body as closures (ISSUE 17): the walk below and
-    # the left_looking graph policy drive the SAME code — the graph
-    # route changes only who owns the issue order, never the ops
+    # the panel loop's four phases, named as the ledger names them;
+    # panel k's state passes between them through S_live and F, and
+    # _writeback drops it before panel k+1 is staged
     S_live, F = {}, {}
 
     def _stage(k):
@@ -580,36 +556,21 @@ def potrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
             eng.put("L", k, stream._embed_rows(Pk, k0, n=n))
         eng.write("L", k, Lk, out[k0:, k0:k1])               # D2H
 
-    def _begin(k):
-        if led is not None:
-            led.begin(k, epoch=epoch0)
-
-    def _end(k):
-        if ck is not None and ck.due(k):
-            eng.wait_writes()           # every panel <= k is durable
-            ck.commit(k + 1)
-        if led is not None:
-            led.commit()
-
     try:
-        if use_graph:
-            g = _sched_policies.left_looking(
-                "potrf_ooc", panels=range(epoch0, nt),
-                updates=lambda k: range(k), stage=_stage,
-                update=_update, factor=_factor,
-                writeback=_writeback)
-            _sched_execute(g, op="potrf_ooc", nt=nt,
-                           begin_step=_begin, end_step=_end)
-        else:
-            for k in range(epoch0, nt):
-                _begin(k)
-                _health.heartbeat("potrf_ooc", k, nt)
-                _stage(k)
-                for j in range(k):
-                    _update(k, j)
-                _factor(k)
-                _writeback(k)
-                _end(k)
+        for k in range(epoch0, nt):
+            if led is not None:
+                led.begin(k, epoch=epoch0)
+            _health.heartbeat("potrf_ooc", k, nt)
+            _stage(k)
+            for j in range(k):
+                _update(k, j)
+            _factor(k)
+            _writeback(k)
+            if ck is not None and ck.due(k):
+                eng.wait_writes()       # every panel <= k is durable
+                ck.commit(k + 1)
+            if led is not None:
+                led.commit()
         _health.heartbeat("potrf_ooc", nt, nt)   # completion beat
         if led is not None:
             led.begin(nt, epoch=epoch0, drain=True)      # final drain record
@@ -879,7 +840,7 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
               chunk: Optional[int] = None,
               ckpt_path: Optional[str] = None,
               ckpt_every: Optional[int] = None,
-              precision=None, scheduler=None):
+              precision=None):
     """LU of a host-resident (m, n) matrix, streaming one column
     panel through the accelerator at a time (left-looking; reference
     src/getrf.cc:327 runs the same factorization at any n the
@@ -955,18 +916,17 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                 a, grid, panel_cols=w, incore_nb=incore_nb,
                 cache_budget_bytes=cache_budget_bytes, chunk=chunk,
                 ckpt_path=ckpt_path, ckpt_every=ckpt_every,
-                precision=precision, scheduler=scheduler),
+                precision=precision),
             lambda: getrf_tntpiv_ooc(
                 a, w, incore_nb, cache_budget_bytes, chunk=chunk,
                 ckpt_path=ckpt_path, ckpt_every=ckpt_every,
-                precision=precision, scheduler=scheduler),
+                precision=precision),
             "getrf_ooc", grid)
     if mode is MethodLUPivot.Tournament:
         return getrf_tntpiv_ooc(a, w, incore_nb, cache_budget_bytes,
                                 chunk=chunk, ckpt_path=ckpt_path,
                                 ckpt_every=ckpt_every,
-                                precision=precision,
-                                scheduler=scheduler)
+                                precision=precision)
     slate_assert(
         ckpt_path is None,
         "partial-pivot OOC LU cannot checkpoint (row-swap fixups "
@@ -1194,7 +1154,7 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                      chunk: Optional[int] = None,
                      ckpt_path: Optional[str] = None,
                      ckpt_every: Optional[int] = None,
-                     precision=None, scheduler=None):
+                     precision=None):
     """Tournament-pivot (CALU) LU of a host-resident (m, n) matrix,
     streaming one column panel at a time — the out-of-core twin of
     getrf_tntpiv (reference src/getrf_tntpiv.cc:169-222). Returns
@@ -1301,11 +1261,9 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                 gdev[j] = dev
         return dev
 
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     led = _ledger.recorder("getrf_tntpiv_ooc", nt=nt,
                            spill_dir=ckpt_path)
-    # loop body as closures (ISSUE 17; potrf_ooc comment) — the walk
-    # and the left_looking graph policy drive the same code
+    # the loop's phases by their ledger names (potrf_ooc comment)
     S_live, F = {}, {}
 
     def _stage(k):
@@ -1383,39 +1341,22 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
             eng.write("LU", k, S,           # columns past kmax: all U
                       stored[:, k0:k1])
 
-    def _begin(k):
-        if led is not None:
-            led.begin(k, epoch=epoch)
-
-    def _end(k):
-        if ck is not None and ck.due(k):
-            eng.wait_writes()           # every panel <= k is durable
-            ck.commit(k + 1)
-        if led is not None:
-            led.commit()
-
     try:
-        if use_graph:
-            g = _sched_policies.left_looking(
-                "getrf_tntpiv_ooc", panels=range(epoch, nt),
-                updates=lambda k: range(ceil_div(min(k * w, kmax),
-                                                 w)),
-                stage=_stage, update=_update, factor=_factor,
-                writeback=_writeback,
-                has_factor=lambda k: k * w < kmax)
-            _sched_execute(g, op="getrf_tntpiv_ooc", nt=nt,
-                           begin_step=_begin, end_step=_end)
-        else:
-            for k in range(epoch, nt):
-                _begin(k)
-                _health.heartbeat("getrf_tntpiv_ooc", k, nt)
-                _stage(k)
-                for j in range(ceil_div(min(k * w, kmax), w)):
-                    _update(k, j)
-                if k * w < kmax:
-                    _factor(k)
-                _writeback(k)
-                _end(k)
+        for k in range(epoch, nt):
+            if led is not None:
+                led.begin(k, epoch=epoch)
+            _health.heartbeat("getrf_tntpiv_ooc", k, nt)
+            _stage(k)
+            for j in range(ceil_div(min(k * w, kmax), w)):
+                _update(k, j)
+            if k * w < kmax:
+                _factor(k)
+            _writeback(k)
+            if ck is not None and ck.due(k):
+                eng.wait_writes()       # every panel <= k is durable
+                ck.commit(k + 1)
+            if led is not None:
+                led.commit()
         _health.heartbeat("getrf_tntpiv_ooc", nt, nt)   # completion
         if led is not None:
             led.begin(nt, epoch=epoch, drain=True)       # final drain record
@@ -1572,7 +1513,7 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
               grid=None, method=None,
               ckpt_path: Optional[str] = None,
               ckpt_every: Optional[int] = None,
-              precision=None, scheduler=None):
+              precision=None):
     """Householder QR of a host-resident (m, n) matrix, streaming one
     column panel at a time (left-looking; reference src/geqrf.cc:26).
     Returns (QR_packed, taus) in the same packed contract as geqrf:
@@ -1622,12 +1563,11 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                 a, grid, panel_cols=w, incore_ib=incore_ib,
                 cache_budget_bytes=cache_budget_bytes,
                 ckpt_path=ckpt_path, ckpt_every=ckpt_every,
-                precision=precision, scheduler=scheduler),
+                precision=precision),
             lambda: geqrf_ooc(a, w, incore_ib, cache_budget_bytes,
                               ckpt_path=ckpt_path,
                               ckpt_every=ckpt_every,
-                              precision=precision,
-                              scheduler=scheduler),
+                              precision=precision),
             "geqrf_ooc", grid)
     nt = ceil_div(n, w)
     # checkpoint/resume (resil/, ISSUE 9): factor + taus live in
@@ -1654,12 +1594,10 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     ld = stream.host_demoter(lo)
     visit = _qr_visit if lo is None else _qr_visit_mx
     epoch0 = ck.epoch if ck is not None else 0
-    use_graph = _resolve_scheduler(scheduler, n, a.dtype)
     led = _ledger.recorder("geqrf_ooc", nt=nt,
                            spill_dir=ckpt_path if engine is None
                            else None)
-    # loop body as closures (ISSUE 17; potrf_ooc comment) — the walk
-    # and the left_looking graph policy drive the same code
+    # the loop's phases by their ledger names (potrf_ooc comment)
     S_live, F = {}, {}
 
     def _stage(k):
@@ -1696,7 +1634,6 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                          cache=False)
 
     def _factor(k):
-        _pref_next(k)
         k0, k1 = k * w, min(k * w + w, n)
         wf = min(k1, kmax) - k0
         S = S_live[k]
@@ -1723,42 +1660,25 @@ def geqrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                                        packed[:m - k0], ptau)
                 eng.write("QR", k, rest, out[k0:, k0 + wf:k1])
         else:
-            _pref_next(k)       # pure-U panels prefetch here instead
             eng.write("QR", k, S, out[:, k0:k1])               # D2H
 
-    def _begin(k):
-        if led is not None:
-            led.begin(k, epoch=epoch0)
-
-    def _end(k):
-        if ck is not None and ck.due(k):
-            eng.wait_writes()           # every panel <= k is durable
-            ck.commit(k + 1)
-        if led is not None:
-            led.commit()
-
     try:
-        if use_graph:
-            g = _sched_policies.left_looking(
-                "geqrf_ooc", panels=range(epoch0, nt),
-                updates=lambda k: range(ceil_div(min(k * w, kmax),
-                                                 w)),
-                stage=_stage, update=_update, factor=_factor,
-                writeback=_writeback,
-                has_factor=lambda k: k * w < kmax)
-            _sched_execute(g, op="geqrf_ooc", nt=nt,
-                           begin_step=_begin, end_step=_end)
-        else:
-            for k in range(epoch0, nt):
-                _begin(k)
-                _health.heartbeat("geqrf_ooc", k, nt)
-                _stage(k)
-                for j in range(ceil_div(min(k * w, kmax), w)):
-                    _update(k, j)
-                if k * w < kmax:
-                    _factor(k)
-                _writeback(k)
-                _end(k)
+        for k in range(epoch0, nt):
+            if led is not None:
+                led.begin(k, epoch=epoch0)
+            _health.heartbeat("geqrf_ooc", k, nt)
+            _stage(k)
+            for j in range(ceil_div(min(k * w, kmax), w)):
+                _update(k, j)
+            _pref_next(k)
+            if k * w < kmax:
+                _factor(k)
+            _writeback(k)
+            if ck is not None and ck.due(k):
+                eng.wait_writes()       # every panel <= k is durable
+                ck.commit(k + 1)
+            if led is not None:
+                led.commit()
         _health.heartbeat("geqrf_ooc", nt, nt)   # completion beat
         if led is not None:
             led.begin(nt, epoch=epoch0, drain=True)      # final drain record

@@ -1,7 +1,7 @@
 """Typed panel-op dependency graphs (ISSUE 17 tentpole, part 1).
 
 A :class:`TaskGraph` is a DAG of :class:`Node`\\ s, each a closure over
-the SAME engines/kernels/broadcaster the legacy walks drive, labelled
+the sharded drivers' engines/kernels/broadcaster, labelled
 with a node *kind* from the closed set :data:`NODE_KINDS`:
 
     stage       host->HBM staging of a panel's input
@@ -16,8 +16,8 @@ with a node *kind* from the closed set :data:`NODE_KINDS`:
 The kind is load-bearing, not cosmetic: :data:`PHASE_OF_KIND` maps
 every kind onto the ledger's closed ``PHASES`` attribution column
 (obs/ledger.py) — the runtime wraps each node in that frame, so graph
-execution lands in the same flight-recorder columns as the walks —
-and :data:`FAULT_SITE_OF_KIND` names the registered fault site
+execution fills the flight-recorder columns the single-engine loops
+fill — and :data:`FAULT_SITE_OF_KIND` names the registered fault site
 (resil/faults.py ``SITES``) covering kinds that perform I/O or comms.
 tools/slate_lint's SL7xx analyzer pins both tables complete and
 consistent with the live registries; they are deliberately plain
@@ -31,10 +31,10 @@ order only).
 
 Determinism contract: the runtime executes nodes one at a time in
 ``(key, seq)`` min-order among ready nodes. Policies choose ``key``
-tuples so that this order is exactly the legacy walk's issue order —
-the graphs don't merely compute the same answer, they run the same
-kernels in the same sequence on the same operands, which is what the
-bitwise pins hold.
+tuples so that every panel absorbs its updates in ascending step
+order at every lookahead depth — the graphs don't merely compute the
+same answer, they run the same kernels on the same operands, which is
+what the bitwise pins hold.
 """
 
 from __future__ import annotations
@@ -51,9 +51,9 @@ NODE_KINDS = ("stage", "factor", "solve", "update", "bcast",
 #: node kind -> obs/ledger.py PHASES attribution column. 1:1 onto the
 #: ledger's closed phase set: the executor wraps each node's closure
 #: in ``_ledger.frame(PHASE_OF_KIND[kind])`` so graph execution fills
-#: the same flight-recorder columns as the hand-written walks
+#: the same flight-recorder columns as the single-engine loops
 #: (bcast completion waits land in ``bcast_wait``; writeback fences
-#: are ``cache`` stalls, same as the walks' credit() sites).
+#: are ``cache`` stalls, same as the engines' credit() sites).
 PHASE_OF_KIND = {
     "stage": "stage",
     "factor": "factor",
@@ -67,8 +67,8 @@ PHASE_OF_KIND = {
 #: that perform I/O or comms (None = pure compute, no site needed).
 #: The stage/writeback sites fire inside StreamEngine (h2d/d2h) and
 #: bcast inside dist collectives (ppermute); the per-panel ``step``
-#: site fires from the policies' closures exactly where the legacy
-#: walks check it, so seeded-fault runs stay order-identical.
+#: site fires from the policy's closures once a panel, in ascending
+#: order, so seeded-fault runs stay order-identical.
 FAULT_SITE_OF_KIND = {
     "stage": "h2d",
     "factor": None,

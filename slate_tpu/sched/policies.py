@@ -1,30 +1,19 @@
-"""Graph constructors reproducing the legacy schedules (ISSUE 17
-tentpole, part 2).
+"""The graph constructor of the sharded streams.
 
-Each policy builds a :class:`~.graph.TaskGraph` whose node closures
-are the legacy walks' loop bodies verbatim — same engines, same jitted
-kernels, same broadcaster, same guard/fault/ledger calls — and whose
-``key`` tuples make the executor's ready-order a linear extension
-matching the walk's issue order exactly (runtime.py doc). Two
-constructors cover the three hand-written walks:
+:func:`sharded_stream` builds the :class:`~.graph.TaskGraph` of one
+sharded out-of-core factorization (shard_potrf/geqrf/getrf_ooc over a
+CyclicSchedule). Its node closures call the driver's own closures —
+same engines, same jitted kernels, same broadcaster, same
+guard/fault/ledger calls — and its ``key`` tuples fix the order in
+which the executor issues nodes that are ready together (runtime.py
+doc). Lookahead is a PURE GRAPH PROPERTY: depth d only changes which
+slot a panel's factor/bcast nodes are keyed at (``max(i-d, 0)``) and
+how many trailing updates ride the promoted window — the dependency
+structure itself (bcast -> writeback -> consuming updates) never
+changes, and no node closure consults the depth. A record's consumers
+are explicit edges, not a per-panel high-water counter.
 
-* :func:`left_looking` — the single-engine OOC streams
-  (potrf_ooc / geqrf_ooc / getrf_tntpiv_ooc): per panel k a
-  ``stage -> update(0..k-1) -> factor -> writeback`` chain, where
-  update j additionally depends on panel j's writeback.
-
-* :func:`sharded_stream` — the CyclicSchedule sharded walk
-  (shard_potrf/geqrf/getrf_ooc). Lookahead is a PURE GRAPH PROPERTY
-  here: depth d only changes which slot a panel's factor/bcast nodes
-  are keyed at (``max(i-d, 0)``) and how many trailing updates ride
-  the promoted window — the dependency structure itself (bcast ->
-  writeback -> consuming updates) never changes, and no node closure
-  consults the depth. ``_ShardState.upto`` bookkeeping dies on this
-  path: a record's consumers are explicit edges, not a per-panel
-  high-water counter.
-
-Slot/key layout of :func:`sharded_stream` (mirrors _BcastPipeline's
-three phases; cls column is the intra-slot ordering class)::
+Slot/key layout (cls column is the intra-slot ordering class)::
 
     node            slot                     cls
     writeback i     i (d=0) | max(i-d+1, 0)  0   realize record i
@@ -37,9 +26,9 @@ three phases; cls column is the intra-slot ordering class)::
 Stage nodes (first-touch H2D of a trailing panel) share their first
 update's key prefix with a trailing 0, so they pop immediately before
 it. The per-panel ``step`` fault check fires exactly once per panel
-from the first node that processes it — the same ascending once-each
-sequence as the walks, so seeded fault plans stay deterministic
-across schedulers (resil/faults.py contract).
+from the first node that processes it, in ascending panel order at
+every depth, so seeded fault plans stay deterministic
+(resil/faults.py contract).
 """
 
 from __future__ import annotations
@@ -55,44 +44,6 @@ from ..resil import faults as _faults
 from .graph import TaskGraph
 
 
-def left_looking(op: str, *,
-                 panels: Sequence[int],
-                 updates: Callable[[int], Sequence[int]],
-                 stage: Callable[[int], None],
-                 update: Callable[[int, int], None],
-                 factor: Callable[[int], None],
-                 writeback: Callable[[int], None],
-                 has_factor: Optional[Callable[[int], bool]] = None
-                 ) -> TaskGraph:
-    """Single-engine left-looking stream as a graph.
-
-    The driver supplies its loop body as four closures (`stage` /
-    `update(k, j)` / `factor` / `writeback`, each the verbatim legacy
-    code over the driver's own engine and state); `panels` is the
-    factor-panel range (``range(epoch, nt)`` on resume), `updates(k)`
-    the panels k visits (left-looking: every finished j < k), and
-    `has_factor(k)` gates the factor node (geqrf/getrf pure-U panels
-    past ``kmax`` only restage + write). Update (k, j) depends on
-    panel j's writeback — for j below the resume epoch that producer
-    is outside the graph (the update closure reads the durable
-    factor mirror), so the edge is simply absent.
-    """
-    g = TaskGraph(op)
-    wb: Dict[int, Any] = {}
-    for k in panels:
-        prev = g.add("stage", partial(stage, k), panel=k, key=(k, 0))
-        for j in updates(k):
-            prev = g.add("update", partial(update, k, j), panel=k,
-                         step=j, key=(k, 1, j),
-                         deps=[prev, wb.get(j)])
-        if has_factor is None or has_factor(k):
-            prev = g.add("factor", partial(factor, k), panel=k,
-                         key=(k, 2), deps=[prev])
-        wb[k] = g.add("writeback", partial(writeback, k), panel=k,
-                      key=(k, 3), deps=[prev])
-    return g
-
-
 def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
                    factor_panels: Sequence[int],
                    tail_panels: Sequence[int],
@@ -105,11 +56,12 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
                    applied_through: Optional[Callable[[int], int]]
                    = None,
                    trailing_to: Optional[int] = None) -> TaskGraph:
-    """The sharded right-looking walk as a graph (module doc table).
+    """The sharded right-looking stream as a graph (module doc
+    table).
 
-    Takes the SAME driver closures _BcastPipeline takes (payload_shape
-    / make_payload / complete / replay / apply — dist/shard_ooc.py
-    doc) plus the driver's `tail(k)` body for the m<n tail panels.
+    Takes the driver's closures (payload_shape / make_payload /
+    complete / replay / apply — dist/shard_ooc.py _run_stream doc)
+    plus the driver's `tail(k)` body for the m<n tail panels.
     `sched` is the CyclicSchedule, `bc` the PanelBroadcaster, `st` the
     _ShardState working set, `depth` the lookahead, `epoch` the agreed
     resume epoch.
@@ -124,8 +76,8 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
     writeback nodes below the epoch materialize only when some
     pruned-aware consumer still needs their record, which keeps the
     per-segment replay H2D proportional to actual catch-up instead
-    of O(nt^2) across segments. Defaults (None/None) are exactly the
-    unsegmented PR 17 construction."""
+    of O(nt^2) across segments. Defaults (None/None) give the
+    unsegmented construction."""
     d = max(int(depth), 0)
     ep = int(epoch)
     at = applied_through if applied_through is not None \
@@ -153,9 +105,9 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
                      if j >= max(1, ep))
     tail_set = set(tail_panels)
 
-    # explicit per-record consumer counts replace _ShardState.upto:
-    # a record dies when its last consuming update ran (the walk's
-    # liveness exactly — the slot-s sweep is always the last use)
+    # explicit per-record consumer counts: a record dies when its
+    # last consuming update ran (the slot-s sweep is always the last
+    # use)
     remaining: Dict[int, int] = {}
     for j in mine_tr:
         for s in range(at(j), min(j, last + 1)):
@@ -168,18 +120,18 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
         return max(i - d, 0)
 
     def ahead(i: int) -> bool:
-        # only depth 0 and the very first panel issue synchronously
-        # (pipeline obtain()'s pending-miss path); everything else is
-        # dispatched ahead — preserves the ooc.shard.bcast_ahead pin
+        # only depth 0 and the very first panel issue synchronously;
+        # everything else is dispatched ahead — what the
+        # ooc.shard.bcast_ahead pin counts
         return d > 0 and not (i == 0 and ep == 0)
 
     def _promo(p: int, s: int) -> bool:
-        # promoted window catch-up (advance()'s _promote) vs trailing
-        # sweep (updates()): factor panels absorb their last d steps
-        # at issue time, everything else sweeps at the record's slot
+        # promoted window catch-up vs trailing sweep: factor panels
+        # absorb their last d steps at issue time, everything else
+        # sweeps at the record's slot
         return p <= last and d > 0 and s >= p - d
 
-    # slot-0 sweep prefetch chain (prefetch_next): every owned
+    # slot-0 sweep prefetch chain: every owned
     # trailing panel first-touches at slot 0 — promoted panels stage
     # synchronously inside the window, sweep panels chain exact
     # prefetches in sweep order (window tails first, then ascending)
@@ -253,7 +205,7 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
     def _run_tail(k: int) -> None:
         _chk(k)
         if k < ep:
-            return          # durable on resume, same as the walk
+            return          # durable on resume
         tail(k)
 
     # --- assembly (ascending panel order, so every dep exists) ------
@@ -306,7 +258,8 @@ def sharded_stream(op: str, *, sched, bc, st, depth: int, epoch: int,
                 # segmented construction: replay only records a
                 # pruned-aware consumer still needs (catch-up
                 # panels); the unsegmented route keeps every replay
-                # node — same fault-check sequence as the walk
+                # node, so the fault-check sequence stays once each
+                # in ascending order
                 wbn[p] = g.add("writeback", partial(_run_replay, p),
                                panel=p, owner=owner,
                                key=(slot_wb(p), 0, p, 0, 0),

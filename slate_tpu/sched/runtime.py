@@ -1,34 +1,34 @@
-"""Graph executor (ISSUE 17 tentpole, part 3).
+"""Graph executor.
 
 :func:`execute` drives a validated :class:`~.graph.TaskGraph` to
-completion through the SAME jitted kernels, engines, broadcaster,
-fault sites, and ledger the hand-written walks use — the graph nodes
-are closures over exactly the walks' code, so the runtime owns only
-*order*, never semantics.
+completion. The graph's nodes are closures over the sharded drivers'
+own code (jitted kernels, engines, broadcaster, fault sites, ledger),
+so the runtime owns only *order*, never semantics.
 
 Deterministic tie-breaking: ready nodes sit in a min-heap keyed
-``(node.key, node.seq)`` and exactly one runs at a time. Policies
-choose keys so the ready-order is a linear extension matching the
-legacy walk's issue order — by induction the executor then reproduces
-that order exactly, which is what keeps graph results BITWISE equal
-to the walk route (the bitwise pin suite holds this per op, per
-lookahead depth, single-engine and sharded).
+``(node.key, node.seq)`` and exactly one runs at a time, so the issue
+order is a function of the graph alone: two runs of one graph issue
+the same nodes in the same sequence, and the policy's keys make every
+lookahead depth apply each panel's updates in ascending step order —
+which is what keeps results BITWISE equal across depths and to the
+single-engine loops (the bitwise pin suite holds this per op and per
+depth).
 
-Slot bookkeeping: ``key[0]`` is the node's *slot* (the panel-step of
-the legacy loop it belongs to). On each slot transition the runtime
-calls ``end_step(prev_slot)`` then heartbeats the stall watchdog
-(obs/health.py — the watchdog beats from the issue loop, same cadence
-as the walks) then ``begin_step(slot)`` — drivers hang their
+Slot bookkeeping: ``key[0]`` is the node's *slot* (the panel-step it
+belongs to). On each slot transition the runtime calls
+``end_step(prev_slot)`` then heartbeats the stall watchdog
+(obs/health.py — one beat a step, the cadence of the single-engine
+loops) then ``begin_step(slot)`` — drivers hang their
 ``led.begin``/``led.commit``/checkpoint-commit bracketing off these
-hooks, so ledger records and checkpoint epochs track graph execution
-the same way they track the walk. Each node's closure runs inside
+hooks, so ledger records and checkpoint epochs advance a step at a
+time. Each node's closure runs inside
 ``_ledger.frame(PHASE_OF_KIND[node.kind])`` (frames nest with
 self-time semantics, so inner frames inside the closures still
 attribute correctly and sums stay exhaustive).
 
 Issue-loop overhead is observable: ``sched.nodes_issued`` counts
 nodes, ``sched.issue_overhead_seconds`` accrues loop wall minus node
-wall (the pure scheduling cost bench.py --graph divides per node).
+wall (the pure scheduling cost), ``sched.graphs`` the graphs run.
 """
 
 from __future__ import annotations
@@ -55,7 +55,7 @@ def execute(graph: TaskGraph, *, op: str,
     slot count (progress denominator). `begin_step`/`end_step` fire
     on slot transitions (slot = ``node.key[0]``), bracketing all the
     nodes that share a slot — the graph analogue of one iteration of
-    the legacy panel loop.
+    a panel loop.
     """
     graph.validate()
     nin = {n: n._nin for n in graph.nodes}
@@ -68,8 +68,9 @@ def execute(graph: TaskGraph, *, op: str,
     executed = 0
     cur_slot: Optional[int] = None
     # On exception (e.g. an injected step fault) the in-flight slot's
-    # end_step does NOT fire — same as the walk, where led.commit and
-    # the checkpoint commit are skipped for an interrupted step.
+    # end_step does NOT fire: led.commit and the checkpoint commit
+    # are skipped for an interrupted step, as in the single-engine
+    # loops.
     while heap:
         _key, _seq, node = heapq.heappop(heap)
         slot = node.key[0] if node.key else 0

@@ -95,15 +95,6 @@ FROZEN: Dict[tuple, Any] = {
     # guarded solves) — an earned (bench --ooc/--shard precision
     # legs) or explicit decision (core/methods.MethodPrecision)
     ("ooc", "precision"): "f32",           # f32 | bf16
-    # OOC issue-loop scheduler (ISSUE 17): "walk" keeps the
-    # hand-written static schedules (the linalg/ooc.py loops and the
-    # dist/shard_ooc.py _BcastPipeline) bit-identically on a cold
-    # cache; "graph" routes the same loop bodies through the
-    # task-graph runtime (slate_tpu/sched/ — construct-then-execute,
-    # bitwise-pinned against the walks per op and lookahead depth) —
-    # an earned (bench --graph) or explicit decision (core/methods
-    # .MethodScheduler)
-    ("ooc", "scheduler"): "walk",          # walk | graph
     # elastic mesh ownership (ISSUE 19): "static" keeps the pure
     # 2D-block-cyclic CyclicSchedule assignment bit-identically on a
     # cold cache; "elastic" re-derives per-host effective throughput

@@ -176,31 +176,6 @@ mp.emit("shard_lookahead", proc=pid,
                            and np.array_equal(np.asarray(piv1),
                                               np.asarray(piv2))))
 
-# -- task-graph runtime (ISSUE 17): scheduler="graph" across the
-# process boundary — all three drivers at depth 1, bitwise vs the
-# legacy walk's depth-1 factors (same kernels, same broadcaster,
-# construct-then-execute issue order)
-Lg = shard_ooc.shard_potrf_ooc(a, grid, panel_cols=w,
-                               cache_budget_bytes=budget,
-                               lookahead=1, scheduler="graph")
-qrg, taug = shard_ooc.shard_geqrf_ooc(g, grid, panel_cols=w,
-                                      cache_budget_bytes=budget,
-                                      lookahead=1, scheduler="graph")
-lug, pivg = shard_ooc.shard_getrf_ooc(lp, grid, panel_cols=w,
-                                      cache_budget_bytes=budget,
-                                      lookahead=1, scheduler="graph")
-mp.emit("shard_graph", proc=pid,
-        potrf_bitwise=bool(np.array_equal(np.asarray(L2),
-                                          np.asarray(Lg))),
-        geqrf_bitwise=bool(np.array_equal(np.asarray(qr2),
-                                          np.asarray(qrg))
-                           and np.array_equal(np.asarray(tau2),
-                                              np.asarray(taug))),
-        getrf_bitwise=bool(np.array_equal(np.asarray(lu2),
-                                          np.asarray(lug))
-                           and np.array_equal(np.asarray(piv2),
-                                              np.asarray(pivg))))
-
 # -- mixed-precision streaming (ISSUE 12): the frozen cold route is
 # bitwise on the REAL mesh for all three drivers (default vs explicit
 # precision="f32"), and the bf16 frames carry exactly half the

@@ -245,24 +245,28 @@ def test_watchdog_eta_gauge(rng, flight_clean):
     assert health.stats()["ops"]["potrf_ooc"]["step"] == 4  # == nt
 
 
-def test_watchdog_eta_and_stall_with_graph_scheduler(rng,
+def test_watchdog_eta_and_stall_on_sharded_lookahead(rng, grid8,
                                                      flight_clean):
-    """ISSUE 18 satellite: the watchdog's coverage is scheduler-
-    independent. With ``scheduler="graph"`` (the ISSUE 17 task-graph
-    executor) a seeded h2d hang still starves the heartbeat, the
-    ``health::stall`` instant attributes the stalled op/step, the
-    ETA gauge is published, and the run completes bitwise-equal to a
-    clean graph run — the graph's per-panel heartbeats ride the same
-    contract as the pipeline walk's."""
+    """ISSUE 18 satellite: the watchdog covers the task-graph
+    executor's slots as it covers a loop's iterations. At lookahead
+    depth 1 (a broadcast in flight while the sweep runs) a seeded
+    h2d hang still starves the heartbeat, the ``health::stall``
+    instant attributes the stalled op/step, the ETA gauge is
+    published, and the run completes bitwise-equal to a clean one."""
     n, w = 128, 32
     a = _spd(rng, n)
-    clean = ooc.potrf_ooc(a, panel_cols=w, scheduler="graph")
+    clean = shard_ooc.shard_potrf_ooc(a, grid8, panel_cols=w,
+                                      lookahead=1)
+    # after=1: panel 3's first touch is step 0's sweep (the cold
+    # prologue the watchdog ignores); its re-stage in step 1 hangs
     faults.install(faults.FaultPlan([
-        {"site": "h2d", "match": {"buf": "A"}, "kind": "hang",
-         "hang_s": 1.2, "after": 1, "times": 1}], seed=0))
+        {"site": "h2d", "match": {"buf": "S", "idx": 3},
+         "kind": "hang", "hang_s": 1.2, "after": 1, "times": 1}],
+        seed=0))
     obs.enable()
     health.enable(min_budget_s=0.3, interval_s=0.02, stall_factor=4)
-    out = ooc.potrf_ooc(a, panel_cols=w, scheduler="graph")
+    out = shard_ooc.shard_potrf_ooc(a, grid8, panel_cols=w,
+                                    lookahead=1)
     faults.clear()
     assert np.array_equal(np.asarray(clean), np.asarray(out))
     stalls = [e for e in obs.bus_events()
@@ -270,14 +274,14 @@ def test_watchdog_eta_and_stall_with_graph_scheduler(rng,
     assert stalls, "watchdog never fired during the 1.2s hang"
     ev = stalls[0]
     assert ev.cat == "health"
-    assert ev.args["op"] == "potrf_ooc"
+    assert ev.args["op"] == "shard_potrf_ooc"
     assert ev.args["step"] >= 1          # past the cold prologue
     assert health.stats()["stalls"] >= 1
     gauges = obs_metrics.snapshot()["gauges"]
     assert "health.eta_seconds" in gauges
     assert gauges["health.eta_seconds"] >= 0
     # progress resumed after the hang: the stall flag cleared
-    assert not health.stats()["ops"]["potrf_ooc"]["stalled"]
+    assert not health.stats()["ops"]["shard_potrf_ooc"]["stalled"]
 
 
 # -- critical-path attribution + export -----------------------------------

@@ -1,9 +1,9 @@
 """Task-graph runtime (ISSUE 17): graph construction/validation, the
-deterministic executor, and the acceptance pins — ``scheduler="graph"``
-BITWISE equal to the legacy walks for all three OOC streams, single
-engine and sharded, at lookahead depths 0/1/2, including budget 0,
+deterministic executor, and the acceptance pins of the streams that
+issue through it — the three sharded OOC drivers BITWISE equal to the
+single-engine loops at lookahead depths 0/1/2, including budget 0,
 forced spills, seeded-fault determinism, and checkpoint resume from
-mid-graph. The FROZEN ``ooc/scheduler`` cold route stays "walk"."""
+mid-graph."""
 
 import json
 
@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from slate_tpu.core.exceptions import SlateError
-from slate_tpu.core.methods import MethodScheduler, str2method
 from slate_tpu.dist import shard_ooc
 from slate_tpu.linalg import ooc
 from slate_tpu.obs import ledger
@@ -120,119 +119,107 @@ def test_kind_tables_total_and_on_vocabulary():
             if s is not None} <= set(faults.SITES)
 
 
-# -- arbitration: the FROZEN cold route -----------------------------------
+# -- the sharded streams issue through the graph ---------------------------
 
-def test_frozen_scheduler_cold_route():
-    from slate_tpu.tune.cache import FROZEN
-    assert FROZEN[("ooc", "scheduler")] == "walk"
-    assert MethodScheduler.resolve(4096, np.float64) \
-        is MethodScheduler.Walk
-    assert str2method("scheduler", "graph") is MethodScheduler.Graph
-    assert str2method("scheduler", "walk") is MethodScheduler.Walk
-
-
-def test_resolve_scheduler_explicit_beats_frozen():
-    assert ooc._resolve_scheduler("graph", 4096, np.float64)
-    assert not ooc._resolve_scheduler("walk", 4096, np.float64)
-    assert not ooc._resolve_scheduler(None, 4096, np.float64)
-    assert ooc._resolve_scheduler(MethodScheduler.Graph, 4096,
-                                  np.float64)
+def _shard_case(rng, op):
+    """(driver, operand, factor panels) of one sharded op at the
+    pins' shape: n=160, w=32, and for QR/LU the m<n shape whose last
+    two panels ride the graph's tail bcast nodes."""
+    if op == "potrf":
+        return shard_ooc.shard_potrf_ooc, _spd(rng, 160), 5
+    g = rng.standard_normal((96, 160))
+    if op == "geqrf":
+        return shard_ooc.shard_geqrf_ooc, g, 3
+    return shard_ooc.shard_getrf_ooc, \
+        g * (1.0 + np.arange(96))[:, None], 3
 
 
-# -- single-engine bitwise pins -------------------------------------------
-
-def test_potrf_graph_bitwise(rng):
-    a = _spd(rng, 160)
-    for budget in (0, int(1.5 * 160 * 32 * 8)):
-        L0 = ooc.potrf_ooc(a, panel_cols=32,
-                           cache_budget_bytes=budget,
-                           scheduler="walk")
-        L1 = ooc.potrf_ooc(a, panel_cols=32,
-                           cache_budget_bytes=budget,
-                           scheduler="graph")
-        np.testing.assert_array_equal(np.asarray(L0), np.asarray(L1))
+def _node_count(sched, nf):
+    """Nodes of one sharded stream by CyclicSchedule's own walk: an
+    update a (step, owned trailing panel) pair, a stage a panel that
+    takes any, factor (owner only) + bcast + writeback a factor
+    panel, one bcast a tail panel."""
+    sweeps = [sched.update_order(k) for k in range(nf)]
+    return (sum(len(u) for u in sweeps)
+            + len({p for u in sweeps for p in u})
+            + sum(1 for k in range(nf) if sched.is_mine(k))
+            + 2 * nf + (sched.nt - nf))
 
 
-def test_geqrf_graph_bitwise(rng):
-    for shape in ((160, 160), (96, 160)):       # square + m<n tail
-        g = rng.standard_normal(shape)
-        qr0, tau0 = ooc.geqrf_ooc(g, panel_cols=32,
-                                  cache_budget_bytes=0,
-                                  scheduler="walk")
-        qr1, tau1 = ooc.geqrf_ooc(g, panel_cols=32,
-                                  cache_budget_bytes=0,
-                                  scheduler="graph")
-        assert np.array_equal(np.asarray(qr0), np.asarray(qr1))
-        assert np.array_equal(np.asarray(tau0), np.asarray(tau1))
+@pytest.mark.parametrize("depth", [0, 2])
+@pytest.mark.parametrize("op", ["potrf", "geqrf", "getrf"])
+def test_shard_stream_issues_one_graph(rng, grid8, obs_on, op, depth):
+    """Every sharded driver runs ONE graph whose node count is the
+    schedule's at every lookahead depth: depth is where nodes are
+    keyed, never which nodes there are."""
+    from slate_tpu.obs import metrics
+    fn, a, nf = _shard_case(rng, op)
+    fn(a, grid8, panel_cols=32, lookahead=depth)
+    c = metrics.snapshot()["counters"]
+    assert c.get("sched.graphs") == 1
+    assert c.get("sched.nodes_issued") == _node_count(
+        shard_ooc.CyclicSchedule(5, grid8), nf)
 
 
-def test_getrf_tntpiv_graph_bitwise(rng):
-    for shape in ((160, 160), (96, 160)):
-        a = rng.standard_normal(shape) \
-            * (1.0 + np.arange(shape[0]))[:, None]
-        lu0, piv0 = ooc.getrf_tntpiv_ooc(a, panel_cols=32,
-                                         cache_budget_bytes=0,
-                                         scheduler="walk")
-        lu1, piv1 = ooc.getrf_tntpiv_ooc(a, panel_cols=32,
-                                         cache_budget_bytes=0,
-                                         scheduler="graph")
-        assert np.array_equal(np.asarray(lu0), np.asarray(lu1))
-        assert np.array_equal(np.asarray(piv0), np.asarray(piv1))
+def test_graph_issue_counters(rng, grid8, obs_on):
+    """sched.* counters: one graph, every node issued, overhead wall
+    accrued."""
+    from slate_tpu.obs import metrics
+    shard_ooc.shard_potrf_ooc(_spd(rng, 96), grid8, panel_cols=32)
+    c = metrics.snapshot()["counters"]
+    assert c.get("sched.graphs") == 1
+    # nt=3, one process owning every panel: 2 stage + 3 update
+    # (2+1+0) + 3 factor + 3 bcast + 3 writeback
+    assert c.get("sched.nodes_issued") == 14
+    assert c.get("sched.nodes_issued") == _node_count(
+        shard_ooc.CyclicSchedule(3, grid8), 3)
+    assert c.get("sched.issue_overhead_seconds", 0) >= 0
 
 
 # -- sharded bitwise pins (8-virtual-device mesh) -------------------------
 
 @pytest.mark.slow
-def test_shard_potrf_graph_bitwise_depths(rng, grid8):
-    """The acceptance pin: sharded graph == walk at depths 0/1/2,
-    budget 0 AND a forced-spill budget."""
+def test_shard_potrf_bitwise_depths(rng, grid8):
+    """The acceptance pin: the sharded factor is the single-engine
+    loop's at depths 0/1/2, budget 0 AND a forced-spill budget."""
     n, w = 160, 32
     a = _spd(rng, n)
+    L0 = np.asarray(ooc.potrf_ooc(a, panel_cols=w))
     for depth in (0, 1, 2):
         for budget in (0, int(1.5 * n * w * 8)):
-            Lw = shard_ooc.shard_potrf_ooc(
+            L = shard_ooc.shard_potrf_ooc(
                 a, grid8, panel_cols=w, lookahead=depth,
-                cache_budget_bytes=budget, scheduler="walk")
-            Lg = shard_ooc.shard_potrf_ooc(
-                a, grid8, panel_cols=w, lookahead=depth,
-                cache_budget_bytes=budget, scheduler="graph")
-            assert np.array_equal(np.asarray(Lw), np.asarray(Lg)), \
+                cache_budget_bytes=budget)
+            assert np.array_equal(L0, np.asarray(L)), \
                 "depth %d budget %d" % (depth, budget)
 
 
 @pytest.mark.slow
-def test_shard_geqrf_getrf_graph_bitwise_depths(rng, grid8):
+def test_shard_geqrf_getrf_bitwise_depths(rng, grid8):
     """Same pin for QR and tournament LU, including the m<n shapes
     whose tail panels ride the graph's tail bcast nodes."""
     w = 32
     for shape in ((160, 160), (96, 160)):
         g = rng.standard_normal(shape)
         lp = g * (1.0 + np.arange(shape[0]))[:, None]
+        q0, t0 = ooc.geqrf_ooc(g, panel_cols=w)
+        l0, p0 = ooc.getrf_tntpiv_ooc(lp, panel_cols=w)
         for depth in (0, 1, 2):
-            qw, tw = shard_ooc.shard_geqrf_ooc(
-                g, grid8, panel_cols=w, lookahead=depth,
-                scheduler="walk")
-            qg, tg = shard_ooc.shard_geqrf_ooc(
-                g, grid8, panel_cols=w, lookahead=depth,
-                scheduler="graph")
-            assert np.array_equal(np.asarray(qw), np.asarray(qg))
-            assert np.array_equal(np.asarray(tw), np.asarray(tg))
-            lw, pw = shard_ooc.shard_getrf_ooc(
-                lp, grid8, panel_cols=w, lookahead=depth,
-                scheduler="walk")
-            lg, pg = shard_ooc.shard_getrf_ooc(
-                lp, grid8, panel_cols=w, lookahead=depth,
-                scheduler="graph")
-            assert np.array_equal(np.asarray(lw), np.asarray(lg))
-            assert np.array_equal(np.asarray(pw), np.asarray(pg))
+            q, t = shard_ooc.shard_geqrf_ooc(
+                g, grid8, panel_cols=w, lookahead=depth)
+            assert np.array_equal(np.asarray(q0), np.asarray(q))
+            assert np.array_equal(np.asarray(t0), np.asarray(t))
+            lu, piv = shard_ooc.shard_getrf_ooc(
+                lp, grid8, panel_cols=w, lookahead=depth)
+            assert np.array_equal(np.asarray(l0), np.asarray(lu))
+            assert np.array_equal(np.asarray(p0), np.asarray(piv))
 
 
 @pytest.mark.slow
-def test_shard_graph_staging_exact_and_ahead(rng, grid8, obs_on):
-    """The graph route keeps the walk's exact staging prediction
-    (depth-invariant schedule bytes) and the lookahead dispatch
-    counter (nt-1 frames ahead at depth 1) — the bench --graph
-    sharded leg's gates, pinned cheaply here."""
+def test_shard_staging_exact_and_ahead(rng, grid8, obs_on):
+    """The stream stages exactly the schedule's prediction
+    (depth-invariant bytes) and dispatches nt-1 frames ahead at
+    depth 1."""
     from slate_tpu.obs import metrics
     n, w, item = 160, 32, 8
     nt = (n + w - 1) // w
@@ -242,107 +229,42 @@ def test_shard_graph_staging_exact_and_ahead(rng, grid8, obs_on):
                                 w, n - (nt - 1) * w, item, depth=1)
     metrics.reset()
     shard_ooc.shard_potrf_ooc(a, grid8, panel_cols=w, lookahead=1,
-                              cache_budget_bytes=64 * n * w * item,
-                              scheduler="graph")
+                              cache_budget_bytes=64 * n * w * item)
     c = metrics.snapshot()["counters"]
     assert int(c["ooc.h2d_bytes"]) == expect
     assert int(c["ooc.shard.bcast_ahead"]) == nt - 1
-    assert int(c["sched.graphs"]) == 1
-    assert int(c["sched.nodes_issued"]) > 0
 
 
-def test_graph_issue_counters(rng, obs_on):
-    """sched.* counters: one graph, every node issued, overhead wall
-    accrued (the bench --graph per-node overhead feed)."""
-    from slate_tpu.obs import metrics
-    a = _spd(rng, 96)
-    ooc.potrf_ooc(a, panel_cols=32, scheduler="graph")
-    c = metrics.snapshot()["counters"]
-    assert c.get("sched.graphs") == 1
-    # nt=3: 3 stage + 3 update (0+1+2) + 3 factor + 3 writeback
-    assert c.get("sched.nodes_issued") == 12
-    assert c.get("sched.issue_overhead_seconds", 0) >= 0
-
-
-# -- seeded-fault determinism across schedulers ---------------------------
-
-def test_fault_log_identical_across_schedulers(rng):
-    """The same seeded fault plan produces the same injection log,
-    retry counts, and factor on both scheduler routes — the per-panel
-    step checks and transfer guards fire in the walk's order."""
-    a = _spd(rng, 160)
-
-    def run(scheduler):
-        guard.reset_counts()
-        plan = faults.install(faults.FaultPlan([
-            {"site": "h2d", "match": {"buf": "A"}, "times": 2,
-             "prob": 0.9},
-            {"site": "d2h", "match": {"buf": "L", "idx": 1},
-             "times": 1},
-        ], seed=11))
-        L = ooc.potrf_ooc(a, panel_cols=32, scheduler=scheduler)
-        faults.clear()
-        return np.asarray(L), plan.log(), guard.counts()
-
-    Lw, logw, cw = run("walk")
-    Lg, logg, cg = run("graph")
-    assert logw == logg
-    assert cw == cg
-    assert np.array_equal(Lw, Lg)
-
+# -- seeded-fault determinism ---------------------------------------------
 
 @pytest.mark.slow
 def test_shard_step_faults_fire_in_same_order(rng, grid8):
-    """Sharded, depth 2: the probabilistic step-site occurrence
-    stream is scheduler-invariant — the graph fires the per-panel
-    check exactly where the pipeline walk does, so the same seeded
-    plan dies at the same step with the same log."""
+    """The per-panel step check fires once a panel in ascending order
+    at every depth: a plan that skips two matches and fires on the
+    third dies at step 2, third occurrence, with the same log at
+    depths 0, 1 and 2 — so seeded plans are depth-invariant."""
     a = _spd(rng, 160)
 
-    def run(scheduler):
+    def run(depth):
         plan = faults.install(faults.FaultPlan(
             [{"site": "step", "match": {"op": "shard_potrf_ooc"},
-              "times": 1, "prob": 0.4}], seed=7))
-        try:
+              "after": 2, "times": 1}], seed=7))
+        with pytest.raises(faults.InjectedFault) as e:
             shard_ooc.shard_potrf_ooc(a, grid8, panel_cols=32,
-                                      lookahead=2,
-                                      scheduler=scheduler)
-            raised = None
-        except faults.InjectedFault as e:
-            raised = (e.site, e.ctx.get("step"), e.occurrence)
+                                      lookahead=depth)
         faults.clear()
-        return raised, plan.log()
+        return (e.value.site, e.value.ctx.get("step"),
+                e.value.occurrence), plan.log()
 
-    rw, logw = run("walk")
-    rg, logg = run("graph")
-    assert rw == rg
-    assert logw == logg
+    r0, log0 = run(0)
+    assert r0 == ("step", 2, 2)
+    assert (r0, log0) == run(1) == run(2)
 
 
 # -- checkpoint/resume from mid-graph -------------------------------------
 
-def test_potrf_graph_crash_resume_bitwise(rng, tmp_path):
-    """Single-engine: crash the graph route mid-run, resume on the
-    graph route, land bitwise on the uninterrupted walk factor."""
-    a = _spd(rng, 160)
-    L0 = np.asarray(ooc.potrf_ooc(a, panel_cols=32))
-    faults.install(faults.FaultPlan(
-        [{"site": "step", "match": {"op": "potrf_ooc", "step": 3},
-          "times": 1}]))
-    with pytest.raises(faults.InjectedFault):
-        ooc.potrf_ooc(a, panel_cols=32, ckpt_path=str(tmp_path),
-                      ckpt_every=1, scheduler="graph")
-    faults.clear()
-    meta = json.loads((tmp_path / "meta.json").read_text())
-    assert meta["epoch"] == 3           # panels 0..2 durable
-    L1 = np.asarray(ooc.potrf_ooc(a, panel_cols=32,
-                                  ckpt_path=str(tmp_path),
-                                  ckpt_every=1, scheduler="graph"))
-    assert np.array_equal(L0, L1)
-
-
 @pytest.mark.slow
-def test_shard_graph_crash_resume_bitwise(rng, grid8, tmp_path):
+def test_shard_crash_resume_bitwise(rng, grid8, tmp_path):
     """Sharded, depth 2: resume FROM MID-GRAPH — the rebuilt graph's
     replay writebacks feed the surviving update chain, landing
     bitwise on the uninterrupted factor."""
@@ -357,17 +279,17 @@ def test_shard_graph_crash_resume_bitwise(rng, grid8, tmp_path):
         shard_ooc.shard_potrf_ooc(a, grid8, panel_cols=32,
                                   lookahead=2,
                                   ckpt_path=str(tmp_path),
-                                  ckpt_every=1, scheduler="graph")
+                                  ckpt_every=1)
     faults.clear()
     epoch = json.loads(
         (tmp_path / "host0" / "meta.json").read_text())["epoch"]
     assert 0 < epoch <= 3               # mid-run, commit trails issue
     L1 = np.asarray(shard_ooc.shard_potrf_ooc(
         a, grid8, panel_cols=32, lookahead=2,
-        ckpt_path=str(tmp_path), ckpt_every=1, scheduler="graph"))
+        ckpt_path=str(tmp_path), ckpt_every=1))
     assert np.array_equal(L0, L1)
-    # cross-scheduler resume parity: a walk crash resumed by the
-    # graph route lands on the same factor too
+    # cross-depth resume parity: a synchronous run's crash resumed
+    # at depth 1 lands on the same factor too
     g = rng.standard_normal((160, 160))
     qr0, tau0 = shard_ooc.shard_geqrf_ooc(g, grid8, panel_cols=32)
     faults.install(faults.FaultPlan(
@@ -377,11 +299,10 @@ def test_shard_graph_crash_resume_bitwise(rng, grid8, tmp_path):
     ck2 = tmp_path / "qr"
     with pytest.raises(faults.InjectedFault):
         shard_ooc.shard_geqrf_ooc(g, grid8, panel_cols=32,
-                                  ckpt_path=str(ck2), ckpt_every=1,
-                                  scheduler="walk")
+                                  ckpt_path=str(ck2), ckpt_every=1)
     faults.clear()
     qr1, tau1 = shard_ooc.shard_geqrf_ooc(
         g, grid8, panel_cols=32, lookahead=1, ckpt_path=str(ck2),
-        ckpt_every=1, scheduler="graph")
+        ckpt_every=1)
     assert np.array_equal(np.asarray(qr0), np.asarray(qr1))
     assert np.array_equal(np.asarray(tau0), np.asarray(tau1))

@@ -96,14 +96,6 @@ def test_two_process_shard_ooc(tmp_path):
         assert la["bcast_ahead"] == nt - 1
         assert la["bcast_inflight_s"] >= la["bcast_wait_s"] > 0
 
-    # task-graph runtime (ISSUE 17): scheduler="graph" is bitwise
-    # against the depth-1 walk for all three drivers on the real
-    # 2-process mesh (the workers compute both routes in-process)
-    for r in recs:
-        gr = r["shard_graph"]
-        assert gr["potrf_bitwise"] and gr["geqrf_bitwise"] \
-            and gr["getrf_bitwise"]
-
     # mixed-precision streaming (ISSUE 12): the frozen cold route is
     # bitwise on the real mesh (default vs explicit "f32" for all
     # three drivers), and the bf16 potrf's broadcast frames carried

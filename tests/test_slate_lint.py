@@ -49,7 +49,7 @@ def test_registry_covers_all_analyzers():
     assert {"SL101", "SL102", "SL103", "SL104", "SL105", "SL106",
             "SL201", "SL202", "SL203", "SL301", "SL401", "SL402",
             "SL501", "SL502", "SL503", "SL601", "SL602",
-            "SL603", "SL701", "SL702", "SL703", "SL801",
+            "SL603", "SL701", "SL702", "SL801",
             "SL802", "SL803", "SL901", "SL902", "SL903"} == codes
 
 
@@ -666,7 +666,7 @@ def test_flight_append_phase_keys_checked(tmp_path):
     assert res.findings[0].path == "slate_tpu/batch/queue.py"
 
 
-# -- sched-graph (SL701/SL702/SL703) --------------------------------------
+# -- sched-graph (SL701/SL702) --------------------------------------------
 
 _SCHED_LEDGER = _FLIGHT_LEDGER
 
@@ -693,31 +693,18 @@ _SCHED_GRAPH_CLEAN = """
     }
 """
 
-_SCHED_TUNE = """
-    FROZEN = {
-        ("ooc", "scheduler"): "walk",
-    }
-"""
-
-_SCHED_READER = """
-    def resolve_scheduler(n, dtype):
-        return _resolve("ooc", "scheduler", n=n, dtype=dtype)
-"""
-
 
 def test_sched_graph_clean(tmp_path):
     repo = _write(tmp_path, {
         "slate_tpu/obs/ledger.py": _SCHED_LEDGER,
         "slate_tpu/resil/faults.py": _SCHED_FAULTS,
         "slate_tpu/sched/graph.py": _SCHED_GRAPH_CLEAN,
-        "slate_tpu/tune/cache.py": _SCHED_TUNE,
-        "slate_tpu/core/methods.py": _SCHED_READER,
     })
     res = _only(repo, "sched-graph")
     assert res.findings == []
 
 
-def test_sched_graph_catches_all_three(tmp_path):
+def test_sched_graph_catches_both(tmp_path):
     repo = _write(tmp_path, {
         "slate_tpu/obs/ledger.py": _SCHED_LEDGER,
         "slate_tpu/resil/faults.py": _SCHED_FAULTS,
@@ -733,18 +720,12 @@ def test_sched_graph_catches_all_three(tmp_path):
                 "factor": None,           # "update" unmapped: SL702
             }
         """,
-        "slate_tpu/tune/cache.py": """
-            FROZEN = {}                   # row missing: SL703
-        """,
-        "slate_tpu/core/methods.py": "",  # no reader: SL703
     })
     res = _only(repo, "sched-graph")
-    assert _codes(res.findings) == ["SL701", "SL702", "SL702",
-                                    "SL703", "SL703"]
+    assert _codes(res.findings) == ["SL701", "SL702", "SL702"]
     msgs = " ".join(f.message for f in res.findings)
     assert "'stag'" in msgs               # the off-vocabulary phase
     assert "'h2dd'" in msgs               # the unknown fault site
-    assert "('ooc', 'scheduler')" in msgs
 
 
 def test_sched_graph_live_tables_match_runtime():

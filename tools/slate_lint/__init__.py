@@ -54,10 +54,9 @@ Rule-numbering history (the check_instrumented.py lineage):
 
 * PR 17 (ISSUE 17):
 
-    SL701/SL702/SL703  task-graph runtime contract: node kinds map
+    SL701/SL702        task-graph runtime contract: node kinds map
                        onto ledger phases and registered fault
-                       sites, FROZEN ooc/scheduler row + literal
-                       reader                 (:mod:`.sched_graph`)
+                       sites                  (:mod:`.sched_graph`)
 
 * PR 18 (ISSUE 18):
 

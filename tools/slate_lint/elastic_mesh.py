@@ -27,8 +27,7 @@ agreements hold — each invisible from any single call site:
          route), and every companion ``("mesh", *)`` knob row
          (remap_every / remap_threshold / throughput_alpha) likewise
          has a literal reader — a row without its reader keeps
-         shipping a default nobody consults (the SL703 failure mode
-         carried into the mesh layer).
+         shipping a default nobody consults.
 """
 
 from __future__ import annotations

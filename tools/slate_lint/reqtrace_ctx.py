@@ -31,8 +31,7 @@ publishers honest:
          exist in tune/cache.py AND each has a literal two-arg key
          read in ``slate_tpu/`` (the gates' ``resolve()`` memos) —
          a row without its reader ships a default nobody consults, a
-         reader without the row silently falls back (the SL703
-         contract, carried to the observability gates).
+         reader without the row silently falls back.
 """
 
 from __future__ import annotations
@@ -163,7 +162,7 @@ def analyze(repo: str) -> List[Finding]:
                 "collectable publisher (span closure should feed "
                 "the serve.latency_s family)"))
 
-    # SL803: gate rows + literal readers (the SL703 pattern)
+    # SL803: gate rows + literal readers
     tpath = os.path.join(repo, TUNE_CACHE_PATH)
     frozen = astutil.frozen_keys(tpath)
     missing_reader = {row: True for row in GATE_ROWS}

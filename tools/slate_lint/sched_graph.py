@@ -1,4 +1,4 @@
-"""Analyzer (f): the task-graph runtime contract (SL701/SL702/SL703,
+"""Analyzer (f): the task-graph runtime contract (SL701/SL702,
 ISSUE 17).
 
 The sched/ runtime only attributes and faults correctly when its
@@ -15,17 +15,10 @@ agreements no single call site can see:
          and its non-None values name registered fault sites
          (resil/faults.SITES) — a kind mapped to an unknown site
          advertises an injection point that can never fire.
-  SL703  the scheduler arbitration ships: the FROZEN
-         ``("ooc", "scheduler")`` row exists in tune/cache.py AND at
-         least one literal ``("ooc", "scheduler")`` key read exists
-         in slate_tpu/ (the MethodScheduler.resolve route) — a row
-         without its reader keeps shipping a default nobody
-         consults, a reader without the row silently falls back.
 """
 
 from __future__ import annotations
 
-import ast
 import os
 from typing import List
 
@@ -35,25 +28,11 @@ from .core import Finding, register
 GRAPH_PATH = "slate_tpu/sched/graph.py"
 LEDGER_PATH = "slate_tpu/obs/ledger.py"
 FAULTS_PATH = "slate_tpu/resil/faults.py"
-TUNE_CACHE_PATH = "slate_tpu/tune/cache.py"
-SCHED_ROW = ("ooc", "scheduler")
 
 
-def _literal_row_reads(tree):
-    """Lines of calls whose first two args are the literal
-    ("ooc", "scheduler") key (tune_keys.KEY_READERS family)."""
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Call) or len(node.args) < 2:
-            continue
-        if astutil.const_str(node.args[0]) == SCHED_ROW[0] \
-                and astutil.const_str(node.args[1]) == SCHED_ROW[1]:
-            yield node.lineno
-
-
-@register("sched-graph", ("SL701", "SL702", "SL703"),
+@register("sched-graph", ("SL701", "SL702"),
           "task-graph node kinds map completely onto ledger phases "
-          "and registered fault sites; the FROZEN ooc/scheduler "
-          "arbitration row ships with a literal reader (ISSUE 17)")
+          "and registered fault sites (ISSUE 17)")
 def analyze(repo: str) -> List[Finding]:
     findings: List[Finding] = []
 
@@ -122,26 +101,4 @@ def analyze(repo: str) -> List[Finding]:
                 "fault site (resil/faults.SITES %r) — an injection "
                 "point that can never fire"
                 % (k, v, tuple(sorted(site_set)))))
-
-    # SL703: the arbitration row plus a literal reader
-    tpath = os.path.join(repo, TUNE_CACHE_PATH)
-    if SCHED_ROW not in astutil.frozen_keys(tpath):
-        findings.append(Finding(
-            "SL703", TUNE_CACHE_PATH, 0,
-            "FROZEN row %r missing — the scheduler cold route must "
-            "ship in the tune table" % (SCHED_ROW,)))
-    reads = []
-    for path in astutil.py_files(os.path.join(repo, "slate_tpu")):
-        tree = astutil.parse(path)
-        if tree is None:
-            continue
-        reads.extend(_literal_row_reads(tree))
-        if reads:
-            break
-    if not reads:
-        findings.append(Finding(
-            "SL703", TUNE_CACHE_PATH, 0,
-            "no literal %r key read anywhere in slate_tpu/ — the "
-            "FROZEN scheduler row has no reader, so the arbitration "
-            "is dead" % (SCHED_ROW,)))
     return findings
