@@ -436,11 +436,11 @@ class MethodLUPivot(enum.Enum):
     """Pivot discipline of the out-of-core LU stream (ISSUE 10):
 
       * ``Partial``: partial pivoting confined to the resident panel
-        (the PR 4 ``getrf_ooc`` discipline) — the panel's row swaps
-        are applied host-side to already-written L panels, which
-        retires every cached L panel (the stream.py epoch bump) and
-        bars the sharded layer (a per-pivot cross-shard re-stage
-        storm);
+        (the PR 4 ``getrf_ooc`` discipline) — since PR 47 the row
+        swaps are applied on the chip at the time of use and a
+        written panel is never rewritten (no cache entry retired),
+        but its rows are final only after one repair at the end,
+        which bars the checkpoint and the sharded layer;
       * ``Tournament``: CALU-style tournament pivoting
         (ca.tournament_pivot_rows) — the pivot permutation is
         finalized BEFORE the panel's factor column is written, factor
@@ -456,10 +456,11 @@ class MethodLUPivot(enum.Enum):
     tunable; FROZEN default "partial"), so with no tune entry a call
     that names no discipline takes the partial stream (pinned by
     tests). Both were read on a TPU v5e at n=32768 in panels of 4096
-    on a matrix whose every panel swaps rows past itself (PERF.md,
-    PR 46): Partial 15.1-15.2 s a warm ``gesv_ooc``, Tournament
-    11.9-12.4 s, once its chunk nomination compiled there
-    (ca._chunk_pivot_rows). The default was not moved by that PR: the
+    on a matrix whose every panel swaps rows past itself: Partial
+    5.4-5.5 s a warm ``gesv_ooc`` (PERF.md, PR 47; 15.1-15.2 s
+    while the host moved the rows), Tournament 11.9-12.4 s (PR 46),
+    once its chunk nomination compiled there
+    (ca._chunk_pivot_rows). The default is ``Partial``: the
     benchmark cell ``stream-gesv`` runs whatever this resolves to,
     and its limits hold for either."""
     Auto = "auto"

@@ -105,9 +105,11 @@ bf16's 256-integer window, rides a byte-split PAIR of payload rows
 transport.
 
 ``shard_getrf_ooc`` (ISSUE 10) closes the LU deferral that PR 7
-recorded: partial pivoting's host-side row-swap fixup rewrites rows
-of already-written L panels — under sharding, an epoch-bump broadcast
-plus a re-stage storm per cross-panel pivot. The unlock is CALU-style
+recorded: partial pivoting's host-side row-swap fixup rewrote rows
+of already-written L panels (until PR 47; its panels' rows are still
+final only after the last panel's pivots) — under sharding, an
+epoch-bump broadcast plus a re-stage storm per cross-panel pivot. The
+unlock is CALU-style
 tournament pivoting (linalg/ca.tournament_pivot_rows, the structure
 "Large Scale Distributed Linear Algebra With TPUs" uses for
 TPU-distributed LU): the owner finalizes panel k's pivot permutation

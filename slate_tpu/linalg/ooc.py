@@ -24,20 +24,21 @@ QR (reference src/getrf.cc:327 / src/geqrf.cc:26 operate at any n the
 cluster's aggregate memory holds; one TPU chip reaches the same
 regime by streaming through host RAM):
 
-- getrf_ooc: panel k is read through the CURRENT row permutation,
-  visited by every earlier factor panel (U12 strip by one unit-lower
-  solve + trailing rank-w update), then factored in-core with partial
-  pivoting CONFINED to the resident panel (the standard left-looking
-  OOC-LU pivot discipline — LAPACK's out-of-core prototypes and
-  CALU's panel-local search share it). The panel's row swaps are then
-  applied host-side to the already-written L panels (cheap row
-  gathers) and folded into the running permutation for future reads.
+- getrf_ooc: panel k is staged as it lies and put into the CURRENT
+  row order on the chip, visited by every earlier factor panel (U12
+  strip by one unit-lower solve + trailing rank-w update), then
+  factored in-core with partial pivoting CONFINED to the resident
+  panel (the standard left-looking OOC-LU pivot discipline —
+  LAPACK's out-of-core prototypes and CALU's panel-local search
+  share it). The panel is written once, in the order it was factored
+  in; a later visit gathers it on the chip into the order of its day,
+  and one repair at the end puts the stored rows into the final
+  order (PR 47: the host moves no row).
   getrf_tntpiv_ooc (ISSUE 10) is the CALU alternative arbitrated by
   the ``ooc/lu_pivot`` tunable: tournament pivot selection finalizes
   each panel's permutation BEFORE its column is written, the factor
   is stored in original row order with the permutation applied at
-  visit time by a device gather, so written panels are immutable —
-  no fixups, zero cache invalidations, checkpointable, and shardable
+  visit time by a device gather — checkpointable, and shardable
   (dist/shard_ooc.shard_getrf_ooc).
 - geqrf_ooc: panel k is visited by every earlier panel's compact-WY
   reflector block (V and T rebuilt on the fly from the packed factor
@@ -813,6 +814,29 @@ def _lu_panel_factor(S: jax.Array, k0, nb: int):
 
 
 @jax.jit
+def _lu_rows(P: jax.Array, idx: jax.Array) -> jax.Array:
+    """Rows `idx` of panel P, in that order: how the partial-pivot
+    stream applies a row permutation (the running one to an input
+    panel staged as it lies, a stored factor panel's order against
+    today's before its visit, the final order in the repair). A
+    program of its own, so that `_lu_visit` and `_lu_panel_factor`
+    are the programs they were, on the operands they saw when the
+    host did the gathers; exact, so the factor is too."""
+    return jnp.take(P, idx, axis=0, mode="clip")
+
+
+@jax.jit
+def _lu_col(S: jax.Array, packed: jax.Array, k0) -> jax.Array:
+    """The factored panel as one full-height column: the visits' U
+    rows of S above traced row k0, `_lu_panel_factor`'s rolled result
+    (live rows first) rolled back below it. What is written to the
+    host and what the cache serves to later visits."""
+    m, wf = packed.shape
+    return jnp.where((jnp.arange(m) < k0)[:, None], S[:, :wf],
+                     jnp.roll(packed, k0, axis=0))
+
+
+@jax.jit
 def _lu_back_visit(S: jax.Array, Pk: jax.Array, k0) -> jax.Array:
     """Backward U sweep step: x_k = U_kk^{-1} S[k0:k1], then eliminate
     U[:k0, k0:k1] x_k from the rows above (streamed upper solve)."""
@@ -876,39 +900,56 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     test). What the two read on a TPU v5e at n=32768, nrhs 8, panels
     of 4096, five of eight resident, on HPL's uniform(-0.5, 0.5)
     matrix, where every panel's pivots leave the panel (gesv_ooc,
-    warm, PERF.md PR 46): "partial" 15.1-15.2 s a solve, 25.2 GB
-    staged, 3.0 s of device time, 5.7 s in the input gathers and
-    4.2 s in the fixups; "tournament" 11.9-12.4 s, 12.9 GB staged,
-    4.4 s of device time, 5.7 s in the final gather. The default was
-    left where it was: the cell ``stream-gesv`` is there to judge it.
+    warm, by hand): "partial" 5.4-5.5 s a solve, 13.8 GB staged,
+    3.1 s of device time, 0.4 s in the one repair (PERF.md PR 47;
+    15.1-15.2 s, 25.2 GB and 3.0 s before it, 9.9 s of them host
+    row gathers and fixups);
+    "tournament" 11.9-12.4 s, 12.9 GB staged, 4.4 s of device time,
+    5.7 s in the final gather (PERF.md PR 46). The default is
+    "partial": the cell ``stream-gesv`` is there to judge it.
     (docs/PERF_HISTORY.md's 2,104 s for n=65536 is another machine's.)
 
       * "partial" (this body): partial pivoting CONFINED to the
         resident panel — each column's pivot search sees rows k0:
         (everything not yet factored), exactly the rows in-core getrf
         would search, so the factorization matches the in-core one up
-        to roundoff. Row swaps are applied host-side to already-
-        written L panels (O(n*w) gathers per panel) and folded into
-        the running permutation that future panel reads go through.
-        The row-swap fixup retires every cached L panel (epoch bump +
-        the ``ooc.lu_invalidations`` counter, stream.py) — a stale
-        pre-swap panel served to a later visit would be a wrong
-        answer — so LU only profits from the cache on swap-free
-        panels (on a random dense matrix there are none: 5 hits in 44
-        visits at n=32768, all in the solve's sweeps). No checkpoint
-        support: the fixups rewrite committed panels, which breaks
-        the durable-epoch contract.
-      * "tournament": the CALU stream (getrf_tntpiv_ooc) — immutable
-        factor panels, zero invalidations, checkpoint/resume, and the
-        route the sharded layer requires.
+        to roundoff. The host moves no row (PR 47). STAGED: input
+        panel k as it lies (a strided view packed into a ring slot,
+        prefetched while panel k-1 is visited), and the factor
+        panels the cache does not hold. GATHERED, on the chip, by one
+        exact row gather each (_lu_rows): the input panel through
+        the running permutation; at each visit the stored panel j
+        through r = inv(P_j)[P_now], its order against today's (the
+        identity above j1). STORED: panel j once, host and cache, in
+        the order it had when it was factored (rows j1: after its own
+        pivots; rows above j1 never move again), so nothing written
+        is rewritten, nothing cached is retired
+        (``ooc.lu_invalidations`` stays 0) and the visits are served
+        from the chip as far as the budget goes. REPAIRED, once,
+        after the last panel (``ooc::lu_fixup``, ``ooc.lu_fixup_
+        bytes``): rows j1: of each panel before the last gathered on
+        the chip into the final order, from the resident panel or
+        from those rows staged alone, and written over the stored
+        ones — each stored row moves at most once, and (lu, ipiv) is
+        LAPACK's packed contract. The factor, the pivots and X are
+        bitwise what the host-order walk before PR 47 returned. No
+        checkpoint support: a committed panel's rows j1: are not
+        final until the repair, which breaks the durable-epoch
+        contract.
+      * "tournament": the CALU stream (getrf_tntpiv_ooc) — the
+        factor stored in ORIGINAL row order, three gathers a visit,
+        one O(n^2) host gather at the end; checkpoint/resume, and
+        the route the sharded layer requires.
 
     With a ``grid``, the MethodOOC arbitration (see potrf_ooc) can
     route to dist/shard_ooc.shard_getrf_ooc — tournament-only by
-    construction (a partial-pivot fixup would be a per-pivot
-    cross-shard re-stage storm, the reason PR 7 deferred LU); asking
-    for the sharded route with an explicit partial mode is an error.
-    HBM residency: two (m, w) panels (plus the residency cache when
-    a budget is set)."""
+    construction (its right-looking schedule needs a panel's rows
+    final when they are written; the partial stream's are final only
+    after the repair); asking for the sharded route with an explicit
+    partial mode is an error.
+    HBM residency: three (m, w) panels (the resident one, the
+    visitor and its gathered copy; plus the residency cache when a
+    budget is set)."""
     from ..core.exceptions import slate_assert
     from ..core.methods import MethodLUPivot, str2method
     a = np.asarray(a)
@@ -923,24 +964,23 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
         mode = MethodLUPivot.resolve(n, a.dtype)
     lo = _resolve_precision(precision, n, a.dtype)
     if lo is not None:
-        # the mixed update path requires the immutable tournament
-        # store (ISSUE 12): a partial-pivot fixup rewrites committed
-        # panels the cache holds in DEMOTED form — re-deriving the
-        # residents after a host-side f32 rewrite would interleave
-        # two rounding histories in one factor. bf16 implies
+        # the mixed update path is the tournament stream's (ISSUE
+        # 12): its visit kernel, its demoted residents and its
+        # checkpoint meta; the partial body stages, caches and
+        # multiplies in the input dtype only. bf16 implies
         # tournament; asking for both explicitly is an error.
         slate_assert(
             asked is not MethodLUPivot.Partial,
             "the mixed-precision OOC LU is tournament-only (the "
-            "partial-pivot fixup rewrites panels the cache holds "
-            "demoted); drop pivot='partial' or precision='bf16'")
+            "partial-pivot stream has no demoted update path); "
+            "drop pivot='partial' or precision='bf16'")
         mode = MethodLUPivot.Tournament
     if _route_shard(n, ceil_div(n, w), grid, method, a.dtype):
         slate_assert(
             asked is None or asked is MethodLUPivot.Tournament,
             "the sharded OOC LU is tournament-only (a partial-pivot "
-            "fixup is a per-pivot cross-shard re-stage storm); drop "
-            "pivot='partial' or route method=Stream")
+            "panel's rows are final only after the last panel's "
+            "pivots); drop pivot='partial' or route method=Stream")
         from ..dist.shard_ooc import shard_getrf_ooc
         return _shard_escalate(
             lambda: shard_getrf_ooc(
@@ -961,18 +1001,26 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                                 precision=precision)
     slate_assert(
         ckpt_path is None,
-        "partial-pivot OOC LU cannot checkpoint (row-swap fixups "
-        "rewrite committed panels); use pivot='tournament'")
+        "partial-pivot OOC LU cannot checkpoint (a committed panel's "
+        "rows are final only after the repair at the end); use "
+        "pivot='tournament'")
     _note_lu_route("partial", m, min(w, kmax), incore_nb, a.dtype)
     perm = np.arange(m)
     out = np.empty_like(a)
     ipiv = np.empty((kmax,), np.int64)
     nt = ceil_div(n, w)
+    # where[j][row]: the position of original row `row` in factor
+    # panel j AS STORED (the order after panel j's own pivots)
+    where = np.empty((ceil_div(kmax, w), m), np.int32)
     eng = stream.engine_for(max(m, n), w, a.dtype,
                             budget_bytes=cache_budget_bytes)
     led = _ledger.recorder("getrf_ooc", nt=nt)
     span = obs_events.span
     word = a.dtype.itemsize
+
+    def stored(j0, j1):
+        return lambda: out[:, j0:j1]
+
     try:
         for k0 in range(0, n, w):
             k1 = min(k0 + w, n)
@@ -981,55 +1029,46 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                 led.begin(k)
             _health.heartbeat("getrf_ooc", k, nt)
             with _ledger.frame("stage"):
-                with span("ooc::lu_gather", cat="staging",
-                          bytes=m * (k1 - k0) * word):
-                    Sh = np.take(a[:, k0:k1], perm, axis=0)
-                S = _h2d(Sh)                                   # H2D
-                del Sh
+                S = eng.fetch("Ain", k, lambda k0=k0, k1=k1: a[:, k0:k1],
+                              cache=False)                     # H2D
+            if k1 < n:
+                eng.prefetch("Ain", k + 1,
+                             lambda n0=k1, n1=min(k1 + w, n): a[:, n0:n1],
+                             cache=False)
+            with _ledger.frame("update"):
+                # (a copy of perm: it changes under a gather in flight)
+                S = _lu_rows(S, jnp.asarray(perm.astype(np.int32)))
             for j0 in range(0, min(k0, kmax), w):
                 j1 = min(j0 + w, kmax)
                 with _ledger.frame("stage"):
-                    Lj = eng.fetch("LU", j0 // w,
-                                   lambda j0=j0, j1=j1:
-                                   out[:, j0:j1])
+                    Lj = eng.fetch("LU", j0 // w, stored(j0, j1))
                 if j0 + w < min(k0, kmax):
-                    p0, p1 = j0 + w, min(j0 + 2 * w, kmax)
-                    eng.prefetch("LU", p0 // w,
-                                 lambda p0=p0, p1=p1: out[:, p0:p1])
+                    eng.prefetch("LU", j0 // w + 1,
+                                 stored(j0 + w, min(j0 + 2 * w, kmax)))
                 with _ledger.frame("update"):
+                    # the stored panel's rows, in today's order
+                    Lj = _lu_rows(Lj, jnp.asarray(where[j0 // w][perm]))
                     S = _lu_visit(S, Lj, j0)
             if k0 < kmax:
                 wf = min(k1, kmax) - k0
                 with _ledger.frame("factor"):
                     packed, piv = _lu_panel_factor(
                         S[:, :wf], k0, min(incore_nb, max(wf, 1)))
+                    col = _lu_col(S, packed, k0)
                 # the one place a panel step waits for the device
                 with span("ooc::lu_pivots", cat="staging", k=k):
                     piv_h = np.asarray(piv)
                 lperm = _swaps_to_perm(piv_h, m - k0)
-                # host fixups: swap rows of the L panels already
-                # written, and of the running permutation for future
-                # reads. The fixup reads+rewrites host rows still in
-                # writeback flight — drain the writer first — and
-                # stale cached copies of the swapped panels must be
-                # retired (wrong-answer guard, pinned by tests)
                 if k0 > 0 and not np.array_equal(
                         lperm, np.arange(m - k0)):
-                    fix_bytes = 2 * (m - k0) * k0 * word
-                    with span("ooc::lu_fixup", cat="staging", k=k,
-                              bytes=fix_bytes):
-                        eng.wait_writes()
-                        out[k0:, :k0] = out[k0:, :k0][lperm]
-                        eng.invalidate("LU", cause="lu")
-                    obs_metrics.inc("ooc.lu_fixup_bytes", fix_bytes)
                     obs_metrics.inc("ooc.lu_panels_swapped")
                 perm[k0:] = perm[k0:][lperm]
+                where[k][perm] = np.arange(m, dtype=np.int32)
                 ipiv[k0:k0 + wf] = k0 + piv_h
-                if k0 > 0:
-                    eng.write("LU", k, S[:k0],    # U rows from visits
-                              out[:k0, k0:k1])
-                eng.write("LU", k, packed[:m - k0],
-                          out[k0:, k0:k0 + wf])
+                # written once, in the order it was factored in, and
+                # served from the chip in that order from now on
+                eng.put("LU", k, col)
+                eng.write("LU", k, col, out[:, k0:k0 + wf])
                 if wf < k1 - k0:
                     # kmax falls inside this panel (m < n): the
                     # columns right of the last diagonal block are
@@ -1038,6 +1077,9 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                     rest = S[k0:, wf:][jnp.asarray(lperm)]
                     U = _unit_lower_solve_capped(packed[:wf, :wf],
                                                  rest[:wf])
+                    if k0 > 0:
+                        eng.write("LU", k, S[:k0, wf:],
+                                  out[:k0, k0 + wf:k1])
                     out[k0:k0 + wf, k0 + wf:k1] = np.asarray(U)
             else:
                 eng.write("LU", k, S,    # columns past kmax: all U
@@ -1047,6 +1089,33 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
         _health.heartbeat("getrf_ooc", nt, nt)   # completion beat
         if led is not None:
             led.begin(nt, drain=True)                # final drain record
+        # the one repair: rows j1: of each panel written before the
+        # pivots below it were known are gathered on the chip into the
+        # final order and written over the stored ones, from the
+        # resident panel where the cache still holds it, from those
+        # rows staged alone where it does not
+        moved = []
+        for j0 in range(0, kmax, w):
+            j1 = min(j0 + w, kmax)
+            idx = where[j0 // w][perm[j1:]]
+            if not np.array_equal(idx, np.arange(j1, m)):
+                moved.append((j0, j1, idx))
+        if moved:
+            fix_bytes = sum(2 * len(idx) * (j1 - j0) * word
+                            for j0, j1, idx in moved)
+            with span("ooc::lu_fixup", cat="staging", bytes=fix_bytes):
+                for j0, j1, idx in moved:
+                    j = j0 // w
+                    Lj = eng.cache.get(eng.cache.key("LU", j), m - j1) \
+                        if eng.caching else None
+                    if Lj is None:
+                        Lj = eng.fetch("LU", j, lambda j0=j0, j1=j1:
+                                       out[j1:, j0:j1], cache=False)
+                        idx = idx - j1
+                    eng.write("LU", j, _lu_rows(Lj, jnp.asarray(idx)),
+                              out[j1:, j0:j1])
+                eng.wait_writes()
+            obs_metrics.inc("ooc.lu_fixup_bytes", fix_bytes)
         eng.wait_writes()
     finally:
         eng.finish()
@@ -1057,20 +1126,24 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
 
 # -- tournament-pivot (CALU) out-of-core LU -------------------------------
 #
-# The partial-pivot stream above must rewrite already-written L panels
-# on every cross-panel pivot (the host fixup + epoch-bump invalidation
-# its docstring records). The tournament variant removes the rewrite
-# structurally (ISSUE 10): factor panels are STORED IN ORIGINAL ROW
-# ORDER and the running permutation is applied at VISIT time by a
-# device-side index gather — a written panel never changes, so the
-# panel-residency cache (`put` at normal form) finally works for LU,
-# and the sharded right-looking schedule (dist/shard_ooc.py) becomes
-# possible because a factor step never touches another shard's bytes.
-# Pivot selection is the CALU tournament (ca.tournament_pivot_rows —
-# the structure the TPU-distributed-linalg paper uses), finalized
-# BEFORE the panel's column is written; one O(n^2) host gather at the
-# end converts the original-order store to the standard LAPACK packed
-# layout, so getrs_ooc consumes either mode's factor unchanged.
+# Both streams keep a written factor panel as it was written and apply
+# row permutations on the chip at the time of use (the partial-pivot
+# stream since PR 47; before it, it rewrote every written L panel on
+# the host after each cross-panel pivot and retired the cache). They
+# differ in the order stored and in who picks the pivots. Partial:
+# panel j in the order it was factored in, ONE gather a visit (the
+# visitor; the resident panel is already in today's order), rows j1:
+# of each panel repaired once at the end, on the chip. Tournament
+# (ISSUE 10): every panel in ORIGINAL row order, three gathers a
+# visit (_lu_visit_orig: both operands in, the result back), one
+# O(n^2) host gather at the end (_finalize_lapack_order) to the
+# standard LAPACK packed layout, so getrs_ooc consumes either mode's
+# factor unchanged; a panel's rows are final when it is written,
+# which is what the checkpoint and the sharded right-looking
+# schedule (dist/shard_ooc.py: a factor step never touches another
+# shard's bytes) need. Pivot selection is the CALU tournament
+# (ca.tournament_pivot_rows — the structure the TPU-distributed-
+# linalg paper uses), finalized BEFORE the panel's column is written.
 
 
 @jax.jit
@@ -1212,12 +1285,11 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     What tournament pivoting buys the stream (section comment above):
     the pivot permutation of panel k is FINAL before its column is
     written, factor panels live in original row order and are never
-    rewritten, so there are no host fixups and ZERO cache
-    invalidations — `put` at factor time makes every left-looking
-    revisit a cache hit under a budget, exactly like potrf/geqrf (the
-    partial-pivot stream retires its whole cache per cross-panel
-    pivot). The permutation is applied at visit time as a device
-    index gather (_lu_visit_orig); index-vector uploads are NOT
+    rewritten, so there are ZERO cache invalidations — `put` at
+    factor time makes every left-looking revisit a cache hit under a
+    budget, exactly like potrf/geqrf (and, since PR 47, the
+    partial-pivot stream). The permutation is applied at visit time
+    as a device index gather (_lu_visit_orig); index-vector uploads are NOT
     routed through _h2d, keeping the h2d counters panel-pure (an
     index vector is ~2/w of a panel — the sharded layer's staged-byte
     prediction stays exact).
@@ -1236,8 +1308,9 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     records ``lu_pivot="tournament"``, so a resume against a
     partial-mode (or any mismatched) checkpoint starts fresh instead
     of mixing disciplines. The partial-pivot stream cannot
-    checkpoint at all (its fixups rewrite committed panels); this
-    path's immutability is what makes the LU checkpoint sound.
+    checkpoint at all (its committed panels' rows are final only
+    after the repair at its end); this path's rows are final when
+    written, which is what makes the LU checkpoint sound.
 
     ``precision`` (ISSUE 12): the mixed-precision mode (potrf_ooc
     doc) — under "bf16" the left-looking visits stage/cache/multiply
@@ -1358,7 +1431,7 @@ def getrf_tntpiv_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
         piv_rel, lperm = tnt_swaps_host(sel, live)
         if k0 > 0 and obs_events.enabled() and not np.array_equal(
                 lperm, np.arange(live)):
-            # pivots that left the panel: a fixup under `partial`
+            # pivots that left the panel (`partial` counts the same)
             obs_metrics.inc("ooc.lu_panels_swapped")
         new_live = perm[k0:][lperm]
         idx2 = np.concatenate([new_live, perm[:k0]])

@@ -324,7 +324,7 @@ def test_mixed_programs_compile_at_the_cells_size(one_chip, program):
         assert ma.output_size_in_bytes < 2 * n * n + (1 << 20)
 
 
-@pytest.mark.parametrize("program", ["panel", "chunks"])
+@pytest.mark.parametrize("program", ["panel", "chunks", "rows", "col"])
 def test_stream_lu_programs_compile_at_the_cells_height(one_chip, program,
                                                         monkeypatch):
     """The streamed LU of `stream-gesv` (PR 46) at its 32768 rows: the
@@ -336,7 +336,11 @@ def test_stream_lu_programs_compile_at_the_cells_height(one_chip, program,
     of 8192 rows, which the batched native LU cannot take
     (`ca._chunk_pivot_rows` runs them one at a time there). The
     routing asks `jax.default_backend()`, which sees the CPU here: it
-    is steered to the chip's answer."""
+    is steered to the chip's answer. And the two programs through
+    which the partial stream moves rows on the chip (PR 47) at the
+    cell's whole panel, (32768, 4096): the row gather and the
+    factored panel put together as one column; neither keeps a
+    second panel of temporaries beside its result."""
     from slate_tpu.linalg import ca, ooc
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     n, w = 32768, 1024
@@ -346,12 +350,18 @@ def test_stream_lu_programs_compile_at_the_cells_height(one_chip, program,
                   {"nb": 256}),       # what the cell's 1024 is capped to
         "chunks": (jax.jit(ca._chunk_pivot_rows),
                    [((4, n // 4, w), f32)], {}),
+        "rows": (ooc._lu_rows, [((n, 4 * w), f32), ((n,), i32)], {}),
+        "col": (ooc._lu_col, [((n, 4 * w), f32), ((n, 4 * w), f32),
+                              ((), i32)], {}),
     }[program]
     compiled = _compile(fn, one_chip, *shapes, kernel=False, limit_s=60.0,
                         **static)
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes < 1 << 30, ma.temp_size_in_bytes
-    if program == "panel":
+    if program in ("rows", "col"):
+        assert ma.output_size_in_bytes == n * 4 * w * 4
+        assert ma.temp_size_in_bytes <= n * 4 * w * 4
+    elif program == "panel":
         assert "LuDecompositionBlock" not in compiled.as_text()
     else:
         # the same nomination, batched, is what the compiler refuses

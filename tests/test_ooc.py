@@ -230,3 +230,109 @@ def test_getrf_ooc_invert_route(rng, monkeypatch):
     assert np.array_equal(piv, ref_piv)
     assert np.abs(lu - ref_lu).max() < 1e-9
     assert np.abs(a @ x - b).max() < 1e-9
+
+
+# -- the partial-pivot stream against the walk it replaced (PR 47) --------
+
+def _host_order_getrf(a, w, incore_nb=1024):
+    """The walk `getrf_ooc`'s partial body made until PR 47, kept as
+    the oracle: the host store in CURRENT row order at every step, so
+    the input panel is gathered on the host through the running
+    permutation and every written panel's rows k0: are rewritten
+    after each panel's pivots; around the same `_lu_visit` and
+    `_lu_panel_factor`. No engine, no cache, no thread."""
+    import jax.numpy as jnp
+    from slate_tpu.linalg import ooc
+    m, n = a.shape
+    kmax = min(m, n)
+    perm, out = np.arange(m), np.empty_like(a)
+    ipiv = np.empty((kmax,), np.int64)
+    for k0 in range(0, n, w):
+        k1 = min(k0 + w, n)
+        S = jnp.asarray(np.take(a[:, k0:k1], perm, axis=0))
+        for j0 in range(0, min(k0, kmax), w):
+            S = ooc._lu_visit(S, jnp.asarray(out[:, j0:min(j0 + w, kmax)]),
+                              j0)
+        if k0 >= kmax:
+            out[:, k0:k1] = np.asarray(S)       # past kmax: all U
+            continue
+        wf = min(k1, kmax) - k0
+        packed, piv = ooc._lu_panel_factor(S[:, :wf], k0,
+                                           min(incore_nb, max(wf, 1)))
+        piv = np.asarray(piv)
+        lperm = ooc._swaps_to_perm(piv, m - k0)
+        out[k0:, :k0] = out[k0:, :k0][lperm]    # the fixup
+        perm[k0:] = perm[k0:][lperm]
+        ipiv[k0:k0 + wf] = k0 + piv
+        out[:k0, k0:k1] = np.asarray(S[:k0])
+        out[k0:, k0:k0 + wf] = np.asarray(packed[:m - k0])
+        if wf < k1 - k0:                        # kmax inside the panel
+            rest = S[k0:, wf:][jnp.asarray(lperm)]
+            out[k0:k0 + wf, k0 + wf:k1] = np.asarray(
+                ooc._unit_lower_solve_capped(packed[:wf, :wf], rest[:wf]))
+    return out, ipiv
+
+
+#: (m, n, w): eight square panels; tall; wide with kmax inside the
+#: fifth panel and five all-U panels after it; n no multiple of w
+_SHAPES = {"square": (256, 256, 32), "tall": (320, 160, 32),
+           "wide": (144, 320, 32), "ragged": (200, 200, 48)}
+#: panels of the factor the cache may hold: none, five of the
+#: square's eight (as in the cell `stream-gesv`) and three of the
+#: others' five, all
+_RESIDENT = {"off": lambda nf: 0, "partly": lambda nf: max(nf - 3, 3),
+             "fully": lambda nf: 64}
+
+
+def _hpl_like(shape, name):
+    """Uniform(-0.5, 0.5), f32: every panel's pivots leave the panel."""
+    m, n, w = _SHAPES[shape]
+    r = np.random.default_rng([len(name)] + list(name.encode()))
+    return (r.random((m, n), dtype=np.float32) - np.float32(0.5)), w
+
+
+@pytest.mark.parametrize("resident", sorted(_RESIDENT))
+@pytest.mark.parametrize("shape", sorted(_SHAPES))
+def test_getrf_ooc_partial_is_the_host_order_walk_bitwise(shape, resident):
+    """Input panels staged as they lie and permuted on the chip,
+    factor panels written once and gathered at each visit through
+    r = inv(P_j)[P_now], one repair at the end: exact gathers around
+    the same two programs, so `lu` and `ipiv` are the oracle's bit
+    for bit, whatever the cache holds."""
+    from slate_tpu.linalg import ooc, stream
+    a, w = _hpl_like(shape, shape + resident)
+    m, n = a.shape
+    nf = -(-min(m, n) // w)                     # factor panels
+    want_lu, want_piv = _host_order_getrf(a, w)
+    lu, ipiv = ooc.getrf_ooc(
+        a, panel_cols=w, pivot="partial",
+        cache_budget_bytes=_RESIDENT[resident](nf) * m * w * 4)
+    assert lu.dtype == a.dtype and lu.tobytes() == want_lu.tobytes()
+    assert np.array_equal(ipiv, want_piv)
+    s = stream.last_stats()
+    assert s["invalidations"] == 0
+    moved = nf - 1              # the panels the repair reorders: all
+    visits = sum(min(k, nf) for k in range(-(-n // w)))
+    if resident == "off":
+        assert (s["hits"], s["misses"]) == (0, 0)
+    elif resident == "fully":
+        # every visit and every repaired panel served from the chip
+        assert (s["hits"], s["misses"]) == (visits + moved, 0)
+    else:
+        assert s["hits"] + s["misses"] == visits + moved
+        assert s["hits"] > 0 and s["misses"] > 0
+
+
+@pytest.mark.parametrize("resident", sorted(_RESIDENT))
+def test_getrf_ooc_partial_second_call_compiles_nothing(resident):
+    """The gathers, the column and the repair's shapes are those of
+    the first call: a second matrix of the shape compiles nothing."""
+    from benchmarks.lib.compiles import Compiles
+    from slate_tpu.linalg import ooc
+    (a, w), (b, _) = _hpl_like("square", "first"), _hpl_like("square", "2nd")
+    budget = _RESIDENT[resident](8) * a.shape[0] * w * 4
+    ooc.getrf_ooc(a, panel_cols=w, pivot="partial", cache_budget_bytes=budget)
+    comp = Compiles()
+    s0 = comp.snap()
+    ooc.getrf_ooc(b, panel_cols=w, pivot="partial", cache_budget_bytes=budget)
+    assert comp.since(s0)["programs"] == 0

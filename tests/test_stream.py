@@ -321,13 +321,16 @@ def test_ooc_composite_drivers_cache_bit_identical(rng):
 
 
 def test_getrf_ooc_rowswap_invalidates_stale_panels(rng):
-    """The wrong-answer guard (ISSUE 4): getrf's host-side row-swap
-    fixup rewrites rows of already-written L panels — the epoch bump
-    must retire their cached device copies, or later visits would be
-    served pre-swap rows. The input is built to pivot ACROSS panel
-    boundaries at every step (later rows strictly dominate), so a
-    stale-cache bug cannot hide; with the guard, cached == uncached
-    == in-core, bit for bit on the pivot sequence."""
+    """The wrong-answer guard (ISSUE 4), as the partial stream keeps
+    it since PR 47: a written factor panel is never rewritten, on the
+    host or in the cache, and a later visit gathers it on the chip
+    through the relative permutation r = inv(P_j)[P_now]. So nothing
+    is retired, and the panel gathered through r has to be the panel
+    the host fixup used to leave: the input is built to pivot ACROSS
+    panel boundaries at every step (later rows strictly dominate), so
+    a visit served a panel in the order it was stored in cannot hide;
+    cached == uncached == in-core, bit for bit on the pivot sequence,
+    with hits inside the factorization."""
     import slate_tpu as st
     n, w = 128, 32
     a = rng.standard_normal((n, n))
@@ -338,8 +341,12 @@ def test_getrf_ooc_rowswap_invalidates_stale_panels(rng):
     lu1, piv1 = ooc.getrf_ooc(a, panel_cols=w,
                               cache_budget_bytes=64 * n * w * 8)
     s = stream.last_stats()
-    assert s["invalidations"] > 0, \
-        "input did not exercise the row-swap fixup"
+    assert s["invalidations"] == 0 and s["invalidated_bytes"] == 0
+    # every visit (6) and every repair (3) of a panel is served
+    assert (s["hits"], s["misses"]) == (9, 0)
+    perm = ooc._swaps_to_perm(piv1, n)
+    assert all((perm[k0:] >= k0 + w).any() for k0 in range(0, n - w, w)), \
+        "input did not pivot across panel boundaries"
     np.testing.assert_array_equal(piv0, piv1)
     np.testing.assert_array_equal(lu0, lu1)
     F = st.getrf(st.Matrix(a, mb=w))
@@ -435,8 +442,8 @@ def test_solve_drivers_instrumented(rng, obs_on):
 
 
 def test_gemm_and_getrf_uploads_counted(rng, obs_on):
-    """Satellite: gemm_ooc's B/A/C uploads and getrf_ooc's permuted
-    panel read are routed through _h2d, so ooc.h2d_bytes covers the
+    """Satellite: gemm_ooc's B/A/C uploads and getrf_ooc's input
+    panels are routed through _h2d, so ooc.h2d_bytes covers the
     FULL transfer volume (it used to undercount the jnp.asarray
     paths)."""
     from slate_tpu.obs import metrics
@@ -658,12 +665,12 @@ _DRIVERS = {
         lambda spd, g, b, w, bud: ooc.potrf_ooc(
             spd, panel_cols=w, cache_budget_bytes=bud),
         lambda n, nt, w, b: 0),
-    # the partial-pivot walk gathers each input panel through its row
-    # permutation, and numpy returns the gather contiguous
+    # the partial-pivot stream stages its input panels as they lie
+    # (PR 47; the host gather it made before arrived contiguous)
     "getrf_ooc": (
         lambda spd, g, b, w, bud: ooc.getrf_ooc(
             g, panel_cols=w, cache_budget_bytes=bud),
-        lambda n, nt, w, b: n * n * 4),
+        lambda n, nt, w, b: 0),
     "getrf_tntpiv_ooc": (
         lambda spd, g, b, w, bud: ooc.getrf_tntpiv_ooc(
             g, panel_cols=w, cache_budget_bytes=bud),

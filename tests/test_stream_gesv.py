@@ -49,6 +49,13 @@ METRICS = ["idle_share.streamlu", "streamlu.launches_per_solve",
 ON_CPU = ["streamlu.h2d_gb", "streamlu.invalidated_gb",
           "streamlu.cache_hit_share", "streamlu.fixup_s"]
 N, W, NRHS = 512, 64, 8             # the rehearsal's shape
+#: what `streamlutrace.SPANS` and `COUNTERS` still list under `partial`
+#: and the partial stream no longer publishes since PR 47 (no host
+#: gather of an input panel, no cache retired): the benchmark's file
+#: is not a `perf_opt` PR's to edit, ROADMAP Queue 2 part C has the
+#: line a `benchmark` issue owes
+GONE = {"partial": {"ooc::lu_gather", "ooc.lu_invalidations",
+                    "ooc.lu_invalidation_bytes"}, "tournament": set()}
 
 
 @pytest.fixture
@@ -126,7 +133,9 @@ def test_spans_counters_and_the_route(bus, pivot):
     events = obs.bus_events()
     seen = Counter(e.name for e in events)
     for name, modes in streamlutrace.SPANS.items():
-        assert (seen[name] > 0) == (pivot in modes), (name, seen[name])
+        assert (seen[name] > 0) == (pivot in modes
+                                    and name not in GONE[pivot]), \
+            (name, seen[name])
     assert all(seen[r] == 1 for r in streamlutrace.ROOTS)
     own = [e for e in events if e.name.startswith("ooc::lu_")
            or e.name == "ooc::rhs_permute"]
@@ -135,24 +144,33 @@ def test_spans_counters_and_the_route(bus, pivot):
     assert seen["ooc::lu_pivots"] == nt and seen["ooc::rhs_permute"] == 1
     counters = obs.snapshot()["metrics"]["counters"]
     for name, modes in streamlutrace.COUNTERS.items():
-        assert (name in counters) == (pivot in modes), name
+        assert (name in counters) == (pivot in modes
+                                      and name not in GONE[pivot]), name
     # HPL's matrix: every panel after the first swaps rows past itself
     assert counters["ooc.lu_panels_swapped"] == nt - 1
     route = next(e for e in events if e.name == "getrf_ooc").args
     assert route["lu_pivot"] == pivot
     if pivot == "partial":
-        assert seen["ooc::lu_gather"] == nt
-        assert seen["ooc::lu_fixup"] == nt - 1
+        # no row moves on the host: the input panels are staged as
+        # they lie, nothing written is rewritten before the one repair
+        assert seen["ooc::lu_gather"] == 0
+        assert seen["ooc::lu_fixup"] == 1
         assert (route["panel"], route["nb"]) == ("native", W)
-        gathered = sum(e.args["bytes"] for e in events
-                       if e.name == "ooc::lu_gather")
-        assert gathered == a.nbytes
         fixed = sum(e.args["bytes"] for e in events
                     if e.name == "ooc::lu_fixup")
-        # rows k0: of the k0 columns written, read and written back
+        # rows j1: of every panel before the last, read and written
         assert fixed == counters["ooc.lu_fixup_bytes"] == sum(
-            2 * (N - k0) * k0 * 4 for k0 in range(W, N, W))
-        assert counters["ooc.lu_invalidations"] > 0
+            2 * (N - j1) * W * 4 for j1 in range(W, N, W))
+        assert counters.get("ooc.cache.invalidations", 0) == 0
+        # 28 visits and 7 repairs of a panel, 16 in the two sweeps:
+        # what the cache does not serve is staged, and nothing else
+        # but A and B; a panel the repair finds gone stages its rows
+        # j1: alone, so whole panels bound it from above
+        hits, misses = counters["ooc.cache.hits"], counters["ooc.cache.misses"]
+        assert hits + misses == nt * (nt - 1) // 2 + (nt - 1) + 2 * nt
+        assert hits > misses
+        staged = counters["ooc.h2d_bytes"] - a.nbytes - b.nbytes
+        assert (misses - (nt - 1)) * N * W * 4 < staged <= misses * N * W * 4
     else:
         assert seen["ooc::lu_finalize"] == 1
         assert (route["panel"], route["nb"]) == ("calu", W)
@@ -489,6 +507,7 @@ def test_rehearsal_runs_end_to_end_and_publishes_its_spans(tmp_path):
         reduce_trace.load(xplane))}
     mode = seen["getrf_ooc"][3]["lu_pivot"]
     assert mode == MethodLUPivot.resolve(N, np.float32).value
-    want = {n for n, modes in streamlutrace.SPANS.items() if mode in modes}
+    want = {n for n, modes in streamlutrace.SPANS.items()
+            if mode in modes} - GONE[mode]
     assert want | set(streamlutrace.ROOTS) <= set(seen), \
         sorted(want - set(seen))

@@ -5,7 +5,7 @@ original-row-order store buys, the MethodLUPivot arbitration (cold
 cache keeps the PR 9 partial path bit-identically), adversarial
 pivot-quality coverage (Wilkinson-style growth, cross-chunk ties,
 rank-deficient chunks), the ooc.lu_invalidations per-cause counter
-on the partial path, and checkpoint/resume with the lu_pivot mode in
+(0 on either path since PR 47), and checkpoint/resume with the lu_pivot mode in
 the durable identity."""
 
 import json
@@ -237,28 +237,32 @@ def test_rank_deficient_chunks(rng):
 # -- the ooc.lu_invalidations per-cause counter ---------------------------
 
 def test_lu_invalidation_counter_partial_vs_tournament(rng, obs_on):
-    """The satellite: the partial path's row-swap fixups now report
-    the evicted-panel bytes per-cause (ooc.lu_invalidations /
-    ooc.lu_invalidation_bytes), and the tournament path's counter
-    stays exactly 0 — the delta bench shows."""
+    """The per-cause counters (ooc.lu_invalidations /
+    ooc.lu_invalidation_bytes) read 0 under BOTH disciplines since
+    PR 47: the partial stream's written panels are as immutable as
+    the tournament's, its one repair (ooc::lu_fixup, once) moves rows
+    j1: of each panel before the last and retires nothing."""
     from slate_tpu.obs import metrics
     n, w = 128, 32
     a = rng.standard_normal((n, n))
     a *= (1.0 + np.arange(n))[:, None]
     budget = 64 * n * w * 8
-    ooc.getrf_ooc(a, panel_cols=w, cache_budget_bytes=budget,
-                  pivot="partial")
-    c = metrics.snapshot()["counters"]
-    assert c.get("ooc.lu_invalidations", 0) > 0
-    assert c.get("ooc.lu_invalidation_bytes", 0) > 0
-    assert stream.last_stats()["invalidated_bytes"] == \
-        c["ooc.lu_invalidation_bytes"]
-    metrics.reset()
-    ooc.getrf_ooc(a, panel_cols=w, cache_budget_bytes=budget,
-                  pivot="tournament")
-    c = metrics.snapshot()["counters"]
-    assert c.get("ooc.lu_invalidations", 0) == 0
-    assert c.get("ooc.lu_invalidation_bytes", 0) == 0
+    for pivot in ("partial", "tournament"):
+        metrics.reset()
+        obs_on.clear()
+        ooc.getrf_ooc(a, panel_cols=w, cache_budget_bytes=budget,
+                      pivot=pivot)
+        c = metrics.snapshot()["counters"]
+        assert c.get("ooc.lu_invalidations", 0) == 0
+        assert c.get("ooc.lu_invalidation_bytes", 0) == 0
+        assert stream.last_stats()["invalidated_bytes"] == 0
+        assert c["ooc.cache.hits"] > 0 and c["ooc.lu_panels_swapped"] == 3
+        fixups = [e for e in obs_on.bus_events()
+                  if e.name == "ooc::lu_fixup"]
+        assert len(fixups) == (pivot == "partial")
+        assert c.get("ooc.lu_fixup_bytes", 0) == sum(
+            e.args["bytes"] for e in fixups) == (pivot == "partial") \
+            * sum(2 * (n - j1) * w * 8 for j1 in range(w, n, w))
 
 
 # -- checkpoint/resume ----------------------------------------------------
