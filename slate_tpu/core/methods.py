@@ -238,30 +238,50 @@ class MethodLUPanel(enum.Enum):
         the native call cannot compile;
       * ``Pallas``: the round-3 rank-1 fused kernel (bf16 fallback /
         bench comparison point);
+      * ``Blocked``: the left-looking blocked kernel
+        (lu.lu_panel_blocked) — pure XLA, a column step rewrites only
+        its own (ib + 1, m) block; the route of panels the native call
+        takes the dtype of but not the height (PR 48: 27 us a column
+        at 32768 rows where the fori kernel takes 55, PERF.md);
       * ``Fori``: the masked fori_loop kernel — pure XLA, always
-        correct, vmappable (the batch layer's route).
+        correct, vmappable (the batch layer's route), and what is
+        left: a width no base block divides, m < w, other dtypes.
 
     ``Auto`` resolves via the tune cache (a MEASURED
     ``method_lu_panel`` entry per (op, size, dtype) bucket), falling
-    back to ``cold_default`` — exactly the pre-round-10 chain, so a
-    cold cache routes bit-identically to the old code."""
+    back to ``cold_default``, which reads the route from the panel's
+    height, width and dtype alone."""
     Auto = "auto"
     Native = "native"
+    Blocked = "blocked"
     Fori = "fori"
     Pallas = "pallas"
     PallasRec = "pallas_rec"
 
     @staticmethod
+    def blocked_ok(m: int, w: int, dtype) -> bool:
+        """The hard gates of ``Blocked``: a dtype the native LU takes
+        (the kernel's row positions ride in a row of the panel's own
+        dtype, which bf16 cannot hold), at least as many rows as
+        columns, and a base block that divides the width."""
+        from ..linalg.lu import _blocked_ib
+        return MethodFactor.native_lu_dtype_ok(dtype) and m >= w \
+            and _blocked_ib(w) > 0
+
+    @staticmethod
     def cold_default(m: int, w: int, dtype) -> "MethodLUPanel":
-        """The frozen (pre-arbitration) routing chain: native custom
-        call where dtype + height allow, the fused rank-1 Pallas
-        kernel where the native cannot (TPU bf16), else the fori
-        kernel. Pinned by test_pallas_rec.py's cold-route test."""
+        """The routing chain of a cold cache: native custom call where
+        dtype + height allow, the fused rank-1 Pallas kernel where the
+        native cannot take the dtype (TPU bf16), the blocked kernel
+        where it cannot take the height, else the fori kernel. Pinned
+        by test_pallas_rec.py's cold-route tests."""
         if MethodFactor.native_lu_ok(dtype, m):
             return MethodLUPanel.Native
         from ..ops import pallas_kernels as pk
         if pk.lu_panel_eligible(m, w, dtype):
             return MethodLUPanel.Pallas
+        if MethodLUPanel.blocked_ok(m, w, dtype):
+            return MethodLUPanel.Blocked
         return MethodLUPanel.Fori
 
     @staticmethod
@@ -277,6 +297,10 @@ class MethodLUPanel(enum.Enum):
             #                   buckets span shapes the probe never
             #                   ran — the getrf Fused revalidation
             #                   rule)
+        if cached is MethodLUPanel.Blocked \
+                and not MethodLUPanel.blocked_ok(m, w, dtype):
+            cached = None     # likewise: a bucket spans widths no
+            #                   base block divides
         if cached is not None and cached is not MethodLUPanel.Auto:
             return cached
         return MethodLUPanel.cold_default(m, w, dtype)

@@ -143,12 +143,12 @@ def _lu_panel(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
     support and height limit allow (v5e, 4096x256: 0.77 ms vs 1.19 ms
     for the round-3 fused panel; tall-panel per-column cost ~3 µs,
     width-independent), the fused Pallas kernel for TPU bf16 panels
-    (the mixed-precision lo path), and the masked fori_loop
+    (the mixed-precision lo path), `lu_panel_blocked` for panels of
+    a native dtype above the native height, and the masked fori_loop
     (lu_panel_fori) for everything else. The block-recursive
     pallas_rec route (ops/pallas_kernels.lu_panel_rec) enters here
     when probed faster — one winning entry lifts every LU consumer
     (getrf, getrf_tntpiv nomination, band, indefinite, ooc, batch)."""
-    from ..core.methods import MethodLUPanel
     from ..ops import pallas_kernels as pk
     m, w = a.shape
     method = MethodLUPanel.resolve(m, w, a.dtype)
@@ -165,6 +165,8 @@ def _lu_panel(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
     if method is MethodLUPanel.Native:
         lu, piv, _perm = jax.lax.linalg.lu(a)
         return lu, piv.astype(jnp.int32)
+    if method is MethodLUPanel.Blocked:
+        return lu_panel_blocked(a, _blocked_ib(w))[:2]
     _surface_fori_fallback(m, w, a.dtype)
     return lu_panel_fori(a)
 
@@ -211,6 +213,18 @@ def lu_panel_fori(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
 #: (its operations are latency-bound): 30.7 ms a panel at 16, 20.2 at
 #: 32, 15.4 at 64, 15.2 at 128, where a pass over the block is 8 MB
 LU_BLOCKED_IB = 64
+
+
+#: widest panel the carry form hands `lu_panel_blocked` above the
+#: native height (`_carry_nb`). Read on the chip (PR 48, PERF.md
+#: section 6) on `ooc._lu_panel_factor`'s (32768, 4096) operand, one
+#: launch at 32768 / at 16384 rows: nb 512 0.1123 / 0.0648 s, 256
+#: 0.1204 / 0.0694, 1024 0.1281 / 0.0694, all at ib 64 (ib 128:
+#: 0.1263, 0.1373, 0.1220 at 32768; ib 32: 0.1284, 0.1280, 0.1668);
+#: the fori form it replaces, in blocks of 256: 0.2270 at every height.
+#: A narrower panel pays one more gather of the rest and one more
+#: update a step, a wider one reads more of a[w:] at every block
+LU_TALL_NB = 512
 
 
 def _blocked_ib(w: int) -> int:
@@ -298,7 +312,7 @@ def lu_panel_blocked(a: jax.Array, ib: int = LU_BLOCKED_IB
         # the other columns into the block's row order: only the rows
         # its swaps touched moved (twice named, a row gets one content)
         touched = jnp.concatenate([j0 + sub[:ib, 0], pv])
-        came_from = tb[ib].astype(jnp.int32)[touched]
+        came_from = jnp.real(tb[ib]).astype(jnp.int32)[touched]
         a = a.at[touched].set(a[came_from])
         a = jax.lax.dynamic_update_slice(a, tb[:ib].T, (zero, j0))
         return (a, gperm.at[touched].set(gperm[came_from]),
@@ -385,11 +399,14 @@ def _carry_panel(trail: jax.Array, w: int, method: MethodLUPanel):
     factored, as (packed LU, local swap targets, their composed
     permutation). `method` is the route the caller resolved; it is
     static, so a tune entry that moves it gets a program of its own.
-    The native custom call returns the composed permutation itself."""
+    The native custom call and the blocked kernel return the composed
+    permutation themselves."""
     panel = trail[:, :w]
     if method is MethodLUPanel.Native:
         lu, piv, perm = jax.lax.linalg.lu(panel)
         return lu, piv.astype(jnp.int32), perm
+    if method is MethodLUPanel.Blocked:
+        return lu_panel_blocked(panel, _blocked_ib(w))
     # panels the native call cannot take (scoped-vmem height limit /
     # dtype) or that the tune cache routed elsewhere: _lu_panel
     # arbitrates (true partial pivoting preserved)
@@ -397,27 +414,27 @@ def _carry_panel(trail: jax.Array, w: int, method: MethodLUPanel):
     return lu, piv, _compose_swaps(piv, trail.shape[0])
 
 
-def _lo_panel_route(m: int, w: int) -> str:
-    """How the lo carry form factors an (m, w) panel, from what it can
-    observe: XLA's native LU of the f32 copy where that compiles at the
+def _lo_panel_route(m: int, w: int) -> MethodLUPanel:
+    """How the lo carry form factors an (m, w) panel: the f32 copy's
+    cold route (`MethodLUPanel.cold_default`, the one decision every
+    f32 panel gets): XLA's native LU where that compiles at the
     height, `lu_panel_blocked` above it, the fori kernel where no base
-    block divides the width."""
-    if MethodFactor.native_lu_ok(jnp.float32, m):
-        return "native"
-    return "blocked" if m >= w and _blocked_ib(w) else "fori"
+    block divides the width. No tune entry moves it: the mixed
+    solves' programs are the same under any cache."""
+    return MethodLUPanel.cold_default(m, w, jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("w", "route"))
-def _carry_panel_lo(trail: jax.Array, w: int, route: str):
+def _carry_panel_lo(trail: jax.Array, w: int, route: MethodLUPanel):
     """`_carry_panel` for a factor stored below f32 (the mixed solves'
     bf16): XLA's LU takes no such operand, so the panel alone is
     raised to f32, factored there (`route`: `_lo_panel_route`) and
     stored rounded, as HPL-MxP codes factor the panel above the
     update's precision. The pivot search sees f32 values."""
     panel = trail[:, :w].astype(jnp.float32)
-    if route == "native":
+    if route is MethodLUPanel.Native:
         lu, piv, perm = jax.lax.linalg.lu(panel)
-    elif route == "blocked":
+    elif route is MethodLUPanel.Blocked:
         lu, piv, perm = lu_panel_blocked(panel, _blocked_ib(w))
     else:
         lu, piv = lu_panel_fori(panel)
@@ -503,6 +520,23 @@ def _carry_finish(panels, perms, urows, pivs, *, nb: int, kmax: int,
     for k, pk in enumerate(perms):
         perm = jnp.concatenate([perm[:k * nb], perm[k * nb:][pk]])
     return out, pivots, perm
+
+
+def _carry_nb(M: int, kmax: int, nb: int, dtype) -> int:
+    """The carry form's blocking for an operand of M rows: the
+    caller's where the native panel takes the height. Above it the
+    tall early panels run `lu_panel_blocked`, capped at LU_TALL_NB, or,
+    where that kernel cannot take them, the fori kernel, whose cost is
+    O(w) sequential full-height passes — narrow panels bound that
+    (getrf_tntpiv, CALU, is the matmul-rate alternative at these
+    heights)."""
+    if MethodFactor.native_lu_ok(dtype, M):
+        return nb
+    tall = min(nb, LU_TALL_NB)
+    if MethodLUPanel.resolve(M, min(tall, kmax), dtype) \
+            is MethodLUPanel.Fori:
+        return min(nb, 256)
+    return tall
 
 
 def _getrf_carry(a: jax.Array, nb: int, lo: bool = False):
@@ -718,15 +752,8 @@ def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
         # the narrow+wide split just adds passes when nothing can
         # overlap). The pipelined form remains the grid-path shape,
         # where mesh shards do run concurrently.
-        if not MethodFactor.native_lu_ok(a.dtype, M):
-            # above the native panel's scoped-vmem height limit the
-            # tall early panels run the fori_loop kernel, whose cost
-            # is O(w) sequential full-height passes — narrow panels
-            # bound that; getrf_tntpiv (CALU) is the matmul-rate
-            # alternative at these heights
-            nb = min(nb, 256)
+        nb = _carry_nb(M, kmax, nb, a.dtype)
         if obs_events.enabled():
-            from ..core.methods import MethodLUPanel
             obs_events.note(form="carry", nb=nb,
                             panel=MethodLUPanel.resolve(
                                 M, min(nb, kmax), a.dtype).value)
@@ -788,7 +815,7 @@ def _lo_route(opts: OptionsLike, tile_nb: int, shape, dtype) -> dict:
     update multiplies."""
     nb = _lu_nb(opts, tile_nb, shape, None, dtype=dtype)
     return dict(form="carry", nb=nb, store=str(dtype),
-                panel=_lo_panel_route(shape[0], min(nb, *shape)),
+                panel=_lo_panel_route(shape[0], min(nb, *shape)).value,
                 panel_dtype="float32",
                 update="one pass %s x %s -> float32" % (dtype, dtype))
 

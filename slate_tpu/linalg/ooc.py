@@ -794,23 +794,45 @@ def _lu_visit(S: jax.Array, Lj: jax.Array, j0, unit: bool = True
     return jax.lax.dynamic_update_slice(S, U, (j0, 0))
 
 
-@functools.partial(jax.jit, static_argnames=("nb",))
-def _lu_panel_factor(S: jax.Array, k0, nb: int):
+def _lu_panel_height(m: int, live: int, dtype) -> int:
+    """The height `_lu_panel_factor` factors a panel of `live` live
+    rows at: the smallest rung of the ladder m, m/2, m/4, ... that
+    holds them. The ladder ends at the first rung the native LU takes
+    (MethodFactor.native_lu_ok), where a column costs 3 us and a
+    shorter program would buy nothing for its compile: three rungs at
+    m=32768, one wherever the native LU takes m itself (the CPU, whose
+    LU has no height limit; every small stream)."""
+    from ..core.methods import MethodFactor
+    h = m
+    while h % 2 == 0 and h // 2 >= live \
+            and not MethodFactor.native_lu_ok(dtype, h):
+        h //= 2
+    return h
+
+
+@functools.partial(jax.jit, static_argnames=("nb", "height"))
+def _lu_panel_factor(S: jax.Array, k0, nb: int,
+                     height: Optional[int] = None):
     """In-core partial-pivot LU of the resident panel's live rows
     [k0:, :] via the measured-fastest blocked form (lu._getrf_dense
     routing). The panel is ROLLED so the diagonal sits at row 0 and
     the dead rows (already factored, wrapped to the bottom) are masked
     to exact zero — they can never win a pivot search against live
-    entries, and their L entries come out exactly zero. One traced k0
-    instead of per-k shapes = ONE compiled program for the whole
-    stream (compile time dominated the first on-chip run). Returns
-    (packed (m, w) rolled — live rows first, piv relative to k0)."""
+    entries, and their L entries come out exactly zero. `height`
+    (static; `_lu_panel_height`, None: all m) is how many of the
+    rolled rows are factored: those cut are all dead, and the result
+    is padded back to m rows of zeros, so the caller sees the operand
+    it would of a full-height call. A traced k0 instead of per-k
+    shapes = ONE compiled program a rung for the whole stream (compile
+    time dominated the first on-chip run). Returns (packed (m, w)
+    rolled — live rows first, piv relative to k0)."""
     from .lu import _getrf_dense
     m = S.shape[0]
-    rows = jnp.arange(m)
-    rolled = jnp.roll(S, -k0, axis=0)
-    rolled = jnp.where((rows < m - k0)[:, None], rolled, 0)
-    return _getrf_dense(rolled, nb, pivot=True)
+    h = m if height is None else height
+    rolled = jnp.roll(S, -k0, axis=0)[:h]
+    rolled = jnp.where((jnp.arange(h) < m - k0)[:, None], rolled, 0)
+    packed, piv = _getrf_dense(rolled, nb, pivot=True)
+    return jnp.pad(packed, ((0, m - h), (0, 0))), piv
 
 
 @jax.jit
@@ -869,9 +891,9 @@ def _note_lu_route(mode: str, m: int, wf: int, incore_nb: int,
     if mode == "tournament":
         obs_events.note(lu_pivot=mode, panel="calu", nb=nb)
         return
-    from ..core.methods import MethodFactor, MethodLUPanel
-    if not MethodFactor.native_lu_ok(dtype, m):
-        nb = min(nb, 256)       # lu._getrf_dense's carry-form cap
+    from ..core.methods import MethodLUPanel
+    from .lu import _carry_nb
+    nb = _carry_nb(m, wf, nb, dtype)
     obs_events.note(lu_pivot=mode, nb=nb,
                     panel=MethodLUPanel.resolve(m, min(nb, wf),
                                                 dtype).value)
@@ -900,10 +922,12 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     test). What the two read on a TPU v5e at n=32768, nrhs 8, panels
     of 4096, five of eight resident, on HPL's uniform(-0.5, 0.5)
     matrix, where every panel's pivots leave the panel (gesv_ooc,
-    warm, by hand): "partial" 5.4-5.5 s a solve, 13.8 GB staged,
-    3.1 s of device time, 0.4 s in the one repair (PERF.md PR 47;
-    15.1-15.2 s, 25.2 GB and 3.0 s before it, 9.9 s of them host
-    row gathers and fixups);
+    warm, by hand): "partial" 4.3 s a solve, 13.8 GB staged, 1.9 s of
+    device time, 0.4 s in the one repair (PERF.md PR 48: each panel
+    factored at the height its live rows have, `_lu_panel_height`;
+    5.4-5.5 s and 3.1 s with every panel at full height on the fori
+    kernel, PR 47; 15.1-15.2 s, 25.2 GB and 3.0 s before that, 9.9 s
+    of them host row gathers and fixups);
     "tournament" 11.9-12.4 s, 12.9 GB staged, 4.4 s of device time,
     5.7 s in the final gather (PERF.md PR 46). The default is
     "partial": the cell ``stream-gesv`` is there to judge it.
@@ -1017,6 +1041,7 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
     led = _ledger.recorder("getrf_ooc", nt=nt)
     span = obs_events.span
     word = a.dtype.itemsize
+    rows_live = rows_factored = 0       # summed over the panels
 
     def stored(j0, j1):
         return lambda: out[:, j0:j1]
@@ -1051,9 +1076,14 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
                     S = _lu_visit(S, Lj, j0)
             if k0 < kmax:
                 wf = min(k1, kmax) - k0
+                # dead rows are not factored (k0 stays traced inside
+                # a rung of the ladder)
+                height = _lu_panel_height(m, m - k0, a.dtype)
+                rows_live += m - k0
+                rows_factored += height
                 with _ledger.frame("factor"):
                     packed, piv = _lu_panel_factor(
-                        S[:, :wf], k0, min(incore_nb, max(wf, 1)))
+                        S[:, :wf], k0, min(incore_nb, max(wf, 1)), height)
                     col = _lu_col(S, packed, k0)
                 # the one place a panel step waits for the device
                 with span("ooc::lu_pivots", cat="staging", k=k):
@@ -1089,6 +1119,13 @@ def getrf_ooc(a: np.ndarray, panel_cols: Optional[int] = None,
         _health.heartbeat("getrf_ooc", nt, nt)   # completion beat
         if led is not None:
             led.begin(nt, drain=True)                # final drain record
+        # what the ladder saved: the rows the panels had live against
+        # the rows `_lu_panel_factor` was given, on the bus and (for
+        # the benchmark's breakdown, which prints the route) the span
+        obs_metrics.inc("ooc.lu_panel_rows_live", rows_live)
+        obs_metrics.inc("ooc.lu_panel_rows_factored", rows_factored)
+        obs_events.note(panel_rows_live=rows_live,
+                        panel_rows_factored=rows_factored)
         # the one repair: rows j1: of each panel written before the
         # pivots below it were known are gathered on the chip into the
         # final order and written over the stored ones, from the

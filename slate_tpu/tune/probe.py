@@ -191,14 +191,18 @@ def probe_lu_panel(m: int, w: int, dtype, reps: int = 3) -> List[Dict]:
     """Time the LU panel-route candidates at (m, w) (ISSUE 6): the
     cold-default route (entry {"method": None} — lu._lu_panel with
     cached entries bypassed, the baseline a winner must beat), the
-    masked fori kernel, and the two Pallas kernels (rank-1 `pallas`,
+    masked fori kernel, the left-looking `blocked` kernel where its
+    gates accept (lu.lu_panel_blocked: a probe of a tall shape sees it
+    beside `fori`), and the two Pallas kernels (rank-1 `pallas`,
     block-recursive `pallas_rec`) where their entry gates accept.
     Fastest first; a persisted winner reroutes _lu_panel for the
     whole (backend, device, dtype, bucket) class — and through it
     every LU consumer."""
     import jax
     import jax.numpy as jnp
-    from ..linalg.lu import _lu_panel, lu_panel_fori
+    from ..core.methods import MethodLUPanel
+    from ..linalg.lu import (_blocked_ib, _lu_panel, lu_panel_blocked,
+                             lu_panel_fori)
     from ..ops import pallas_kernels as pk
     from ..utils import trace
     from . import select as _select
@@ -214,6 +218,12 @@ def probe_lu_panel(m: int, w: int, dtype, reps: int = 3) -> List[Dict]:
         out.append({"method": "fori",
                     "seconds": measure(lambda: lu_panel_fori(p)[0],
                                        reps=reps)})
+        if MethodLUPanel.blocked_ok(m, w, p.dtype):
+            blocked = jax.jit(lu_panel_blocked, static_argnums=1)
+            out.append({"method": "blocked",
+                        "seconds": measure(
+                            lambda: blocked(p, _blocked_ib(w))[0],
+                            reps=reps)})
         for label, fn in (("pallas", pk.lu_panel),
                           ("pallas_rec", pk.lu_panel_rec)):
             if fn(p) is None:        # entry gate rejected this shape

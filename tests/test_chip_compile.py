@@ -324,15 +324,46 @@ def test_mixed_programs_compile_at_the_cells_size(one_chip, program):
         assert ma.output_size_in_bytes < 2 * n * n + (1 << 20)
 
 
-@pytest.mark.parametrize("program", ["panel", "chunks", "rows", "col"])
+#: `_lu_panel_factor`'s temporaries at the cell's (32768, 4096) panel,
+#: by rung (sandbox, compiled for a described v5e, PR 48: 926, 451
+#: and 165 MB; the fori form it replaced held 0.83 GB at every k)
+_RUNG_TEMPS = {32768: 1_100 << 20, 16384: 500 << 20, 8192: 200 << 20}
+
+
+@pytest.mark.parametrize("height", sorted(_RUNG_TEMPS, reverse=True))
+def test_stream_lu_panel_compiles_at_each_rung(one_chip, height,
+                                               monkeypatch):
+    """The partial stream's panel factor of `stream-gesv` at its whole
+    (32768, 4096) panel, one program a rung of `ooc._lu_panel_height`'s
+    ladder (PR 48): above the native LU's height the carry form with
+    `lu_panel_blocked` panels (XLA's LU refuses those rows), at 8192
+    rows the native LU. The routing asks `jax.default_backend()`,
+    which sees the CPU here: it is steered to the chip's answer. Each
+    compiles in a quarter of a minute of wall (66-181 processor
+    seconds), and its temporaries are held to what was read."""
+    from slate_tpu.linalg import ooc
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, w = 32768, 4096
+    assert ooc._lu_panel_height(n, height, jnp.float32) == height
+    assert ooc._lu_panel_height(n, height + 1, jnp.float32) == \
+        min(2 * height, n)
+    compiled = _compile(ooc._lu_panel_factor, one_chip,
+                        ((n, w), jnp.float32), ((), jnp.int32),
+                        kernel=False, limit_s=400.0, nb=1024, height=height)
+    ma = compiled.memory_analysis()
+    print("rung %d: %d bytes of temporaries, %d of code" % (
+        height, ma.temp_size_in_bytes, ma.generated_code_size_in_bytes))
+    assert ma.temp_size_in_bytes < _RUNG_TEMPS[height], \
+        ma.temp_size_in_bytes
+    assert ma.output_size_in_bytes < n * w * 4 + (1 << 20)
+    assert ("LuDecompositionBlock" in compiled.as_text()) == (height == 8192)
+
+
+@pytest.mark.parametrize("program", ["chunks", "rows", "col"])
 def test_stream_lu_programs_compile_at_the_cells_height(one_chip, program,
                                                         monkeypatch):
     """The streamed LU of `stream-gesv` (PR 46) at its 32768 rows: the
-    partial stream's panel factor (XLA's LU refuses that height, so
-    lu._getrf_dense's carry form in blocks of 256 with fori panels;
-    a quarter of the cell's 4096 columns here, the whole panel takes
-    29 s of this compiler and 0.83 GB of temporaries, sandbox, PR 46),
-    and the tournament's chunk nomination at the stream's four chunks
+    tournament's chunk nomination at the stream's four chunks
     of 8192 rows, which the batched native LU cannot take
     (`ca._chunk_pivot_rows` runs them one at a time there). The
     routing asks `jax.default_backend()`, which sees the CPU here: it
@@ -340,14 +371,13 @@ def test_stream_lu_programs_compile_at_the_cells_height(one_chip, program,
     which the partial stream moves rows on the chip (PR 47) at the
     cell's whole panel, (32768, 4096): the row gather and the
     factored panel put together as one column; neither keeps a
-    second panel of temporaries beside its result."""
+    second panel of temporaries beside its result. (The partial
+    stream's panel factor: the test above.)"""
     from slate_tpu.linalg import ca, ooc
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     n, w = 32768, 1024
     f32, i32 = jnp.float32, jnp.int32
     fn, shapes, static = {
-        "panel": (ooc._lu_panel_factor, [((n, w), f32), ((), i32)],
-                  {"nb": 256}),       # what the cell's 1024 is capped to
         "chunks": (jax.jit(ca._chunk_pivot_rows),
                    [((4, n // 4, w), f32)], {}),
         "rows": (ooc._lu_rows, [((n, 4 * w), f32), ((n,), i32)], {}),
@@ -361,8 +391,6 @@ def test_stream_lu_programs_compile_at_the_cells_height(one_chip, program,
     if program in ("rows", "col"):
         assert ma.output_size_in_bytes == n * 4 * w * 4
         assert ma.temp_size_in_bytes <= n * 4 * w * 4
-    elif program == "panel":
-        assert "LuDecompositionBlock" not in compiled.as_text()
     else:
         # the same nomination, batched, is what the compiler refuses
         batched = jax.jit(lambda b: jax.vmap(jax.lax.linalg.lu)(b)[2])

@@ -24,6 +24,7 @@ import pytest
 
 import slate_tpu as st
 from slate_tpu import obs
+from slate_tpu.core.methods import MethodFactor, MethodLUPanel
 from slate_tpu.core.options import Option
 from slate_tpu.linalg import lu as lu_mod
 from slate_tpu.linalg import refine
@@ -126,16 +127,46 @@ def test_f32_factors_carry_no_permutation_and_run_the_old_programs():
     assert np.array_equal(np.asarray(F.pivots), np.asarray(lo.pivots))
 
 
-@pytest.mark.parametrize("m,w", [(256, 64), (128, 128), (512, 96),
-                                 (384, 40)])
-def test_blocked_panel_is_the_fori_panel(m, w):
-    """`lu_panel_blocked` (the tall panels of the lo factor on the
-    chip) against the masked fori kernel on a matrix that pivots."""
-    a = jnp.asarray(np.random.default_rng(m + w).standard_normal((m, w)),
-                    jnp.float32)
+def _pivoting(m, w, dtype):
+    r = np.random.default_rng(m + w)
+    a = r.standard_normal((m, w))
+    if np.dtype(dtype).kind == "c":
+        a = a + 1j * r.standard_normal((m, w))
+    return jnp.asarray(a, dtype)
+
+
+@pytest.mark.parametrize("m,w,dtype,via", [
+    (256, 64, "float32", "kernel"), (128, 128, "float32", "kernel"),
+    (512, 96, "float32", "kernel"), (384, 40, "float32", "kernel"),
+    # the f32 route (PR 48): tall, square-ish and ragged panels above
+    # a native height forced to 96 rows, through `_lu_panel` and the
+    # carry form's `_carry_panel`
+    (1024, 64, "float32", "panel"), (192, 160, "float32", "panel"),
+    (300, 72, "float32", "panel"), (1024, 128, "float32", "carry"),
+    (200, 136, "float32", "carry"), (256, 64, "complex64", "carry")])
+def test_blocked_panel_is_the_fori_panel(m, w, dtype, via, monkeypatch):
+    """`lu_panel_blocked` (the tall panels of the lo factor and, since
+    PR 48, of every f32 factor above the native height on the chip)
+    against the masked fori kernel on a matrix that pivots."""
+    a = _pivoting(m, w, dtype)
     lu0, piv0 = lu_mod.lu_panel_fori(a)
-    lu1, piv1, perm = jax.jit(lu_mod.lu_panel_blocked, static_argnums=1)(
-        a, lu_mod._blocked_ib(w))
+    if via == "kernel":
+        lu1, piv1, perm = jax.jit(lu_mod.lu_panel_blocked,
+                                  static_argnums=1)(a, lu_mod._blocked_ib(w))
+    else:
+        monkeypatch.setattr(MethodFactor, "native_lu_ok", staticmethod(
+            lambda dtype, m: m <= 96))
+        route = MethodLUPanel.resolve(m, w, a.dtype)
+        assert route is MethodLUPanel.Blocked
+        if via == "panel":
+            lu1, piv1 = jax.jit(lu_mod._lu_panel)(a)
+            perm = lu_mod._compose_swaps(piv1, m)
+        else:
+            # (a jit of its own: `_carry_panel` caches by shape, and
+            # the route under the patch is not the CPU's)
+            lu1, piv1, perm = jax.jit(
+                lu_mod._carry_panel.__wrapped__, static_argnums=(1, 2))(
+                    a, w, route)
     assert np.array_equal(np.asarray(piv0), np.asarray(piv1))
     assert (np.asarray(piv1) != np.arange(w)).sum() > w // 2
     assert np.abs(np.asarray(lu0) - np.asarray(lu1)).max() < 2e-4
@@ -285,12 +316,14 @@ def test_spans_reach_the_host_plane(bus, host_plane):
 
 
 def test_lo_panel_route_is_read_from_the_height(monkeypatch):
-    assert lu_mod._lo_panel_route(16384, 1024) == "native"     # the CPU
+    def route(m, w):
+        return lu_mod._lo_panel_route(m, w).value
+    assert route(16384, 1024) == "native"                      # the CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert lu_mod._lo_panel_route(8192, 1024) == "native"
-    assert lu_mod._lo_panel_route(16384, 1024) == "blocked"
-    assert lu_mod._lo_panel_route(9216, 1024) == "blocked"
-    assert lu_mod._lo_panel_route(16384, 100) == "fori"
+    assert route(8192, 1024) == "native"
+    assert route(16384, 1024) == "blocked"
+    assert route(9216, 1024) == "blocked"
+    assert route(16384, 100) == "fori"
 
 
 # -- the comparison that decides `correct` ---------------------------------

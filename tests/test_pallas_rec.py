@@ -18,6 +18,7 @@ from slate_tpu.core.methods import MethodLUPanel
 from slate_tpu.linalg.lu import _lu_panel, lu_panel_fori
 from slate_tpu.ops import pallas_kernels as pk
 from slate_tpu.tune import cache as tcache
+from slate_tpu.tune import select as tune_select
 
 
 @pytest.fixture
@@ -286,6 +287,55 @@ def test_lu_panel_cold_routes_exactly_as_before(tune_env, rng,
         is MethodLUPanel.Native
     assert MethodLUPanel.cold_default(256, 64, jnp.bfloat16) \
         is MethodLUPanel.Fori
+
+
+@pytest.mark.parametrize("m,w,dtype,route", [
+    (32768, 256, "float32", "blocked"), (16384, 1024, "float32", "blocked"),
+    (8192, 256, "float32", "native"), (8193, 256, "float32", "blocked"),
+    (16384, 32768, "float32", "fori"),        # m < w
+    (32768, 100, "float32", "fori"),          # no base block divides
+    (32768, 256, "bfloat16", "fori"),         # not the native LU's dtype
+    (8192, 256, "complex64", "blocked"),      # itemsize 8: 4096 rows
+    (4096, 256, "complex64", "native")])
+def test_cold_route_reads_height_width_and_dtype(tune_env, monkeypatch,
+                                                 m, w, dtype, route):
+    """PR 48: above the native LU's height a panel of a dtype it takes
+    runs `lu_panel_blocked`, and the fori kernel keeps what is left.
+    The native height is the chip's (the CPU's LU has none)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert MethodLUPanel.cold_default(m, w, jnp.dtype(dtype)).value == route
+    assert MethodLUPanel.resolve(m, w, jnp.dtype(dtype)).value == route
+
+
+def test_cached_blocked_route_is_revalidated(tune_env, monkeypatch):
+    """A measured 'blocked' entry reroutes the panels it can take and
+    no other: its bucket spans widths no base block divides."""
+    monkeypatch.setattr(tune_select, "tuned_method",
+                        lambda *a, **k: MethodLUPanel.Blocked)
+    assert MethodLUPanel.resolve(256, 64, jnp.float32) \
+        is MethodLUPanel.Blocked
+    assert MethodLUPanel.resolve(256, 100, jnp.float32) \
+        is MethodLUPanel.Native
+    assert MethodLUPanel.resolve(256, 64, jnp.bfloat16) \
+        is MethodLUPanel.Fori
+    a = jnp.asarray(np.random.default_rng(5).standard_normal((256, 64)),
+                    jnp.float32)
+    lu_, piv = _lu_panel(a)
+    ref, rpiv = lu_panel_fori(a)
+    assert np.array_equal(np.asarray(piv), np.asarray(rpiv))
+    assert np.abs(np.asarray(lu_) - np.asarray(ref)).max() < 2e-4
+
+
+def test_probe_times_the_blocked_route_beside_fori(tune_env):
+    """`probe_lu_panel` sees `lu_panel_blocked` under its own label
+    where the kernel's gates accept the shape, and not elsewhere."""
+    from slate_tpu.tune import probe
+    labels = [r["method"] for r in probe.probe_lu_panel(
+        256, 64, jnp.float32, reps=1)]
+    assert {None, "fori", "blocked"} <= set(labels)
+    labels = [r["method"] for r in probe.probe_lu_panel(
+        256, 100, jnp.float32, reps=1)]
+    assert "fori" in labels and "blocked" not in labels
 
 
 def test_lu_panel_cached_pallas_rec_reroutes(tune_env, rng,

@@ -176,18 +176,90 @@ def test_spans_counters_and_the_route(bus, pivot):
         assert (route["panel"], route["nb"]) == ("calu", W)
 
 
-def test_route_names_the_tall_panels_kernel(bus, monkeypatch):
-    """At the cell's height the chip's native LU panel is refused
-    (NATIVE_LU_MAX_M) and lu._getrf_dense caps the inner blocking at
-    256: the route says so without tracing anything."""
+@pytest.fixture
+def native_height(monkeypatch):
+    """The chip's routing at sizes the CPU holds: the native LU takes
+    `cap` rows and no more (the CPU's has no height limit), for
+    programs traced inside the fixture."""
     from slate_tpu.core.methods import MethodFactor
-    monkeypatch.setattr(MethodFactor, "native_lu_ok",
-                        staticmethod(lambda dtype, m: m <= 8192))
+
+    def force(cap):
+        monkeypatch.setattr(MethodFactor, "native_lu_ok", staticmethod(
+            lambda dtype, m: MethodFactor.native_lu_dtype_ok(dtype)
+            and m <= cap))
+    jax.clear_caches()
+    yield force
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+def test_route_names_the_tall_panels_kernel(bus, native_height):
+    """At the cell's height the chip's native LU panel is refused
+    (NATIVE_LU_MAX_M) and the tall panels run `lu_panel_blocked` at
+    lu._carry_nb's blocking: the route says so without tracing
+    anything. A width no base block divides keeps the fori kernel in
+    blocks of 256."""
+    from slate_tpu.linalg import lu
+    native_height(8192)
     obs.enable(beacon=False)
     with obs_events.driver("getrf_ooc"):
         ooc._note_lu_route("partial", 32768, 4096, 1024, np.float32)
     route = next(e for e in obs.bus_events() if e.name == "getrf_ooc").args
-    assert route["nb"] == 256 and route["panel"] != "native"
+    assert (route["panel"], route["nb"]) == ("blocked", lu.LU_TALL_NB)
+    assert lu._carry_nb(32768, 4096, 1024, np.float32) == lu.LU_TALL_NB
+    assert lu._carry_nb(8192, 4096, 1024, np.float32) == 1024
+    assert lu._carry_nb(32768, 4096, 300, np.float32) == 256
+
+
+@pytest.mark.parametrize("k0,height", [
+    (0, 512), (192, 512), (256, 256), (320, 256), (384, 128), (448, 128)])
+def test_a_panel_is_factored_at_the_height_of_its_live_rows(
+        native_height, k0, height):
+    """`_lu_panel_factor` at each rung of the ladder (the native LU
+    forced to 128 rows: 512, 256, 128) against the full-height call,
+    for k0 at a rung's edge and inside it: the same pivots, the same
+    live rows, dead rows exactly zero."""
+    native_height(128)
+    m, w = 512, 64
+    S = jnp.asarray(system(4800000100 + k0)[0][:, :w])
+    assert ooc._lu_panel_height(m, m - k0, np.float32) == height
+    full, piv_full = ooc._lu_panel_factor(S, k0, w)
+    packed, piv = ooc._lu_panel_factor(S, k0, w, height)
+    assert packed.shape == full.shape == (m, w)
+    np.testing.assert_array_equal(np.asarray(piv), np.asarray(piv_full))
+    assert (np.asarray(piv) != np.arange(w)).sum() > w // 2
+    packed, full = np.asarray(packed), np.asarray(full)
+    assert not packed[m - k0:].any() and not full[m - k0:].any()
+    assert np.abs(packed - full).max() <= 1e-4 * np.abs(full).max()
+    # and the ladder is one rung where the native LU takes m itself
+    native_height(m)
+    assert ooc._lu_panel_height(m, m - k0, np.float32) == m
+
+
+def test_the_rehearsal_on_the_tall_route(bus, native_height):
+    """The cell's rehearsal (n=512, eight panels of 64) through
+    `getrf_ooc` with the chip's tall route forced: blocked panels at
+    512 and 256 rows, the native LU at 128, the counters of the rows
+    factored, and an answer inside the rehearsal's limits."""
+    native_height(128)
+    cfg, cell = rehearsal_cell(4800000101)
+    obs.enable(beacon=False)
+    F, x = cell.sys.solve()
+    route = next(e for e in obs.bus_events() if e.name == "getrf_ooc").args
+    assert (route["lu_pivot"], route["panel"], route["nb"]) == \
+        ("partial", "blocked", W)
+    counters = obs.snapshot()["metrics"]["counters"]
+    assert counters["ooc.lu_panel_rows_live"] == sum(range(W, N + W, W))
+    assert counters["ooc.lu_panel_rows_factored"] == 4 * 512 + 2 * 256 \
+        + 2 * 128
+    obs.disable()
+    assert KIND.ipiv_valid(F[1], N)
+    cell.answers, cell.walls = [answer_of(cell, F, x)], [0.1]
+    got = cell.check()
+    assert got["correct"] is True and got["failed"] == 0, got
+    # the same pivots as the plain reference's search over the live rows
+    np.testing.assert_array_equal(
+        np.asarray(F[1]), plainref_streamlu.gesv(cell.sys.a, cell.sys.b)[0][1])
 
 
 # -- the comparison that decides `correct` ---------------------------------
