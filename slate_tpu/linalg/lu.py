@@ -32,6 +32,7 @@ from ..core.methods import MethodFactor, MethodLU, MethodLUPanel
 from ..core.options import Option, OptionsLike, get_option
 from ..core.tiles import TiledMatrix, ceil_div, pad_diag_identity
 from ..obs import events as obs_events
+from ..obs import metrics as obs_metrics
 from ..obs.events import instrument_driver
 from ..resil import guard as _rguard
 from .blas3 import _store, trsm
@@ -663,19 +664,14 @@ def _getrf_pipelined(a: jax.Array, nb: int, grid=None
     return a, ipiv
 
 
-def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
-                 tournament: bool = False, lookahead: int = 1,
-                 tile_nb: Optional[int] = None
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """Blocked right-looking LU on padded (M, N) dense; returns packed
-    LU and global pivot swaps (length min(M,N)). With a grid, trailing
-    updates are sharding-constrained over the mesh (the load-balance
-    role of the reference's 2D block-cyclic distribution; panels run
-    replicated, the analogue of the reference's panel-column rank set
-    working one panel together, getrf.cc:91)."""
+def _dense_blocking(M: int, N: int, nb: int, pivot: bool, dtype,
+                    tile_nb: Optional[int]) -> Tuple[int, int]:
+    """What `_getrf_dense` factors an (M, N) operand in, from its
+    shape alone: (the blocking of the carry, pipelined and unrolled
+    forms, the blocking of the scan form or 0 where the operand does
+    not take it). `getrf` asks it at dispatch for the route a
+    compiled program will take."""
     from ..ops import pallas_kernels as pk
-    from ..parallel.sharding import constrain
-    M, N = a.shape
     kmax = min(M, N)
     # the fused kernel's width cap, resolved ONCE through the tune
     # arbitration (("lu_panel", "max_w"), FROZEN == LU_PANEL_MAX_W) so
@@ -683,10 +679,10 @@ def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
     # entry moves the cap
     lu_max_w = pk._lu_max_w()
     pallas_capped = (pivot
-                     and not MethodFactor.native_lu_dtype_ok(a.dtype)
+                     and not MethodFactor.native_lu_dtype_ok(dtype)
                      and pk.lu_panel_eligible(
                          min(M, 128), min(nb, lu_max_w),
-                         a.dtype)
+                         dtype)
                      # capping to the fused width multiplies the step
                      # count, and the unrolled compile grows with it:
                      # the 16-step cap is not measured on the current
@@ -730,8 +726,7 @@ def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
         # natively (program size grows with nt — the documented trade
         # for honoring an explicit Option.BlockSize there).
         if N % nb == 0:
-            obs_events.note(form="scan", nb=nb)
-            return _lu_scan(a, nb, pivot, grid, tournament=tournament)
+            return nb, nb
         cand = _scan_nb(N, nb, 8)     # %8 widths suit every panel path
         if tile_nb and N % tile_nb == 0 and \
                 (not pallas_capped or (tile_nb <= lu_max_w
@@ -741,8 +736,41 @@ def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
             # a degenerate divisor (N with no usable factor <= nb)
             # would make the scan run absurdly narrow steps; the
             # carry/unrolled fall-through is the better cliff
-            obs_events.note(form="scan", nb=cand)
-            return _lu_scan(a, cand, pivot, grid, tournament=tournament)
+            return nb, cand
+    return nb, 0
+
+
+def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
+                 tournament: bool = False, lookahead: int = 1,
+                 tile_nb: Optional[int] = None,
+                 composed: Optional[list] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Blocked right-looking LU on padded (M, N) dense; returns packed
+    LU and global pivot swaps (length min(M,N)). With a grid, trailing
+    updates are sharding-constrained over the mesh (the load-balance
+    role of the reference's 2D block-cyclic distribution). In the
+    unrolled and pipelined forms the partitioner places the panels;
+    in the scan form (`_lu_scan_grid`) each panel is factored on
+    every chip alike from a replicated copy of its column block (the
+    analogue of the reference's panel-column rank set working one
+    panel together, getrf.cc:91), and its row exchanges and blocks
+    stay on the chips that own them. `composed`, a list, receives the
+    swaps composed to one permutation of the rows where the form
+    composes it on the way (the scan form under a grid)."""
+    from ..parallel.sharding import constrain
+    M, N = a.shape
+    kmax = min(M, N)
+    nb, scan_nb = _dense_blocking(M, N, nb, pivot, a.dtype, tile_nb)
+    nt = ceil_div(kmax, nb)
+    if scan_nb:
+        obs_events.note(form="scan", nb=scan_nb)
+        if grid is None:
+            return _lu_scan(a, scan_nb, pivot, tournament=tournament)
+        lu, ipiv, perm = _lu_scan_grid(a, scan_nb, pivot, grid,
+                                       tournament=tournament)
+        if composed is not None:
+            composed.append(perm)
+        return lu, ipiv
     if pivot and not tournament and grid is None and nt > 1 \
             and MethodFactor.native_lu_dtype_ok(a.dtype):
         # single-device fast path: carry-the-trailing-matrix form.
@@ -864,10 +892,12 @@ def _scan_nb(N: int, nb: int, mult: int = 1) -> int:
     return fallback or 1
 
 
-def _lu_scan(a: jax.Array, nb: int, pivot: bool, grid=None,
+def _lu_scan(a: jax.Array, nb: int, pivot: bool,
              tournament: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Blocked right-looking LU as ONE compiled block step iterated by
-    fori_loop (compile-time-safe form of _getrf_dense for huge nt).
+    fori_loop (compile-time-safe form of _getrf_dense for huge nt), on
+    one device; a matrix spread over a grid takes `_lu_scan_grid`,
+    the same step on the chips that own its blocks.
 
     The panel is extracted full-height and ROLLED so its diagonal sits
     at row 0 — the packing every panel kernel assumes — with the
@@ -879,7 +909,6 @@ def _lu_scan(a: jax.Array, nb: int, pivot: bool, grid=None,
     every round), so getrf_tntpiv keeps its contract at huge nt
     (reference getrf_tntpiv.cc:169-222). Square matrices only (callers
     guarantee)."""
-    from ..parallel.sharding import constrain
     N = a.shape[0]
     nt = ceil_div(N, nb)
     rows = jnp.arange(N)
@@ -928,15 +957,14 @@ def _lu_scan(a: jax.Array, nb: int, pivot: bool, grid=None,
         rowblk = jax.lax.dynamic_slice(a, (k0, 0), (nb, N))
         cols = jnp.arange(N)
         rowblk_right = jnp.where((cols >= k0 + nb)[None, :], rowblk, 0)
-        u12 = _lu_u12(lkk, rowblk_right, grid)
+        u12 = _lu_u12(lkk, rowblk_right, None)
         a = jax.lax.dynamic_update_slice(
             a, jnp.where((cols >= k0 + nb)[None, :], u12, rowblk),
             (k0, 0))
         # trailing update with the panel's sub-block, full height masked
         lcol = jax.lax.dynamic_slice(a, (0, k0), (N, nb))
         lcol = jnp.where((rows >= k0 + nb)[:, None], lcol, 0)
-        upd = jnp.matmul(lcol, u12, precision=_HIP)
-        a = constrain(a - upd, grid)
+        a = a - jnp.matmul(lcol, u12, precision=_HIP)
         return a, ipiv
 
     a, ipiv = jax.lax.fori_loop(0, nt, step, (a, ipiv))
@@ -944,6 +972,174 @@ def _lu_scan(a: jax.Array, nb: int, pivot: bool, grid=None,
 
 
 _HIP = jax.lax.Precision.HIGHEST
+
+
+def lu_scan_plan(n: int, nb: int, grid, itemsize: int = 4) -> dict:
+    """What `_lu_scan_grid` does to an order-n matrix in nb-blocks on
+    `grid`, from the shapes alone (`getrf` counts it at dispatch and
+    the benchmark's readers hold the counters to it): the stages of
+    `blocked.chol_scan_stages`; the FLOPs of the trailing updates (a
+    stage of w columns on a trailing square of order m runs w / nb
+    steps of 2 m^2 nb each) beside the 2 n^3 / 3 an LU needs; the
+    bytes of the rows the steps exchange (2 nb rows a step, of the
+    stage's square and, past the first stage, of the result whose
+    factored columns left of the square take the same swaps) beside
+    what a permutation of the whole matrix a step reads; and the rows
+    the panels are factored over (a stage's height at each of its
+    steps) beside the rows that are live."""
+    from .blocked import chol_scan_stages, grid_blocks
+    stages = chol_scan_stages(n, nb, grid)
+    steps = [(r, n - r, w // nb) for r, w in stages]
+    return {
+        "stages": len(stages),
+        "heights": [m for _, m, _ in steps],
+        "blocks": "slice" if grid is None else grid_blocks(n, nb, grid),
+        "steps": n // nb,
+        "update_flops": sum(2 * m * m * nb * k for _, m, k in steps),
+        "update_flops_needed": 2 * n ** 3 // 3,
+        "exchange_bytes": sum(2 * nb * (m + (n if r else 0)) * itemsize * k
+                              for r, m, k in steps),
+        "exchange_bytes_full": (n // nb) * n * n * itemsize,
+        "panel_rows_factored": sum(m * k for _, m, k in steps),
+        "panel_rows_live": sum(n - j * nb for j in range(n // nb)),
+    }
+
+
+def _lu_scan_grid(a: jax.Array, nb: int, pivot: bool, grid,
+                  tournament: bool = False
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """`_lu_scan` for a matrix spread over a grid, built the way
+    `blocked.cholesky_scan` is: the block step iterated by fori_loop
+    in the few static stages of `chol_scan_stages`, stage s over its
+    trailing square t = a[r_s:, r_s:], spread over the grid again as
+    P('p','q'); then its factored columns and its finished rows of U
+    go into the result through `_move_rect` and the next stage takes
+    t[w_s:, w_s:]. Returns (packed LU, global swap targets, the swaps
+    composed to one permutation of the rows). Program size goes with
+    the stages, not with nt, and no value in it is more of the matrix
+    than a chip's block of a stage's square (`_lu_scan`'s
+    `dynamic_slice`s, its roll and its `a[perm]` each gather the
+    matrix onto every chip: 19 GB asked of a 16 GB chip at n=49152).
+
+    A step of a stage of height m:
+
+    * the column block by `_take_block`, replicated (m x nb: 100 MB
+      at the first stage of n=49152), and the panel factored from it
+      on every chip alike, under `shard_map`: rolled so that its
+      diagonal sits at row 0 and the stage's finished rows masked to
+      zero, as `_lu_scan` does, by the kernel `MethodLUPanel` resolves
+      for (m, nb) (`_carry_panel`: the native LU, or
+      `lu_panel_blocked` above its height), by the CALU tournament, or
+      without pivoting;
+    * the row exchange: the at most 2 nb rows the panel's swaps
+      touched, moved by `blocked._exchange_rows` on the chips that
+      hold them, in the square and (past the first stage) in the
+      result, whose columns left of the square hold the earlier
+      stages' L;
+    * the row block by `_take_block`, U12 by `_lu_u12`, one update of
+      the square at `highest`, and both blocks written by
+      `_put_block`.
+
+    Where a block can straddle two chips (`grid_blocks` is "masked")
+    the masked forms of the block helpers carry it in ONE stage; a
+    row lies on one chip of each mesh column either way, so the
+    exchange is the same. Square matrices, nb | N (callers
+    guarantee)."""
+    from ..parallel.sharding import constrain
+    from ..parallel.smap import shard_map
+    from .blocked import (COLUMN_BLOCK, _exchange_rows, _move_rect,
+                          _put_block, _take_block, chol_scan_stages)
+    from jax.sharding import PartitionSpec as P
+    N = a.shape[0]
+    head = jnp.arange(nb, dtype=jnp.int32)
+
+    def panel_of(colblk, k0):
+        """The factored column block (rows from k0 on), the packed
+        diagonal block, the local swap targets, and the rows the swaps
+        touched with where each one's content came from (offsets from
+        k0). Runs on every chip alike."""
+        m = colblk.shape[0]
+        rows = jnp.arange(m, dtype=jnp.int32)
+        live = (rows < m - k0)[:, None]
+        rolled = jnp.where(live, jnp.roll(colblk, -k0, axis=0), 0)
+        if pivot and tournament:
+            from .ca import calu_factor_sorted, tournament_pivot_rows
+            piv, pperm = _tnt_swap_sequence(
+                tournament_pivot_rows(rolled), m)
+            panel = calu_factor_sorted(_permute_rows(rolled, pperm))
+        elif pivot:
+            panel, piv, pperm = _carry_panel(
+                rolled, nb, MethodLUPanel.resolve(m, nb, rolled.dtype))
+        else:
+            panel, piv = _nopiv_panel(rolled)
+            pperm = rows
+        back = jnp.roll(jnp.where(live, panel, 0), k0, axis=0)
+        piv = piv.astype(jnp.int32)
+        touched = jnp.concatenate([head, piv])
+        return (jnp.where((rows >= k0)[:, None], back, colblk),
+                panel[:nb], piv, touched, pperm[touched].astype(jnp.int32))
+
+    if grid is not None:
+        panel_of = shard_map(panel_of, grid.mesh, P(), P())
+
+    def stage(t, out, ipiv, perm, r, steps):
+        m = t.shape[0]
+        rows = jnp.arange(m)
+
+        def step(k, carry):
+            t, out, ipiv, perm = carry
+            k0 = jnp.asarray(k, jnp.int32) * nb
+            k1 = k0 + nb
+            with jax.named_scope("lu_panel"):
+                newcol, lkk, piv, touched, came = panel_of(
+                    _take_block(t, k, nb, 1, grid), k0)
+            if pivot:
+                with jax.named_scope("lu_exchange"):
+                    t = _exchange_rows(t, k0 + touched, k0 + came, grid)
+                    g0 = r + k0
+                    if out is not None:
+                        out = _exchange_rows(out, g0 + touched,
+                                             g0 + came, grid)
+                    ipiv = jax.lax.dynamic_update_slice(
+                        ipiv, g0 + piv, (g0,))
+                    perm = perm.at[g0 + touched].set(perm[g0 + came])
+            with jax.named_scope("lu_update"):
+                rowblk = _take_block(t, k, nb, 0, grid)
+                right = (rows >= k1)[None, :]
+                u12 = _lu_u12(lkk, jnp.where(right, rowblk, 0), grid)
+                lcol = constrain(jnp.where((rows >= k1)[:, None],
+                                           newcol, 0), grid, COLUMN_BLOCK)
+                t = constrain(t - jnp.matmul(lcol, u12, precision=_HIP),
+                              grid)
+            # the update is zero in both blocks; the row block first,
+            # the column block over the diagonal block it left unfactored
+            t = _put_block(t, jnp.where(right, u12, rowblk), k, nb, 0,
+                           grid)
+            t = _put_block(t, constrain(newcol, grid, COLUMN_BLOCK), k,
+                           nb, 1, grid)
+            return t, out, ipiv, perm
+
+        return jax.lax.fori_loop(0, steps, step, (t, out, ipiv, perm))
+
+    ipiv = jnp.arange(N, dtype=jnp.int32)
+    perm = jnp.arange(N, dtype=jnp.int32)
+    out, t = None, a
+    for r, w in chol_scan_stages(N, nb, grid):
+        m = N - r
+        t, out, ipiv, perm = stage(t, out, ipiv, perm, r, w // nb)
+        if r == 0:
+            out = t         # the first stage's square is the matrix
+        else:
+            out = _move_rect(t, out, (m, w), (0, 0), (r, r), grid)
+            if w < m:
+                out = _move_rect(t, out, (w, m - w), (0, w),
+                                 (r, r + w), grid)
+        if w < m:
+            t = _move_rect(t, (m - w, m - w), (m - w, m - w), (w, w),
+                           (0, 0), grid)
+            # the next stage starts when this one's blocks are home
+            out, t = jax.lax.optimization_barrier((out, t))
+    return out, ipiv, perm
 
 
 def _prep(A: TiledMatrix) -> Tuple[TiledMatrix, jax.Array]:
@@ -978,6 +1174,87 @@ def _lu_nb(opts: OptionsLike, tile_nb: int, shape, grid,
                      dtype=dtype) or nb_frozen
 
 
+@functools.lru_cache(maxsize=None)
+def _grid_getrf_programs(grid):
+    """`_prep` and the grid's factorization as compiled programs whose
+    results stay on `grid` (`chol._grid_potrf_programs`' pattern); jit
+    keys them by the matrix's shape, dtype and structure and by the
+    blocking. The factor program hands back the packed factor, the
+    swap targets, the swaps composed to one permutation of the padded
+    rows (the scan form composes it step by step; for the unrolled
+    forms `_compose_swaps` does, inside the program) and info."""
+    from ..parallel.sharding import constrain
+    from .info import lu_info
+
+    def prep(A):
+        return constrain(_prep(A)[1], grid)
+
+    def factor(a, nb, lookahead, tile_nb, m, n):
+        composed = []
+        lu, ipiv = _getrf_dense(a, nb, pivot=True, grid=grid,
+                                lookahead=lookahead, tile_nb=tile_nb,
+                                composed=composed)
+        perm = composed[0] if composed \
+            else _compose_swaps(ipiv, a.shape[0])
+        return constrain(lu, grid), ipiv, perm, lu_info(lu, m, n)
+
+    return jax.jit(prep), jax.jit(factor, static_argnums=(1, 2, 3, 4, 5))
+
+
+def _grid_prep(A: TiledMatrix, grid) -> Tuple[TiledMatrix, jax.Array]:
+    """`_prep` under a grid: A's storage as it is where there is
+    nothing to prepare (a general matrix that fills its tiles), else
+    one compiled program."""
+    r = A.uniform().resolve()
+    if r.mtype is MatrixType.General and r.data.shape == (r.m, r.n):
+        return r, r.data
+    return r, _grid_getrf_programs(grid)[0](A)
+
+
+def _count_lu_block_steps(blocks: str, steps: int) -> None:
+    """`steps` block steps of a scan form of the LU or its solve are
+    being dispatched under a grid: count them by how the form reaches
+    its blocks (`blocked.grid_blocks`)."""
+    if blocks == "local":
+        obs_metrics.inc("grid.lu_block_steps_local", steps)
+    else:
+        obs_metrics.inc("grid.lu_block_steps_masked", steps)
+
+
+def _count_grid_route(shape, nb: int, tile_nb: int, dtype, grid,
+                      lookahead: int) -> None:
+    """A factorization is being dispatched under `grid`: with the bus
+    on, the route its program takes on the driver span that is open,
+    and where that is the scan form the counters of `lu_scan_plan`
+    (the helpers themselves run at trace time only)."""
+    if not obs_events.enabled():
+        return
+    M, N = shape
+    at = "%dx%d" % (grid.p, grid.q)
+    nb, scan_nb = _dense_blocking(M, N, nb, True, dtype, tile_nb)
+    if not scan_nb:
+        # `_getrf_dense`'s choice for a pivoted factor under a grid
+        nt = ceil_div(min(M, N), nb)
+        obs_events.note(
+            form="pipelined" if lookahead >= 1 and nt > 1 else "unrolled",
+            nb=nb, nt=nt, grid=at, blocks="slice", stages=0)
+        return
+    plan = lu_scan_plan(N, scan_nb, grid, jnp.dtype(dtype).itemsize)
+    inc = obs_metrics.inc
+    _count_lu_block_steps(plan["blocks"], plan["steps"])
+    inc("grid.lu_update_flops", plan["update_flops"])
+    inc("grid.lu_update_flops_needed", plan["update_flops_needed"])
+    inc("grid.lu_exchange_bytes", plan["exchange_bytes"])
+    inc("grid.lu_exchange_bytes_full", plan["exchange_bytes_full"])
+    inc("grid.lu_panel_rows_live", plan["panel_rows_live"])
+    inc("grid.lu_panel_rows_factored", plan["panel_rows_factored"])
+    obs_events.note(
+        form="scan", nb=scan_nb, nt=plan["steps"], grid=at,
+        blocks=plan["blocks"], stages=plan["stages"],
+        panel="/".join(sorted({MethodLUPanel.resolve(m, scan_nb, dtype).value
+                               for m in plan["heights"]})))
+
+
 @instrument_driver("getrf")
 def getrf(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
     """Partial-pivoting LU: P A = L U (reference src/getrf.cc:327;
@@ -987,9 +1264,9 @@ def getrf(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
         return getrf_nopiv(A, opts)
     if method is MethodLU.CALU:
         return getrf_tntpiv(A, opts)
-    with obs_events.span("getrf::prep", cat="step"):
-        r, a = _prep(A)
     grid = get_option(opts, Option.Grid, None)
+    with obs_events.span("getrf::prep", cat="step"):
+        r, a = _prep(A) if grid is None else _grid_prep(A, grid)
     perm = None
     dtype_ok = MethodFactor.native_lu_dtype_ok(a.dtype)
     fmethod = get_option(opts, Option.MethodFactor, MethodFactor.Auto)
@@ -1046,6 +1323,20 @@ def getrf(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
         route = _lo_route(opts, r.nb, a.shape, a.dtype)
         obs_events.note(**route)
         lu, ipiv, perm = _getrf_carry(a, route["nb"], lo=True)
+    elif grid is not None:
+        # across a mesh the factorization, the composed permutation
+        # and info are one compiled program: dispatched eagerly every
+        # mask and slice of the steps is an array and a launch of its
+        # own, and some are whole matrices on one device
+        nb = _lu_nb(opts, r.nb, a.shape, grid, dtype=a.dtype)
+        lookahead = get_option(opts, Option.Lookahead)
+        _count_grid_route(a.shape, nb, r.nb, a.dtype, grid, lookahead)
+        with obs_events.span("getrf::grid_factor", cat="step"):
+            lu, ipiv, perm, info = _grid_getrf_programs(grid)[1](
+                a, nb, lookahead, r.nb, r.m, r.n)
+        return LUFactors(dataclasses.replace(r, data=lu,
+                                             mtype=MatrixType.General),
+                         ipiv, info, perm=perm)
     else:
         lu, ipiv = _getrf_dense(
             a, _lu_nb(opts, r.nb, a.shape, grid, dtype=a.dtype),
@@ -1096,6 +1387,31 @@ def getrf_tntpiv(A: TiledMatrix, opts: OptionsLike = None) -> LUFactors:
 
 # -- solves ---------------------------------------------------------------
 
+@functools.lru_cache(maxsize=None)
+def _grid_getrs_program(grid):
+    """`getrs` (NoTrans) on `grid` as one compiled program
+    (`chol._grid_potrs_program`'s pattern): B's rows permuted by the
+    factor's composed permutation and the two sweeps of
+    `blocked.trsm_dense`,
+    which read the two triangles where they lie in the packed factor:
+    no masked copy of it is made."""
+    from .blocked import trsm_dense
+
+    def solve(LU, perm, B):
+        rl = LU.resolve()
+        b = B.to_dense()
+        y = _permute_rows(
+            jnp.pad(b, ((0, rl.data.shape[0] - b.shape[0]), (0, 0))),
+            perm)[:rl.n]
+        tri = rl.data[:rl.n, :rl.n]
+        y = trsm_dense(tri, y, left=True, lower=True, nb=rl.nb,
+                       unit_diagonal=True, grid=grid)
+        return _store(B, trsm_dense(tri, y, left=True, lower=False,
+                                    nb=rl.nb, grid=grid))
+
+    return jax.jit(solve)
+
+
 def getrs(F: LUFactors, B: TiledMatrix, opts: OptionsLike = None,
           trans=Op.NoTrans) -> TiledMatrix:
     """Solve using getrf factors (reference src/getrs.cc:88-111:
@@ -1113,6 +1429,21 @@ def getrs(F: LUFactors, B: TiledMatrix, opts: OptionsLike = None,
         # band-convention factors (block-local swaps) need gbtrs's
         # interleaved sweeps
         return gbtrs(F, B, opts, trans=trans)
+    grid = get_option(opts, Option.Grid, None)
+    if grid is not None and trans is Op.NoTrans:
+        if obs_events.enabled():
+            from .blocked import grid_blocks, trsm_form
+            rl = F.LU.resolve()
+            if trsm_form(rl.n, rl.nb) == "scan":
+                # the two sweeps, rl.n / rl.nb block steps each
+                _count_lu_block_steps(grid_blocks(rl.n, rl.nb, grid),
+                                      2 * (rl.n // rl.nb))
+        with obs_events.span("getrs::grid_solve", cat="step"):
+            # a factor `getrf` made under the grid carries its
+            # permutation; any other's swaps are composed here
+            perm = F.perm if F.perm is not None else _compose_swaps(
+                F.pivots, F.LU.resolve().data.shape[0])
+            return _grid_getrs_program(grid)(F.LU, perm, B)
     LU = F.LU
     L = dataclasses.replace(LU, mtype=MatrixType.Triangular,
                             uplo=Uplo.Lower, diag=Diag.Unit)

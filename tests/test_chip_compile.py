@@ -26,6 +26,11 @@ from jax.sharding import SingleDeviceSharding
 V5E_HBM = 16 << 30
 
 
+#: the four described chips, for the programs of a grid (set by the
+#: fixture, which alone may describe the topology)
+_DESCRIBED = {}
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -46,6 +51,7 @@ def one_chip():
     # it, and so do these compiles (the Mosaic lowering recurses
     # without end on x64 weak-typed scalars)
     jax.config.update("jax_enable_x64", False)
+    _DESCRIBED["devices"] = topo.devices
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", was[0])
     jax.config.update("jax_enable_x64", was[1])
@@ -396,3 +402,63 @@ def test_stream_lu_programs_compile_at_the_cells_height(one_chip, program,
         batched = jax.jit(lambda b: jax.vmap(jax.lax.linalg.lu)(b)[2])
         with pytest.raises(Exception, match="vmem"):
             _compile(batched, one_chip, *shapes, kernel=False)
+
+
+#: bytes a chip may hold of the grid LU's programs at the cell's size,
+#: arguments, outputs and temporaries together (ISSUE 49: under 12 GB;
+#: compiled for the described v5e 2x2, PR 49: the factor 8.27 GB, 2.42
+#: in, 2.42 out, 3.44 of temporaries; the solve 2.42 GB, its factor)
+_GRID_LU_BYTES = {"factor": 9 << 30, "solve": 3 << 30}
+
+
+@pytest.mark.parametrize("program", ["factor", "solve"])
+def test_grid_lu_programs_compile_at_the_cells_size(one_chip, program,
+                                                    monkeypatch):
+    """The two programs of `st.gesv` under `Option.Grid` on the cell
+    `grid-gesv` (PR 49), compiled for the described v5e 2x2 at
+    n=49152, nb=512, nrhs=64: `getrf`'s staged scan form (panels by
+    `lu_panel_blocked` at 49152, 36864, 24576 and 12288 rows, the row
+    exchange, the stage moves) and `getrs`'s permutation and two
+    sweeps. Each fits a chip with room, and no value in either is more
+    of the matrix than a chip's block (the form before PR 49 asks
+    18.0 GB of the chip's 15.75 and is refused in 8 s). The routing
+    asks `jax.default_backend()`, which sees the CPU here: it is
+    steered to the chip's answer."""
+    import dataclasses
+    import re
+    import slate_tpu as st
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from slate_tpu.linalg import lu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, nb, nrhs = 49152, 512, 64
+    grid = st.make_grid(2, 2, devices=_DESCRIBED["devices"])
+    on = NamedSharding(grid.mesh, P("p", "q"))
+    a = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=on)
+    t0 = time.process_time()
+    if program == "factor":
+        lowered = lu._grid_getrf_programs(grid)[1].lower(a, nb, 1, nb, n, n)
+    else:
+        LU = dataclasses.replace(
+            st.Matrix(jnp.zeros((nb, nb), jnp.float32), mb=nb),
+            data=a, m=n, n=n)
+        B = dataclasses.replace(LU, n=nrhs, data=jax.ShapeDtypeStruct(
+            (n, nrhs), jnp.float32, sharding=on))
+        perm = jax.ShapeDtypeStruct((n,), jnp.int32,
+                                    sharding=NamedSharding(grid.mesh, P()))
+        lowered = lu._grid_getrs_program(grid).lower(LU, perm, B)
+    compiled = lowered.compile()
+    took = time.process_time() - t0
+    assert took < 400.0, took
+    ma = compiled.memory_analysis()
+    held = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print("%s: %d bytes a chip (%d of temporaries), %d of code, %.0f "
+          "processor seconds" % (program, held, ma.temp_size_in_bytes,
+                                 ma.generated_code_size_in_bytes, took))
+    assert held < _GRID_LU_BYTES[program], held
+    text = compiled.as_text()
+    assert not [m for m in re.findall(r"f32\[(\d+),(\d+)\]", text)
+                if int(m[0]) * int(m[1]) > n * n // 4]
+    if program == "factor":
+        assert "LuDecompositionBlock" not in text   # too tall for it
+        assert "f32[%d,%d]" % (2 * nb, n // 2) in text  # the exchange
