@@ -239,10 +239,14 @@ class MethodLUPanel(enum.Enum):
       * ``Pallas``: the round-3 rank-1 fused kernel (bf16 fallback /
         bench comparison point);
       * ``Blocked``: the left-looking blocked kernel
-        (lu.lu_panel_blocked) — pure XLA, a column step rewrites only
-        its own (ib + 1, m) block; the route of panels the native call
-        takes the dtype of but not the height (PR 48: 27 us a column
-        at 32768 rows where the fori kernel takes 55, PERF.md);
+        (lu.lu_panel_blocked) — a column step rewrites only its own
+        (ib + 1, m) block; the route of panels the native call takes
+        the dtype of but not the height (PR 48: 27 us a column at
+        32768 rows where the fori kernel takes 55, PERF.md). Its
+        column recurrence runs out of VMEM as one Pallas kernel a
+        block where the platform and the shape allow
+        (ops/pallas_kernels.lu_block_columns, PR 50), as an XLA loop
+        elsewhere: the shape decides, no member or option of its own;
       * ``Fori``: the masked fori_loop kernel — pure XLA, always
         correct, vmappable (the batch layer's route), and what is
         left: a width no base block divides, m < w, other dtypes.

@@ -111,26 +111,28 @@ def apply_pivots(pivots: jax.Array, B: TiledMatrix,
 _FORI_FALLBACK_SEEN: set = set()
 
 
+def _first_sighting(seen: set, key) -> bool:
+    """True once a key, and only with obs on: the one-shot is not
+    consumed while obs is off, so the user who enables obs to diagnose
+    a slow panel still sees the shape's first traced fall-back."""
+    if key in seen or not obs_events.enabled():
+        return False
+    seen.add(key)
+    return True
+
+
 def _surface_fori_fallback(m: int, w: int, dtype) -> None:
     """ISSUE 6 satellite: the fori fallback used to be silent — now
     the first panel of each (m, w, dtype) publishes an obs instant
     carrying WHY the fused kernels rejected it (dtype / height /
     width / platform, pallas_kernels.lu_panel_reject_reason), so a
     trace of a slow getrf shows the panel route and its reason."""
-    key = (m, w, str(dtype))
-    if key in _FORI_FALLBACK_SEEN:
+    if not _first_sighting(_FORI_FALLBACK_SEEN, (m, w, str(dtype))):
         return
-    from ..obs import events as obs
-    if not obs.enabled():
-        # don't consume the one-shot while obs is off: the user who
-        # enables obs to diagnose a slow panel must still see the
-        # shape's first traced fallback
-        return
-    _FORI_FALLBACK_SEEN.add(key)
     from ..ops import pallas_kernels as pk
-    obs.instant("getrf.panel_fori_fallback", cat="kernel",
-                m=m, w=w, dtype=str(dtype),
-                reason=pk.lu_panel_reject_reason(m, w, dtype))
+    obs_events.instant("getrf.panel_fori_fallback", cat="kernel",
+                       m=m, w=w, dtype=str(dtype),
+                       reason=pk.lu_panel_reject_reason(m, w, dtype))
 
 
 def _lu_panel(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -167,7 +169,7 @@ def _lu_panel(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
         lu, piv, _perm = jax.lax.linalg.lu(a)
         return lu, piv.astype(jnp.int32)
     if method is MethodLUPanel.Blocked:
-        return lu_panel_blocked(a, _blocked_ib(w))[:2]
+        return lu_panel_blocked(a, _blocked_ib(w, m, a.dtype))[:2]
     _surface_fori_fallback(m, w, a.dtype)
     return lu_panel_fori(a)
 
@@ -207,12 +209,26 @@ def lu_panel_fori(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return a, piv
 
 
-#: base-block width of `lu_panel_blocked`: the (ib, m) block one
-#: column step rewrites. Read on the chip at 16384 x 1024 (PR 42): a
-#: block costs 0.29 ms (the solve against the factored columns, the
-#: matmul under it) and a column 10.7 us whatever ib is from 16 to 64
-#: (its operations are latency-bound): 30.7 ms a panel at 16, 20.2 at
-#: 32, 15.4 at 64, 15.2 at 128, where a pass over the block is 8 MB
+#: base-block width of `lu_panel_blocked` under the XLA column loop:
+#: the (ib, m) block one column step rewrites. Read on the chip at
+#: 16384 x 1024 (PR 42): a block costs 0.29 ms (the solve against the
+#: factored columns, the matmul under it) and a column 10.7 us
+#: whatever ib is from 16 to 64 (its operations are latency-bound):
+#: 30.7 ms a panel at 16, 20.2 at 32, 15.4 at 64, 15.2 at 128, where a
+#: pass over the block is 8 MB. Where the VMEM kernel runs the column
+#: recurrence the base block is twice as wide (`_blocked_ib`). Read
+#: on the chip (PR 50, call 2; ms a panel, kernel at ib 64 / 128 /
+#: the XLA loop at 64): (9216, 1024) 6.54 / 5.24 / 11.72, (16384,
+#: 1024) 9.46 / 7.86 / 15.32, (32768, 512) 6.64 / 6.08 / 10.82,
+#: (36864, 512) 7.29 / 6.83 / 11.76, (49152, 512) 9.88 / 10.14 /
+#: 26.05, (49152, 1024) 27.3 / 21.8 / 36.9. A column step of the
+#: kernel is 2.3 us at 9216 rows, 3.1 at 16384, 4.8 at 32768 and 6.6
+#: at 49152 at ib 64 (2.5, 3.8, 6.8, 9.8 at 128: a step updates twice
+#: the sublane groups; with the NaN-proof integer search of the
+#: second round, call A: 2.3, 3.1, 5.1, 6.9 and 2.6, 3.9, 7.0,
+#: 10.2), so what is left of a block is its XLA part,
+#: 0.39 ms at (16384, 1024) and 0.81 at (49152, 512) at ib 64, 0.50
+#: and 1.28 at 128: half as many blocks win except at the tallest
 LU_BLOCKED_IB = 64
 
 
@@ -224,14 +240,111 @@ LU_BLOCKED_IB = 64
 #: 0.1263, 0.1373, 0.1220 at 32768; ib 32: 0.1284, 0.1280, 0.1668);
 #: the fori form it replaces, in blocks of 256: 0.2270 at every height.
 #: A narrower panel pays one more gather of the rest and one more
-#: update a step, a wider one reads more of a[w:] at every block
+#: update a step, a wider one reads more of a[w:] at every block.
+#: Read again with the VMEM kernel (PR 50, call 2, the same operand):
+#: nb 512 0.0839 / 0.0425 s at ib 64 and 0.0809 / 0.0419 at 128; nb
+#: 1024 0.0967 / 0.0466 and 0.0823 / 0.0413; nb 256 0.1037 / 0.0528
+#: at ib 64 (call 1): 512 stays
 LU_TALL_NB = 512
 
 
-def _blocked_ib(w: int) -> int:
-    """Widest base block of `lu_panel_blocked` that divides `w`
-    (0: none does, the caller keeps the fori kernel)."""
-    return next((ib for ib in (LU_BLOCKED_IB, 32, 16, 8) if w % ib == 0), 0)
+def _blocked_ib(w: int, m: int = 0, dtype=None) -> int:
+    """Widest base block of `lu_panel_blocked` that divides `w` (0:
+    none does, the caller keeps the fori kernel): LU_BLOCKED_IB or
+    under, and twice LU_BLOCKED_IB for an (m, w) panel of `dtype`
+    whose blocks that wide the VMEM kernel takes (`_column_kernel`):
+    a rule on the platform and the shape."""
+    ib = next((ib for ib in (LU_BLOCKED_IB, 32, 16, 8) if w % ib == 0), 0)
+    if ib == LU_BLOCKED_IB and w % (2 * ib) == 0 and m \
+            and _column_kernel(2 * ib, m, dtype) == "vmem":
+        return 2 * ib
+    return ib
+
+
+def _block_columns_xla(tb: jax.Array, j0: jax.Array, ib: int
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """The column recurrence of one base block of `lu_panel_blocked`
+    as an XLA loop: `tb` is the (ib + 1, m) block, row jj the panel's
+    column j0 + jj and the last row the row positions. Every backend
+    and shape the VMEM kernel does not take runs this; some ten device
+    operations a column, each a pass over the whole block (10.7 us a
+    column at 16384 rows, 27 at 32768, 30 at 49152, PR 42-49)."""
+    m = tb.shape[1]
+    rows = jnp.arange(m, dtype=jnp.int32)
+    sub = jnp.arange(ib + 1, dtype=jnp.int32)[:, None]
+    zero = jnp.zeros((), jnp.int32)
+
+    def column(jj, c):
+        tb, pv = c
+        jj = jnp.asarray(jj, jnp.int32)
+        j = j0 + jj
+        col = jax.lax.dynamic_slice(tb, (jj, zero), (1, m))
+        p = jnp.argmax(jnp.where(rows[None, :] >= j, jnp.abs(col),
+                                 -jnp.inf)).astype(jnp.int32)
+        at_j = jax.lax.dynamic_slice(tb, (zero, j), (ib + 1, 1))
+        at_p = jax.lax.dynamic_slice(tb, (zero, p), (ib + 1, 1))
+        # rows j <-> p of the block (p == j: at_p is at_j)
+        tb = jnp.where(rows[None, :] == j, at_p,
+                       jnp.where(rows[None, :] == p, at_j, tb))
+        pivval = at_p[jj, 0]
+        safe = jnp.where(pivval == 0, jnp.ones((), tb.dtype), pivval)
+        col = jax.lax.dynamic_slice(tb, (jj, zero), (1, m))
+        mult = jnp.where(rows[None, :] > j, col / safe, 0)
+        urow = jnp.where((sub > jj) & (sub < ib), at_p, 0)
+        tb = jnp.where((sub == jj) & (rows[None, :] > j), mult,
+                       tb - urow * mult)
+        return tb, pv.at[jj].set(p)
+
+    return jax.lax.fori_loop(0, ib, column,
+                             (tb, jnp.zeros((ib,), jnp.int32)))
+
+
+#: (ib, m, dtype) blocks whose fall-back from the VMEM kernel to the
+#: XLA loop on a TPU was already surfaced, as _FORI_FALLBACK_SEEN
+_COLUMNS_FALLBACK_SEEN: set = set()
+
+
+def _column_kernel(ib: int, m: int, dtype) -> str:
+    """Which column recurrence a base block of `lu_panel_blocked` runs,
+    by platform and shape alone: 'vmem' (the Pallas kernel that holds
+    the block in VMEM, ops/pallas_kernels.lu_block_columns) or 'xla'
+    (`_block_columns_xla`). The route notes carry it."""
+    from ..ops import pallas_kernels as pk
+    return "xla" if pk.lu_block_columns_reject_reason(ib, m, dtype) \
+        else "vmem"
+
+
+def _block_columns(tb: jax.Array, j0: jax.Array, ib: int
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """(factored block, its ib swap targets) of one base block of
+    `lu_panel_blocked`: one Pallas kernel where `_column_kernel` says
+    so, the XLA loop elsewhere. A TPU run that keeps the XLA loop
+    says why once a shape (an obs instant of `_reject`'s form)."""
+    from ..ops import pallas_kernels as pk
+    m = tb.shape[1]
+    reason = pk.lu_block_columns_reject_reason(ib, m, tb.dtype)
+    if reason is None:
+        return pk.lu_block_columns(tb, j0, ib)
+    if reason != "platform" and _first_sighting(
+            _COLUMNS_FALLBACK_SEEN, (ib, m, str(tb.dtype))):
+        pk._reject("lu_block_columns", reason, ib=ib, m=m,
+                   dtype=str(tb.dtype))
+    return _block_columns_xla(tb, j0, ib)
+
+
+def _panel_note(m: int, w: int, dtype,
+                method: Optional[MethodLUPanel] = None) -> dict:
+    """How an (m, w) panel is factored, for a route note: the panel
+    route (`method`, or what `MethodLUPanel.resolve` says), and under
+    `blocked` which column recurrence its base blocks run
+    (`panel_columns`: vmem or xla, `_column_kernel`)."""
+    if method is None:
+        method = MethodLUPanel.resolve(m, w, dtype)
+    note = {"panel": method.value}
+    if method is MethodLUPanel.Blocked:
+        note["panel_columns"] = _column_kernel(_blocked_ib(w, m, dtype), m,
+                                               dtype)
+    return note
 
 
 def lu_panel_blocked(a: jax.Array, ib: int = LU_BLOCKED_IB
@@ -251,14 +364,16 @@ def lu_panel_blocked(a: jax.Array, ib: int = LU_BLOCKED_IB
     Block i first takes what the factored columns left of it owe it
     (the solve against their unit-lower square gives its U rows and
     its updated rows inside the top w x w, the matmul the rows under
-    that), then factors itself column by column: masked argmax, the
-    two rows exchanged, the multipliers, and the rank-1 update of the
-    block's later columns as ONE elementwise pass. A device operation
-    inside the loop costs a microsecond or more however small (read
-    on the chip, PR 42: 4.4 us a column for two scalar updates of a
-    permutation vector), so the block's permutation rides in the same
-    pass as one more row of the block: the row positions, exchanged
-    with the rows. Same pivots as `lu_panel_fori` (ties aside).
+    that), then factors itself column by column (`_block_columns`):
+    masked argmax, the two rows exchanged, the multipliers, and the
+    rank-1 update of the block's later columns. On the chip that
+    recurrence is one Pallas kernel a block, the block resident in
+    VMEM (PR 50); elsewhere an XLA loop, where a device operation
+    costs a microsecond or more however small (read on the chip, PR
+    42: 4.4 us a column for two scalar updates of a permutation
+    vector), so the block's permutation rides in the same pass as one
+    more row of the block: the row positions, exchanged with the
+    rows. Same pivots as `lu_panel_fori` (ties aside).
     Returns (packed LU, local swap targets (w,), their composed
     permutation (m,))."""
     m, w = a.shape
@@ -286,30 +401,8 @@ def lu_panel_blocked(a: jax.Array, ib: int = LU_BLOCKED_IB
         else:
             blk = top
 
-        def column(jj, c):
-            tb, pv = c
-            jj = jnp.asarray(jj, jnp.int32)
-            j = j0 + jj
-            col = jax.lax.dynamic_slice(tb, (jj, zero), (1, m))
-            p = jnp.argmax(jnp.where(rows[None, :] >= j, jnp.abs(col),
-                                     -jnp.inf)).astype(jnp.int32)
-            at_j = jax.lax.dynamic_slice(tb, (zero, j), (ib + 1, 1))
-            at_p = jax.lax.dynamic_slice(tb, (zero, p), (ib + 1, 1))
-            # rows j <-> p of the block (p == j: at_p is at_j)
-            tb = jnp.where(rows[None, :] == j, at_p,
-                           jnp.where(rows[None, :] == p, at_j, tb))
-            pivval = at_p[jj, 0]
-            safe = jnp.where(pivval == 0, jnp.ones((), tb.dtype), pivval)
-            col = jax.lax.dynamic_slice(tb, (jj, zero), (1, m))
-            mult = jnp.where(rows[None, :] > j, col / safe, 0)
-            urow = jnp.where((sub > jj) & (sub < ib), at_p, 0)
-            tb = jnp.where((sub == jj) & (rows[None, :] > j), mult,
-                           tb - urow * mult)
-            return tb, pv.at[jj].set(p)
-
-        tb, pv = jax.lax.fori_loop(
-            0, ib, column, (jnp.concatenate([blk.T, positions], axis=0),
-                            jnp.zeros((ib,), jnp.int32)))
+        tb, pv = _block_columns(
+            jnp.concatenate([blk.T, positions], axis=0), j0, ib)
         # the other columns into the block's row order: only the rows
         # its swaps touched moved (twice named, a row gets one content)
         touched = jnp.concatenate([j0 + sub[:ib, 0], pv])
@@ -407,7 +500,8 @@ def _carry_panel(trail: jax.Array, w: int, method: MethodLUPanel):
         lu, piv, perm = jax.lax.linalg.lu(panel)
         return lu, piv.astype(jnp.int32), perm
     if method is MethodLUPanel.Blocked:
-        return lu_panel_blocked(panel, _blocked_ib(w))
+        return lu_panel_blocked(
+            panel, _blocked_ib(w, panel.shape[0], panel.dtype))
     # panels the native call cannot take (scoped-vmem height limit /
     # dtype) or that the tune cache routed elsewhere: _lu_panel
     # arbitrates (true partial pivoting preserved)
@@ -436,7 +530,8 @@ def _carry_panel_lo(trail: jax.Array, w: int, route: MethodLUPanel):
     if route is MethodLUPanel.Native:
         lu, piv, perm = jax.lax.linalg.lu(panel)
     elif route is MethodLUPanel.Blocked:
-        lu, piv, perm = lu_panel_blocked(panel, _blocked_ib(w))
+        lu, piv, perm = lu_panel_blocked(
+            panel, _blocked_ib(w, panel.shape[0], panel.dtype))
     else:
         lu, piv = lu_panel_fori(panel)
         perm = _compose_swaps(piv, trail.shape[0])
@@ -783,8 +878,7 @@ def _getrf_dense(a: jax.Array, nb: int, pivot: bool, grid=None,
         nb = _carry_nb(M, kmax, nb, a.dtype)
         if obs_events.enabled():
             obs_events.note(form="carry", nb=nb,
-                            panel=MethodLUPanel.resolve(
-                                M, min(nb, kmax), a.dtype).value)
+                            **_panel_note(M, min(nb, kmax), a.dtype))
         return _getrf_carry(a, nb)
     if pivot and not tournament and lookahead >= 1 and nt > 1:
         obs_events.note(form="pipelined", nb=nb)
@@ -842,8 +936,9 @@ def _lo_route(opts: OptionsLike, tile_nb: int, shape, dtype) -> dict:
     the first (tallest) panel is factored, what is stored and what an
     update multiplies."""
     nb = _lu_nb(opts, tile_nb, shape, None, dtype=dtype)
+    m, w = shape[0], min(nb, *shape)
     return dict(form="carry", nb=nb, store=str(dtype),
-                panel=_lo_panel_route(shape[0], min(nb, *shape)).value,
+                **_panel_note(m, w, jnp.float32, _lo_panel_route(m, w)),
                 panel_dtype="float32",
                 update="one pass %s x %s -> float32" % (dtype, dtype))
 
@@ -1248,11 +1343,15 @@ def _count_grid_route(shape, nb: int, tile_nb: int, dtype, grid,
     inc("grid.lu_exchange_bytes_full", plan["exchange_bytes_full"])
     inc("grid.lu_panel_rows_live", plan["panel_rows_live"])
     inc("grid.lu_panel_rows_factored", plan["panel_rows_factored"])
+    # the stages' panels, each route and column kernel named once
+    panels = {}
+    for m in plan["heights"]:
+        for key, value in _panel_note(m, scan_nb, dtype).items():
+            panels.setdefault(key, set()).add(value)
     obs_events.note(
         form="scan", nb=scan_nb, nt=plan["steps"], grid=at,
         blocks=plan["blocks"], stages=plan["stages"],
-        panel="/".join(sorted({MethodLUPanel.resolve(m, scan_nb, dtype).value
-                               for m in plan["heights"]})))
+        **{key: "/".join(sorted(values)) for key, values in panels.items()})
 
 
 @instrument_driver("getrf")

@@ -891,12 +891,10 @@ def _note_lu_route(mode: str, m: int, wf: int, incore_nb: int,
     if mode == "tournament":
         obs_events.note(lu_pivot=mode, panel="calu", nb=nb)
         return
-    from ..core.methods import MethodLUPanel
-    from .lu import _carry_nb
+    from .lu import _carry_nb, _panel_note
     nb = _carry_nb(m, wf, nb, dtype)
     obs_events.note(lu_pivot=mode, nb=nb,
-                    panel=MethodLUPanel.resolve(m, min(nb, wf),
-                                                dtype).value)
+                    **_panel_note(m, min(nb, wf), dtype))
 
 
 @instrument_driver("getrf_ooc")

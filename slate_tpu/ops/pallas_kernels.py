@@ -40,6 +40,14 @@ ARBITRATION CONTRACT: every public kernel entry point here
     gates (``pallas_available``-based) still require real TPU, so
     driver cold paths are identical on CPU.
 
+ON A MEASURED ROUTE (PR 50): ``lu_block_columns`` is the column
+recurrence of one base block of lu.lu_panel_blocked, the (ib + 1, m)
+block resident in VMEM, m along the lanes: the tall panels of the
+pivoted LU above the native LU's height in the cells `grid-gesv`,
+`stream-gesv` and `incore-gesv-mixed`. No tune entry routes it: the
+platform and the shape decide (its registry row names `lu_panel`'s op
+for the lint; nothing reads a key for it).
+
 Float32/bfloat16 only on hardware (the TPU backend has no complex
 support; scalar recurrences run in f32 because Mosaic cannot squeeze
 bf16 scalars); the interpreter additionally takes f64 where a kernel
@@ -106,6 +114,11 @@ def _reject(kernel: str, reason: str, **args) -> None:
                     reason=reason, **args)
 
 
+#: Mosaic lane tile: a DYNAMIC offset in a ref's last dimension must
+#: be provably a multiple of this
+_LANE = 128
+
+
 #: public kernel entry point -> (eligibility gate, tune-cache op).
 #: The arbitration contract (module doc): tools/check_instrumented.py
 #: statically verifies every entry that dispatches a Pallas kernel is
@@ -116,6 +129,7 @@ KERNEL_REGISTRY = {
     "qr_panel": ("qr_panel_eligible", "qr_panel"),
     "lu_panel": ("lu_panel_eligible", "lu_panel"),
     "lu_panel_rec": ("lu_panel_rec_eligible", "lu_panel"),
+    "lu_block_columns": ("lu_block_columns_reject_reason", "lu_panel"),
     "trtri_lower": ("trtri_eligible", "trtri"),
     "chol_panel": ("chol_panel_eligible", "chol_panel"),
     "givens_chain_apply": ("givens_chain_eligible", "steqr2"),
@@ -702,6 +716,206 @@ def lu_panel_rec(a: jax.Array, ib: Optional[int] = None,
     return _lu_rec_split(a, ib, _rec_max_elems(a.dtype, max_elems))
 
 
+# -- VMEM-resident column recurrence of lu.lu_panel_blocked (PR 50) ------
+
+#: bytes of VMEM the resident block may take: the tallest block that
+#: was compiled for a v5e (tests/test_chip_compile.py), 65536 lanes x
+#: 4 B x the 136 sublanes of a base block of 128, 35.7 MB of a core's
+#: 128 MiB. It is the kernel's one large buffer; the tallest a cell
+#: runs is 26.7 MB (49152 lanes at 128; 14.2 at 64). A taller block
+#: keeps the XLA loop and says so (lu._block_columns)
+LU_COLS_MAX_BYTES = 136 * 65536 * 4
+
+
+#: the bits of |x| above +inf's are NaNs: the column search's one NaN
+_NAN_BITS = 0x7f800001
+
+
+def _cols_rows(ib: int) -> int:
+    """Sublanes of the resident block: the ib columns of the base
+    block, the row positions, padded to the f32 sublane tile."""
+    return -(-(ib + 1) // 8) * 8
+
+
+def _cols_chunk(m: int) -> int:
+    """Lanes one pass of the kernel's loops takes at a time: the most
+    whole lane tiles, up to eight, that divide m (every height the
+    cells run is a multiple of 1024)."""
+    return next(_LANE * k for k in (8, 4, 2, 1) if m % (_LANE * k) == 0)
+
+
+def lu_block_columns_reject_reason(ib: int, m: int, dtype,
+                                   platform: bool = True
+                                   ) -> Optional[str]:
+    """ROUTING gate of `lu_block_columns`, by platform and shape
+    alone (no tune entry moves it): why the column recurrence of an
+    (ib + 1, m) base block will not run out of VMEM, None where it
+    will. 'platform' (no TPU; `platform=False` leaves the question
+    out, for the interpreter), 'dtype' (the block, its row positions
+    included, is f32), 'align' (m off the lane tile, ib off the
+    sublane tile) or 'height' (the block above LU_COLS_MAX_BYTES)."""
+    if platform and not _on_tpu():
+        return "platform"
+    if jnp.dtype(dtype) != jnp.float32:
+        return "dtype"
+    if ib % 8 != 0 or m % _LANE != 0:
+        return "align"
+    if _cols_rows(ib) * m * 4 > LU_COLS_MAX_BYTES:
+        return "height"
+    return None
+
+
+@functools.partial(jax.jit, static_argnames=("ib", "m", "interp"))
+def _lu_block_columns_pallas(tb: jax.Array, j0: jax.Array, ib: int,
+                             m: int, interp: bool):
+    """The ib column steps of one base block of lu.lu_panel_blocked on
+    the (R, m) block held in VMEM (R = `_cols_rows(ib)`: row jj the
+    panel's column j0 + jj, row ib the row positions, m along the
+    lanes). One DMA each way a block; a column step is the masked
+    first-index argmax over row jj, the two 128-lane tiles that hold
+    lanes j and p rewritten for the exchange, and one pass over the
+    sublane groups at or below jj's for the multipliers and the
+    rank-1 update. Every pass starts at the lane chunk that holds j:
+    the lanes left of it are finished rows. Same arithmetic as the
+    XLA `column` loop it replaces. Returns (block, swap targets
+    (ib,) int32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    R, C = _cols_rows(ib), _cols_chunk(m)
+    nchunk = m // C
+    i32 = jnp.int32
+
+    def kernel(j0_ref, tb_hbm, out_hbm, pv_ref, buf, sem):
+        load = pltpu.make_async_copy(tb_hbm, buf, sem)
+        load.start()
+        load.wait()
+        j0 = j0_ref[0]
+        lane = jax.lax.broadcasted_iota(i32, (1, C), 1)
+        lane_t = jax.lax.broadcasted_iota(i32, (R, _LANE), 1)
+        sub_r = jax.lax.broadcasted_iota(i32, (R, 1), 0)
+
+        def column(jj, _):
+            j = j0 + jj
+            first = j // C
+
+            def magnitudes(c):
+                # |x|'s bits as an integer: they order as the floats
+                # do, and a NaN stands above every number (all NaNs
+                # alike), so the search never comes back empty-handed
+                # and takes the first NaN, as jnp.argmax does on the
+                # CPU (the TPU's takes one of them, not always the
+                # first: read on the chip, PR 50). 3-5% of a column
+                # step over a float search that a NaN sends out of
+                # the block
+                c0 = pl.multiple_of(c * C, C)
+                bits = jax.lax.bitcast_convert_type(
+                    jnp.abs(buf[pl.ds(jj, 1), pl.ds(c0, C)]), i32)
+                return jnp.minimum(bits, _NAN_BITS)
+
+            def search(c, best):
+                val, chunk = best
+                mag = magnitudes(c)
+                take = mag > val
+                return jnp.where(take, mag, val), jnp.where(take, c, chunk)
+
+            # the chunk that holds j is the one with finished rows in
+            # it; a slot keeps the first chunk that brought its
+            # maximum, so the lowest index wins among equal
+            # magnitudes, as in jnp.argmax. Some lane always equals an
+            # integer maximum: p is a lane of the block
+            val, chunk = jax.lax.fori_loop(
+                first + 1, i32(nchunk), search,
+                (jnp.where(lane + first * C >= j, magnitudes(first), -1),
+                 jnp.full((1, C), first, i32)))
+            p = jnp.min(jnp.where(val == jnp.max(val), chunk * C + lane, m))
+            pv_ref[jj] = p
+            # lanes j <-> p of every row: the two tiles that hold them
+            tj = pl.multiple_of((j // _LANE) * _LANE, _LANE)
+            tp = pl.multiple_of((p // _LANE) * _LANE, _LANE)
+            at_j = jnp.sum(jnp.where(lane_t == j - tj,
+                                     buf[:, pl.ds(tj, _LANE)], 0.0),
+                           axis=1, keepdims=True)
+            tile_p = buf[:, pl.ds(tp, _LANE)]
+            at_p = jnp.sum(jnp.where(lane_t == p - tp, tile_p, 0.0),
+                           axis=1, keepdims=True)
+            buf[:, pl.ds(tp, _LANE)] = jnp.where(lane_t == p - tp, at_j,
+                                                 tile_p)
+            buf[:, pl.ds(tj, _LANE)] = jnp.where(
+                lane_t == j - tj, at_p, buf[:, pl.ds(tj, _LANE)])
+            pivval = jnp.sum(jnp.where(sub_r == jj, at_p, 0.0), axis=0,
+                             keepdims=True)
+            safe = jnp.where(pivval == 0, 1.0, pivval)
+            ucol = jnp.where((sub_r > jj) & (sub_r < ib), at_p, 0.0)
+
+            def update(c, _):
+                c0 = pl.multiple_of(c * C, C)
+                below = lane + c0 > j
+                row = buf[pl.ds(jj, 1), pl.ds(c0, C)]
+                mult = jnp.where(below, row / safe, 0.0)
+                wide = jnp.broadcast_to(mult, (8, C))
+                # the groups above jj's are finished rows; the last
+                # group (the positions, the padding) is never updated
+                for g in range(ib // 8):
+                    @pl.when(jj < 8 * g + 8)
+                    def _(g=g):
+                        buf[8 * g:8 * g + 8, pl.ds(c0, C)] = (
+                            buf[8 * g:8 * g + 8, pl.ds(c0, C)]
+                            - ucol[8 * g:8 * g + 8] * wide)
+                # row jj itself (its ucol is 0) keeps the multipliers
+                buf[pl.ds(jj, 1), pl.ds(c0, C)] = jnp.where(below, mult, row)
+                return 0
+
+            jax.lax.fori_loop(first, i32(nchunk), update, 0)
+            return 0
+
+        jax.lax.fori_loop(i32(0), i32(ib), column, 0)
+        store = pltpu.make_async_copy(buf, out_hbm, sem)
+        store.start()
+        store.wait()
+
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        kernel,
+        in_specs=[smem, any_space],
+        out_specs=(any_space, smem),
+        out_shape=(jax.ShapeDtypeStruct((R, m), jnp.float32),
+                   jax.ShapeDtypeStruct((ib,), i32)),
+        scratch_shapes=[pltpu.VMEM((R, m), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+        # the block is read once, at the top: it may back the output
+        input_output_aliases={1: 0},
+        # the block and a step's few vector registers' worth of
+        # values, no more: XLA keeps the panel the block loop carries
+        # in VMEM too (100.7 MB at (49152, 512)) and moves it out and
+        # back around a call that asks for what is left (compiled for
+        # a described v5e, PR 50: with 16 MiB of room a block of 128
+        # columns cost two such moves a block, with 2 none)
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=R * m * 4 + (2 << 20)),
+        interpret=interp,
+    )(jnp.reshape(j0, (1,)).astype(i32), tb)
+
+
+def lu_block_columns(tb: jax.Array, j0: jax.Array, ib: int):
+    """(factored block, swap targets (ib,) int32) of one base block of
+    lu.lu_panel_blocked, `tb` the (ib + 1, m) f32 block (its last row
+    the row positions) whose first column is the panel's column `j0`:
+    the column recurrence run out of VMEM (`_lu_block_columns_pallas`;
+    interpreted off the chip). The caller routes by
+    `lu_block_columns_reject_reason` and keeps its XLA loop where
+    that refuses; a shape it refuses is an error here."""
+    m = tb.shape[1]
+    reason = lu_block_columns_reject_reason(ib, m, tb.dtype, platform=False)
+    if reason is not None:
+        raise ValueError("lu_block_columns: %s (ib=%d, m=%d, %s)"
+                         % (reason, ib, m, tb.dtype))
+    pad = _cols_rows(ib) - (ib + 1)
+    out, pv = _lu_block_columns_pallas(
+        jnp.pad(tb, ((0, pad), (0, 0))), j0, ib, m, pallas_interpret())
+    return out[:ib + 1], pv
+
+
 # -- blocked Givens-chain apply (steqr2/bdsqr bulge chase) ---------------
 
 #: rotation-group width b: factors are (2b, 2b) windows on b-spaced
@@ -1151,11 +1365,6 @@ def _ragged_donate_ok() -> bool:
     the top of its grid step), so a donated stack factors in place —
     the bucket path's donation contract carried to the ragged route."""
     return jax.default_backend() != "cpu"
-
-
-#: Mosaic lane tile: a DYNAMIC offset in a ref's last dimension must
-#: be provably a multiple of this
-_LANE = 128
 
 
 def _lane_up(n: int) -> int:

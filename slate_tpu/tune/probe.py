@@ -222,7 +222,8 @@ def probe_lu_panel(m: int, w: int, dtype, reps: int = 3) -> List[Dict]:
             blocked = jax.jit(lu_panel_blocked, static_argnums=1)
             out.append({"method": "blocked",
                         "seconds": measure(
-                            lambda: blocked(p, _blocked_ib(w))[0],
+                            lambda: blocked(
+                                p, _blocked_ib(w, m, p.dtype))[0],
                             reps=reps)})
         for label, fn in (("pallas", pk.lu_panel),
                           ("pallas_rec", pk.lu_panel_rec)):

@@ -78,6 +78,16 @@ def _compile(fn, dev, *shapes, kernel=True, limit_s=60.0, **static):
     return compiled
 
 
+def _as_on_the_chip(monkeypatch):
+    """The routing asks `jax.default_backend()` and the kernels' gates
+    `pallas_kernels._on_tpu()`, which both see the CPU here: steer
+    them to the chip's answers, so that the program compiled is the
+    one the chip runs."""
+    from slate_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+
+
 #: the serve phase of chip_smoke.py drives these shapes
 _B, _N, _K = 8, 1024, 4
 
@@ -144,6 +154,56 @@ def test_lu_panel_rec_compiles_at_its_gate(one_chip, m, w):
     assert pk._rec_shape_reason(m, w, jnp.float32) is None
     _compile(pk._lu_panel_rec_pallas, one_chip, ((m, w), jnp.float32),
              m=m, w=w, ib=pk.LU_REC_IB, interp=False)
+
+
+@pytest.mark.parametrize("ib", [128, 64])
+@pytest.mark.parametrize("m", [65536, 49152, 36864, 32768, 16384, 9216])
+def test_lu_block_columns_compiles_at_the_cells_heights(one_chip, m, ib,
+                                                        monkeypatch):
+    """The VMEM-resident column recurrence of `lu.lu_panel_blocked`
+    (PR 50) at the block heights the cells run: `grid-gesv`'s first
+    two stages, `stream-gesv`'s two tall rungs, `incore-gesv-mixed`'s
+    first and last tall panel; at the base block the route picks on
+    the chip (128: a (136, m) buffer, 26.7 MB at the tallest) and at
+    the XLA loop's 64 (72 sublanes, 14.2 MB). Either is over the
+    scoped default, so the kernel asks for its own limit. And at the
+    gate itself: 65536 rows at 128 is `LU_COLS_MAX_BYTES`, the
+    tallest block the route hands the kernel (`grid-gesv`'s source
+    size before its one reduction). Seconds each (2.6 s the tallest,
+    sandbox, PR 50)."""
+    from slate_tpu.linalg import lu
+    from slate_tpu.ops import pallas_kernels as pk
+    _as_on_the_chip(monkeypatch)
+    assert pk._cols_rows(ib) * m * 4 <= pk.LU_COLS_MAX_BYTES
+    assert lu._column_kernel(128, 2 * 65536, jnp.float32) == "xla"
+    assert lu._blocked_ib(512, m, jnp.float32) == 128
+    assert lu._column_kernel(ib, m, jnp.float32) == "vmem"
+    _compile(pk._lu_block_columns_pallas, one_chip,
+             ((pk._cols_rows(ib), m), jnp.float32), ((), jnp.int32),
+             ib=ib, m=m, interp=False)
+
+
+@pytest.mark.parametrize("m", [49152, 65536])
+def test_blocked_panel_is_not_moved_around_the_column_kernel(one_chip, m,
+                                                            monkeypatch):
+    """`grid-gesv`'s tallest panel, (49152, 512) at the base block of
+    128: the compiler keeps the panel the block loop carries in VMEM
+    (100.7 MB of 128 MiB) and the column kernel asks for its block
+    beside it (26.7 MB). With more room than it needs (16 MiB, PR 50's
+    first form) the panel was moved out and back at every block, two
+    100 MB copies: the only whole-panel moves are the program's first
+    and last. At the gate's 65536 rows the panel (134 MB) is no longer
+    kept in VMEM and the program still compiles around the 35.7 MB
+    block (2.2 s, no whole-panel move, sandbox, PR 50)."""
+    from slate_tpu.linalg import lu
+    _as_on_the_chip(monkeypatch)
+    w = 512
+    ib = lu._blocked_ib(w, m, jnp.float32)
+    text = _compile(jax.jit(lambda a: lu.lu_panel_blocked(a, ib)), one_chip,
+                    ((m, w), jnp.float32)).as_text()
+    moves = [line for line in text.splitlines()
+             if " copy-start(" in line and "= (f32[%d,%d]" % (m, w) in line]
+    assert len(moves) <= 2, len(moves)
 
 
 def test_givens_apply_compiles(one_chip):
@@ -291,7 +351,8 @@ def test_svd_programs_compile_at_the_cells_size(one_chip, program):
 
 
 @pytest.mark.parametrize("program", ["panel", "solve0", "sweeps", "update"])
-def test_mixed_programs_compile_at_the_cells_size(one_chip, program):
+def test_mixed_programs_compile_at_the_cells_size(one_chip, program,
+                                                  monkeypatch):
     """The programs PR 42 adds for `incore-gesv-mixed`, n=16384 with
     a bf16 factor in panels of 1024: the tall panel by
     `lu_panel_blocked` (XLA's LU refuses 16384 rows), the first lo
@@ -299,7 +360,9 @@ def test_mixed_programs_compile_at_the_cells_size(one_chip, program):
     factor kept this compiler over five minutes for one of them;
     `refine.tri_sweep` is O(1) in n), the first step's one-pass
     update. Each in seconds, its temporaries a small part of a chip."""
+    from slate_tpu.core.methods import MethodLUPanel
     from slate_tpu.linalg import lu, refine
+    _as_on_the_chip(monkeypatch)
     n, nb = 16384, 1024
     bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
     fac = ((n, n), bf), ((n,), i32)
@@ -314,14 +377,14 @@ def test_mixed_programs_compile_at_the_cells_size(one_chip, program):
 
     fn, shapes, static = {
         "panel": (lu._carry_panel_lo, [((n, n), bf)],
-                  {"w": nb, "route": "blocked"}),
+                  {"w": nb, "route": MethodLUPanel.Blocked}),
         "solve0": (jax.jit(solve0), [*fac, ((n, 1), f32)], {}),
         "sweeps": (jax.jit(sweeps), [*fac, ((n, n), f32), ((n, 1), f32),
                                      ((n, 1), f32)], {}),
         "update": (lu._carry_update, [((n, nb), bf), ((n, n - nb), bf)], {}),
     }[program]
-    compiled = _compile(fn, one_chip, *shapes, kernel=False, limit_s=60.0,
-                        **static)
+    compiled = _compile(fn, one_chip, *shapes, kernel=program == "panel",
+                        limit_s=60.0, **static)
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes < 1 << 30, ma.temp_size_in_bytes
     if program == "update":
@@ -332,7 +395,9 @@ def test_mixed_programs_compile_at_the_cells_size(one_chip, program):
 
 #: `_lu_panel_factor`'s temporaries at the cell's (32768, 4096) panel,
 #: by rung (sandbox, compiled for a described v5e, PR 48: 926, 451
-#: and 165 MB; the fori form it replaced held 0.83 GB at every k)
+#: and 165 MB; the fori form it replaced held 0.83 GB at every k;
+#: PR 50, with the column kernel and its base block of 128: 977, 449
+#: and 165 MB, under the same limits)
 _RUNG_TEMPS = {32768: 1_100 << 20, 16384: 500 << 20, 8192: 200 << 20}
 
 
@@ -342,13 +407,13 @@ def test_stream_lu_panel_compiles_at_each_rung(one_chip, height,
     """The partial stream's panel factor of `stream-gesv` at its whole
     (32768, 4096) panel, one program a rung of `ooc._lu_panel_height`'s
     ladder (PR 48): above the native LU's height the carry form with
-    `lu_panel_blocked` panels (XLA's LU refuses those rows), at 8192
-    rows the native LU. The routing asks `jax.default_backend()`,
-    which sees the CPU here: it is steered to the chip's answer. Each
-    compiles in a quarter of a minute of wall (66-181 processor
+    `lu_panel_blocked` panels (XLA's LU refuses those rows; since PR
+    50 their column recurrence is the Mosaic call), at 8192 rows the
+    native LU. The routing asks the backend, which is the CPU here:
+    `_as_on_the_chip`. Each compiles in a quarter of a minute of wall (66-181 processor
     seconds), and its temporaries are held to what was read."""
     from slate_tpu.linalg import ooc
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _as_on_the_chip(monkeypatch)
     n, w = 32768, 4096
     assert ooc._lu_panel_height(n, height, jnp.float32) == height
     assert ooc._lu_panel_height(n, height + 1, jnp.float32) == \
@@ -362,7 +427,9 @@ def test_stream_lu_panel_compiles_at_each_rung(one_chip, height,
     assert ma.temp_size_in_bytes < _RUNG_TEMPS[height], \
         ma.temp_size_in_bytes
     assert ma.output_size_in_bytes < n * w * 4 + (1 << 20)
-    assert ("LuDecompositionBlock" in compiled.as_text()) == (height == 8192)
+    text = compiled.as_text()
+    assert ("LuDecompositionBlock" in text) == (height == 8192)
+    assert ("tpu_custom_call" in text) == (height > 8192)
 
 
 @pytest.mark.parametrize("program", ["chunks", "rows", "col"])
@@ -390,7 +457,13 @@ def test_stream_lu_programs_compile_at_the_cells_height(one_chip, program,
         "col": (ooc._lu_col, [((n, 4 * w), f32), ((n, 4 * w), f32),
                               ((), i32)], {}),
     }[program]
-    compiled = _compile(fn, one_chip, *shapes, kernel=False, limit_s=60.0,
+    # the chunk nomination's four native LUs compile on several
+    # threads at once: 10-12 s of wall and 51.7-61.4 processor seconds
+    # alone (sandbox, PR 50: 61.4 and 57.4 on PR 49's tree, 51.7, 57.0
+    # and 61.3 on PR 50's, the same program), so a limit of 60 failed
+    # by the draw; twice the reading still fails a slow compile
+    compiled = _compile(fn, one_chip, *shapes, kernel=False,
+                        limit_s=120.0 if program == "chunks" else 60.0,
                         **static)
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes < 1 << 30, ma.temp_size_in_bytes
@@ -429,7 +502,7 @@ def test_grid_lu_programs_compile_at_the_cells_size(one_chip, program,
     import slate_tpu as st
     from jax.sharding import NamedSharding, PartitionSpec as P
     from slate_tpu.linalg import lu
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _as_on_the_chip(monkeypatch)
     n, nb, nrhs = 49152, 512, 64
     grid = st.make_grid(2, 2, devices=_DESCRIBED["devices"])
     on = NamedSharding(grid.mesh, P("p", "q"))
@@ -461,4 +534,5 @@ def test_grid_lu_programs_compile_at_the_cells_size(one_chip, program,
                 if int(m[0]) * int(m[1]) > n * n // 4]
     if program == "factor":
         assert "LuDecompositionBlock" not in text   # too tall for it
+        assert "tpu_custom_call" in text    # the panels' column kernel
         assert "f32[%d,%d]" % (2 * nb, n // 2) in text  # the exchange
